@@ -1,0 +1,649 @@
+#!/usr/bin/env python3
+"""The quickest proof that paddle_tpu still starts on the chip.
+
+    python chip_smoke.py                 # needs a TPU; exits 2 without one
+    python chip_smoke.py --rehearse-cpu  # CPU dry run, never a pass
+
+One process drives the main path once at the full width of GPT-base
+(12 layers, hidden 768, 12 heads of 64, FFN 3,072, vocab 32,000, 2,048
+positions), the one model this repository both trains and serves, with
+seeded random weights:
+
+  kernels  both Pallas kernels compiled (never interpreted) and compared
+           with their in-file oracles
+  train    gpt_pretrain at 8 x 2,048 + Adam + bf16 AMP through
+           Executor.run and Executor.run_steps
+  serve    GPTGenerator -> InferenceServer(kv_paged=True) -> six
+           concurrent Client.generate calls over the loopback socket,
+           then one logits check of paged decode against a full
+           recompute
+  mesh     only with four or more devices: the train step under
+           with_data_parallel over every chip, and tp=2 paged generation
+
+Every phase prints one JSON line (platform, device kind and count, jax,
+jaxlib and libtpu versions, compile seconds, persistent-cache hits, the
+implementation each attention op resolved to). Every timing is a smoke
+timing of one cold pass, not a benchmark number. The last line of stdout
+is ``{"ok": true, "device": {...}}`` and the exit code is 0 only if every
+phase passed.
+
+It sets no JAX platform and falls back to nothing: where JAX finds no
+TPU it names the platform it found on stderr, prints no result and exits
+2. ``--rehearse-cpu`` is the exception made explicit: GPTConfig.tiny(),
+kernels through the Pallas interpreter, every line marked a rehearsal.
+"""
+import argparse
+import gc
+import importlib.metadata
+import json
+import math
+import re
+import sys
+import threading
+import time
+import traceback
+
+import numpy as np
+
+
+class Sizes:
+    """What one pass runs at. ``full`` is the contract; ``rehearsal`` is
+    the same code at GPTConfig.tiny() for the CPU."""
+
+    def __init__(self, rehearsal):
+        from paddle_tpu.models import gpt
+        self.rehearsal = rehearsal
+        if rehearsal:
+            self.cfg = gpt.GPTConfig.tiny()
+            self.batch, self.seq = 4, 64
+            self.flash = (4, 2, 64, 16)
+            self.paged_rows, self.paged_positions = 2, 64
+            self.paged_pos = (0, 37)
+            self.prompt_lens = (3, 5, 9, 12, 17, 20)
+            self.new_tokens = 4
+            self.check_prompt, self.check_steps = 9, 2
+            self.kernel_impl = "interpret"
+        else:
+            self.cfg = gpt.GPTConfig.base()
+            self.batch, self.seq = 8, 2048
+            self.flash = (8, 12, 2048, 64)
+            self.paged_rows, self.paged_positions = 8, 2048
+            # first slot, block edges either side, mid-cache, last slot
+            self.paged_pos = (0, 15, 16, 100, 777, 1000, 1500, 2047)
+            self.prompt_lens = (17, 100, 300, 700, 1100, 1500)
+            self.new_tokens = 32
+            self.check_prompt, self.check_steps = 100, 3
+            self.kernel_impl = "pallas"
+        self.max_len = self.cfg.max_position
+
+
+class Smoke:
+    """Shared state of one pass: the device, the versions every line
+    carries, the compile-cache handle, and the per-phase results."""
+
+    def __init__(self, sizes, cache):
+        import jax
+        import jaxlib
+        self.sizes = sizes
+        self.cache = cache
+        dev = jax.devices()[0]
+        self.device = {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": len(jax.devices())}
+        try:
+            libtpu = importlib.metadata.version("libtpu")
+        except importlib.metadata.PackageNotFoundError:
+            libtpu = None
+        self.versions = {"jax": jax.__version__,
+                         "jaxlib": jaxlib.__version__, "libtpu": libtpu}
+        self.results = {}
+
+    def run_phase(self, name, fn):
+        from paddle_tpu.kernels import _dispatch
+        cache = self.cache
+        hits, misses, compile_s = (cache.hits, cache.misses,
+                                   cache.compile_seconds)
+        resolved = _dispatch.resolved_counts()
+        t0 = time.perf_counter()
+        detail, error = {}, None
+        try:
+            detail = fn(self) or {}
+        except Exception as exc:  # noqa: BLE001 — a phase boundary
+            traceback.print_exc(file=sys.stderr)
+            error = f"{type(exc).__name__}: {exc}"[:2000]
+        impl = {"/".join(k): n - resolved.get(k, 0)
+                for k, n in sorted(_dispatch.resolved_counts().items())
+                if n - resolved.get(k, 0)}
+        if error:
+            status = "fail"
+        else:
+            status = "rehearsed" if self.sizes.rehearsal else "pass"
+        line = {
+            "phase": name, "status": status,
+            "rehearsal_not_a_chip_run": self.sizes.rehearsal,
+            "platform": self.device["platform"],
+            "device_kind": self.device["kind"],
+            "device_count": self.device["count"],
+            **self.versions,
+            "compile_s": round(cache.compile_seconds - compile_s, 2),
+            "persistent_cache_hits": cache.hits - hits,
+            "persistent_cache_misses": cache.misses - misses,
+            "attention_impl": impl,
+            "smoke_wall_s": round(time.perf_counter() - t0, 2),
+            **detail,
+        }
+        if error:
+            line["error"] = error
+        print(json.dumps(line), flush=True)
+        self.results[name] = status
+        gc.collect()
+
+
+def _max_err(a, b):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)))
+
+
+def _peak_bytes():
+    import jax
+    stats = jax.devices()[0].memory_stats()
+    return None if not stats else stats.get("peak_bytes_in_use")
+
+
+# ------------------------------------------------------------------ kernels
+
+def phase_kernels(smoke):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.flags import flag
+    from paddle_tpu.kernels.flash_attention import (_xla_attention,
+                                                    flash_attention)
+    from paddle_tpu.kernels.paged_attention import (_xla_paged_attention,
+                                                    paged_attention,
+                                                    quantize_kv)
+    from paddle_tpu.serving.kvpool import _DTYPES
+
+    sz = smoke.sizes
+    impl = sz.kernel_impl
+    out = {"smoke_timings_s": {}, "max_abs_err": {}}
+
+    # ---- flash: forward and backward, causal, bf16
+    B, H, S, D = sz.flash
+    kq, kk, kv, kc = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v = (jax.random.normal(x, (B, H, S, D), jnp.bfloat16)
+               for x in (kq, kk, kv))
+    cot = jax.random.normal(kc, (B, H, S, D), jnp.float32)
+    scale = float(D) ** -0.5
+
+    def kern_loss(q, k, v, cot):
+        o = flash_attention(q, k, v, causal=True, impl=impl)
+        return jnp.sum(o.astype(jnp.float32) * cot)
+
+    def ref_fwd(q, k, v):
+        return _xla_attention(q, k, v, None, scale, True)
+
+    def ref_loss(q, k, v, cot):
+        return jnp.sum(ref_fwd(q, k, v) * cot)
+
+    fwd = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl=impl)).lower(q, k, v).compile()
+    bwd = jax.jit(jax.grad(kern_loss, argnums=(0, 1, 2))).lower(
+        q, k, v, cot).compile()
+    t0 = time.perf_counter()
+    o = jax.block_until_ready(fwd(q, k, v))
+    out["smoke_timings_s"]["flash_fwd"] = round(time.perf_counter() - t0, 4)
+    t0 = time.perf_counter()
+    grads = jax.block_until_ready(bwd(q, k, v, cot))
+    out["smoke_timings_s"]["flash_fwd_bwd"] = round(
+        time.perf_counter() - t0, 4)
+
+    # the oracle materialises [rows, H, S, S] fp32 scores: two batch rows
+    # of it (rows are independent) keep it inside one chip's memory
+    n = min(2, B)
+    q32, k32, v32 = (x[:n].astype(jnp.float32) for x in (q, k, v))
+    with jax.default_matmul_precision("highest"):
+        ref_o = jax.jit(ref_fwd)(q32, k32, v32)
+        ref_g = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(
+            q32, k32, v32, cot[:n])
+    # bf16 carries 8 mantissa bits and the kernel rounds the scaled q,
+    # the probabilities and the output to it while the fp32 oracle
+    # rounds nothing: 5e-2 is the bound this repository's own bf16
+    # kernel test uses (tests/test_flash_attention.py,
+    # test_bf16_single_block_path). A wrong mask or block moves outputs
+    # by O(1).
+    np.testing.assert_allclose(np.asarray(o[:n], np.float32),
+                               np.asarray(ref_o), rtol=5e-2, atol=5e-2)
+    out["max_abs_err"]["flash_fwd"] = _max_err(o[:n], ref_o)
+    for name, g, r in zip(("dq", "dk", "dv"), grads, ref_g):
+        err = _max_err(g[:n], r)
+        top = float(np.max(np.abs(np.asarray(r))))
+        out["max_abs_err"][f"flash_{name}"] = err
+        # same reason; gradients span orders of magnitude across
+        # positions, so the bound is taken against the largest entry
+        assert err <= 5e-2 * top, (name, err, top)
+    assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
+               for g in grads)
+
+    # ---- paged decode, every dtype FLAGS_kv_cache_dtype accepts
+    bs = int(flag("kv_block_size"))
+    rows, positions = sz.paged_rows, sz.paged_positions
+    nblk = positions // bs
+    N = rows * nblk + 1
+    rng = np.random.default_rng(0)
+    qd = jnp.asarray(rng.normal(size=(rows, H, 1, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.float32)
+    tables = jnp.asarray(
+        rng.permutation(np.arange(1, N)).reshape(rows, nblk), jnp.int32)
+    pos = jnp.asarray(sz.paged_pos, jnp.int32)
+    out["paged_block_size"] = bs
+    for kv_dtype in _DTYPES:
+        if kv_dtype == "int8":
+            (pk, ks), (pv, vs) = quantize_kv(kp), quantize_kv(vp)
+        else:
+            dt = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
+            pk, pv, ks, vs = kp.astype(dt), vp.astype(dt), None, None
+        args = (qd, pk, pv, tables, pos, ks, vs)
+        fn = jax.jit(lambda *a: paged_attention(*a, impl=impl)).lower(
+            *args).compile()
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(fn(*args))
+        out["smoke_timings_s"][f"paged_{kv_dtype}"] = round(
+            time.perf_counter() - t0, 4)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a: _xla_paged_attention(*a, scale))(*args)
+        # kernel and oracle read the same stored values and both
+        # accumulate in fp32, so only the multiply differs: the kernel's
+        # dot_generals ask for no precision, which lets the MXU take
+        # fp32 operands as bf16 passes (8 mantissa bits). Scores are
+        # O(1) and outputs are convex combinations of values of
+        # magnitude <= ~4, so rounding moves an output by at most a few
+        # 1e-2; a wrong block, mask or scale moves it by O(1).
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=0, atol=5e-2)
+        out["max_abs_err"][f"paged_{kv_dtype}"] = _max_err(got, ref)
+    return out
+
+
+# -------------------------------------------------------------------- train
+
+def _build_train(sz):
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.models import gpt
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        out = gpt.gpt_pretrain(sz.cfg, sz.batch, sz.seq)
+        opt = fluid.optimizer.AdamOptimizer(1e-4)
+        opt = mp.decorate(opt, init_loss_scaling=1.0,
+                          use_dynamic_loss_scaling=False)
+        opt.minimize(out["loss"])
+    feed = gpt.random_batch(sz.cfg, sz.batch, sz.seq,
+                            rng=np.random.default_rng(0))
+    return main, startup, out["loss"].name, feed
+
+
+def _check_losses(sz, losses):
+    first = math.log(sz.cfg.vocab_size)
+    assert all(np.isfinite(losses)), losses
+    # random weights predict a uniform next token: ln(vocab)
+    assert abs(losses[0] - first) < 0.5, (losses[0], first)
+    assert losses[-1] < losses[0], losses
+
+
+def phase_train(smoke):
+    import jax
+    import paddle_tpu as fluid
+    sz = smoke.sizes
+    main, startup, loss_name, feed = _build_train(sz)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    losses, step_s = [], []
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        compiles = None
+        for _ in range(3):
+            t0 = time.perf_counter()
+            loss, = exe.run(main, feed=feed, fetch_list=[loss_name],
+                            return_numpy=False)
+            jax.block_until_ready(loss)
+            step_s.append(round(time.perf_counter() - t0, 3))
+            losses.append(float(np.asarray(loss).reshape(())))
+            if compiles is None:
+                compiles = exe.cache_stats()["compiles"]
+        assert exe.cache_stats()["compiles"] == compiles, \
+            "Executor.run recompiled after its first step"
+        slab = {n: np.stack([a] * 4) for n, a in feed.items()}
+        slab_s = []
+        for i in range(2):
+            t0 = time.perf_counter()
+            out, = exe.run_steps(main, feed=slab, fetch_list=[loss_name],
+                                 return_numpy=False)
+            jax.block_until_ready(out)
+            slab_s.append(round(time.perf_counter() - t0, 3))
+            losses += [float(x) for x in np.asarray(out).reshape(-1)]
+            if i == 0:
+                compiles = exe.cache_stats()["compiles"]
+        assert exe.cache_stats()["compiles"] == compiles, \
+            "Executor.run_steps recompiled on its second slab"
+        stats = exe.cache_stats()
+    _check_losses(sz, losses)
+    return {"losses": [round(x, 4) for x in losses],
+            "executor_compiles": stats["compiles"],
+            "executor_trace_s": round(stats["trace_ms"] / 1e3, 2),
+            "executor_compile_s": round(stats["compile_ms"] / 1e3, 2),
+            "smoke_timings_s": {"run_steps_incl_first": step_s,
+                                "run_steps_k4_slabs_incl_first": slab_s},
+            "peak_bytes_in_use": _peak_bytes()}
+
+
+# -------------------------------------------------------------------- serve
+
+def _startup_scope(cfg):
+    """Startup-initialised GPT parameters in a fresh scope."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import gpt
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.gpt_logits(cfg)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor().run(startup)
+    return scope
+
+
+def _logits_check(sz, gen):
+    """Next-token logits after prefill plus a few paged decode steps
+    against a full recompute of the same prefix. Logits, not tokens:
+    with random weights and reduced-precision fp32 matmuls an argmax can
+    flip on rounding with nothing wrong."""
+    import jax
+    from paddle_tpu.serving.kvpool import KVBlockPool
+    cfg = sz.cfg
+    rng = np.random.default_rng(1)
+    seq = list(rng.integers(1, cfg.vocab_size, sz.check_prompt))
+    key = jax.random.PRNGKey(0)
+    pool = KVBlockPool(slots=1, num_layers=cfg.num_layers,
+                       num_heads=cfg.num_heads,
+                       d_head=cfg.hidden_size // cfg.num_heads,
+                       max_seq_len=gen.max_len, name="smoke_check")
+
+    def recompute(seq):
+        t, p, last = gen._pack_prompts([np.asarray(seq, np.int32)])
+        logits, _ = gen._run_logits(t, p, last, key)
+        return np.asarray(logits)[0]
+
+    diffs, agree = [], []
+    pool.alloc(0, len(seq))
+    tokens, pos_ids, last = gen._pack_prompts([np.asarray(seq, np.int32)])
+    logits, row_caches, _ = gen._run_prefill(tokens, pos_ids, last, key)
+    pool.scatter_prefill([0], row_caches, tokens.shape[1])
+    got = np.asarray(logits)[0]
+    for step in range(sz.check_steps + 1):
+        ref = recompute(seq)
+        diffs.append(_max_err(got, ref))
+        agree.append(bool(np.argmax(got) == np.argmax(ref)))
+        assert np.all(np.isfinite(got))
+        # fp32 weights, but fp32 matmuls on a TPU default to bf16
+        # passes, and the cached path and the recompute contract in
+        # different shapes and orders through every layer. Logits of a
+        # startup-initialised GPT have a standard deviation of about
+        # 0.5 (unit-variance hidden state against N(0, 0.02) tied
+        # embeddings), so 5e-2 is a tenth of one: rounding stays under
+        # it, a stale, misplaced or masked-out key does not.
+        assert diffs[-1] <= 5e-2, (step, diffs)
+        if step == sz.check_steps:
+            break
+        tok = int(np.argmax(ref))
+        pos = len(seq)
+        seq.append(tok)
+        pool.ensure(0, pos)
+        logits, _ = gen._run_decode_paged(
+            np.asarray([tok], np.int32), np.asarray([pos], np.int32),
+            pool, key)
+        got = np.asarray(logits)[0]
+    pool.free_slot(0)
+    return {"max_abs_diff_per_step": [round(d, 6) for d in diffs],
+            "top1_agrees_per_step": agree,
+            "logits_std": round(float(np.std(ref)), 4)}
+
+
+def phase_serve(smoke):
+    from paddle_tpu.models.generation import GPTGenerator
+    from paddle_tpu.serving import Client, InferenceServer
+    sz = smoke.sizes
+    cfg = sz.cfg
+    scope = _startup_scope(cfg)
+    gen = GPTGenerator(cfg, scope, max_len=sz.max_len)
+    # the loop watchdog "must exceed the worst-case first-shape compile"
+    # (flags.py): a cold GPT-base prefill or decode compile is not a hung
+    # chip call, and nothing here relies on a warmup having run
+    server = InferenceServer(generator=gen, kv_paged=True, decode_slots=8,
+                             loop_watchdog_s=600.0).start()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in sz.prompt_lens]
+    replies, errors, walls = {}, {}, {}
+
+    def call(i):
+        t0 = time.perf_counter()
+        try:
+            with Client(server.endpoint) as client:
+                replies[i] = client.generate(
+                    prompts[i], max_new_tokens=sz.new_tokens)
+        except Exception as exc:  # noqa: BLE001 — reported below
+            errors[i] = f"{type(exc).__name__}: {exc}"[:500]
+        walls[i] = round(time.perf_counter() - t0, 2)
+
+    try:
+        threads = [threading.Thread(target=call, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not errors, f"error replies: {errors}"
+        for i, n in enumerate(sz.prompt_lens):
+            toks = replies[i]
+            assert toks.dtype == np.int32 and toks.shape == (
+                sz.new_tokens,), (n, toks.shape)
+            assert np.all((toks >= 0) & (toks < cfg.vocab_size)), n
+        with Client(server.endpoint) as client:
+            health = client.health()
+        # a dying decode loop turns into "degraded" under the
+        # LoopSupervisor while ping keeps answering: ask
+        assert health["state"] == "serving", health
+        assert health["breaker"] == "closed", health
+        assert all(loop["alive"] and loop["restarts"] == 0
+                   for loop in health["loops"].values()), health
+        pool = server.gen_engine.pool
+        leaked = pool.blocks_in_use()
+        assert leaked == 0, pool.stats()
+        stats = server.stats()
+    finally:
+        stopper = threading.Thread(target=server.stop)
+        stopper.start()
+        stopper.join(timeout=120)
+        stopped = not stopper.is_alive()
+    assert stopped, "server.stop() did not return within 120 s"
+    out = {"prompt_lens": list(sz.prompt_lens),
+           "new_tokens": sz.new_tokens,
+           "replies": len(replies), "error_replies": len(errors),
+           "health_state": health["state"], "breaker": health["breaker"],
+           "blocks_in_use_after": leaked,
+           "generator_compiles": int(stats.get("compiles", 0)),
+           "decode_steps": int(stats.get("decode_steps", 0)),
+           "kv_cache_dtype": pool.dtype,
+           "smoke_timings_s": {"request_walls_incl_compile": [
+               walls[i] for i in range(len(prompts))]}}
+    out["logits_check"] = _logits_check(sz, gen)
+    out["peak_bytes_in_use"] = _peak_bytes()
+    return out
+
+
+# --------------------------------------------------------------------- mesh
+
+def _assert_per_shard(texts, global_shape, what, expect_kernel):
+    """The compiled per-device HLO must never hold the GLOBAL shape of
+    an attention operand: were a Pallas custom call fed by an
+    all-gather (of the batch, or of the head-sharded pool), that shape
+    is what the all-gather would produce."""
+    pat = re.compile(r"\[" + ",".join(str(d) for d in global_shape) + r"\]")
+    calls = 0
+    for text in texts:
+        assert not pat.search(text), \
+            f"{what}: global shape {global_shape} in the per-device HLO"
+        calls += text.count('custom_call_target="tpu_custom_call"')
+    if expect_kernel:
+        assert calls > 0, f"{what}: no Pallas custom call in the HLO"
+    return calls
+
+
+def phase_mesh(smoke):
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu.flags import flag
+    from paddle_tpu.models.generation import GPTGenerator
+    from paddle_tpu.parallel.mesh import default_mesh, set_mesh
+    sz = smoke.sizes
+    cfg = sz.cfg
+    on_chip = not sz.rehearsal
+    ndev = len(jax.devices())
+    out = {"smoke_timings_s": {}}
+
+    def in_use():
+        stats = [d.memory_stats() for d in jax.devices()]
+        return [m["bytes_in_use"] for m in stats] if all(stats) else None
+
+    # ---- data-parallel train step over every chip
+    main, startup, loss_name, feed = _build_train(sz)
+    mesh = default_mesh()
+    before = in_use()       # chip 0 still holds what earlier phases left
+    exe, scope = fluid.Executor(), fluid.Scope()
+    losses = []
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        compiled = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss_name, mesh=mesh)
+        for _ in range(3):
+            t0 = time.perf_counter()
+            loss, = exe.run(compiled, feed=feed, fetch_list=[loss_name],
+                            return_numpy=False)
+            jax.block_until_ready(loss)
+            losses.append(float(np.asarray(loss).reshape(())))
+        out["smoke_timings_s"]["dp_step"] = round(
+            time.perf_counter() - t0, 3)
+        texts = [v[0].as_text() for k, v in exe._cache.items()
+                 if k[0] == main._uid]
+    _check_losses(sz, losses)
+    heads, d_head = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    out["dp_devices"] = ndev
+    out["dp_losses"] = [round(x, 4) for x in losses]
+    out["dp_attention_custom_calls"] = _assert_per_shard(
+        texts, (sz.batch, heads, sz.seq, d_head), "dp train step", on_chip)
+    out["dp_all_gathers"] = sum(
+        len(re.findall(r"\ball-gather(?:-start)?\(", t)) for t in texts)
+    if before is not None:
+        after = in_use()
+        grew = [a - b for a, b in zip(after, before)]
+        out["dp_bytes_in_use_per_device"] = after
+        out["dp_bytes_grown_per_device"] = grew
+        out["dp_peak_bytes_per_device"] = [
+            d.memory_stats().get("peak_bytes_in_use") for d in jax.devices()]
+        # every chip holds the same replicated parameters and optimizer
+        # state and nothing else outlives a step: a chip that grew by
+        # several times another's bytes is holding state for all of them
+        assert min(grew) > 0 and max(grew) <= 2 * min(grew), grew
+    del exe, scope, compiled
+    gc.collect()
+
+    # ---- tensor-parallel paged generation
+    scope = _startup_scope(cfg)
+    gen = GPTGenerator(cfg, scope, max_len=sz.max_len, tp=2)
+    try:
+        rng = np.random.default_rng(2)
+        prompt = rng.integers(1, cfg.vocab_size,
+                              sz.check_prompt).astype(np.int32)
+        t0 = time.perf_counter()
+        toks, = gen.generate([prompt], max_new_tokens=sz.new_tokens,
+                             paged=True)
+        out["smoke_timings_s"]["tp2_generate_incl_compile"] = round(
+            time.perf_counter() - t0, 2)
+        assert toks.shape == (sz.new_tokens,), toks.shape
+        assert np.all((toks >= 0) & (toks < cfg.vocab_size))
+        texts = [v.as_text() for _, v in gen.cache.items()]
+        nblocks = gen._paged_pools[
+            (1, flag("kv_cache_dtype"), int(flag("kv_block_size")))
+        ].num_blocks
+        out["tp2_attention_custom_calls"] = _assert_per_shard(
+            texts, (nblocks, heads, int(flag("kv_block_size")), d_head),
+            "tp=2 paged decode", on_chip)
+        out["tp2_new_tokens"] = int(toks.size)
+    finally:
+        set_mesh(None)      # GPTGenerator(tp=) installs its mesh as ambient
+    return out
+
+
+# --------------------------------------------------------------------- main
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument(
+        "--rehearse-cpu", action="store_true",
+        help="dry run on the CPU at GPTConfig.tiny() with interpreted "
+             "kernels; every line says rehearsal and none says pass")
+    args = ap.parse_args(argv)
+
+    import jax
+    platform = jax.devices()[0].platform
+    if args.rehearse_cpu:
+        if platform != "cpu":
+            print(f"chip_smoke: --rehearse-cpu is for the CPU, JAX found "
+                  f"{platform!r}; run without it", file=sys.stderr)
+            return 2
+    elif platform != "tpu":
+        print(f"chip_smoke: needs a TPU and JAX found platform "
+              f"{platform!r} ({jax.devices()[0].device_kind}); nothing "
+              f"was run. --rehearse-cpu dry-runs the script on the CPU.",
+              file=sys.stderr)
+        return 2
+
+    from paddle_tpu.kernels import _dispatch
+    from paddle_tpu.observability import utilization
+    from paddle_tpu.utils import compile_cache
+    cache = compile_cache.enable()
+    sizes = Sizes(args.rehearse_cpu)
+    if args.rehearse_cpu:
+        # what a TPU would resolve to, through the Pallas interpreter
+        _dispatch.auto_impl = lambda: "interpret"
+    elif utilization.peak_flops() is None or utilization.hbm_peak() is None:
+        print(f"chip_smoke: device kind "
+              f"{jax.devices()[0].device_kind!r} is not in "
+              f"observability/utilization.py's peak tables; add it with "
+              f"its source before measuring on it", file=sys.stderr)
+        return 2
+
+    smoke = Smoke(sizes, cache)
+    print(json.dumps({"phase": "start", **smoke.device, **smoke.versions,
+                      "rehearsal_not_a_chip_run": sizes.rehearsal,
+                      "compile_cache_dir": cache.directory}), flush=True)
+    smoke.run_phase("kernels", phase_kernels)
+    smoke.run_phase("train", phase_train)
+    smoke.run_phase("serve", phase_serve)
+    if smoke.device["count"] >= 4:
+        smoke.run_phase("mesh", phase_mesh)
+
+    failed = sorted(n for n, s in smoke.results.items() if s == "fail")
+    if sizes.rehearsal:
+        print(json.dumps({"ok": None, "rehearsal_not_a_chip_run": True,
+                          "phases": smoke.results}), flush=True)
+        return 1 if failed else 0
+    if failed:
+        print(json.dumps({"ok": False, "device": smoke.device,
+                          "failed": failed}), flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": smoke.device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -11,6 +11,14 @@ PADDLE_TRAINER_ENDPOINTS / PADDLE_CURRENT_ENDPOINT (trainer 0's endpoint
 doubles as the jax.distributed coordinator — fleet.init dials it).
 PS mode additionally launches PSERVER-role processes with
 PADDLE_PSERVERS_IP_PORT_LIST, exactly the env PaddleCloudRoleMaker reads.
+
+One process for each TPU host: a chip belongs to one process at a time,
+one process drives every chip of its host through the mesh, and this
+launcher does not narrow which chips a child may open. So it starts more
+than one child only when the children are pinned off the TPU
+(``--device=cpu``, or an inherited ``JAX_PLATFORMS`` that does not name
+it); otherwise the second child would fail or hang at its first use of
+JAX, and :class:`TPUProcessLimitError` is raised before any child starts.
 """
 import argparse
 import os
@@ -18,6 +26,27 @@ import signal
 import socket
 import subprocess
 import sys
+
+
+class TPUProcessLimitError(RuntimeError):
+    """More than one child was asked for and nothing keeps the children
+    off the TPU: all but the first would find the chips taken."""
+
+
+def _check_tpu_process_limit(args, n_children):
+    """Refuse a multi-child launch whose children may open the TPU.
+    Judged from ``--device`` and the inherited ``JAX_PLATFORMS`` alone:
+    the launcher itself must stay off JAX, or it would hold the chips
+    its one child needs."""
+    platforms = (args.device or os.environ.get("JAX_PLATFORMS", "")).lower()
+    names = [p.strip() for p in platforms.split(",") if p.strip()]
+    if n_children > 1 and (not names or "tpu" in names):
+        raise TPUProcessLimitError(
+            f"refusing to start {n_children} processes that may each open "
+            f"the TPU (children's platform: {platforms or 'JAX default'!r}): "
+            f"a chip belongs to one process at a time and one process "
+            f"drives every chip of its host. Start one process per TPU "
+            f"host, or pass --device=cpu for a multi-process CPU run.")
 
 
 def _free_ports(n, ip="127.0.0.1"):
@@ -81,6 +110,7 @@ def launch(args):
         # ---- PS cluster ----
         n_servers = args.server_num or 1
         n_workers = args.worker_num or 1
+        _check_tpu_process_limit(args, n_servers + n_workers)
         sports = _free_ports(n_servers, args.node_ip)
         server_eps = ",".join(f"{args.node_ip}:{p}" for p in sports)
         for i in range(n_servers):
@@ -102,6 +132,7 @@ def launch(args):
     else:
         # ---- collective ----
         n = args.nproc_per_node or 1
+        _check_tpu_process_limit(args, n)
         ports = ([args.started_port + i for i in range(n)]
                  if args.started_port else _free_ports(n, args.node_ip))
         eps = ",".join(f"{args.node_ip}:{p}" for p in ports)

@@ -1350,7 +1350,9 @@ def _shard_feed(feed_arrays, mesh, program):
     out = {}
     multi = jax.process_count() > 1
     for n, a in feed_arrays.items():
-        arr = np.asarray(a)
+        # a batch the DataLoader prefetched is a device array already:
+        # reshard it chip to chip, never through the host
+        arr = a if isinstance(a, jax.Array) and not multi else np.asarray(a)
         sharding = NamedSharding(mesh, _batch_pspec(mesh, arr))
         if multi:
             out[n] = jax.make_array_from_process_local_data(sharding, arr)

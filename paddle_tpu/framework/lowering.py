@@ -362,7 +362,6 @@ def wrap_hier_dp_steps(fn, mesh, feed_slab):
     K-leading aux feeds) replicate instead.
     """
     from jax.sharding import PartitionSpec as P
-    from ..ops._shard_compat import shard_map
 
     axes = hier_dp_axes(mesh)
     denom = 1
@@ -383,6 +382,6 @@ def wrap_hier_dp_steps(fn, mesh, feed_slab):
         ys = [_hier_fetch_reduce(y, axes) for y in ys]
         return ys, final_state, final_key, viols, slots
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(), feed_specs, P()),
-                     out_specs=P(), check_vma=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(), feed_specs, P()),
+                         out_specs=P(), check_vma=False)

@@ -438,7 +438,8 @@ class GPTGenerator:
         feed_names = list(outs["feed_names"])
         fetch_names = self._fetch_names(outs)
         state_in, _ = analyze_block_io(main, 0, feed_names)
-        fn = build_block_fn(main, 0, feed_names, fetch_names, state_in, [])
+        fn = build_block_fn(main, 0, feed_names, fetch_names, state_in, [],
+                            mesh=self.mesh)
 
         # only the decode step's KV caches are worth donating (XLA
         # aliases the cache append in place — no 2x cache traffic);

@@ -31,9 +31,10 @@ Rooflining divides each axis's per-step wire bytes by the ICI (or DCN,
 for axes the caller marks cross-slice) bandwidth peak tables in
 :mod:`utilization` — the same ``set_peaks()``-overridable tables
 ``bench.py`` reads, so the live ``device_comm_bound_ratio`` gauge and
-the offline bench agree by construction. On hardware with no table
-entry (CPU dev boxes) the reference-chip peaks below rank/predict
-instead, flagged ``ref_peaks`` — the profiling.py convention.
+the offline bench agree by construction. Off a TPU (CPU dev boxes) the
+reference-chip peaks below rank/predict instead, flagged ``ref_peaks``
+— the profiling.py convention. On a TPU they are never used: a chip
+that is missing from the tables is an error (:func:`_fabric_peaks`).
 """
 import re
 import time
@@ -50,6 +51,30 @@ from .utilization import dcn_peak, ici_peak, peak_flops, hbm_peak
 # what matter offline, not absolute seconds (profiling.REF_PEAK_* idiom)
 REF_ICI_PEAK = 200e9
 REF_DCN_PEAK = 25e9
+
+
+
+class UnknownDevicePeakError(LookupError):
+    """A TPU is present but its ``device_kind`` is in neither fabric
+    peak table: pricing its collectives at another chip's bandwidth
+    would put a made-up number under a device metric's name."""
+
+
+def _fabric_peaks():
+    """``(ici, dcn, ici_is_ref, dcn_is_ref)`` bytes/s for the local
+    device: the :mod:`utilization` tables (or ``set_peaks`` overrides),
+    else the reference peaks — off a TPU only."""
+    ici, dcn = ici_peak(), dcn_peak()
+    if ici is None or dcn is None:
+        import jax
+        if jax.default_backend() == "tpu":
+            raise UnknownDevicePeakError(
+                f"TPU device kind {jax.devices()[0].device_kind!r} has no "
+                f"ICI/DCN entry in observability/utilization.py; add it "
+                f"with its source or call utilization.set_peaks(...)")
+    return (REF_ICI_PEAK if ici is None else ici,
+            REF_DCN_PEAK if dcn is None else dcn, ici is None, dcn is None)
+
 
 COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
                     "all-to-all", "collective-permute")
@@ -318,18 +343,16 @@ class CommLedger:
         the flag is True iff any axis ACTUALLY divided by a reference
         peak (a fabric whose table/override has a real value never
         taints the flag)."""
-        ici = ici_peak()
-        dcn = dcn_peak()
+        ici, dcn, ici_ref, dcn_ref = _fabric_peaks()
         total = 0.0
         ref = False
         for axis, wire in self.totals()["by_axis"].items():
             if _rides_dcn(axis, dcn_axes):
-                bw = dcn if dcn is not None else REF_DCN_PEAK
-                ref = ref or dcn is None
+                total += wire / dcn
+                ref = ref or dcn_ref
             else:
-                bw = ici if ici is not None else REF_ICI_PEAK
-                ref = ref or ici is None
-            total += wire / bw
+                total += wire / ici
+                ref = ref or ici_ref
         return total, ref
 
     def comm_bound_ratio(self, cost, dcn_axes=()):
@@ -492,8 +515,7 @@ def _record_tracks(where, ledger, dcn_axes=()):
     """One ``comms/ledger_<where>`` parent span with a child span per
     (collective, axis) — each child's duration is its predicted wire
     time — plus cumulative ``comms/<axis>_bytes`` counter samples."""
-    ici = ici_peak() or REF_ICI_PEAK
-    dcn = dcn_peak() or REF_DCN_PEAK
+    ici, dcn, _, _ = _fabric_peaks()
     parent = _tracing.current() or _tracing.new_trace()
     t0 = time.perf_counter()
     cursor = t0

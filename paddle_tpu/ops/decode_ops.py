@@ -161,7 +161,8 @@ def paged_attention_op(ctx, ins, attrs):
     Q [B, H, 1, D], K/V pools [N, H, bs, D] (+ KScale/VScale [N, H, bs]
     for int8), Tables [B, nblk] int32, Pos [B] int32 -> Out [B, H, 1, D].
     Dispatches to kernels/paged_attention (Pallas fused gather+attend on
-    TPU; jnp.take reference elsewhere — attrs["impl"] overrides)."""
+    TPU; jnp.take reference elsewhere — attrs["impl"] overrides). Under
+    a mesh the kernel runs per shard, heads over tp."""
     from ..kernels.paged_attention import paged_attention as _kernel
 
     q = x_of(ins, "Q")
@@ -173,7 +174,8 @@ def paged_attention_op(ctx, ins, attrs):
                   k_scale=x_of(ins, "KScale"),
                   v_scale=x_of(ins, "VScale"),
                   scale=float(attrs.get("scale", 0.0)) or None,
-                  impl=attrs.get("impl") or None)
+                  impl=attrs.get("impl") or None,
+                  mesh=None if ctx.abstract else ctx.mesh)
     return {"Out": out}
 
 

@@ -23,7 +23,6 @@ identical math, so pipelined and non-pipelined runs are numerically equal
 """
 import jax
 import jax.numpy as jnp
-from ._shard_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..framework.registry import register_op
@@ -118,7 +117,7 @@ def pipeline_op(ctx, ins, attrs):
             jnp.where(idx == S - 1, outbuf, jnp.zeros_like(outbuf)), "pp")
         return outbuf
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(tuple(P("pp") for _ in stacked),
                   tuple(P() for _ in repl), xspec),

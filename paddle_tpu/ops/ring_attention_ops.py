@@ -22,7 +22,6 @@ numerically comparable.
 """
 import jax
 import jax.numpy as jnp
-from ._shard_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..framework.registry import register_op
@@ -150,7 +149,7 @@ def ring_attention(ctx, ins, attrs):
             step, (k_l, v_l, b0, m, l, acc), jnp.arange(sp))
         return acc / l[..., None]
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(qspec, qspec, qspec, bspec),
         out_specs=qspec, check_vma=False)
@@ -159,13 +158,15 @@ def ring_attention(ctx, ins, attrs):
 
 @register_op("flash_attention", infer_shape=False)
 def flash_attention_op(ctx, ins, attrs):
-    """Single-device fused attention via the Pallas flash kernel
+    """Fused attention via the Pallas flash kernel
     (kernels/flash_attention.py) — the TPU-native equivalent of the
     reference's fused CUDA attention
     (operators/fused/multihead_matmul_op.cu). inputs: Q, K, V
     [B, H, S, D] (+ optional additive key Bias [B, 1, 1, S], treated as a
     constant mask); attrs: scale (default 1/sqrt(D)), causal, impl
-    ("" = auto: Pallas on TPU, XLA composite elsewhere)."""
+    ("" = auto: Pallas on TPU, XLA composite elsewhere). Under a mesh
+    the kernel runs per shard: batch over the data axes, heads over
+    tp."""
     from ..kernels.flash_attention import flash_attention as _fa
 
     q = x_of(ins, "Q")
@@ -178,7 +179,8 @@ def flash_attention_op(ctx, ins, attrs):
               causal=bool(attrs.get("causal", False)),
               impl=attrs.get("impl") or None,
               block_q=int(attrs.get("block_q", 0)) or None,
-              block_k=int(attrs.get("block_k", 0)) or None)
+              block_k=int(attrs.get("block_k", 0)) or None,
+              mesh=None if ctx.abstract else ctx.mesh)
     return {"Out": out}
 
 
@@ -259,7 +261,7 @@ def ulysses_attention(ctx, ins, attrs):
         return jax.lax.all_to_all(out_h, "sp", split_axis=2,
                                   concat_axis=1, tiled=True)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(qspec, qspec, qspec, bspec),
         out_specs=qspec, check_vma=False)

@@ -380,7 +380,20 @@ def test_adamw_fused_parity():
     l0, s0 = _run_k_steps(main, startup, loss, feeds, "0")
     l1, s1 = _run_k_steps(main, startup, loss, feeds, "1")
     assert np.array_equal(l0, l1)
-    _assert_snapshots_equal(s0, s1)
+    # the fused op updates the bucket as ONE flat vector, and XLA:CPU
+    # picks its vector loop (and where it contracts the decay multiply
+    # into an FMA) by that vector's length, not each parameter's: the
+    # decayed update lands within a few ulp (of the tensor's largest
+    # entry: every entry moves by an update of one scale) of the
+    # per-parameter op, not on it. What is not a float stays exact.
+    assert sorted(s0) == sorted(s1)
+    for n in s0:
+        if np.issubdtype(s0[n].dtype, np.floating):
+            np.testing.assert_allclose(
+                s1[n], s0[n], rtol=0, err_msg=n,
+                atol=4 * np.spacing(np.max(np.abs(s0[n]))))
+        else:
+            assert np.array_equal(s0[n], s1[n]), n
 
 
 def test_sparse_grad_stays_unfused():

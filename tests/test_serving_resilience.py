@@ -257,7 +257,11 @@ def test_drain_completes_inflight_and_stops(tmp_path):
     assert server.state == "stopped"
     for req in reqs:                     # admitted-before-drain: completed
         got, = req.wait(timeout=1)
-        np.testing.assert_array_equal(got, ref)
+        # the six may share one padded batch while ref ran alone, and
+        # XLA:CPU picks its codegen by batch size: a few ulp (of the
+        # largest softmax output), not bitwise
+        np.testing.assert_allclose(got, ref, rtol=0,
+                                   atol=4 * np.spacing(np.max(ref)))
 
 
 @pytest.mark.slow

@@ -1,0 +1,198 @@
+"""Both Pallas kernels must lower for the TPU platform, checked on the CPU.
+
+``jax.jit(f).trace(*specs).lower(lowering_platforms=("tpu",))`` runs the
+Pallas-to-Mosaic lowering with no TPU and no libtpu: block-shape rules,
+scalar-store rules and unsupported ops all fail here, in seconds. Every
+other kernel test runs ``impl="interpret"`` or ``"xla"``, which checks
+the arithmetic and none of those rules. The full Mosaic + XLA:TPU
+compile against a compile-only v5e topology is the ``slow`` test at the
+bottom.
+"""
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.kernels import _dispatch
+from paddle_tpu.kernels.flash_attention import flash_attention
+from paddle_tpu.kernels.paged_attention import paged_attention, quantize_kv
+from paddle_tpu.serving.kvpool import _DTYPES, _np_pool_dtype
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# GPT-base and BERT-base share the head shape (12 heads of 64); what
+# differs is the mask (causal against a key bias) and the lengths run
+H, D = 12, 64
+FLASH_CASES = [
+    # (label, S, causal, key bias)
+    ("gpt_base_prefill_bucket", 32, True, False),
+    ("gpt_base_train", 2048, True, False),
+    ("bert_base", 128, False, True),
+    ("bert_base_long", 2048, False, True),
+]
+
+
+def _spec(shape, dtype, sharding=None):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _flash_fns(causal):
+    def fwd(q, k, v, bias=None):
+        return flash_attention(q, k, v, bias, causal=causal, impl="pallas")
+
+    def loss(q, k, v, bias=None):
+        return jnp.sum(fwd(q, k, v, bias).astype(jnp.float32))
+
+    return fwd, jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _flash_specs(seq, with_bias, dtype, sharding=None, batch=2):
+    qkv = _spec((batch, H, seq, D), dtype, sharding)
+    specs = [qkv, qkv, qkv]
+    if with_bias:
+        specs.append(_spec((batch, 1, 1, seq), jnp.float32, sharding))
+    return specs
+
+
+def _paged_specs(kv_dtype, block, rows=8, positions=2048, sharding=None):
+    nblk = positions // block
+    pool = _spec((rows * nblk + 1, H, block, D), _np_pool_dtype(kv_dtype),
+                 sharding)
+    specs = [_spec((rows, H, 1, D), jnp.float32, sharding), pool, pool,
+             _spec((rows, nblk), jnp.int32, sharding),
+             _spec((rows,), jnp.int32, sharding)]
+    if kv_dtype == "int8":
+        scale = _spec((rows * nblk + 1, H, block), jnp.float32, sharding)
+        specs += [scale, scale]
+    return specs
+
+
+def _paged_fn(q, k, v, tables, pos, k_scale=None, v_scale=None):
+    return paged_attention(q, k, v, tables, pos, k_scale, v_scale,
+                           impl="pallas")
+
+
+@pytest.mark.parametrize("label,seq,causal,with_bias", FLASH_CASES)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_lowers_for_tpu(label, seq, causal, with_bias, dtype):
+    fwd, grad = _flash_fns(causal)
+    specs = _flash_specs(seq, with_bias, dtype)
+    for fn in (fwd, grad):
+        text = jax.jit(fn).trace(*specs).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_dtype", _DTYPES)
+@pytest.mark.parametrize("block", [16, 128])
+def test_paged_decode_lowers_for_tpu(kv_dtype, block):
+    """Every dtype FLAGS_kv_cache_dtype accepts, at the default block
+    size and at a lane-wide one. Failed on the seed in every dtype:
+    scalar stores to VMEM (fp32, bf16) and an illegal scale block
+    (int8)."""
+    text = jax.jit(_paged_fn).trace(*_paged_specs(kv_dtype, block)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_composite_fallbacks_are_counted(monkeypatch):
+    """Where the kernel would run (a TPU), more than one query per row
+    and a general bias still take the composite, and the counter says
+    why."""
+    monkeypatch.setattr(_dispatch, "auto_impl", lambda: "interpret")
+    before = _dispatch.resolved_counts()
+
+    def grew(op, impl, reason):
+        key = (op, impl, reason)
+        return _dispatch.resolved_counts().get(key, 0) - before.get(key, 0)
+
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, 2, 3, 8)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(4, 2, 8, 8)), jnp.float32)
+    tables = jnp.asarray([[1, 2]], jnp.int32)
+    pos = jnp.asarray([5], jnp.int32)
+    paged_attention(q, pool, pool, tables, pos)
+    assert grew("paged_attention", "xla", "multi_query") == 1
+    paged_attention(q[:, :, :1], pool, pool, tables, pos)
+    assert grew("paged_attention", "interpret", "backend") == 1
+
+    kv = jnp.asarray(rng.normal(size=(1, 2, 8, 8)), jnp.float32)
+    flash_attention(kv, kv, kv, bias=jnp.zeros((1, 2, 8, 8)))
+    assert grew("flash_attention", "xla", "general_bias") == 1
+    flash_attention(kv, kv, kv, causal=True)
+    assert grew("flash_attention", "interpret", "backend") == 1
+
+
+def test_kernels_run_per_shard_under_a_mesh():
+    """Under a mesh the kernels go through shard_map (GSPMD cannot
+    partition a Pallas custom call): same numbers as the unsharded call,
+    batch over dp for flash, heads over tp for paged."""
+    from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
+    rng = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(rng.normal(size=(4, 2, 16, 8)), jnp.float32)
+               for _ in range(3))
+    mesh = make_mesh(MeshConfig(dp=2, tp=2), devices=jax.devices()[:4])
+    ref = flash_attention(q, k, v, causal=True, impl="interpret")
+    out = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl="interpret", mesh=mesh))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+    assert "shard_map" in str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl="interpret", mesh=mesh))(q, k, v))
+
+    pool_k, pool_v = (rng.normal(size=(5, 2, 8, 8)).astype(np.float32)
+                      for _ in range(2))
+    qk, ks = quantize_kv(jnp.asarray(pool_k))
+    qv, vs = quantize_kv(jnp.asarray(pool_v))
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    pos = jnp.asarray([5, 12], jnp.int32)
+    qd = jnp.asarray(rng.normal(size=(2, 2, 1, 8)), jnp.float32)
+    ref = paged_attention(qd, qk, qv, tables, pos, ks, vs, impl="interpret")
+    out = jax.jit(lambda *a: paged_attention(
+        *a, impl="interpret", mesh=mesh))(qd, qk, qv, tables, pos, ks, vs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """``JAX_PLATFORMS=cpu python chip_smoke.py`` names the platform it
+    found, prints no result line and exits non-zero."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(REPO,
+                                                       "chip_smoke.py")],
+                         capture_output=True, text=True, env=env,
+                         timeout=120)
+    assert out.returncode != 0
+    assert "cpu" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+@pytest.mark.slow
+def test_kernels_compile_for_a_compile_only_v5e():
+    """Mosaic and XLA:TPU, no chip: libtpu's compile-only topology gives
+    ``TPU v5 lite`` devices to lower against. One process at a time may
+    hold libtpu, so this cannot share a machine with a second copy of
+    itself."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no compile-only TPU topology here: {exc}")
+    on = SingleDeviceSharding(topo.devices[0])
+    fwd, grad = _flash_fns(True)
+    specs = _flash_specs(2048, False, jnp.bfloat16, on, batch=8)
+    jax.jit(fwd).lower(*specs).compile()
+    jax.jit(grad).lower(*specs).compile()
+    fwd, grad = _flash_fns(False)
+    specs = _flash_specs(2048, True, jnp.bfloat16, on, batch=8)
+    jax.jit(grad).lower(*specs).compile()
+    for kv_dtype in _DTYPES:
+        for block in (16, 128):
+            jax.jit(_paged_fn).lower(
+                *_paged_specs(kv_dtype, block, sharding=on)).compile()

@@ -59,6 +59,7 @@ def registered_names():
     import paddle_tpu.serving  # noqa: F401 — ServingStats bridge
     import paddle_tpu.train  # noqa: F401 — train supervisor families
     import paddle_tpu.models.generation  # noqa: F401 — decode stages
+    import paddle_tpu.kernels  # noqa: F401 — attention impl resolution
     from paddle_tpu.observability import default_registry
     return sorted(default_registry().catalog())
 
