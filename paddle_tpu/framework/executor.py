@@ -19,6 +19,7 @@ from .core import Program, Variable, default_main_program
 from .dtype import np_dtype
 from .lowering import analyze_block_io, build_block_fn, build_multi_step_fn
 from ..flags import flag as _flag
+from ..observability import tracing as _trace
 from ..observability import utilization as _util
 from ..observability import metrics as _obs_metrics
 from ..observability.metrics import default_registry as _registry
@@ -239,6 +240,11 @@ class Executor:
         # FLAGS_profile_ops sampling counters, per cache key (bounded:
         # cleared when the key universe outgrows the compile cache)
         self._profile_seq = {}
+        # loop spans (observability.tracing): the trace this executor's
+        # runs belong to, and the step number each run carries
+        self._span_root = _trace.loop_root(
+            f"exe:{id(self) & 0xffffff:x}")
+        self._runs = 0
         # closures bind the stat containers, never self; clearing the
         # cache on retire drops the compiled executables (device memory)
         _exec_agg.track(
@@ -522,152 +528,180 @@ class Executor:
                       if op.type == "listen_and_serv"), None)
         if ls_op is not None:
             return self._run_pserver(ls_op, scope)
-        feed = self._feed_dict(feed)
-        fetch_names = self._fetch_names(fetch_list)
+        self._runs += 1
+        with _trace.loop_span("executor/run", self._span_root,
+                              step_num=self._runs,
+                              program=program._uid) as span:
+            return self._run(program, mesh, feed, fetch_list, scope,
+                             return_numpy, use_program_cache,
+                             check_nan_inf, skip_nonfinite_steps,
+                             span.attrs)
 
-        feed_arrays = {}
-        feed_sig = []
-        for name, val in feed.items():
-            arr = np.asarray(val) if not isinstance(val, jax.Array) else val
-            if isinstance(arr, np.ndarray):
-                arr = _sanitize_np_feed(program.global_block(), name, arr)
-            feed_arrays[name] = arr
-            feed_sig.append((name, tuple(arr.shape), str(arr.dtype)))
+    def _run(self, program, mesh, feed, fetch_list, scope, return_numpy,
+             use_program_cache, check_nan_inf, skip_nonfinite_steps,
+             attrs):
+        """The body of :meth:`run`, inside its ``executor/run`` loop
+        span (``attrs`` are the span's): ``executor/prepare`` (feed
+        conversion, the cache key, the state gathered from the scope;
+        on a miss the compile), ``executor/dispatch`` (the compiled
+        call), ``executor/commit`` (utilization, the scope's new
+        state) and, where the fetches come back as numpy,
+        ``executor/fetch_wait``."""
+        with _trace.loop_span("executor/prepare"):
+            feed = self._feed_dict(feed)
+            fetch_names = self._fetch_names(fetch_list)
 
-        from .passes import pipeline_signature
-        cache_key = (program._uid, program.version, tuple(sorted(feed_sig)),
-                     tuple(fetch_names), id(mesh), pipeline_signature())
-        entry = self._cache.get(cache_key) if use_program_cache else None
-        if entry is not None and not self._entry_valid(entry, scope):
-            entry = None               # scope-state fetch binding changed
-        if entry is not None:
-            compiled, jitted, state_in, state_out, state_fetches = entry
-        else:
-            opt_prog = self._optimize(program, fetch_names,
-                                      feed_names=feed_arrays.keys(),
-                                      scope=scope)
-            state_in, state_out = analyze_block_io(
-                opt_prog, 0, list(feed_arrays.keys()))
-            state_in, state_fetches = self._state_fetches(
-                opt_prog, fetch_names, feed_arrays, state_in, scope)
+            feed_arrays = {}
+            feed_sig = []
+            for name, val in feed.items():
+                arr = val if isinstance(val, jax.Array) \
+                    else np.asarray(val)
+                if isinstance(arr, np.ndarray):
+                    arr = _sanitize_np_feed(program.global_block(), name,
+                                            arr)
+                feed_arrays[name] = arr
+                feed_sig.append((name, tuple(arr.shape), str(arr.dtype)))
 
-        base_key = self._ensure_rng(scope, program)
-        state_out_set = set(state_out)
-        state_mut, state_ro = self._split_scope_state(scope, state_in,
-                                                      state_out_set)
-
-        if mesh is not None:
-            feed_arrays = _shard_feed(feed_arrays, mesh, program)
-            # esp. read-only params of inference programs
-            self._reshard_state_to_scope(scope, program, mesh, state_mut,
-                                         state_ro)
-
-        if entry is None:
-            fn = build_block_fn(opt_prog, 0, list(feed_arrays.keys()),
-                                fetch_names, state_in, state_out,
-                                mesh=mesh)
-            if mesh is not None:
-                jitted = _jit_with_mesh(fn, mesh, opt_prog)
+            from .passes import pipeline_signature
+            cache_key = (program._uid, program.version,
+                         tuple(sorted(feed_sig)), tuple(fetch_names),
+                         id(mesh), pipeline_signature())
+            entry = self._cache.get(cache_key) if use_program_cache \
+                else None
+            if entry is not None and not self._entry_valid(entry, scope):
+                entry = None           # scope-state fetch binding changed
+            attrs["compiled"] = entry is None
+            if entry is not None:
+                compiled, jitted, state_in, state_out, state_fetches = entry
             else:
-                jitted = jax.jit(fn, donate_argnums=(0,))
-            compiled = self._lower_and_compile(
-                jitted, f"program_{program._uid}",
-                (state_mut, state_ro, feed_arrays, base_key))
-            if use_program_cache:
-                self._cache[cache_key] = (compiled, jitted, state_in,
-                                          state_out, state_fetches)
-            self._maybe_shard_obs("step", cache_key, compiled, mesh,
-                                  program, tuple(feed_arrays))
-            if mesh is not None and "dcn_dp" in mesh.axis_names \
-                    and _flag("dcn_hierarchical") \
-                    and any(op.type == "hier_allreduce"
-                            for op in program.global_block().ops):
-                # the single-step run() path lowers through plain GSPMD:
-                # hier_allreduce collapses to identity (no bound axes) and
-                # the gradient sync comes back as ONE flat all-reduce over
-                # dcn_dp+dp — numerically right, but every byte of it
-                # crosses the DCN. Warn once per compiled executable; the
-                # decomposed path is run_steps.
-                _flightrec().record(
-                    "hier_single_step_flat",
-                    where=f"program_{program._uid}",
-                    mesh_axes=",".join(mesh.axis_names),
-                    hint="FLAGS_dcn_hierarchical is on and the program "
-                         "carries hier_allreduce sync ops, but "
-                         "Executor.run lowers flat-GSPMD; use "
-                         "run_steps for the hierarchical DCN path")
+                opt_prog = self._optimize(program, fetch_names,
+                                          feed_names=feed_arrays.keys(),
+                                          scope=scope)
+                state_in, state_out = analyze_block_io(
+                    opt_prog, 0, list(feed_arrays.keys()))
+                state_in, state_fetches = self._state_fetches(
+                    opt_prog, fetch_names, feed_arrays, state_in, scope)
 
-        if check_nan_inf is None:
-            check_nan_inf = _flag("check_nan_inf")
-        backup = None
-        if skip_nonfinite_steps:
-            # the executable donates state_mut buffers, so rollback needs
-            # host copies taken BEFORE the step (the price of the opt-in)
-            backup = {n: np.asarray(v) for n, v in state_mut.items()}
+            base_key = self._ensure_rng(scope, program)
+            state_out_set = set(state_out)
+            state_mut, state_ro = self._split_scope_state(
+                scope, state_in, state_out_set)
 
-        # sampled measured op profiling (FLAGS_profile_ops=N): every
-        # N-th dispatch of a program replays the optimized clone
-        # op-by-op BEFORE the fused invoke (its buffers are donated
-        # after). The committed result below is still the fused
-        # executable's — numerics are untouched; with the default N=0
-        # this costs one flag read.
-        prof_n = int(_flag("profile_ops"))
-        if prof_n > 0 and mesh is None:
-            self._maybe_profile_ops(prof_n, cache_key, program,
-                                    fetch_names, feed_arrays, state_mut,
-                                    state_ro, base_key, scope)
+            if mesh is not None:
+                feed_arrays = _shard_feed(feed_arrays, mesh, program)
+                # esp. read-only params of inference programs
+                self._reshard_state_to_scope(scope, program, mesh,
+                                             state_mut, state_ro)
+
+            if entry is None:
+                fn = build_block_fn(opt_prog, 0, list(feed_arrays.keys()),
+                                    fetch_names, state_in, state_out,
+                                    mesh=mesh)
+                if mesh is not None:
+                    jitted = _jit_with_mesh(fn, mesh, opt_prog)
+                else:
+                    jitted = jax.jit(fn, donate_argnums=(0,))
+                compiled = self._lower_and_compile(
+                    jitted, f"program_{program._uid}",
+                    (state_mut, state_ro, feed_arrays, base_key))
+                if use_program_cache:
+                    self._cache[cache_key] = (compiled, jitted, state_in,
+                                              state_out, state_fetches)
+                self._maybe_shard_obs("step", cache_key, compiled, mesh,
+                                      program, tuple(feed_arrays))
+                if mesh is not None and "dcn_dp" in mesh.axis_names \
+                        and _flag("dcn_hierarchical") \
+                        and any(op.type == "hier_allreduce"
+                                for op in program.global_block().ops):
+                    # the single-step run() path lowers through plain GSPMD:
+                    # hier_allreduce collapses to identity (no bound axes) and
+                    # the gradient sync comes back as ONE flat all-reduce over
+                    # dcn_dp+dp — numerically right, but every byte of it
+                    # crosses the DCN. Warn once per compiled executable; the
+                    # decomposed path is run_steps.
+                    _flightrec().record(
+                        "hier_single_step_flat",
+                        where=f"program_{program._uid}",
+                        mesh_axes=",".join(mesh.axis_names),
+                        hint="FLAGS_dcn_hierarchical is on and the program "
+                             "carries hier_allreduce sync ops, but "
+                             "Executor.run lowers flat-GSPMD; use "
+                             "run_steps for the hierarchical DCN path")
+
+            if check_nan_inf is None:
+                check_nan_inf = _flag("check_nan_inf")
+            backup = None
+            if skip_nonfinite_steps:
+                # the executable donates state_mut buffers, so rollback needs
+                # host copies taken BEFORE the step (the price of the opt-in)
+                backup = {n: np.asarray(v) for n, v in state_mut.items()}
+
+            # sampled measured op profiling (FLAGS_profile_ops=N): every
+            # N-th dispatch of a program replays the optimized clone
+            # op-by-op BEFORE the fused invoke (its buffers are donated
+            # after). The committed result below is still the fused
+            # executable's — numerics are untouched; with the default N=0
+            # this costs one flag read.
+            prof_n = int(_flag("profile_ops"))
+            if prof_n > 0 and mesh is None:
+                self._maybe_profile_ops(prof_n, cache_key, program,
+                                        fetch_names, feed_arrays, state_mut,
+                                        state_ro, base_key, scope)
 
         from .. import profiler as _prof
-        invoke_args = (compiled, jitted,
-                       (state_mut, state_ro, feed_arrays, base_key),
-                       f"program_{program._uid}",
-                       cache_key if use_program_cache else None)
-        if _prof.is_profiling():
-            with _prof.record_event(f"run/program_{program._uid}"):
+        with _trace.loop_span("executor/dispatch"):
+            invoke_args = (compiled, jitted,
+                           (state_mut, state_ro, feed_arrays, base_key),
+                           f"program_{program._uid}",
+                           cache_key if use_program_cache else None)
+            if _prof.is_profiling():
+                with _prof.record_event(f"run/program_{program._uid}"):
+                    fetches, new_state, new_key = self._invoke(*invoke_args)
+                    jax.block_until_ready(fetches)
+            else:
                 fetches, new_state, new_key = self._invoke(*invoke_args)
-                jax.block_until_ready(fetches)
-        else:
-            fetches, new_state, new_key = self._invoke(*invoke_args)
-        self._observe_utilization("step", cache_key, compiled)
+        with _trace.loop_span("executor/commit"):
+            self._observe_utilization("step", cache_key, compiled)
 
-        bad = None
-        if check_nan_inf or skip_nonfinite_steps:
-            bad = _scan_nonfinite(fetch_names, fetches, new_state)
-        if bad is not None and skip_nonfinite_steps:
-            # roll the step back: pre-step params/accumulators and RNG go
-            # back into the scope, nothing is committed
-            kind, name, count = bad
-            _flightrec().record("nonfinite", program=program._uid,
-                                var=name, count=count, where=kind,
-                                rolled_back=True)
-            for n, a in backup.items():
-                scope.set(n, a)
-            scope.set(RNG_STATE_NAME, base_key)
-            print(f"[executor] skip_nonfinite_steps: {kind} {name!r} has "
-                  f"{count} non-finite value(s) — step rolled back")
-            if return_numpy:
-                return [np.asarray(f) for f in fetches]
-            return fetches
+            bad = None
+            if check_nan_inf or skip_nonfinite_steps:
+                bad = _scan_nonfinite(fetch_names, fetches, new_state)
+            if bad is not None and skip_nonfinite_steps:
+                # roll the step back: pre-step params/accumulators and RNG go
+                # back into the scope, nothing is committed
+                kind, name, count = bad
+                _flightrec().record("nonfinite", program=program._uid,
+                                    var=name, count=count, where=kind,
+                                    rolled_back=True)
+                for n, a in backup.items():
+                    scope.set(n, a)
+                scope.set(RNG_STATE_NAME, base_key)
+                print(f"[executor] skip_nonfinite_steps: {kind} {name!r} has "
+                      f"{count} non-finite value(s) — step rolled back")
+                if return_numpy:
+                    return [np.asarray(f) for f in fetches]
+                return fetches
 
-        # commit even when about to raise: state_mut buffers were donated
-        # to the jit, so the scope must reference the step's outputs (the
-        # error is a diagnostic about the step, not a rollback)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        scope.set(RNG_STATE_NAME, new_key)
-        if bad is not None:
-            kind, name, count = bad
-            _flightrec().record("nonfinite", program=program._uid,
-                                var=name, count=count, where=kind)
-            raise NonFiniteError(
-                f"Operator output contains Inf/Nan (FLAGS_check_nan_inf): "
-                f"{kind} {name!r} has {count} non-finite value(s) in "
-                f"program_{program._uid}. Feed data, learning rate, or "
-                f"loss scaling are the usual suspects.",
-                var_name=name, count=count)
+            # commit even when about to raise: state_mut buffers were donated
+            # to the jit, so the scope must reference the step's outputs (the
+            # error is a diagnostic about the step, not a rollback)
+            for n, v in new_state.items():
+                scope.set(n, v)
+            scope.set(RNG_STATE_NAME, new_key)
+            if bad is not None:
+                kind, name, count = bad
+                _flightrec().record("nonfinite", program=program._uid,
+                                    var=name, count=count, where=kind)
+                raise NonFiniteError(
+                    f"Operator output contains Inf/Nan (FLAGS_check_nan_inf): "
+                    f"{kind} {name!r} has {count} non-finite value(s) in "
+                    f"program_{program._uid}. Feed data, learning rate, or "
+                    f"loss scaling are the usual suspects.",
+                    var_name=name, count=count)
 
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with _trace.loop_span("executor/fetch_wait"):
+                return [np.asarray(f) for f in fetches]
         return fetches
 
     # -- fused multi-step entry -----------------------------------------
@@ -718,240 +752,260 @@ class Executor:
         if program is None:
             program = default_main_program()
         scope = scope or global_scope()
-        if isinstance(feed, (list, tuple)):
-            feed = _stack_feed_slab([self._feed_dict(f) for f in feed])
-        feed = self._feed_dict(feed)
-        if not feed:
-            raise ValueError(
-                "run_steps needs at least one fed variable: the slab's "
-                "leading axis defines the step count")
-        fetch_names = self._fetch_names(fetch_list)
+        self._runs += 1
+        with _trace.loop_span("executor/run", self._span_root,
+                              step_num=self._runs,
+                              program=program._uid) as span:
+            return self._run_steps(
+                program, mesh, feed, fetch_list, scope, return_numpy,
+                use_program_cache, check_nan_inf, skip_nonfinite_steps,
+                steps_per_run, unroll, span.attrs)
 
-        feed_arrays = {}
-        feed_sig = []
-        k_steps = None
-        for name, val in feed.items():
-            arr = np.asarray(val) if not isinstance(val, jax.Array) else val
-            if arr.ndim == 0:
+    def _run_steps(self, program, mesh, feed, fetch_list, scope,
+                   return_numpy, use_program_cache, check_nan_inf,
+                   skip_nonfinite_steps, steps_per_run, unroll, attrs):
+        """The body of :meth:`run_steps`, inside its ``executor/run``
+        loop span: the four phases of :meth:`_run`, once a slab."""
+        with _trace.loop_span("executor/prepare"):
+            if isinstance(feed, (list, tuple)):
+                feed = _stack_feed_slab([self._feed_dict(f) for f in feed])
+            feed = self._feed_dict(feed)
+            if not feed:
                 raise ValueError(
-                    f"feed {name!r} is a scalar — run_steps feeds need a "
-                    f"leading steps axis")
-            if k_steps is None:
-                k_steps = int(arr.shape[0])
-            elif int(arr.shape[0]) != k_steps:
+                    "run_steps needs at least one fed variable: the slab's "
+                    "leading axis defines the step count")
+            fetch_names = self._fetch_names(fetch_list)
+
+            feed_arrays = {}
+            feed_sig = []
+            k_steps = None
+            for name, val in feed.items():
+                arr = val if isinstance(val, jax.Array) \
+                    else np.asarray(val)
+                if arr.ndim == 0:
+                    raise ValueError(
+                        f"feed {name!r} is a scalar — run_steps feeds "
+                        f"need a leading steps axis")
+                if k_steps is None:
+                    k_steps = int(arr.shape[0])
+                elif int(arr.shape[0]) != k_steps:
+                    raise ValueError(
+                        f"feed {name!r} has {arr.shape[0]} steps on its "
+                        f"leading axis, other feeds have {k_steps}")
+                if isinstance(arr, np.ndarray):
+                    arr = _sanitize_np_feed(program.global_block(), name, arr)
+                feed_arrays[name] = arr
+                feed_sig.append((name, tuple(arr.shape), str(arr.dtype)))
+            if steps_per_run is not None and int(steps_per_run) != k_steps:
                 raise ValueError(
-                    f"feed {name!r} has {arr.shape[0]} steps on its "
-                    f"leading axis, other feeds have {k_steps}")
-            if isinstance(arr, np.ndarray):
-                arr = _sanitize_np_feed(program.global_block(), name, arr)
-            feed_arrays[name] = arr
-            feed_sig.append((name, tuple(arr.shape), str(arr.dtype)))
-        if steps_per_run is not None and int(steps_per_run) != k_steps:
-            raise ValueError(
-                f"steps_per_run={steps_per_run} but the fed slab carries "
-                f"{k_steps} steps on its leading axis")
+                    f"steps_per_run={steps_per_run} but the fed slab carries "
+                    f"{k_steps} steps on its leading axis")
 
-        if check_nan_inf is None:
-            check_nan_inf = _flag("check_nan_inf")
-        guard = bool(check_nan_inf or skip_nonfinite_steps)
-        if unroll is None:
-            unroll = _flag("scan_unroll")
-        unroll = int(unroll)
-        if unroll <= 0:
-            # auto: XLA CPU runs while-loop bodies without intra-op
-            # threading — full unroll restores it; accelerators keep the
-            # loop form so compile time stays K-independent
-            unroll = k_steps if jax.default_backend() == "cpu" else 1
+            if check_nan_inf is None:
+                check_nan_inf = _flag("check_nan_inf")
+            guard = bool(check_nan_inf or skip_nonfinite_steps)
+            if unroll is None:
+                unroll = _flag("scan_unroll")
+            unroll = int(unroll)
+            if unroll <= 0:
+                # auto: XLA CPU runs while-loop bodies without intra-op
+                # threading — full unroll restores it; accelerators keep the
+                # loop form so compile time stays K-independent
+                unroll = k_steps if jax.default_backend() == "cpu" else 1
 
-        # hierarchical multi-slice path: a dcn_dp mesh whose program went
-        # through the hier_grad_sync pass runs under shard_map so the
-        # gradient reduction decomposes per fabric (RS in-slice / AR
-        # cross-slice / AG in-slice). Requires the explicit sync ops —
-        # without them per-device state would silently diverge — and a
-        # pure data-parallel mesh (tp/pp/sp compose via GSPMD only).
-        # FLAGS_dcn_hierarchical=False is the flat-GSPMD A/B baseline:
-        # same program, hier_allreduce collapses to identity.
-        from .lowering import hier_dp_axes
-        hier_axes = ()
-        if mesh is not None and _flag("dcn_hierarchical") \
-                and set(mesh.axis_names) <= {"dcn_dp", "dp"} \
-                and any(op.type == "hier_allreduce"
-                        for op in program.global_block().ops):
-            hier_axes = hier_dp_axes(mesh)
-        hier_on = bool(hier_axes)
+            # hierarchical multi-slice path: a dcn_dp mesh whose program went
+            # through the hier_grad_sync pass runs under shard_map so the
+            # gradient reduction decomposes per fabric (RS in-slice / AR
+            # cross-slice / AG in-slice). Requires the explicit sync ops —
+            # without them per-device state would silently diverge — and a
+            # pure data-parallel mesh (tp/pp/sp compose via GSPMD only).
+            # FLAGS_dcn_hierarchical=False is the flat-GSPMD A/B baseline:
+            # same program, hier_allreduce collapses to identity.
+            from .lowering import hier_dp_axes
+            hier_axes = ()
+            if mesh is not None and _flag("dcn_hierarchical") \
+                    and set(mesh.axis_names) <= {"dcn_dp", "dp"} \
+                    and any(op.type == "hier_allreduce"
+                            for op in program.global_block().ops):
+                hier_axes = hier_dp_axes(mesh)
+            hier_on = bool(hier_axes)
 
-        from .passes import pipeline_signature
-        cache_key = (program._uid, program.version,
-                     tuple(sorted(feed_sig)), tuple(fetch_names), id(mesh),
-                     "steps", k_steps, guard, bool(skip_nonfinite_steps),
-                     unroll, hier_on, pipeline_signature())
-        entry = self._cache.get(cache_key) if use_program_cache else None
-        if entry is not None and not self._entry_valid(entry, scope):
-            entry = None               # scope-state fetch binding changed
-        fresh_compile = entry is None
-        if entry is not None:
-            (compiled, jitted, state_in, state_out, mut_names, slot_names,
-             wo_avals, state_fetches) = entry
-        else:
-            opt_prog = self._optimize(program, fetch_names,
-                                      feed_names=feed_arrays.keys(),
-                                      scope=scope)
-            state_in, state_out = analyze_block_io(
-                opt_prog, 0, list(feed_arrays.keys()))
-            state_in, state_fetches = self._state_fetches(
-                opt_prog, fetch_names, feed_arrays, state_in, scope)
-
-        base_key = self._ensure_rng(scope, program)
-        state_out_set = set(state_out)
-        state_mut, state_ro = self._split_scope_state(scope, state_in,
-                                                      state_out_set)
-
-        if mesh is not None:
-            feed_arrays = _shard_feed_slab(feed_arrays, mesh)
-            self._reshard_state_to_scope(scope, program, mesh, state_mut,
-                                         state_ro)
-
-        from .. import profiler as _prof
-        if fresh_compile:
-            step_fn = build_block_fn(
-                opt_prog, 0, list(feed_arrays.keys()), fetch_names,
-                state_in, state_out, mesh=mesh)
-            feed_row = {n: jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
-                        for n, a in feed_arrays.items()}
-            _, new_state_s, _ = jax.eval_shape(
-                step_fn, state_mut, state_ro, feed_row, base_key)
-            mut_names = [n for n in state_in if n in state_out_set]
-            slot_names = (["fetched output " + repr(n)
-                           for n in fetch_names]
-                          + ["updated variable " + repr(n)
-                             for n in new_state_s])
-            wo_avals = {n: jax.ShapeDtypeStruct(s.shape, s.dtype)
-                        for n, s in new_state_s.items()
-                        if n not in state_mut}
-
-        # write-only persistable outputs ride the scan carry so a
-        # rolled-back step restores what the scope held (sequential-skip
-        # parity); vars the scope has never seen are seeded with zeros
-        # and un-committed below if every step rolled back
-        absent_wo = set()
-        for n, aval in wo_avals.items():
-            v = scope.find_var(n)
-            if v is None:
-                v = np.zeros(aval.shape, aval.dtype)
-                absent_wo.add(n)
-            state_mut[n] = v
-        if mesh is not None and wo_avals:
-            tmp = {n: state_mut[n] for n in wo_avals}
-            _shard_state(tmp, mesh, program)
-            state_mut.update(tmp)
-
-        if fresh_compile:
-            fn = build_multi_step_fn(
-                opt_prog, 0, list(feed_arrays.keys()), fetch_names,
-                state_in, state_out, mut_names, mesh=mesh,
-                guard=guard,
-                skip_nonfinite=bool(skip_nonfinite_steps),
-                unroll=unroll,
-                viol_axes=hier_axes)
-            if hier_on:
-                from .lowering import wrap_hier_dp_steps
-                jitted = jax.jit(wrap_hier_dp_steps(fn, mesh, feed_arrays),
-                                 donate_argnums=(0,))
-            elif mesh is not None:
-                jitted = _jit_with_mesh_steps(fn, mesh)
+            from .passes import pipeline_signature
+            cache_key = (program._uid, program.version,
+                         tuple(sorted(feed_sig)), tuple(fetch_names), id(mesh),
+                         "steps", k_steps, guard, bool(skip_nonfinite_steps),
+                         unroll, hier_on, pipeline_signature())
+            entry = self._cache.get(cache_key) if use_program_cache else None
+            if entry is not None and not self._entry_valid(entry, scope):
+                entry = None               # scope-state fetch binding changed
+            fresh_compile = entry is None
+            attrs["compiled"], attrs["steps"] = fresh_compile, k_steps
+            if entry is not None:
+                (compiled, jitted, state_in, state_out, mut_names, slot_names,
+                 wo_avals, state_fetches) = entry
             else:
-                jitted = jax.jit(fn, donate_argnums=(0,))
-            compiled = self._lower_and_compile(
-                jitted, f"fused_program_{program._uid}_x{k_steps}",
-                (state_mut, state_ro, feed_arrays, base_key))
-            if use_program_cache:
-                self._cache[cache_key] = (compiled, jitted, state_in,
-                                          state_out, mut_names,
-                                          slot_names, wo_avals,
-                                          state_fetches)
-            # batch_dim=1: the slab's leading K axis replicates by
-            # design; the batch dim the dp axis should shard sits
-            # under it
-            self._maybe_shard_obs("train", cache_key, compiled, mesh,
-                                  program, tuple(feed_arrays),
-                                  batch_dim=1)
-            if hier_on and _flag("dcn_assert_hier"):
-                # pre-burn gate: parse the compiled HLO and prove the
-                # hierarchical decomposition landed — DCN-priced traffic
-                # only on the designated axes, cross-slice wire bytes
-                # strictly below the flat all-reduce — BEFORE the first
-                # slab is dispatched to hardware
-                from ..observability.comms import assert_hier_decomposition
-                assert_hier_decomposition(
-                    compiled, mesh,
-                    where=f"fused_program_{program._uid}_x{k_steps}")
+                opt_prog = self._optimize(program, fetch_names,
+                                          feed_names=feed_arrays.keys(),
+                                          scope=scope)
+                state_in, state_out = analyze_block_io(
+                    opt_prog, 0, list(feed_arrays.keys()))
+                state_in, state_fetches = self._state_fetches(
+                    opt_prog, fetch_names, feed_arrays, state_in, scope)
 
-        # chaos point for the training dispatch stage: fires BEFORE the
-        # executable runs, so the scope still holds pre-slab state and a
-        # supervised restart resumes bitwise from the last checkpoint
-        _maybe_fail("train.dispatch")
-        if hier_axes:
-            # chaos point for the cross-slice reduction stage: raising
-            # simulates a slice whose DCN collective fails; delay=
-            # simulates a straggling slice stretching the step
-            _maybe_fail("train.allreduce_dcn")
-        profiling = _prof.is_profiling()
-        t0 = time.perf_counter()
-        fetches, final_state, final_key, viols, slots = self._invoke(
-            compiled, jitted, (state_mut, state_ro, feed_arrays, base_key),
-            f"fused_program_{program._uid}_x{k_steps}",
-            cache_key if use_program_cache else None)
-        if profiling:
-            t1 = time.perf_counter()
-            jax.block_until_ready(fetches if fetches else final_key)
-            span = time.perf_counter() - t0
-            _prof.record_duration(
-                f"dispatch/program_{program._uid}_x{k_steps}", t1 - t0)
-            _prof.record_duration(
-                f"scan/program_{program._uid}_x{k_steps}", span)
-            _prof.record_step_time(span / k_steps, k_steps)
-        self._observe_utilization("train", cache_key, compiled)
+            base_key = self._ensure_rng(scope, program)
+            state_out_set = set(state_out)
+            state_mut, state_ro = self._split_scope_state(scope, state_in,
+                                                          state_out_set)
 
-        v = np.asarray(viols) if guard else None  # ONE small readback
-        # commit (buffers were donated); guard diagnostics after. If
-        # EVERY step rolled back, scope-absent write-only vars stay
-        # uncommitted — K sequential skipped run() calls never create
-        # them either (their committed value would be the zeros seed).
-        all_rolled = bool(skip_nonfinite_steps and v is not None
-                          and v.size and (v > 0).all())
-        for n, val in final_state.items():
-            if all_rolled and n in absent_wo:
-                continue
-            scope.set(n, val)
-        scope.set(RNG_STATE_NAME, final_key)
+            if mesh is not None:
+                feed_arrays = _shard_feed_slab(feed_arrays, mesh)
+                self._reshard_state_to_scope(scope, program, mesh, state_mut,
+                                             state_ro)
 
-        if guard and v.any():
-            first = int(np.argmax(v > 0))
-            name = self._slot_name(slots, first, slot_names)
-            _flightrec().record(
-                "nonfinite", program=program._uid, var=name,
-                count=int(v[first]), where=f"fused step {first}",
-                rolled_back=bool(skip_nonfinite_steps))
-            if skip_nonfinite_steps:
-                rolled = int((v > 0).sum())
-                print(f"[executor] skip_nonfinite_steps: {rolled} of "
-                      f"{k_steps} fused step(s) rolled back in-graph "
-                      f"(first at slab step {first}: {int(v[first])} "
-                      f"non-finite value(s) across outputs/state, "
-                      f"first offender {name})")
-            else:
-                raise NonFiniteError(
-                    f"Operator output contains Inf/Nan "
-                    f"(FLAGS_check_nan_inf): fused step "
-                    f"{first}/{k_steps} of program_{program._uid} "
-                    f"produced {int(v[first])} non-finite value(s) "
-                    f"across outputs/state; first offender {name}. "
-                    f"Feed data, learning rate, or loss scaling are "
-                    f"the usual suspects.",
-                    var_name=name, count=int(v[first]))
+            from .. import profiler as _prof
+            if fresh_compile:
+                step_fn = build_block_fn(
+                    opt_prog, 0, list(feed_arrays.keys()), fetch_names,
+                    state_in, state_out, mesh=mesh)
+                feed_row = {n: jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                            for n, a in feed_arrays.items()}
+                _, new_state_s, _ = jax.eval_shape(
+                    step_fn, state_mut, state_ro, feed_row, base_key)
+                mut_names = [n for n in state_in if n in state_out_set]
+                slot_names = (["fetched output " + repr(n)
+                               for n in fetch_names]
+                              + ["updated variable " + repr(n)
+                                 for n in new_state_s])
+                wo_avals = {n: jax.ShapeDtypeStruct(s.shape, s.dtype)
+                            for n, s in new_state_s.items()
+                            if n not in state_mut}
+
+            # write-only persistable outputs ride the scan carry so a
+            # rolled-back step restores what the scope held (sequential-skip
+            # parity); vars the scope has never seen are seeded with zeros
+            # and un-committed below if every step rolled back
+            absent_wo = set()
+            for n, aval in wo_avals.items():
+                v = scope.find_var(n)
+                if v is None:
+                    v = np.zeros(aval.shape, aval.dtype)
+                    absent_wo.add(n)
+                state_mut[n] = v
+            if mesh is not None and wo_avals:
+                tmp = {n: state_mut[n] for n in wo_avals}
+                _shard_state(tmp, mesh, program)
+                state_mut.update(tmp)
+
+            if fresh_compile:
+                fn = build_multi_step_fn(
+                    opt_prog, 0, list(feed_arrays.keys()), fetch_names,
+                    state_in, state_out, mut_names, mesh=mesh,
+                    guard=guard,
+                    skip_nonfinite=bool(skip_nonfinite_steps),
+                    unroll=unroll,
+                    viol_axes=hier_axes)
+                if hier_on:
+                    from .lowering import wrap_hier_dp_steps
+                    jitted = jax.jit(wrap_hier_dp_steps(fn, mesh, feed_arrays),
+                                     donate_argnums=(0,))
+                elif mesh is not None:
+                    jitted = _jit_with_mesh_steps(fn, mesh)
+                else:
+                    jitted = jax.jit(fn, donate_argnums=(0,))
+                compiled = self._lower_and_compile(
+                    jitted, f"fused_program_{program._uid}_x{k_steps}",
+                    (state_mut, state_ro, feed_arrays, base_key))
+                if use_program_cache:
+                    self._cache[cache_key] = (compiled, jitted, state_in,
+                                              state_out, mut_names,
+                                              slot_names, wo_avals,
+                                              state_fetches)
+                # batch_dim=1: the slab's leading K axis replicates by
+                # design; the batch dim the dp axis should shard sits
+                # under it
+                self._maybe_shard_obs("train", cache_key, compiled, mesh,
+                                      program, tuple(feed_arrays),
+                                      batch_dim=1)
+                if hier_on and _flag("dcn_assert_hier"):
+                    # pre-burn gate: parse the compiled HLO and prove the
+                    # hierarchical decomposition landed — DCN-priced traffic
+                    # only on the designated axes, cross-slice wire bytes
+                    # strictly below the flat all-reduce — BEFORE the first
+                    # slab is dispatched to hardware
+                    from ..observability.comms import assert_hier_decomposition
+                    assert_hier_decomposition(
+                        compiled, mesh,
+                        where=f"fused_program_{program._uid}_x{k_steps}")
+
+        with _trace.loop_span("executor/dispatch"):
+            # chaos point for the training dispatch stage: fires BEFORE the
+            # executable runs, so the scope still holds pre-slab state and a
+            # supervised restart resumes bitwise from the last checkpoint
+            _maybe_fail("train.dispatch")
+            if hier_axes:
+                # chaos point for the cross-slice reduction stage: raising
+                # simulates a slice whose DCN collective fails; delay=
+                # simulates a straggling slice stretching the step
+                _maybe_fail("train.allreduce_dcn")
+            profiling = _prof.is_profiling()
+            t0 = time.perf_counter()
+            fetches, final_state, final_key, viols, slots = self._invoke(
+                compiled, jitted, (state_mut, state_ro, feed_arrays, base_key),
+                f"fused_program_{program._uid}_x{k_steps}",
+                cache_key if use_program_cache else None)
+            if profiling:
+                t1 = time.perf_counter()
+                jax.block_until_ready(fetches if fetches else final_key)
+                span = time.perf_counter() - t0
+                _prof.record_duration(
+                    f"dispatch/program_{program._uid}_x{k_steps}", t1 - t0)
+                _prof.record_duration(
+                    f"scan/program_{program._uid}_x{k_steps}", span)
+                _prof.record_step_time(span / k_steps, k_steps)
+        with _trace.loop_span("executor/commit"):
+            self._observe_utilization("train", cache_key, compiled)
+
+            v = np.asarray(viols) if guard else None  # ONE small readback
+            # commit (buffers were donated); guard diagnostics after. If
+            # EVERY step rolled back, scope-absent write-only vars stay
+            # uncommitted — K sequential skipped run() calls never create
+            # them either (their committed value would be the zeros seed).
+            all_rolled = bool(skip_nonfinite_steps and v is not None
+                              and v.size and (v > 0).all())
+            for n, val in final_state.items():
+                if all_rolled and n in absent_wo:
+                    continue
+                scope.set(n, val)
+            scope.set(RNG_STATE_NAME, final_key)
+
+            if guard and v.any():
+                first = int(np.argmax(v > 0))
+                name = self._slot_name(slots, first, slot_names)
+                _flightrec().record(
+                    "nonfinite", program=program._uid, var=name,
+                    count=int(v[first]), where=f"fused step {first}",
+                    rolled_back=bool(skip_nonfinite_steps))
+                if skip_nonfinite_steps:
+                    rolled = int((v > 0).sum())
+                    print(f"[executor] skip_nonfinite_steps: {rolled} of "
+                          f"{k_steps} fused step(s) rolled back in-graph "
+                          f"(first at slab step {first}: {int(v[first])} "
+                          f"non-finite value(s) across outputs/state, "
+                          f"first offender {name})")
+                else:
+                    raise NonFiniteError(
+                        f"Operator output contains Inf/Nan "
+                        f"(FLAGS_check_nan_inf): fused step "
+                        f"{first}/{k_steps} of program_{program._uid} "
+                        f"produced {int(v[first])} non-finite value(s) "
+                        f"across outputs/state; first offender {name}. "
+                        f"Feed data, learning rate, or loss scaling are "
+                        f"the usual suspects.",
+                        var_name=name, count=int(v[first]))
 
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with _trace.loop_span("executor/fetch_wait"):
+                return [np.asarray(f) for f in fetches]
         return fetches
 
     @staticmethod

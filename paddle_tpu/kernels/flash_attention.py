@@ -296,6 +296,7 @@ def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret):
                  m_sc, acc_sc, l_sc, v_sc)
     out, lse = pl.pallas_call(
         kern,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -480,6 +481,7 @@ def _bwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
                      delta_ref, dq_ref, dk_ref, dv_ref, dk_sc, dv_sc)
         dq, dk, dv = pl.pallas_call(
             kern,
+            name="flash_attention_bwd",
             grid=(B, H, nq),
             in_specs=specs + [qspec, rspec, rspec],
             out_specs=[qspec, kspec, kspec],
@@ -512,6 +514,7 @@ def _bwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
                     dq_ref, dq_sc)
     dq = pl.pallas_call(
         dq_kern,
+        name="flash_attention_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=dq_specs + [qspec, rspec, rspec],
         out_specs=qspec,
@@ -540,6 +543,7 @@ def _bwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
                      dk_ref, dv_ref, dk_sc, dv_sc)
     dk, dv = pl.pallas_call(
         dkv_kern,
+        name="flash_attention_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=dkv_specs + [qspec_i, rspec_i, rspec_i],
         out_specs=[kspec_o, kspec_o],

@@ -226,7 +226,7 @@ def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
                         pltpu.VMEM((1, D), jnp.float32)],
     )
     return pl.pallas_call(
-        kern, grid_spec=grid_spec,
+        kern, name="paged_attention_decode", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), *args)

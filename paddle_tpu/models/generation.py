@@ -23,8 +23,8 @@ import time
 
 import numpy as np
 
-from .. import profiler as _prof
 from ..flags import flag
+from ..observability import tracing as _trace
 from ..observability import utilization as _util
 from . import gpt
 
@@ -221,8 +221,10 @@ class GPTGenerator:
                             temperature=0.8, top_k=40, seed=7)
 
     ``stats`` (a ``serving.ServingStats``) routes per-stage latencies
-    into the prefill/decode/sample histograms; the same spans land in
-    ``paddle_tpu.profiler`` event tables while profiling is active.
+    into the prefill/decode/sample histograms (``serving/<stage>`` rows
+    of ``paddle_tpu.profiler``'s event table while profiling is
+    active); every call leaves a ``generator/dispatch`` and a
+    ``generator/wait`` loop span (``observability.tracing``).
     """
 
     def __init__(self, cfg, scope=None, *, max_len=None, bucket_min=None,
@@ -516,7 +518,10 @@ class GPTGenerator:
             ((f"__program__/{kind}", (), "meta"),)
             + feed_signature(feed)))
 
-    def _invoke(self, kind, stage, feed, key):
+    def _invoke(self, kind, stage, feed, key, parent=None):
+        """Run the ``kind`` executable on ``feed``. ``parent`` is the
+        loop span that caused the call, for a caller on another thread
+        than the span's (the decode step under its watchdog)."""
         import jax
         jitted, state = self._ensure_fn(kind)
         sig = self._signature(kind, feed)
@@ -534,11 +539,10 @@ class GPTGenerator:
             rest = {n: jax.device_put(a, rep) for n, a in rest.items()}
             key = jax.device_put(key, rep)
         compiled = self.cache.get(sig)
-        if compiled is None:
+        fresh = compiled is None
+        if fresh:
             t0 = time.perf_counter()
-            with _prof.record_event(f"decode/compile_{kind}"):
-                compiled = jitted.lower(state, caches, rest,
-                                        key).compile()
+            compiled = jitted.lower(state, caches, rest, key).compile()
             dt = time.perf_counter() - t0
             from ..serving.engine import ServingEngine
             self.cache.put(sig, compiled,
@@ -559,21 +563,22 @@ class GPTGenerator:
             if self.stats:
                 self.stats.bump("compiles")
                 self.stats.hist["compile"].observe(dt)
-            # (no stats: the record_event above already logged the span)
-        t0 = time.perf_counter()
-        fetches, new_key = compiled(state, caches, rest, key)
-        # block before recording so the span holds device time, not
-        # dispatch time (the per-token loop is serial anyway — the next
-        # step needs this token)
-        jax.block_until_ready(fetches)
-        dt = time.perf_counter() - t0
+        # two spans, so that a trace tells the host's dispatch from the
+        # wait for the device (the per-token loop is serial anyway —
+        # the next step needs this token); their two outer clock reads
+        # are the one interval every consumer below takes
+        with _trace.loop_span("generator/dispatch", parent, kind=kind,
+                              stage=stage, compiled=fresh) as sent:
+            fetches, new_key = compiled(state, caches, rest, key)
+        with _trace.loop_span("generator/wait", parent, kind=kind,
+                              stage=stage) as waited:
+            jax.block_until_ready(fetches)
+        dt = waited.t1 - sent.t0
         cost = _util.cost_for(self._exec_costs, sig, compiled)
         if cost:
             _util.observe_execution(stage, cost, dt)
         if self.stats:
             self.stats.hist[stage].observe(dt)
-        else:
-            _prof.record_duration(f"decode/{stage}", dt)
         return fetches, new_key
 
     # -- stage runners ----------------------------------------------------
@@ -593,11 +598,12 @@ class GPTGenerator:
         logits, caches = self._unpack_caches(fetches)
         return logits, caches, key
 
-    def _run_decode(self, token, pos, caches, key):
+    def _run_decode(self, token, pos, caches, key, parent=None):
         feed = dict(caches)
         feed["token"] = token
         feed["pos"] = pos
-        fetches, key = self._invoke("decode", "decode", feed, key)
+        fetches, key = self._invoke("decode", "decode", feed, key,
+                                    parent=parent)
         logits, caches = self._unpack_caches(fetches)
         return logits, caches, key
 

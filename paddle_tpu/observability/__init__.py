@@ -70,8 +70,9 @@ from .sharding import (  # noqa: F401
 )
 from .slo import SloMonitor, SloRule, default_server_rules  # noqa: F401
 from .tracing import (  # noqa: F401
-    SpanContext, ambient, current, from_wire, maybe_trace, new_trace,
-    record_child, record_span, span, to_wire,
+    SpanContext, ambient, current, current_loop, from_wire, loop_root,
+    loop_span, loop_spans, maybe_trace, new_trace, record_child,
+    record_span, span, to_wire,
 )
 from .utilization import (  # noqa: F401
     dcn_peak, executable_cost, hbm_peak, ici_peak, observe_execution,

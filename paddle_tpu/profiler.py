@@ -17,14 +17,15 @@ import time
 import numpy as np
 
 _events = {}          # name -> [calls, total_s, max_s, min_s]
-# (name, start_s, end_s, tid[, trace_id, span_id, parent_id]) — the
-# unified timeline source: profiler events AND sampled request-trace
-# spans (observability.tracing) land here, so tools/timeline.py renders
-# one Chrome trace interleaving both. A deque: at the _MAX_SPANS cap a
-# bounded PROFILING session keeps the first N (a run's head is what a
-# bench wants), while the always-on traced stream of a long-lived
-# server rotates the OLDEST span out (a postmortem wants the newest) —
-# either way drops are counted, never silent
+# (name, start_s, end_s, tid[, trace_id, span_id, parent_id[, attrs]])
+# — the unified timeline source: profiler events, sampled request-trace
+# spans AND the always-on loop spans (observability.tracing) land here,
+# so tools/timeline.py renders one Chrome trace interleaving them. A
+# deque: at the _MAX_SPANS cap a bounded PROFILING session keeps the
+# first N (a run's head is what a bench wants), while the always-on
+# traced and loop streams of a long-lived server rotate the OLDEST span
+# out (a postmortem wants the newest) — either way drops are counted,
+# never silent
 import collections as _collections
 _spans = _collections.deque()
 # the traced stream appends from server threads while a driver may be
@@ -70,7 +71,7 @@ def _record(name, seconds, start=None):
                 _spans_dropped_cum += 1
 
 
-def record_span(name, start_s, end_s, trace=None):
+def record_span(name, start_s, end_s, trace=None, attrs=None):
     """Append a completed span to the unified span table. ``trace`` is
     an optional ``(trace_id, span_id, parent_id)`` triple from
     ``observability.tracing``; TRACED spans record even while profiling
@@ -79,19 +80,38 @@ def record_span(name, start_s, end_s, trace=None):
     ``_MAX_SPANS`` cap an active profiling session keeps the FIRST N
     spans, the always-on traced stream rotates the oldest out — a
     long-lived server's stream never silently dies; drops are counted
-    either way (:func:`spans_dropped`)."""
+    either way (:func:`spans_dropped`).
+
+    ``attrs`` (a dict of counts at the span's boundary) marks a LOOP
+    span (``tracing.loop_span``): the row carries it as an eighth field
+    and always rotates at the cap, profiling session or not — the ring
+    is what an operator reads to see what the loop did last."""
     global _spans_dropped, _spans_dropped_cum
     if trace is None and not _active:
         return
     row = (name, float(start_s), float(end_s), threading.get_ident())
+    if trace is not None:
+        row += tuple(trace)
+        if attrs is not None:
+            row += (attrs,)
     with _spans_lock:
         if len(_spans) >= _MAX_SPANS:
             _spans_dropped += 1
             _spans_dropped_cum += 1
-            if _active:
+            if _active and attrs is None:
                 return          # profiling session: keep the run's head
             _spans.popleft()    # traced stream: keep the newest
-        _spans.append(row if trace is None else row + tuple(trace))
+        _spans.append(row)
+
+
+def spans_between(since_s, until_s):
+    """Snapshot of the traced rows (those that carry ids) that overlap
+    ``[since_s, until_s]`` on the ``perf_counter`` clock, each padded
+    to eight fields (``attrs`` {} where the row has none)."""
+    with _spans_lock:
+        rows = [s for s in _spans
+                if len(s) >= 7 and s[2] >= since_s and s[1] <= until_s]
+    return [s if len(s) == 8 else s + ({},) for s in rows]
 
 
 # counter track: (name, t_s, value) samples — the memory profiler's

@@ -118,15 +118,22 @@ class BadRequestError(ServingError):
     fixing the input will not help."""
 
 
-def _record_queue_span(req, now):
-    """One copy of the queue-span arithmetic for both batchers: the
-    span ends NOW and covers the monotonic time since enqueue, re-based
-    onto the profiler's perf_counter clock."""
-    if req.trace is None:
+def _record_stage_span(req, name, now, stats, stage):
+    """One copy of the request-stage arithmetic for both batchers: the
+    stage began at enqueue and ends NOW (monotonic). Its length goes to
+    the ``stage`` histogram of ``stats`` (where the batcher has any)
+    and, re-based onto the profiler's perf_counter clock, to a span
+    ``name``: for every generate request (under its ``span_root``),
+    for a sampled infer request (under the client's context)."""
+    lasted = now - req.t_enqueue
+    if stats:
+        stats.hist[stage].observe(lasted)
+    root = getattr(req, "span_root", req.trace)
+    if root is None:
         return
     pc = time.perf_counter()
-    _trace.record_child("serving/queue", pc - (now - req.t_enqueue), pc,
-                        req.trace)
+    _trace.record_child(name, pc - lasted, pc, root,
+                        getattr(req, "span_attrs", None))
 
 
 class Request:
@@ -474,7 +481,7 @@ class GenerationRequest(Request):
 
     __slots__ = ("prompt", "max_new_tokens", "temperature", "top_k",
                  "eos_id", "out_tokens", "slot", "export_kv", "kv",
-                 "first_token")
+                 "first_token", "span_root")
 
     def __init__(self, prompt, max_new_tokens=32, temperature=0.0,
                  top_k=0, eos_id=None, deadline_ms=None,
@@ -499,6 +506,10 @@ class GenerationRequest(Request):
         self.rows = 1
         self.example_sig = None
         self._init_lifecycle(deadline_ms, priority)
+        # the decode loop records serving/queue, serving/first_token and
+        # serving/generate for EVERY request, under the client's trace
+        # where it sent one and else under an id minted here
+        self.span_root = _trace.request_root(self.trace)
         self.prompt = prompt
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
@@ -510,6 +521,11 @@ class GenerationRequest(Request):
         self.kv = kv
         self.first_token = None if first_token is None \
             else int(first_token)
+
+    @property
+    def span_attrs(self):
+        return {"prompt_len": int(self.prompt.size),
+                "new_tokens": len(self.out_tokens)}
 
 
 class SwapHandle:
@@ -603,6 +619,11 @@ class DecodeBatcher:
         self._admitting = 0     # popped from the queue, not yet in a slot
         self._admitting_reqs = []
         self._steps_since_sweep = 0             # paged-pool leak sweep
+        # loop spans (observability.tracing): the trace every round of
+        # this batcher belongs to, and the step index its rounds carry
+        self._span_root = _trace.loop_root(
+            f"loop:{id(self) & 0xffffff:x}")
+        self._steps = 0
         # chunked-prefill states (engine.start_prefill dicts): rows
         # whose prompt is being ingested one chunk per decode round —
         # they hold a slot but are not yet in _active
@@ -728,11 +749,12 @@ class DecodeBatcher:
                 self.stats.bump("requests_failed")
             return
         req.set_result([np.asarray(req.out_tokens, np.int32)])
-        record_class_done(req.priority, time.monotonic() - req.t_enqueue)
+        now = time.monotonic()
+        record_class_done(req.priority, now - req.t_enqueue)
+        _record_stage_span(req, "serving/generate", now, self.stats,
+                           "total")
         if self.stats:
             self.stats.bump("requests_completed")
-            self.stats.hist["total"].observe(
-                time.monotonic() - req.t_enqueue)
 
     def _deliver_token(self, req, tok):
         """Record one sampled token; finish the row on EOS or budget.
@@ -741,6 +763,10 @@ class DecodeBatcher:
             self._finish(req)
             return False
         req.out_tokens.append(tok)
+        if len(req.out_tokens) == 1:
+            _record_stage_span(req, "serving/first_token",
+                               time.monotonic(), self.stats,
+                               "first_token")
         if self.stats:
             self.stats.bump("tokens_generated")
         if len(req.out_tokens) >= req.max_new_tokens:
@@ -888,8 +914,11 @@ class DecodeBatcher:
 
     # -- admission --------------------------------------------------------
     def _admit(self, epoch=None):
+        """Take what the queue holds into free slots; returns how many
+        requests it took."""
         try:
-            self._admit_inner(self._epoch if epoch is None else epoch)
+            return self._admit_inner(
+                self._epoch if epoch is None else epoch)
         except BaseException:
             # a crash mid-collection (e.g. an injected queue fault on
             # the SECOND pop) must not silently drop the requests
@@ -951,11 +980,20 @@ class DecodeBatcher:
                 if self.stats:
                     self.stats.bump("requests_failed")
                 continue
-            _record_queue_span(req, now)
+            _record_stage_span(req, "serving/queue", now, self.stats,
+                               "queue")
             take.append(req)
             self._admitting = len(take)
-        if not take:
-            return
+        if take:
+            with _trace.loop_span(
+                    "serving/admit", rows=len(take),
+                    prompt_max=int(max(r.prompt.size for r in take))):
+                self._place(take, epoch)
+        return len(take)
+
+    def _place(self, take, epoch):
+        """Prefill (or import) the requests ``_admit_inner`` took off
+        the queue into free slots and deliver their first tokens."""
         if self._epoch != epoch:
             for req in take:
                 if not req.done():
@@ -1195,133 +1233,14 @@ class DecodeBatcher:
         try:
             while not self._stop.is_set() and self._epoch == epoch:
                 self.heartbeat = time.monotonic()
-                sw = self._swap
-                if sw is not None:
-                    # a pending swap stops admission so the bank drains;
-                    # in-flight rows (decoding OR mid chunked-prefill)
-                    # keep running on the old weights
-                    if not self._active and not self._prefilling:
-                        sw.apply()
-                        with self._swap_lock:
-                            if self._swap is sw:
-                                self._swap = None
-                        continue
-                else:
-                    self._admit(epoch)
-                if not self._active and not self._prefilling:
-                    continue
-                self._check_deadlines(time.monotonic())
-                self._advance_prefill(epoch)
-                if self._epoch != epoch:
+                with _trace.loop_span("serving/round",
+                                      self._span_root) as rnd:
+                    alive = self._round(epoch, rnd.attrs)
+                    # an empty poll of the queue is no round: nobody
+                    # wants 20 rows a second of an idle server
+                    rnd.dropped = not rnd.attrs
+                if not alive:
                     return
-                if not self._active:
-                    continue
-                # paged pool: allocation-on-append for the live rows;
-                # rows the pool cannot grow are shed TYPED while the
-                # rest of the bank keeps decoding (their freed blocks
-                # unblock the next step's growth)
-                # speculative rows draft BEFORE the allocation pass so
-                # the whole verify span [pos, pos + nd + 1) is covered
-                # by blocks (and COW-duplicated when shared) up front
-                drafts = nd = None
-                if self.spec_k > 0 and self._active:
-                    drafts, nd = self._propose_drafts(self.spec_k)
-                prep = getattr(self.engine, "prepare_step", None)
-                if prep is not None:
-                    widths = None
-                    if nd is not None:
-                        widths = {slot: int(nd[slot]) + 1
-                                  for slot in self._active}
-                    shed = prep({slot: int(self._pos[slot])
-                                 for slot in self._active},
-                                widths=widths)
-                    for slot, exc in shed.items():
-                        req = self._active.get(slot)
-                        if req is None:
-                            continue
-                        if isinstance(exc, ServerOverloadedError):
-                            # overload shed, not a failure: same
-                            # bookkeeping as the admission-time shed
-                            # (shed_overload only, no requests_failed),
-                            # then reclaim the slot + its blocks
-                            if not req.done():
-                                req.set_error(exc)
-                            if self.stats:
-                                self.stats.bump("shed_overload")
-                            self._finish(req)
-                        else:
-                            self._finish(req, exc)
-                    if not self._active:
-                        continue
-                # per-token spans for TRACED rows only (sampled at the
-                # client edge): untraced traffic pays one list-comp over
-                # <= slots entries per step
-                traced = [r for r in self._active.values()
-                          if r.trace is not None]
-                t_step0 = time.perf_counter()
-                try:
-                    if drafts is not None:
-                        live_mask = np.zeros((self.slots,), bool)
-                        live_mask[list(self._active)] = True
-                        out, acc = self.engine.spec_step(
-                            self._tok, self._pos, self._temp,
-                            self._topk, drafts, nd, live_mask,
-                            budget=self.watchdog_s or None)
-                    else:
-                        toks = self.engine.step(
-                            self._tok, self._pos, self._temp,
-                            self._topk, budget=self.watchdog_s or None)
-                except Exception as exc:  # noqa: BLE001
-                    if self._epoch != epoch:
-                        return       # deposed mid-step: restart() owns
-                    self.consecutive_failures += 1      # the row state
-                    if self.stats:
-                        self.stats.bump("engine_failures")
-                        if isinstance(exc, WatchdogTimeout):
-                            self.stats.bump("watchdog_timeouts")
-                    for req in list(self._active.values()):
-                        self._finish(req, exc)
-                    continue
-                if self._epoch != epoch:
-                    # deposed while blocked in the step (hung chip call
-                    # that eventually returned): the restarted loop owns
-                    # _active/_free now — do not touch them
-                    return
-                self.consecutive_failures = 0
-                if traced:
-                    t_step1 = time.perf_counter()
-                    for r in traced:
-                        _trace.record_child("serving/decode", t_step0,
-                                            t_step1, r.trace)
-                live = len(self._active)
-                if self.stats:
-                    # inter-token latency: the WHOLE step's wall time
-                    # (decode + sample + any stall), the signal the SLO
-                    # monitor's default p99 rule evaluates windowed
-                    self.stats.hist["token"].observe(
-                        time.perf_counter() - t_step0)
-                    self.stats.observe_decode_step(live, self.slots)
-                if drafts is not None:
-                    self._deliver_spec(out, acc, nd)
-                else:
-                    for slot in list(self._active):
-                        req = self._active[slot]
-                        if req.done():      # abandoned by its waiter
-                            self._finish(req)
-                            continue
-                        self._pos[slot] += 1
-                        self._tok[slot] = toks[slot]
-                        self._deliver_token(req, int(toks[slot]))
-                # periodic paged-pool leak sweep: blocks held by slots
-                # no longer active are a bug — reclaim + flight-record
-                # them instead of bleeding capacity
-                self._steps_since_sweep += 1
-                if self._steps_since_sweep >= 256:
-                    self._steps_since_sweep = 0
-                    sweep = getattr(self.engine, "reclaim_leaks", None)
-                    if sweep is not None:
-                        sweep(list(self._active)
-                              + [st["slot"] for st in self._prefilling])
         finally:
             # rows still mid-generation when the loop exits (stop() or
             # a crash) must fail fast, not leave their clients waiting.
@@ -1353,6 +1272,157 @@ class DecodeBatcher:
                         "decode loop exited with the weight swap "
                         "pending"))
 
+    def _round(self, epoch, attrs):
+        """One iteration of the loop: admit, advance a chunked prefill,
+        step the bank, deliver. ``attrs`` are the ``serving/round``
+        span's: left empty, the iteration did nothing and is not
+        recorded. Returns False once the loop is deposed."""
+        sw = self._swap
+        if sw is not None:
+            # a pending swap stops admission so the bank drains;
+            # in-flight rows (decoding OR mid chunked-prefill)
+            # keep running on the old weights
+            if not self._active and not self._prefilling:
+                sw.apply()
+                with self._swap_lock:
+                    if self._swap is sw:
+                        self._swap = None
+                return True
+        else:
+            admitted = self._admit(epoch)
+            if admitted:
+                attrs["admitted"] = admitted
+        if not self._active and not self._prefilling:
+            return True
+        self._check_deadlines(time.monotonic())
+        if self._prefilling:
+            attrs["prefilling"] = len(self._prefilling)
+            self._advance_prefill(epoch)
+        if self._epoch != epoch:
+            return False
+        if not self._active:
+            return True
+        # paged pool: allocation-on-append for the live rows;
+        # rows the pool cannot grow are shed TYPED while the
+        # rest of the bank keeps decoding (their freed blocks
+        # unblock the next step's growth)
+        # speculative rows draft BEFORE the allocation pass so
+        # the whole verify span [pos, pos + nd + 1) is covered
+        # by blocks (and COW-duplicated when shared) up front
+        drafts = nd = None
+        if self.spec_k > 0 and self._active:
+            drafts, nd = self._propose_drafts(self.spec_k)
+        prep = getattr(self.engine, "prepare_step", None)
+        if prep is not None:
+            with _trace.loop_span("serving/prepare_step") as prepared:
+                widths = None
+                if nd is not None:
+                    widths = {slot: int(nd[slot]) + 1
+                              for slot in self._active}
+                shed = prep({slot: int(self._pos[slot])
+                             for slot in self._active},
+                            widths=widths)
+                self._shed_rows(shed)
+                prepared.attrs["shed"] = len(shed)
+            if not self._active:
+                return True
+        # per-token spans for TRACED rows only (sampled at the
+        # client edge): untraced traffic pays one list-comp over
+        # <= slots entries per step
+        traced = [r for r in self._active.values()
+                  if r.trace is not None]
+        self._steps += 1
+        attrs["step"] = self._steps
+        attrs["live"] = len(self._active)
+        pool = getattr(self.engine, "pool", None)
+        if pool is not None:
+            attrs["blocks_in_use"] = pool.blocks_in_use()
+            attrs["blocks_total"] = pool.capacity_blocks
+        try:
+            with _trace.loop_span("engine/step") as stepped:
+                if drafts is not None:
+                    live_mask = np.zeros((self.slots,), bool)
+                    live_mask[list(self._active)] = True
+                    out, acc = self.engine.spec_step(
+                        self._tok, self._pos, self._temp,
+                        self._topk, drafts, nd, live_mask,
+                        budget=self.watchdog_s or None)
+                else:
+                    toks = self.engine.step(
+                        self._tok, self._pos, self._temp,
+                        self._topk, budget=self.watchdog_s or None)
+        except Exception as exc:  # noqa: BLE001
+            if self._epoch != epoch:
+                return False     # deposed mid-step: restart() owns
+            self.consecutive_failures += 1      # the row state
+            if self.stats:
+                self.stats.bump("engine_failures")
+                if isinstance(exc, WatchdogTimeout):
+                    self.stats.bump("watchdog_timeouts")
+            for req in list(self._active.values()):
+                self._finish(req, exc)
+            return True
+        if self._epoch != epoch:
+            # deposed while blocked in the step (hung chip call
+            # that eventually returned): the restarted loop owns
+            # _active/_free now — do not touch them
+            return False
+        self.consecutive_failures = 0
+        for r in traced:
+            _trace.record_child("serving/decode", stepped.t0,
+                                stepped.t1, r.trace)
+        if self.stats:
+            # inter-token latency: the WHOLE step's wall time
+            # (decode + sample + any stall), the signal the SLO
+            # monitor's default p99 rule evaluates windowed
+            self.stats.hist["token"].observe(stepped.t1 - stepped.t0)
+            self.stats.observe_decode_step(attrs["live"], self.slots)
+        with _trace.loop_span("serving/deliver") as delivered:
+            before = len(self._active)
+            if drafts is not None:
+                self._deliver_spec(out, acc, nd)
+            else:
+                for slot in list(self._active):
+                    req = self._active[slot]
+                    if req.done():      # abandoned by its waiter
+                        self._finish(req)
+                        continue
+                    self._pos[slot] += 1
+                    self._tok[slot] = toks[slot]
+                    self._deliver_token(req, int(toks[slot]))
+            delivered.attrs["finished"] = before - len(self._active)
+        # periodic paged-pool leak sweep: blocks held by slots
+        # no longer active are a bug — reclaim + flight-record
+        # them instead of bleeding capacity
+        self._steps_since_sweep += 1
+        if self._steps_since_sweep >= 256:
+            self._steps_since_sweep = 0
+            sweep = getattr(self.engine, "reclaim_leaks", None)
+            if sweep is not None:
+                sweep(list(self._active)
+                      + [st["slot"] for st in self._prefilling])
+        return True
+
+    def _shed_rows(self, shed):
+        """Finish the rows the pool could not grow (``prepare_step``'s
+        ``{slot: exception}``)."""
+        for slot, exc in shed.items():
+            req = self._active.get(slot)
+            if req is None:
+                continue
+            if isinstance(exc, ServerOverloadedError):
+                # overload shed, not a failure: same
+                # bookkeeping as the admission-time shed
+                # (shed_overload only, no requests_failed),
+                # then reclaim the slot + its blocks
+                if not req.done():
+                    req.set_error(exc)
+                if self.stats:
+                    self.stats.bump("shed_overload")
+                self._finish(req)
+            else:
+                self._finish(req, exc)
+
 
 def next_bucket(rows, min_bucket=1):
     """Smallest power-of-two >= rows (>= min_bucket): bounded padding
@@ -1372,6 +1442,7 @@ class MicroBatcher:
     serially, which is exactly what a single-TPU serving process wants
     (the chip is the bottleneck resource; concurrency lives in the
     connection threads)."""
+
 
     def __init__(self, queue, execute_fn, max_batch_size=None,
                  batch_timeout_ms=None, stats=None, watchdog_s=None):
@@ -1489,9 +1560,8 @@ class MicroBatcher:
                 req.expire(now, where="batcher")
             else:
                 req.t_flush = now
-                if self.stats:
-                    self.stats.hist["queue"].observe(now - req.t_enqueue)
-                _record_queue_span(req, now)
+                _record_stage_span(req, "serving/queue", now,
+                                   self.stats, "queue")
                 live.append(req)
         if not live:
             return

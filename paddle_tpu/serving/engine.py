@@ -172,9 +172,7 @@ class ServingEngine:
         from .. import profiler as _prof
         maybe_fail("serving.compile")
         t0 = time.perf_counter()
-        with _prof.record_event("serving/compile_inner"):
-            lowered = self._infer.lower(self._state, feed)
-            compiled = lowered.compile()
+        compiled = self._infer.lower(self._state, feed).compile()
         dt = time.perf_counter() - t0
         nbytes = self._executable_bytes(compiled, feed)
         sig = feed_signature(feed)
@@ -648,45 +646,53 @@ class GenerationEngine:
         self._ensure_caches()
         t0 = time.perf_counter()
         n = len(requests)
-        tokens, pos_ids, last = self.gen._pack_prompts(
-            [req.prompt for req in requests])
-        bb = tokens.shape[0]
-        temp = np.zeros((bb,), np.float32)
-        topk = np.zeros((bb,), np.int32)
-        for r, req in enumerate(requests):
-            temp[r] = req.temperature
-            topk[r] = req.top_k
+        span = _trace.loop_span
+        with span("engine/pack", rows=n):
+            tokens, pos_ids, last = self.gen._pack_prompts(
+                [req.prompt for req in requests])
+            bb = tokens.shape[0]
+            temp = np.zeros((bb,), np.float32)
+            topk = np.zeros((bb,), np.int32)
+            for r, req in enumerate(requests):
+                temp[r] = req.temperature
+                topk[r] = req.top_k
 
         if self.pool is not None:
             # allocate each row's prompt blocks BEFORE the prefill (the
             # scatter routes through the tables); a mid-batch failure
             # rolls this batch's allocations back untouched
             allocated = []
-            try:
-                for req, slot in zip(requests, slot_ids):
-                    self.pool.free_slot(slot)   # stale holder (if any)
-                    self.pool.alloc(slot, int(req.prompt.size))
-                    allocated.append(slot)
-            except Exception:
-                for sl in allocated:
-                    self.pool.free_slot(sl)
-                raise
-        logits, row_caches, self._key = self.gen._run_prefill(
-            tokens, pos_ids, last, self._key)
-        toks, self._key = self.gen._run_sample(logits, temp, topk,
-                                               self._key)
+            with span("pool/alloc", rows=n):
+                try:
+                    for req, slot in zip(requests, slot_ids):
+                        self.pool.free_slot(slot)   # stale holder (if any)
+                        self.pool.alloc(slot, int(req.prompt.size))
+                        allocated.append(slot)
+                except Exception:
+                    for sl in allocated:
+                        self.pool.free_slot(sl)
+                    raise
+        with span("generator/prefill", rows=n):
+            logits, row_caches, self._key = self.gen._run_prefill(
+                tokens, pos_ids, last, self._key)
+        with span("generator/sample", rows=n):
+            toks, self._key = self.gen._run_sample(logits, temp, topk,
+                                                   self._key)
         if self.pool is not None:
-            try:
-                self.pool.scatter_prefill(list(slot_ids), row_caches,
-                                          tokens.shape[1])
-            except Exception:
-                # the donated device pool is lost (scatter dropped it);
-                # this batch's blocks go back, the batcher fails the
-                # other active rows via bank_lost
-                for sl in slot_ids:
-                    self.pool.free_slot(sl)
-                self.bank_lost = True
-                raise
+            # (rows, blocks) is the pair the scatter's jit retraces on
+            with span("pool/scatter", rows=n,
+                      blocks=self.pool.blocks_for_tokens(tokens.shape[1])):
+                try:
+                    self.pool.scatter_prefill(list(slot_ids), row_caches,
+                                              tokens.shape[1])
+                except Exception:
+                    # the donated device pool is lost (scatter dropped
+                    # it); this batch's blocks go back, the batcher
+                    # fails the other active rows via bank_lost
+                    for sl in slot_ids:
+                        self.pool.free_slot(sl)
+                    self.bank_lost = True
+                    raise
         else:
             self._insert(row_caches, list(slot_ids))
         if self.pool is not None and self.pool.prefix_enabled:
@@ -696,7 +702,8 @@ class GenerationEngine:
             # prompt prefix adopt them instead of recomputing
             for req, slot in zip(requests, slot_ids):
                 self.pool.prefix_insert(req.prompt, slot)
-        out = np.asarray(toks)[:n]
+        with span("engine/fetch", rows=n):
+            out = np.asarray(toks)[:n]
         t1 = time.perf_counter()
         for req in requests:
             if getattr(req, "trace", None) is not None:
@@ -871,6 +878,9 @@ class GenerationEngine:
         never resurrect a bank this thread already dropped."""
         maybe_fail("serving.decode_step")
         self._ensure_caches()
+        # the caller's span (the batcher's engine/step): the watchdog's
+        # worker thread is inside none, so the parent goes with the call
+        span = _trace.current_loop()
         tok = np.ascontiguousarray(tokens, dtype=np.int32)
         posc = np.ascontiguousarray(pos, dtype=np.int32)
         key = self._key
@@ -885,7 +895,8 @@ class GenerationEngine:
             kind = f"decode_paged_{self.pool.dtype}"
 
             def _decode_paged():
-                return self.gen._invoke(kind, "decode", feed, key)
+                return self.gen._invoke(kind, "decode", feed, key,
+                                        parent=span)
 
             try:
                 if budget:
@@ -903,7 +914,8 @@ class GenerationEngine:
             caches = self._caches
 
             def _decode():
-                return self.gen._run_decode(tok, posc, caches, key)
+                return self.gen._run_decode(tok, posc, caches, key,
+                                            parent=span)
 
             try:
                 if budget:
@@ -918,7 +930,8 @@ class GenerationEngine:
         toks, self._key = self.gen._run_sample(
             logits, np.ascontiguousarray(temperature, dtype=np.float32),
             np.ascontiguousarray(top_k, dtype=np.int32), self._key)
-        return np.asarray(toks)
+        with _trace.loop_span("engine/fetch"):
+            return np.asarray(toks)
 
     def spec_step(self, tokens, pos, temperature, top_k, drafts,
                   num_draft, live, budget=None):
@@ -946,6 +959,7 @@ class GenerationEngine:
                 "trash-routed multi-token write")
         maybe_fail("serving.decode_step")
         self._ensure_caches()
+        span = _trace.current_loop()    # as in step()
         tok = np.ascontiguousarray(tokens, dtype=np.int32)
         posc = np.ascontiguousarray(pos, dtype=np.int32)
         drafts = np.ascontiguousarray(drafts, dtype=np.int32)
@@ -965,7 +979,8 @@ class GenerationEngine:
         key = self._key
 
         def _verify():
-            return self.gen._invoke(kind, "decode", feed, key)
+            return self.gen._invoke(kind, "decode", feed, key,
+                                    parent=span)
 
         try:
             if budget:
@@ -983,4 +998,5 @@ class GenerationEngine:
             logits, drafts,
             np.ascontiguousarray(temperature, dtype=np.float32),
             np.ascontiguousarray(top_k, dtype=np.int32), nd, self._key)
-        return np.asarray(out), np.asarray(acc)
+        with _trace.loop_span("engine/fetch"):
+            return np.asarray(out), np.asarray(acc)

@@ -283,8 +283,10 @@ class ServingStats:
               # decode-loop step (engine.step wall: decode + sample +
               # host work — the inter-token latency the SLO monitor's
               # default p99 rule watches; a stall anywhere in the step
-              # lands here even if the compiled call itself was fast)
-              "prefill", "decode", "sample", "token")
+              # lands here even if the compiled call itself was fast),
+              # first_token = enqueue to the first token of a generate
+              # request (queue wait + its prefill and first sample)
+              "prefill", "decode", "sample", "token", "first_token")
 
     def __init__(self):
         self.hist = {s: LatencyHistogram(f"serving/{s}")

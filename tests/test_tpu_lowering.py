@@ -99,6 +99,33 @@ def test_paged_decode_lowers_for_tpu(kv_dtype, block):
     assert "tpu_custom_call" in text
 
 
+def _kernel_names(fn, specs):
+    import re
+    text = jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return set(re.findall(r'kernel_name = "([^"]+)"', text))
+
+
+@pytest.mark.parametrize("name", [
+    "flash_attention_fwd", "flash_attention_bwd", "flash_attention_bwd_dq",
+    "flash_attention_bwd_dkv", "paged_attention_decode"])
+def test_each_kernel_lowers_under_its_own_name(name):
+    """``pl.pallas_call(name=...)`` becomes ``kernel_name`` in the
+    ``tpu_custom_call`` config, so a device trace can tell the flash
+    forward from its backward calls and both from the paged kernel."""
+    if name == "paged_attention_decode":
+        found = _kernel_names(_paged_fn, _paged_specs("bf16", 16))
+    else:
+        # the short bucket takes the fused backward, the training length
+        # the dq and dkv pair
+        seq = 32 if name == "flash_attention_bwd" else 2048
+        fwd, grad = _flash_fns(causal=True)
+        fn = fwd if name == "flash_attention_fwd" else grad
+        found = _kernel_names(fn, _flash_specs(seq, False, jnp.bfloat16))
+    assert name in found
+    assert not found & {"kern", "dq_kern", "dkv_kern"}
+
+
 def test_composite_fallbacks_are_counted(monkeypatch):
     """Where the kernel would run (a TPU), more than one query per row
     and a general bias still take the composite, and the counter says

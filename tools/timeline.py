@@ -9,6 +9,14 @@ Spans come in two shapes, unified in one span table:
     [name, start_s, end_s, tid]                          profiler event
     [name, start_s, end_s, tid, trace_id, span_id,
      parent_id]                                          traced request
+    [name, start_s, end_s, tid, trace_id, span_id,
+     parent_id, attrs]                                   loop span
+
+Loop spans (``tracing.loop_span``: what the decode loop, the generator
+and ``Executor.run`` did, every round and step, always on) carry the
+counts at their boundary as ``attrs``, shown in the event's ``args``.
+Without a profiler session, dump the ring's last minutes with
+``json.dump({"spans": tracing.loop_spans(since_s, until_s)}, f)``.
 
 Traced spans (observability.tracing, wire-propagated request tracing)
 carry their ids in the event ``args`` and are linked parent -> child
@@ -60,6 +68,8 @@ def to_chrome_trace(spans, counters=()):
             ev["cat"] = "request"
             ev["args"] = {"trace_id": trace_id, "span_id": span_id,
                           "parent_span_id": parent_id}
+            if len(s) >= 8:
+                ev["args"].update(s[7])
             by_span_id[span_id] = (ev["ts"], ev["dur"], tids[tid])
             traced.append(ev)
         events.append(ev)
