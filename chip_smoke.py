@@ -10,7 +10,8 @@ positions), the one model this repository both trains and serves, with
 seeded random weights:
 
   kernels  both Pallas kernels compiled (never interpreted) and compared
-           with their in-file oracles
+           with their in-file oracles; the paged decode kernel timed
+           alone at the shapes of the benchmark's serve cell
   train    gpt_pretrain at 8 x 2,048 + Adam + bf16 AMP through
            Executor.run and Executor.run_steps
   serve    GPTGenerator -> InferenceServer(kv_paged=True) -> six
@@ -37,6 +38,7 @@ import gc
 import importlib.metadata
 import json
 import math
+import os
 import re
 import sys
 import threading
@@ -59,6 +61,8 @@ class Sizes:
             self.flash = (4, 2, 64, 16)
             self.paged_rows, self.paged_positions = 2, 64
             self.paged_pos = (0, 37)
+            self.paged_timed = dict(rows=4, heads=2, blocks=8, block=4,
+                                    d_head=16, pos=(3, 20))
             self.prompt_lens = (3, 5, 9, 12, 17, 20)
             self.new_tokens = 4
             self.check_prompt, self.check_steps = 9, 2
@@ -70,6 +74,11 @@ class Sizes:
             self.paged_rows, self.paged_positions = 8, 2048
             # first slot, block edges either side, mid-cache, last slot
             self.paged_pos = (0, 15, 16, 100, 777, 1000, 1500, 2047)
+            # the decode step of benchmark cell
+            # gpt2-medium.serve_chat_closed32: 32 slots over 64 blocks of
+            # 16, bf16 pool, contexts of 48 to 320 tokens
+            self.paged_timed = dict(rows=32, heads=16, blocks=64, block=16,
+                                    d_head=64, pos=(48, 320))
             self.prompt_lens = (17, 100, 300, 700, 1100, 1500)
             self.new_tokens = 32
             self.check_prompt, self.check_steps = 100, 3
@@ -262,7 +271,57 @@ def phase_kernels(smoke):
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=0, atol=5e-2)
         out["max_abs_err"][f"paged_{kv_dtype}"] = _max_err(got, ref)
+    out["paged_timed"] = _time_paged_decode(sz)
     return out
+
+
+def _time_paged_decode(sz, calls=20):
+    """Device microseconds of one ``paged_attention_decode`` call at
+    ``sz.paged_timed``, positions log-uniform over its range: the kernel
+    alone, from the ``XLA Ops`` line of a profiler trace. A rehearsal
+    runs the same calls and has no device time to report."""
+    import glob
+    import statistics
+    import tempfile
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    from paddle_tpu.kernels.paged_attention import (decode_grid,
+                                                    paged_attention)
+    t = sz.paged_timed
+    B, H, nblk, bs, D = (t[k] for k in ("rows", "heads", "blocks", "block",
+                                        "d_head"))
+    rng = np.random.default_rng(0)
+    pos = np.exp(rng.uniform(*np.log(t["pos"]), B)).astype(np.int32)
+    live = pos // bs + 1
+    N = B * nblk + 1
+    tables = np.zeros((B, nblk), np.int32)
+    tables[np.arange(nblk) < live[:, None]] = \
+        rng.permutation(np.arange(1, N))[:live.sum()]
+    args = (jnp.asarray(rng.normal(size=(B, H, 1, D)), jnp.float32),
+            jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.bfloat16),
+            jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.bfloat16),
+            jnp.asarray(tables), jnp.asarray(pos))
+    fn = jax.jit(lambda *a: paged_attention(*a, impl=sz.kernel_impl))
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                jax.block_until_ready(fn(*args))
+        path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        us = [ev.duration_ns / 1e3
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/device:TPU")
+              for line in plane.lines if line.name == "XLA Ops"
+              for ev in line.events if "paged_attention_decode" in ev.name]
+    grid, G = decode_grid(B, H, bs, D, jnp.bfloat16, nblk)
+    return {"shape": {k: v for k, v in t.items() if k != "pos"},
+            "positions": [int(pos.min()), int(pos.max())],
+            "live_blocks": int(live.sum()), "table_blocks": B * nblk,
+            "grid_steps": math.prod(grid), "blocks_per_step": G,
+            "calls": len(us),
+            "device_us_per_call": statistics.median(us) if us else None}
 
 
 # -------------------------------------------------------------------- train

@@ -5,21 +5,28 @@ fused prefill): one query token per row attends over that row's KV cache
 stored as BLOCKS of a shared pool (vLLM/PagedAttention, Kwon et al.
 2023) instead of a dense per-slot ``[B, H, max_len, D]`` bank. The
 block-table gather IS the kernel's index map — each grid step's
-``BlockSpec`` resolves ``(tables[b, j], h, 0, 0)`` from a
-scalar-prefetched block table, so the gather and the attention read are
-one fused pass over VMEM-resident blocks and the ``[B, max_len]`` dense
-cache is never materialized (decode is bandwidth-bound: bytes streamed
-per token IS the token rate).
+``BlockSpec``s resolve their blocks from a scalar-prefetched table, so
+the gather and the attention read are one fused pass over VMEM-resident
+blocks and the ``[B, max_len]`` dense cache is never materialized (decode
+is bandwidth-bound: bytes streamed per token IS the token rate).
 
 Two implementations, same math:
 
-- ``pallas``: grid ``(B, H, blocks_per_row)``, online-softmax running
-  state (m, l, acc) in VMEM scratch carried across a row's blocks,
-  dead-block skipping via the per-row position counter (a block past
-  ``pos[b]`` is never fetched into the running state — table padding
-  rides the same skip), int8 blocks dequantized in-register against
-  their per-slot scales. ``interpret`` runs the SAME kernel through the
-  Pallas interpreter on CPU.
+- ``pallas``: grid ``(B, cdiv(blocks_per_row, G))``. A step fetches G
+  consecutive table entries of a row as G whole ``[H, block_size, D]``
+  tiles of K and of V (G pool operands each, 256 KB and more a step at
+  serving shapes) and folds all H heads of them at once into the row's
+  online-softmax state (m, l, acc in VMEM scratch carried across the
+  row's steps). G follows from H, block_size, D, the pool's dtype and
+  the table's width (:func:`blocks_per_step`). Past a row's last live
+  block ``pos[b] // block_size`` every operand's block index stands
+  still (:func:`_live_tables`), so a dead step costs neither a DMA nor
+  a fold — table padding rides the same skip. int8 blocks are
+  dequantized against their per-slot scales as they leave VMEM.
+  ``interpret`` runs the SAME kernel through the Pallas interpreter on
+  CPU. What a step costs on a v5e (PERF.md, PR 27): 0.04 us for each
+  operand whose index map reads the table, whether it moves or not;
+  the tiles' bytes and the fold are a tenth of that at 12% live.
 - ``xla``: a ``jnp.take``-based gather + masked softmax composite — the
   CPU-CI path and the parity oracle the kernel is tested against.
 
@@ -40,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -110,21 +118,99 @@ def _xla_paged_attention(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
 
 # ----------------------------------------------------------------- kernel
 
-def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
-                  vs_ref, out_ref, m_sc, l_sc, acc_sc, *, scale, bs,
-                  nblk):
-    """One (b, h, j) grid step folds block j of row b into the running
-    online-softmax state. The block-table gather already happened in the
-    BlockSpec index map — k_ref/v_ref hold block ``tables[b, j]``.
+# VMEM the K and V tiles of a grid step may hold, double-buffered and
+# padded to their dtype's tile, and the widest row of scores
+# [H, G * H * bs] a step's fold keeps in registers
+_VMEM_BUDGET = 4 * 1024 * 1024
+_SCORE_LANES = 2048
+_NO_SLOT = 2 ** 30     # _slot_of's entry for another head's key: never visible
 
-    The running max and sum live in lane 0 of ``(1, 128)`` VMEM tiles
-    (the flash kernel's idiom): Mosaic stores vectors to VMEM, never
-    scalars. int8 scales arrive as the block's whole ``[H, bs]`` tile
-    and stay slot-major ``[1, bs]`` rows: ``q . (k_int * ks)`` equals
-    ``(q . k_int) * ks`` and ``sum_j p_j vs_j v_int_j`` equals
-    ``(p * vs) @ v_int``, so dequantization rides the score and
-    probability rows and no ``[bs, 1]`` relayout is needed."""
-    b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+def _tile_bytes(H, bs, D, dtype):
+    """VMEM bytes of one block's ``[H, bs, D]`` tile: its last two dims
+    pad to the dtype's (sublanes, 128 lanes) tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * (4 // itemsize)
+    return H * pl.cdiv(bs, sublanes) * sublanes * pl.cdiv(D, _LANES) \
+        * _LANES * itemsize
+
+
+def blocks_per_step(H, bs, D, dtype, nblk):
+    """G, the consecutive table entries of a row that one grid step
+    fetches and folds: the largest power of two no wider than the table
+    whose K and V tiles, double-buffered, sit in ``_VMEM_BUDGET`` and
+    whose scores fit ``_SCORE_LANES`` (1 where a single block already
+    exceeds either)."""
+    tile = _tile_bytes(H, bs, D, dtype)
+    g = 1
+    while (2 * g <= nblk and 8 * g * tile <= _VMEM_BUDGET
+           and 2 * g * H * bs <= _SCORE_LANES):
+        g *= 2
+    return g
+
+
+def decode_grid(B, H, bs, D, dtype, nblk):
+    """``(grid, G)`` of one ``paged_attention_decode`` call: a step for
+    every G table entries of every row."""
+    g = blocks_per_step(H, bs, D, dtype, nblk)
+    return (B, pl.cdiv(nblk, g)), g
+
+
+def _live_tables(tables, pos, bs, G, steps):
+    """``[B, steps * G]``: the block each (row, step, operand) fetches.
+    Entry (b, j * G + i) is ``tables[b, j * G + i]`` up to the row's last
+    live block ``pos[b] // bs``; past it, a live step's tail repeats that
+    last block (fetched again, masked in the fold) and a dead step
+    repeats the last live step's entries, so every operand's block index
+    stands still and Pallas elides the fetch. Computed here, once a
+    decode step, because an index map pays for its arithmetic at every
+    (operand, grid step): 0.09 us with the clamp inside it, 0.04 us as
+    one table read."""
+    last = jnp.clip(pos // bs, 0, tables.shape[1] - 1)               # [B]
+    step = jnp.minimum(jnp.arange(steps, dtype=jnp.int32)[None, :],
+                       (last // G)[:, None])                   # [B, steps]
+    col = step[:, :, None] * G + jnp.arange(G, dtype=jnp.int32)
+    col = jnp.minimum(col, last[:, None, None]).reshape(-1, steps * G)
+    return jnp.take_along_axis(tables, col, axis=1)
+
+
+def _slot_of(H, bs, G):
+    """``[H, G * H * bs]`` int32: for query head h and a step's key
+    column (g, h', t), the key's slot in the step ``g * bs + t`` where
+    ``h' == h`` and ``_NO_SLOT`` elsewhere. One compare against ``pos``
+    less the step's first slot masks the other heads' keys and the slots
+    past the row's position together."""
+    g, h2, t = np.meshgrid(np.arange(G), np.arange(H), np.arange(bs),
+                           indexing="ij")
+    own = h2.reshape(1, -1) == np.arange(H)[:, None]
+    return np.where(own, (g * bs + t).reshape(1, -1),
+                    _NO_SLOT).astype(np.int32)
+
+
+def _paged_kernel(live_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
+                  G, quant):
+    """Grid step (b, j) folds table entries ``[j * G, (j + 1) * G)`` of
+    row b, all H heads at once, into the row's online-softmax state. The
+    gather already happened in the index maps: ``refs`` hold G key
+    tiles, G value tiles (and with ``quant`` G + G scale tiles) of
+    ``[H, bs, D]`` each, then the output and the (m, l, acc) scratch.
+
+    All heads fold in two matrix products: q ``[H, D]`` against the
+    step's keys ``[G * H * bs, D]`` scores every head against every
+    head's keys, ``slot_of`` keeps a head's own and drops the slots past
+    ``pos[b]``, and the probabilities, exactly zero off a head's own
+    columns, times the values ``[G * H * bs, D]`` are the H weighted
+    sums. The running max and sum live in lane 0 of ``(H, 128)`` VMEM
+    tiles (the flash kernel's idiom): Mosaic stores vectors to VMEM,
+    never scalars. int8 tiles are dequantized against their ``[H, bs]``
+    scale tiles as they leave VMEM."""
+    k_refs, v_refs, refs = refs[:G], refs[G:2 * G], refs[2 * G:]
+    ks_refs = vs_refs = (None,) * G
+    if quant:
+        ks_refs, vs_refs, refs = refs[:G], refs[G:2 * G], refs[2 * G:]
+    out_ref, m_sc, l_sc, acc_sc = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    H, D = q_ref.shape[1:]
 
     @pl.when(j == 0)
     def _init():
@@ -134,40 +220,43 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
 
     p = pos_ref[b]
 
-    # dead-block skip: block j covers key slots [j*bs, (j+1)*bs); nothing
-    # there is visible once j*bs > pos[b]. Block-table padding (trash
-    # block 0) only ever appears PAST a row's allocation, so the same
-    # predicate keeps garbage out of the state.
-    @pl.when(j * bs <= p)
+    def tiles(refs, scale_refs):
+        rows = []
+        for ref, scale_ref in zip(refs, scale_refs):
+            x = ref[0].astype(jnp.float32)                    # [H, bs, D]
+            if scale_ref is not None:
+                x = x * scale_ref[0][:, :, None]
+            rows.append(x.reshape(H * bs, D))
+        return jnp.concatenate(rows, axis=0)                  # [G*H*bs, D]
+
+    # dead-step skip: step j covers key slots [j*G*bs, (j+1)*G*bs);
+    # nothing there is visible once j*G*bs > pos[b], and nothing was
+    # fetched for it (_live_tables). Block-table padding (trash block 0)
+    # only ever appears PAST a row's allocation, so the same predicate
+    # and slot_of's compare keep garbage out of the state.
+    @pl.when(j * (G * bs) <= p)
     def _fold():
-        qv = q_ref[0, 0].astype(jnp.float32)                  # [1, D]
-        kb = k_ref[0, 0].astype(jnp.float32)                  # [bs, D]
         s = jax.lax.dot_general(
-            qv, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [1, bs]
-        if ks_ref is not None:
-            s = s * ks_ref[0, pl.ds(h, 1), :]
-        idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(idx <= p, s, _NEG_INF)
-        m_prev = m_sc[:, :1]                                  # [1, 1]
+            q_ref[0].astype(jnp.float32), tiles(k_refs, ks_refs),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [H, G*H*bs]
+        s = jnp.where(slot_of_ref[...] <= p - j * (G * bs), s, _NEG_INF)
+        m_prev = m_sc[:, :1]                                  # [H, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        pr = jnp.exp(s - m_new)                               # [1, bs]
+        pr = jnp.exp(s - m_new)
         l_sc[:, :1] = l_sc[:, :1] * corr + jnp.sum(pr, axis=-1,
                                                    keepdims=True)
         m_sc[:, :1] = m_new
-        if vs_ref is not None:
-            pr = pr * vs_ref[0, pl.ds(h, 1), :]
         acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            pr, v_ref[0, 0].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [1, D]
+            pr, tiles(v_refs, vs_refs), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [H, D]
 
-    @pl.when(j == nblk - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         l = l_sc[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = (acc_sc[:] / l).astype(out_ref.dtype)
+        out_ref[0] = (acc_sc[:] / l).astype(out_ref.dtype)
 
 
 def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
@@ -178,58 +267,59 @@ def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
             f"paged_attention kernel decodes ONE query per row (S=1), "
             f"got S={S}; prefill goes through flash_attention")
     bs = k_pool.shape[2]
-    nblk = tables.shape[1]
+    tile = _tile_bytes(H, bs, D, k_pool.dtype)
+    if 4 * tile > _VMEM_BUDGET:
+        raise ValueError(
+            f"paged_attention: one [H={H}, block_size={bs}, D={D}] "
+            f"{k_pool.dtype.name} block is {tile} bytes of VMEM; K and V "
+            f"double-buffered take {4 * tile} of the kernel's "
+            f"{_VMEM_BUDGET}: lower kv_block_size")
     quant = k_scale is not None
+    grid, G = decode_grid(B, H, bs, D, k_pool.dtype, tables.shape[1])
+    slot_of = _slot_of(H, bs, G)
 
-    # index maps see the grid indices THEN the scalar-prefetch refs:
-    # the pool block for (b, j) is whatever the row's table names — the
-    # fused gather
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, D), lambda b, h, j, t, p: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D),
-                     lambda b, h, j, t, p: (t[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D),
-                     lambda b, h, j, t, p: (t[b, j], h, 0, 0)),
-    ]
-    args = [q, k_pool, v_pool]
-    if quant:
-        # the block's whole [H, bs] scale tile: a (1, 1, bs) block
-        # over [N, H, bs] breaks the TPU rule that a block's last two
-        # dims divide (8, 128) or equal the array's; the kernel picks
-        # row h
-        in_specs += [
-            pl.BlockSpec((1, H, bs),
-                         lambda b, h, j, t, p: (t[b, j], 0, 0)),
-            pl.BlockSpec((1, H, bs),
-                         lambda b, h, j, t, p: (t[b, j], 0, 0)),
-        ]
-        args += [k_scale, v_scale]
+    # index maps see the grid indices THEN the scalar-prefetch refs: the
+    # i-th pool operand's block for (b, j) is whatever the row's live
+    # table names at j * G + i — the fused gather
+    def row(b, j, live, p):
+        return (b, 0, 0)
 
-    body = functools.partial(_paged_kernel, scale=scale, bs=bs, nblk=nblk)
+    def block(i, ndim):
+        return lambda b, j, live, p: (live[b, j * G + i],) + (0,) * ndim
 
-    if quant:
-        kern = body
-    else:
-        def kern(tables_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
-                 m_sc, l_sc, acc_sc):
-            body(tables_ref, pos_ref, q_ref, k_ref, v_ref, None, None,
-                 out_ref, m_sc, l_sc, acc_sc)
+    def pool_specs(pool):
+        return [pl.BlockSpec((1,) + pool.shape[1:], block(i, pool.ndim - 1))
+                for i in range(G)]
+
+    in_specs = [pl.BlockSpec((1, H, D), row),
+                pl.BlockSpec(slot_of.shape, lambda b, j, live, p: (0, 0))]
+    args = [q.reshape(B, H, D), slot_of]
+    # whole [H, bs, D] tiles, and for int8 the block's whole [H, bs]
+    # scale tile: a block's last two dims divide (8, 128) or equal the
+    # array's
+    for pool in (k_pool, v_pool) + ((k_scale, v_scale) if quant else ()):
+        in_specs += pool_specs(pool)
+        args += [pool] * G
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, nblk),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, D),
-                               lambda b, h, j, t, p: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, _LANES), jnp.float32),
-                        pltpu.VMEM((1, _LANES), jnp.float32),
-                        pltpu.VMEM((1, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, H, D), row),
+        scratch_shapes=[pltpu.VMEM((H, _LANES), jnp.float32),
+                        pltpu.VMEM((H, _LANES), jnp.float32),
+                        pltpu.VMEM((H, D), jnp.float32)],
     )
-    return pl.pallas_call(
-        kern, name="paged_attention_decode", grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+    pos = pos.astype(jnp.int32)
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, scale=scale, bs=bs, G=G,
+                          quant=quant),
+        name="paged_attention_decode", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32), *args)
+    )(_live_tables(tables.astype(jnp.int32), pos, bs, G, grid[1]), pos,
+      *args)
+    return out.reshape(B, H, 1, D)
 
 
 # ----------------------------------------------------------- public entry

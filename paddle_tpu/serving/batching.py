@@ -1348,6 +1348,15 @@ class DecodeBatcher:
                         self._topk, drafts, nd, live_mask,
                         budget=self.watchdog_s or None)
                 else:
+                    if pool is not None:
+                        # the paged kernel's grid against the blocks it
+                        # has to read: over slots * blocks_per_row, how
+                        # much of the table it no longer walks
+                        stepped.attrs["grid_steps"] = getattr(
+                            self.engine, "kernel_grid_steps", None)
+                        stepped.attrs["live_blocks"] = int(np.sum(
+                            self._pos[list(self._active)]
+                            // pool.block_size + 1))
                     toks = self.engine.step(
                         self._tok, self._pos, self._temp,
                         self._topk, budget=self.watchdog_s or None)

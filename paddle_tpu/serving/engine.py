@@ -10,6 +10,7 @@ device-resident and shared by every executable; feeds are the only
 per-call traffic.
 """
 import json
+import math
 import os
 import time
 
@@ -457,8 +458,10 @@ class GenerationEngine:
         # reuse across requests.
         self.paged = bool(flag("kv_paged") if paged is None else paged)
         self.pool = None
+        self.kernel_grid_steps = None
         if self.paged:
-            from .kvpool import KVBlockPool
+            from .kvpool import KVBlockPool, _np_pool_dtype
+            from ..kernels.paged_attention import decode_grid
             cfg = generator.cfg
             self.pool = KVBlockPool(
                 slots=self.slots, num_layers=cfg.num_layers,
@@ -472,6 +475,15 @@ class GenerationEngine:
                 # tensor-parallel serving: the pool's block arrays live
                 # sharded on the head axis of the generator's tp mesh
                 generator.apply_pool_sharding(self.pool)
+            # what one paged_attention_decode call of a decode step
+            # launches (the engine/step span's grid_steps): the kernel's
+            # own function of the shapes a shard of it sees
+            grid, _ = decode_grid(
+                self.slots,
+                cfg.num_heads // max(getattr(generator, "tp", 1), 1),
+                self.pool.block_size, self.pool.d_head,
+                _np_pool_dtype(self.pool.dtype), self.pool.blocks_per_row)
+            self.kernel_grid_steps = math.prod(grid)
         # a generator WITHOUT its own sink adopts the server's (stage
         # histograms land in server.stats()), and a sink a PREVIOUS
         # engine bound is rebound to the live server (else a reused

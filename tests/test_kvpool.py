@@ -200,6 +200,97 @@ def test_paged_attention_interpret_matches_xla_reference():
                                atol=1e-5)
 
 
+# the kernel's grid: (rows, table width / G) steps of G blocks, all heads
+# a step. H=16 takes the serve cell's tile (bs 16, D 64: G is 8 from the
+# score budget); H=2 takes a small one with the budget lowered to G = 4,
+# so both walk a table that is a multiple of G, one that is not, and one
+# narrower than the budget's G
+_GRID_SHAPES = {2: dict(bs=4, D=16, score_lanes=4 * 2 * 4,
+                        widths={"multiple": 8, "ragged": 6, "narrow": 2}),
+                16: dict(bs=16, D=64, score_lanes=None,
+                         widths={"multiple": 16, "ragged": 11,
+                                 "narrow": 4})}
+
+
+def _grid_positions(pattern, bs, nblk):
+    """(positions, rows whose table is all trash) of one pattern."""
+    mid = min(bs + bs // 2, nblk * bs - 2)
+    one = {"first_slot": 0, "block_end": bs - 1, "block_start": bs,
+           "mid_block": mid, "last_slot": nblk * bs - 1}
+    if pattern in one:
+        return [one[pattern]], []
+    if pattern == "mixed":
+        return list(one.values()), []
+    assert pattern == "free_row"
+    return [mid, 0, nblk * bs - 1, mid], [1, 3]
+
+
+@pytest.mark.parametrize("H", [2, 16])
+@pytest.mark.parametrize("width", ["multiple", "ragged", "narrow"])
+@pytest.mark.parametrize("pattern", [
+    "first_slot", "block_end", "block_start", "mid_block", "last_slot",
+    "mixed", "free_row"])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
+                                          width, H):
+    """The grouped grid through the Pallas interpreter against the
+    gather composite: every pool type, positions at each edge of a block
+    and of the table, alone and mixed in one batch, a free row (table
+    all trash block 0) beside live ones, and every way the table's
+    width meets G."""
+    import importlib
+    import jax.numpy as jnp
+    # the package exports the function under the module's name
+    pa = importlib.import_module("paddle_tpu.kernels.paged_attention")
+    shape = _GRID_SHAPES[H]
+    bs, D, nblk = shape["bs"], shape["D"], shape["widths"][width]
+    if shape["score_lanes"]:
+        monkeypatch.setattr(pa, "_SCORE_LANES", shape["score_lanes"])
+    (_, steps), G = pa.decode_grid(1, H, bs, D, jnp.float32, nblk)
+    assert G == {"multiple": nblk // 2, "ragged": 8 if H == 16 else 4,
+                 "narrow": nblk}[width]
+    assert steps == -(-nblk // G)
+
+    pos, free = _grid_positions(pattern, bs, nblk)
+    B, N = len(pos), len(pos) * nblk + 1
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.normal(size=(B, H, 1, D)).astype(np.float32))
+    kp, vp = (jnp.asarray(rng.normal(size=(N, H, bs, D)).astype(np.float32))
+              for _ in range(2))
+    tables = rng.permutation(np.arange(1, N)).reshape(B, nblk)
+    tables[free] = 0
+    tables = jnp.asarray(tables.astype(np.int32))
+    pos = jnp.asarray(np.array(pos, np.int32))
+    if kv_dtype == "int8":
+        (kp, ks), (vp, vs) = pa.quantize_kv(kp), pa.quantize_kv(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    else:
+        dt = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
+        kp, vp, scales = kp.astype(dt), vp.astype(dt), {}
+    ref = pa.paged_attention(q, kp, vp, tables, pos, impl="xla", **scales)
+    out = pa.paged_attention(q, kp, vp, tables, pos, impl="interpret",
+                             **scales)
+    # a free row reads one slot of the trash block, like the oracle
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_paged_kernel_names_a_block_too_large_for_vmem():
+    """A block whose K and V tiles cannot sit double-buffered in the
+    kernel's VMEM budget is an error that names the shape, not a silent
+    fall to the composite."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import paged_attention
+    pool = jax.ShapeDtypeStruct((3, 16, 512, 128), jnp.float32)
+    with pytest.raises(ValueError, match=r"H=16, block_size=512, D=128"):
+        jax.eval_shape(
+            lambda q, k, v, t, p: paged_attention(q, k, v, t, p,
+                                                  impl="interpret"),
+            jax.ShapeDtypeStruct((1, 16, 1, 128), jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((1, 2), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+
+
 def test_paged_attention_input_validation():
     import jax.numpy as jnp
     from paddle_tpu.kernels.paged_attention import paged_attention

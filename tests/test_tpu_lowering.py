@@ -58,15 +58,17 @@ def _flash_specs(seq, with_bias, dtype, sharding=None, batch=2):
     return specs
 
 
-def _paged_specs(kv_dtype, block, rows=8, positions=2048, sharding=None):
+def _paged_specs(kv_dtype, block, rows=8, positions=2048, sharding=None,
+                 heads=H):
     nblk = positions // block
-    pool = _spec((rows * nblk + 1, H, block, D), _np_pool_dtype(kv_dtype),
-                 sharding)
-    specs = [_spec((rows, H, 1, D), jnp.float32, sharding), pool, pool,
+    pool = _spec((rows * nblk + 1, heads, block, D),
+                 _np_pool_dtype(kv_dtype), sharding)
+    specs = [_spec((rows, heads, 1, D), jnp.float32, sharding), pool, pool,
              _spec((rows, nblk), jnp.int32, sharding),
              _spec((rows,), jnp.int32, sharding)]
     if kv_dtype == "int8":
-        scale = _spec((rows * nblk + 1, H, block), jnp.float32, sharding)
+        scale = _spec((rows * nblk + 1, heads, block), jnp.float32,
+                      sharding)
         specs += [scale, scale]
     return specs
 
@@ -97,6 +99,25 @@ def test_paged_decode_lowers_for_tpu(kv_dtype, block):
     text = jax.jit(_paged_fn).trace(*_paged_specs(kv_dtype, block)).lower(
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_call_at_the_serve_cell_shapes():
+    """What ``paged_attention_roofline.serve`` finds the kernel by in a
+    device trace (benchmark/layer_metrics): the one
+    ``paged_attention_decode`` call of gpt2-medium's 32 slots over 64
+    blocks of 16 is a ``tpu_custom_call`` with an ``s32[32,64]`` block
+    table among its operands."""
+    import re
+    lowered = jax.jit(_paged_fn).trace(*_paged_specs(
+        "bf16", 16, rows=32, positions=1024, heads=16)).lower(
+        lowering_platforms=("tpu",))
+    assert re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()) == [
+        "paged_attention_decode"]
+    calls = [line for line in lowered.compiler_ir(
+        dialect="hlo").as_hlo_text().splitlines() if "custom-call(" in line
+        and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert "s32[32,64]" in calls[0]
 
 
 def _kernel_names(fn, specs):
