@@ -63,6 +63,11 @@ class Sizes:
             self.paged_pos = (0, 37)
             self.paged_timed = dict(rows=4, heads=2, blocks=8, block=4,
                                     d_head=16, pos=(3, 20))
+            # (query heads, KV heads, head width, window, block, expert
+            # width in, hidden, experts, experts a token, prefill length)
+            self.gqa = dict(hq=4, hkv=2, d=8, window=8, block=4, hidden=32,
+                            f=16, experts=8, top_k=2, seq=32, rows=4,
+                            positions=40)
             self.prompt_lens = (3, 5, 9, 12, 17, 20)
             self.new_tokens = 4
             self.check_prompt, self.check_steps = 9, 2
@@ -79,6 +84,11 @@ class Sizes:
             # 16, bf16 pool, contexts of 48 to 320 tokens
             self.paged_timed = dict(rows=32, heads=16, blocks=64, block=16,
                                     d_head=64, pos=(48, 320))
+            # the shapes of mellum2-12b-a2.5b: 32 query heads over 4 KV
+            # heads of 128, window 1024, 64 experts of 896 top-8
+            self.gqa = dict(hq=32, hkv=4, d=128, window=1024, block=16,
+                            hidden=2304, f=896, experts=64, top_k=8,
+                            seq=4096, rows=32, positions=6400)
             self.prompt_lens = (17, 100, 300, 700, 1100, 1500)
             self.new_tokens = 32
             self.check_prompt, self.check_steps = 100, 3
@@ -272,7 +282,102 @@ def phase_kernels(smoke):
                                    rtol=0, atol=5e-2)
         out["max_abs_err"][f"paged_{kv_dtype}"] = _max_err(got, ref)
     out["paged_timed"] = _time_paged_decode(sz)
+    out["grouped_window"] = _grouped_window_kernels(sz)
     return out
+
+
+def _grouped_window_kernels(sz):
+    """The flash forward and the paged decode kernel with grouped
+    queries, with and without a window, and the routed expert kernel at
+    decode and prefill row counts, each against its oracle at
+    ``sz.gqa``'s shapes (mellum2-12b-a2.5b's on the chip)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.flash_attention import (_xla_attention,
+                                                    flash_attention)
+    from paddle_tpu.kernels.moe_experts import routed_experts
+    from paddle_tpu.kernels.paged_attention import (_xla_paged_attention,
+                                                    paged_attention,
+                                                    window_blocks)
+    g, impl = sz.gqa, sz.kernel_impl
+    hq, hkv, d, bs = g["hq"], g["hkv"], g["d"], g["block"]
+    scale = float(d) ** -0.5
+    rng = np.random.default_rng(1)
+    err, secs = {}, {}
+
+    def timed(name, fn, *args):
+        fn = jax.jit(fn).lower(*args).compile()
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(fn(*args))
+        secs[name] = round(time.perf_counter() - t0, 5)
+        return got
+
+    seq = g["seq"]
+    q = jnp.asarray(rng.normal(size=(1, hq, seq, d)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(1, hkv, seq, d)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(1, hkv, seq, d)), jnp.bfloat16)
+    rows, positions = g["rows"], g["positions"]
+    pos = jnp.asarray(np.exp(rng.uniform(
+        np.log(max(positions // 6, 1)), np.log(positions - 1),
+        rows)).astype(np.int32))
+    qd = jnp.asarray(rng.normal(size=(rows, hq, 1, d)), jnp.float32)
+    for window in (None, g["window"]):
+        tag = "window" if window else "full"
+        got = timed(f"flash_{tag}", lambda q, k, v: flash_attention(
+            q, k, v, causal=True, impl=impl, window=window), q, k, v)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda q, k, v: _xla_attention(
+                q, k, v, None, scale, True, window))(
+                    *(x.astype(jnp.float32) for x in (q, k, v)))
+        # as the flash check above: bf16 probabilities against an fp32
+        # oracle; a wrong head group or window edge moves outputs by O(1)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref), rtol=5e-2, atol=5e-2)
+        err[f"flash_{tag}"] = _max_err(got, ref)
+
+        # a full table, or a ring one block wider than the window needs
+        nblk = -(-positions // bs) if window is None \
+            else window_blocks(window, bs) + 1
+        n = rows * nblk + 1
+        kp = jnp.asarray(rng.normal(size=(n, hkv, bs, d)), jnp.bfloat16)
+        vp = jnp.asarray(rng.normal(size=(n, hkv, bs, d)), jnp.bfloat16)
+        tables = jnp.asarray(rng.permutation(np.arange(1, n)).reshape(
+            rows, nblk), jnp.int32)
+        args = (qd, kp, vp, tables, pos)
+        got = timed(f"paged_{tag}", lambda *a: paged_attention(
+            *a, impl=impl, window=window), *args)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a: _xla_paged_attention(
+                *a, None, None, scale, window))(*args)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=0, atol=5e-2)
+        err[f"paged_{tag}"] = _max_err(got, ref)
+
+    h, f, e, top = g["hidden"], g["f"], g["experts"], g["top_k"]
+    wg, wu = (jnp.asarray(0.02 * rng.normal(size=(e, h, f)), jnp.bfloat16)
+              for _ in range(2))
+    wd = jnp.asarray(0.02 * rng.normal(size=(e, f, h)), jnp.bfloat16)
+    for tag, n in (("decode", rows), ("prefill", seq)):
+        x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+        probs = jax.nn.softmax(jnp.asarray(rng.normal(size=(n, e)),
+                                           jnp.float32))
+        w, idx = jax.lax.top_k(probs, top)
+        valid = jnp.asarray(np.arange(n) % 5 != 0)
+        args = (x, idx, w / w.sum(-1, keepdims=True), wg, wu, wd, valid)
+        got, counts = timed(f"experts_{tag}", lambda *a: routed_experts(
+            *a[:6], valid=a[6], impl=impl), *args)
+        # the oracle's ragged_dot takes the same bf16 operands (their
+        # products are exact in float32; Mosaic refuses bf16 operands at
+        # precision=highest) and accumulates in float32 as the kernel
+        # does; sums of 8 weighted expert outputs of magnitude ~0.02
+        ref, ref_counts = jax.jit(lambda *a: routed_experts(
+            *a[:6], valid=a[6], impl="xla"))(*args)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-2, atol=2e-3)
+        assert np.array_equal(np.asarray(counts), np.asarray(ref_counts))
+        err[f"experts_{tag}"] = _max_err(got, ref)
+    return {"shape": g, "max_abs_err": err, "second_call_s": secs}
 
 
 def _time_paged_decode(sz, calls=20):

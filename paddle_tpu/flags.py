@@ -188,7 +188,8 @@ _DEFS = {
     "decode_max_len": (2048, int, None),
     # minimum prefill sequence bucket: prompts pad up to the next
     # power-of-two >= this, bounding the universe of compiled prefill
-    # shapes (buckets: decode_bucket_min, 2x, 4x, ... decode_max_len)
+    # shapes (buckets: decode_bucket_min, 2x, 4x, ... decode_max_len;
+    # from 2048 up their midpoints too, models/generation.length_bucket)
     "decode_bucket_min": (16, int, None),
     # serving decode batch: fixed number of generation slots stepped by
     # one compiled decode executable; finished rows free their slot for

@@ -97,18 +97,38 @@ def _block_sizes(sq, sk, bq, bk, per_elem_bytes=6, causal=False):
     return bq, bk
 
 
-def _causal_mask(s, qi, ki, bq, bk):
+def _causal_mask(s, qi, ki, bq, bk, window=None):
+    """Key j is visible to query i iff ``j <= i`` and, with a window,
+    ``i - window < j``."""
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+    keep = rows >= cols
+    if window is not None:
+        keep = keep & (rows - cols < window)
+    return jnp.where(keep, s, _NEG_INF)
 
 
-def _block_live(causal, qi, ki, bq, bk):
+def _block_live(causal, qi, ki, bq, bk, window=None):
     """Whether k-block ki intersects the causal lower triangle of q-block
-    qi (always true without causal)."""
+    qi (always true without causal) and, with a window, the band under
+    it: the block's last key is inside the first query's window."""
     if not causal:
         return True
-    return ki * bk <= qi * bq + bq - 1
+    live = ki * bk <= qi * bq + bq - 1
+    if window is not None:
+        live = live & (ki * bk + bk - 1 > qi * bq - window)
+    return live
+
+
+def _live_k_block(window, bq, bk):
+    """Index of the k-block that (q-block i, step j) fetches under a
+    window: j held inside the band's first and last block, so a dead
+    step names the block a live neighbour fetched and moves nothing."""
+    def clamp(i, j):
+        first = jnp.maximum(i * bq - window + 1, 0) // bk
+        last = (i * bq + bq - 1) // bk
+        return jnp.clip(j, first, last)
+    return clamp
 
 
 def _bias2(bias_ref):
@@ -124,7 +144,7 @@ def _bias2(bias_ref):
 # ---------------------------------------------------------------- forward
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
-                       v_sc, *, scale, bq, causal):
+                       v_sc, *, scale, bq, causal, window=None):
     """Whole Sk in one tile: no online state. Grid (B, H, nq)."""
     b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     d = q_ref.shape[-1]
@@ -148,7 +168,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
         q, k_ref[0, 0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                 # [bq, Sk]
     if causal:
-        s2 = _causal_mask(s2, i, 0, bq, k_ref.shape[2])
+        s2 = _causal_mask(s2, i, 0, bq, k_ref.shape[2], window)
     if bias_ref is not None:
         # the broadcast add fuses into both s2 passes (same VMEM
         # traffic); an unbiased max could underflow every real key when
@@ -177,7 +197,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
 
 def _fwd_online_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
                        m_sc, acc_sc, l_sc, v_sc, *, scale, bq, bk, nk,
-                       causal):
+                       causal, window=None):
     """Running (m, acc_aug) state; acc_aug lane D is the normalizer, so
     the rescale correction covers acc and l in one [bq, 128] multiply.
     Grid (B, H, nq, nk)."""
@@ -199,7 +219,7 @@ def _fwd_online_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
         if not aug:
             l_sc[:] = jnp.zeros_like(l_sc)
 
-    @pl.when(_block_live(causal, qi, ki, bq, bk))
+    @pl.when(_block_live(causal, qi, ki, bq, bk, window))
     def _fold():
         q = (q_ref[0, 0].astype(jnp.float32) * (scale * _LOG2E)).astype(
             q_ref.dtype)
@@ -207,7 +227,7 @@ def _fwd_online_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
             q, k_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # [bq, bk]
         if causal:
-            s2 = _causal_mask(s2, qi, ki, bq, bk)
+            s2 = _causal_mask(s2, qi, ki, bq, bk, window)
         if bias_ref is not None:
             s2 = s2 + _bias2(bias_ref)
         m_prev = m_sc[:, :1]                                # [bq, 1]
@@ -238,21 +258,36 @@ def _fwd_online_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
         lse_ref[0, 0] = (m_sc[:, :1] + jnp.log2(l)).reshape(1, -1)
 
 
-def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret):
+def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
+                window=None):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
+    # grouped queries: query head h reads KV head h // rep through the
+    # index map, so the KV is never repeated in memory
+    rep = H // k.shape[1]
     aug = D < _LANES
     bq, bk = _block_sizes(Sq, Sk, bq, bk, per_elem_bytes=6,
                           causal=causal)
     nq, nk = Sq // bq, Sk // bk
     single = nk == 1
+    kblock = _live_k_block(window, bq, bk) if window is not None \
+        else (lambda i, j: j)
+
+    if rep == 1 and window is None:
+        # one KV head a query head, every block: the plain map (an
+        # integer division in an index map is paid at every grid step
+        # and in every compile, so it is there only where heads group)
+        def kv_map(b, h, i, *j):
+            return (b, h, j[0], 0) if j else (b, h, 0, 0)
+    else:
+        def kv_map(b, h, i, *j):
+            return (b, h // rep, kblock(i, j[0]), 0) if j \
+                else (b, h // rep, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, h, i, *j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, i, *j: (b, h, j[0], 0)
-                     if j else (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, i, *j: (b, h, j[0], 0)
-                     if j else (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, bk, D), kv_map),
+        pl.BlockSpec((1, 1, bk, D), kv_map),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -263,7 +298,7 @@ def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret):
 
     if single:
         body = functools.partial(_fwd_single_kernel, scale=scale, bq=bq,
-                                 causal=causal)
+                                 causal=causal, window=window)
         grid = (B, H, nq)
         scratch = [pltpu.VMEM((bk, _LANES), v.dtype)] if aug else []
         n_sc = len(scratch)
@@ -276,7 +311,8 @@ def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret):
             body(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref, v_sc)
     else:
         body = functools.partial(_fwd_online_kernel, scale=scale, bq=bq,
-                                 bk=bk, nk=nk, causal=causal)
+                                 bk=bk, nk=nk, causal=causal,
+                                 window=window)
         grid = (B, H, nq, nk)
         scratch = [
             pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -558,34 +594,49 @@ def _bwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
 
 # ---------------------------------------------------------- public entry
 
-def _xla_attention(q, k, v, bias, scale, causal):
-    """Composite fallback: identical math, materialized scores."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+def _xla_attention(q, k, v, bias, scale, causal, window=None):
+    """Composite fallback: identical math, materialized scores. Grouped
+    queries fold into ``[B, Hkv, rep, Sq, D]`` against the KV heads they
+    share."""
+    B, H, Sq, D = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
+    rep = H // Hkv
+    qg = q.reshape(B, Hkv, rep, Sq, D)
+    s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k) * scale
     if bias is not None:
         # match the Pallas path's constant-mask contract (zero cotangent)
-        s = s + jax.lax.stop_gradient(bias).astype(s.dtype)
+        s = s + jax.lax.stop_gradient(bias).astype(s.dtype)[:, :, None]
     if causal:
-        Sq, Sk = q.shape[2], k.shape[2]
         rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        keep = rows >= cols
+        if window is not None:
+            keep = keep & (rows - cols < window)
+        s = jnp.where(keep, s, _NEG_INF)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return jnp.einsum("bgrqk,bgkd->bgrqd", p, v).reshape(B, H, Sq, D)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, bias, scale, causal, bq, bk, interpret):
-    out, _ = _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, bias, scale, causal, bq, bk, interpret, window):
+    out, _ = _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
+                         window)
     return out
 
 
-def _flash_fwd(q, k, v, bias, scale, causal, bq, bk, interpret):
-    out, lse = _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret)
+def _flash_fwd(q, k, v, bias, scale, causal, bq, bk, interpret, window):
+    out, lse = _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
+                           window)
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_bwd(scale, causal, bq, bk, interpret, res, do):
+def _flash_bwd(scale, causal, bq, bk, interpret, window, res, do):
     q, k, v, bias, out, lse = res
+    if window is not None or q.shape[1] != k.shape[1]:
+        raise NotImplementedError(
+            "flash_attention's Pallas backward has neither a window nor "
+            "grouped queries (forward only: serving prefill); train "
+            "through impl='xla'")
     dq, dk, dv = _bwd_pallas(q, k, v, bias, scale, causal, bq, bk,
                              interpret, out, lse, do)
     dbias = None if bias is None else jnp.zeros_like(bias)
@@ -596,14 +647,28 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
-                    impl=None, block_q=None, block_k=None, mesh=None):
-    """Blockwise fused attention. q [B,H,Sq,D], k/v [B,H,Sk,D], optional
-    additive key bias [B,1,1,Sk] (constant — zero cotangent). Returns
-    [B,H,Sq,D]. impl: None (auto), "pallas", "interpret", "xla". Under
-    ``mesh`` the kernel runs per shard (batch over the data axes, heads
-    over tp); the composite is left to GSPMD."""
+                    impl=None, block_q=None, block_k=None, mesh=None,
+                    window=None):
+    """Blockwise fused attention. q [B,H,Sq,D], k/v [B,Hkv,Sk,D] with
+    ``H`` a multiple of ``Hkv`` (query head h reads KV head
+    ``h // (H // Hkv)``; the KV is not repeated in memory), optional
+    additive key bias [B,1,1,Sk] (constant — zero cotangent). ``window``
+    (with ``causal``) keeps key j for query i iff ``i - window < j <=
+    i``; blocks wholly outside the band are skipped as the causal
+    triangle's are. Returns [B,H,Sq,D]. impl: None (auto), "pallas",
+    "interpret", "xla". The Pallas backward raises for a window or
+    grouped queries. Under ``mesh`` the kernel runs per shard (batch
+    over the data axes, heads over tp); the composite is left to
+    GSPMD."""
     if scale is None or scale == 0.0:
         scale = float(q.shape[-1]) ** -0.5
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            f"flash_attention: {q.shape[1]} query heads do not divide "
+            f"into {k.shape[1]} key / {v.shape[1]} value heads")
+    window = int(window) if window else None
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
     reason = "requested" if impl else "backend"
     requested, impl = impl, impl or _dispatch.auto_impl()
     if bias is not None and (bias.ndim != 4 or bias.shape[1] != 1
@@ -619,11 +684,11 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
             impl, reason = "xla", "general_bias"
     with _dispatch.resolved("flash_attention", impl, reason):
         if impl == "xla":
-            return _xla_attention(q, k, v, bias, scale, causal)
+            return _xla_attention(q, k, v, bias, scale, causal, window)
 
         def kernel(q, k, v, bias):
             return _flash(q, k, v, bias, float(scale), bool(causal),
-                          block_q, block_k, impl == "interpret")
+                          block_q, block_k, impl == "interpret", window)
 
         bhsd = ("batch", "heads", None, None)
         return _dispatch.per_shard(

@@ -82,38 +82,64 @@ def dequantize_kv(q, scale):
 
 # -------------------------------------------------------------- reference
 
+def window_blocks(window, bs):
+    """Blocks a window layer's row can still read: the one that holds
+    the query and ``ceil(window / bs)`` behind it."""
+    return -(-int(window) // int(bs)) + 1
+
+
+def _first_block(pos, window, bs):
+    """The first logical block a row at ``pos`` still reads."""
+    return jnp.maximum(pos - (window - 1), 0) // bs
+
+
 def _xla_paged_attention(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                         scale):
+                         scale, window=None):
     """Gather-then-attend composite: per-row ``jnp.take`` of the row's
     blocks, per-row position mask, fp32 softmax — identical math to
     ``ops.decode_ops.kv_cached_attention`` over the gathered layout.
-    Runs anywhere (CPU CI) and is the kernel's parity oracle."""
+    Runs anywhere (CPU CI) and is the kernel's parity oracle. Grouped
+    queries fold against the KV heads they share; with a ``window`` the
+    table is a ring (logical block ``b`` at column ``b % width``) and a
+    key is visible iff it is at most ``window - 1`` behind its query."""
     B, H, S, D = q.shape
-    bs = k_pool.shape[2]
+    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
     nblk = tables.shape[1]
     L = nblk * bs
+    rep = H // Hkv
+    pos = pos.astype(jnp.int32)
 
     def gather(pool, sc):
-        # [B, nblk, H, bs, D] -> [B, H, L, D], dequantized
+        # [B, nblk, Hkv, bs, D] -> [B, Hkv, L, D], dequantized
         g = jnp.take(pool, tables, axis=0)
         if sc is not None:
-            gs = jnp.take(sc, tables, axis=0)        # [B, nblk, H, bs]
+            gs = jnp.take(sc, tables, axis=0)        # [B, nblk, Hkv, bs]
             g = dequantize_kv(g, gs)
         g = g.astype(jnp.float32)
-        return g.transpose(0, 2, 1, 3, 4).reshape(B, H, L, D)
+        return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, L, D)
 
     k = gather(k_pool, k_scale)
     v = gather(v_pool, v_scale)
-    scores = jnp.einsum("bhsd,bhld->bhsl", q.astype(jnp.float32),
-                        k) * scale
-    key_idx = jnp.arange(L, dtype=jnp.int32)[None, None, :]       # [1,1,L]
-    qry_pos = pos.astype(jnp.int32)[:, None, None] \
-        + jnp.arange(S, dtype=jnp.int32)[None, :, None]
-    mask = key_idx <= qry_pos                                     # [B,S,L]
-    scores = jnp.where(mask[:, None, :, :], scores, _NEG_INF)
+    qg = q.astype(jnp.float32).reshape(B, Hkv, rep, S, D)
+    scores = jnp.einsum("bgrsd,bgld->bgrsl", qg, k) * scale
+    qry_pos = pos[:, None, None] \
+        + jnp.arange(S, dtype=jnp.int32)[None, :, None]          # [B,S,1]
+    if window is None:
+        key_pos = jnp.arange(L, dtype=jnp.int32)[None, None, :]  # [1,1,L]
+        mask = key_pos <= qry_pos                                # [B,S,L]
+    else:
+        # column c of the ring holds the one logical block of
+        # [lo, lo + nblk) that is c modulo nblk
+        lo = _first_block(pos, window, bs)                       # [B]
+        col = jnp.arange(nblk, dtype=jnp.int32)[None, :]
+        blk = lo[:, None] + (col - lo[:, None]) % nblk           # [B,nblk]
+        key_pos = (blk[:, :, None] * bs + jnp.arange(
+            bs, dtype=jnp.int32)[None, None, :]).reshape(B, 1, L)
+        mask = (key_pos <= qry_pos) & (key_pos > qry_pos - window)
+    scores = jnp.where(mask[:, None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhsl,bhld->bhsd", probs, v)
-    return out.astype(q.dtype)
+    out = jnp.einsum("bgrsl,bgld->bgrsd", probs, v)
+    return out.reshape(B, H, S, D).astype(q.dtype)
 
 
 # ----------------------------------------------------------------- kernel
@@ -156,7 +182,7 @@ def decode_grid(B, H, bs, D, dtype, nblk):
     return (B, pl.cdiv(nblk, g)), g
 
 
-def _live_tables(tables, pos, bs, G, steps):
+def _live_tables(tables, pos, bs, G, steps, window=None):
     """``[B, steps * G]``: the block each (row, step, operand) fetches.
     Entry (b, j * G + i) is ``tables[b, j * G + i]`` up to the row's last
     live block ``pos[b] // bs``; past it, a live step's tail repeats that
@@ -165,30 +191,38 @@ def _live_tables(tables, pos, bs, G, steps):
     stands still and Pallas elides the fetch. Computed here, once a
     decode step, because an index map pays for its arithmetic at every
     (operand, grid step): 0.09 us with the clamp inside it, 0.04 us as
-    one table read."""
-    last = jnp.clip(pos // bs, 0, tables.shape[1] - 1)               # [B]
+    one table read. With a ``window`` entry 0 is the row's first block
+    still in reach (:func:`_first_block`) and the table a ring."""
+    if window is None:
+        lo = 0
+        last = jnp.clip(pos // bs, 0, tables.shape[1] - 1)           # [B]
+    else:
+        lo = _first_block(pos, window, bs)
+        last = pos // bs - lo                      # live blocks less one
     step = jnp.minimum(jnp.arange(steps, dtype=jnp.int32)[None, :],
                        (last // G)[:, None])                   # [B, steps]
     col = step[:, :, None] * G + jnp.arange(G, dtype=jnp.int32)
     col = jnp.minimum(col, last[:, None, None]).reshape(-1, steps * G)
+    if window is not None:
+        col = (lo[:, None] + col) % tables.shape[1]
     return jnp.take_along_axis(tables, col, axis=1)
 
 
-def _slot_of(H, bs, G):
-    """``[H, G * H * bs]`` int32: for query head h and a step's key
-    column (g, h', t), the key's slot in the step ``g * bs + t`` where
-    ``h' == h`` and ``_NO_SLOT`` elsewhere. One compare against ``pos``
-    less the step's first slot masks the other heads' keys and the slots
-    past the row's position together."""
+def _slot_of(H, bs, G, rep=1):
+    """``[H * rep, G * H * bs]`` int32: for query head h and a step's
+    key column (g, h', t), the key's slot in the step ``g * bs + t``
+    where ``h'`` is the KV head that h reads (``h // rep``) and
+    ``_NO_SLOT`` elsewhere. One compare against ``pos`` less the step's
+    first slot masks the other heads' keys and the slots past the row's
+    position together."""
     g, h2, t = np.meshgrid(np.arange(G), np.arange(H), np.arange(bs),
                            indexing="ij")
-    own = h2.reshape(1, -1) == np.arange(H)[:, None]
+    own = h2.reshape(1, -1) == (np.arange(H * rep) // rep)[:, None]
     return np.where(own, (g * bs + t).reshape(1, -1),
                     _NO_SLOT).astype(np.int32)
 
 
-def _paged_kernel(live_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
-                  G, quant):
+def _paged_kernel(*refs, scale, bs, G, quant, window=None):
     """Grid step (b, j) folds table entries ``[j * G, (j + 1) * G)`` of
     row b, all H heads at once, into the row's online-softmax state. The
     gather already happened in the index maps: ``refs`` hold G key
@@ -204,13 +238,19 @@ def _paged_kernel(live_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
     tiles (the flash kernel's idiom): Mosaic stores vectors to VMEM,
     never scalars. int8 tiles are dequantized against their ``[H, bs]``
     scale tiles as they leave VMEM."""
+    # scalar prefetch: the live table (read by the index maps alone),
+    # pos and, with a window, the position of the table's first slot
+    pos_ref, first_ref = refs[1], (refs[2] if window is not None else None)
+    refs = refs[3 if window is not None else 2:]
+    q_ref, slot_of_ref, refs = refs[0], refs[1], refs[2:]
     k_refs, v_refs, refs = refs[:G], refs[G:2 * G], refs[2 * G:]
     ks_refs = vs_refs = (None,) * G
     if quant:
         ks_refs, vs_refs, refs = refs[:G], refs[G:2 * G], refs[2 * G:]
     out_ref, m_sc, l_sc, acc_sc = refs
     b, j = pl.program_id(0), pl.program_id(1)
-    H, D = q_ref.shape[1:]
+    D = q_ref.shape[2]
+    H = k_refs[0].shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -218,7 +258,8 @@ def _paged_kernel(live_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    p = pos_ref[b]
+    # the query's position counted from the table's first slot
+    p = pos_ref[b] if window is None else pos_ref[b] - first_ref[b]
 
     def tiles(refs, scale_refs):
         rows = []
@@ -240,7 +281,11 @@ def _paged_kernel(live_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
             q_ref[0].astype(jnp.float32), tiles(k_refs, ks_refs),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # [H, G*H*bs]
-        s = jnp.where(slot_of_ref[...] <= p - j * (G * bs), s, _NEG_INF)
+        rel = p - j * (G * bs)          # the query's slot in this step
+        keep = slot_of_ref[...] <= rel
+        if window is not None:
+            keep = keep & (slot_of_ref[...] > rel - window)
+        s = jnp.where(keep, s, _NEG_INF)
         m_prev = m_sc[:, :1]                                  # [H, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -260,13 +305,14 @@ def _paged_kernel(live_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
 
 
 def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
-                            v_scale, scale, interpret):
-    B, H, S, D = q.shape
+                            v_scale, scale, interpret, window=None):
+    B, Hq, S, D = q.shape
     if S != 1:
         raise ValueError(
             f"paged_attention kernel decodes ONE query per row (S=1), "
             f"got S={S}; prefill goes through flash_attention")
-    bs = k_pool.shape[2]
+    H, bs = k_pool.shape[1], k_pool.shape[2]
+    rep = Hq // H
     tile = _tile_bytes(H, bs, D, k_pool.dtype)
     if 4 * tile > _VMEM_BUDGET:
         raise ValueError(
@@ -275,25 +321,29 @@ def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
             f"double-buffered take {4 * tile} of the kernel's "
             f"{_VMEM_BUDGET}: lower kv_block_size")
     quant = k_scale is not None
-    grid, G = decode_grid(B, H, bs, D, k_pool.dtype, tables.shape[1])
-    slot_of = _slot_of(H, bs, G)
+    # a window row reads at most window_blocks of its ring, whatever the
+    # ring's width
+    nblk = tables.shape[1] if window is None \
+        else min(tables.shape[1], window_blocks(window, bs))
+    grid, G = decode_grid(B, H, bs, D, k_pool.dtype, nblk)
+    slot_of = _slot_of(H, bs, G, rep)
 
     # index maps see the grid indices THEN the scalar-prefetch refs: the
     # i-th pool operand's block for (b, j) is whatever the row's live
     # table names at j * G + i — the fused gather
-    def row(b, j, live, p):
+    def row(b, j, *scalars):
         return (b, 0, 0)
 
     def block(i, ndim):
-        return lambda b, j, live, p: (live[b, j * G + i],) + (0,) * ndim
+        return lambda b, j, live, *_: (live[b, j * G + i],) + (0,) * ndim
 
     def pool_specs(pool):
         return [pl.BlockSpec((1,) + pool.shape[1:], block(i, pool.ndim - 1))
                 for i in range(G)]
 
-    in_specs = [pl.BlockSpec((1, H, D), row),
-                pl.BlockSpec(slot_of.shape, lambda b, j, live, p: (0, 0))]
-    args = [q.reshape(B, H, D), slot_of]
+    in_specs = [pl.BlockSpec((1, Hq, D), row),
+                pl.BlockSpec(slot_of.shape, lambda b, j, *scalars: (0, 0))]
+    args = [q.reshape(B, Hq, D), slot_of]
     # whole [H, bs, D] tiles, and for int8 the block's whole [H, bs]
     # scale tile: a block's last two dims divide (8, 128) or equal the
     # array's
@@ -301,37 +351,46 @@ def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
         in_specs += pool_specs(pool)
         args += [pool] * G
 
+    pos = pos.astype(jnp.int32)
+    scalars = [_live_tables(tables.astype(jnp.int32), pos, bs, G, grid[1],
+                            window), pos]
+    if window is not None:
+        scalars.append(_first_block(pos, window, bs) * bs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D), row),
-        scratch_shapes=[pltpu.VMEM((H, _LANES), jnp.float32),
-                        pltpu.VMEM((H, _LANES), jnp.float32),
-                        pltpu.VMEM((H, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, Hq, D), row),
+        scratch_shapes=[pltpu.VMEM((Hq, _LANES), jnp.float32),
+                        pltpu.VMEM((Hq, _LANES), jnp.float32),
+                        pltpu.VMEM((Hq, D), jnp.float32)],
     )
-    pos = pos.astype(jnp.int32)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=bs, G=G,
-                          quant=quant),
+                          quant=quant, window=window),
         name="paged_attention_decode", grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
-    )(_live_tables(tables.astype(jnp.int32), pos, bs, G, grid[1]), pos,
-      *args)
-    return out.reshape(B, H, 1, D)
+    )(*scalars, *args)
+    return out.reshape(B, Hq, 1, D)
 
 
 # ----------------------------------------------------------- public entry
 
 def paged_attention(q, k_pool, v_pool, block_tables, pos, k_scale=None,
-                    v_scale=None, scale=None, impl=None, mesh=None):
+                    v_scale=None, scale=None, impl=None, mesh=None,
+                    window=None):
     """Decode attention of one query per row over a block-paged KV pool.
 
-    q ``[B, H, 1, D]``; k_pool/v_pool ``[num_blocks, H, block_size, D]``
-    (float32/bfloat16, or int8 with ``k_scale``/``v_scale``
-    ``[num_blocks, H, block_size]``); block_tables ``[B, blocks_per_row]``
-    int32; pos ``[B]`` int32. Returns ``[B, H, 1, D]`` in q's dtype.
+    q ``[B, H, 1, D]``; k_pool/v_pool ``[num_blocks, Hkv, block_size,
+    D]`` with ``H`` a multiple of ``Hkv`` (query head h reads KV head
+    ``h // (H // Hkv)``; float32/bfloat16, or int8 with
+    ``k_scale``/``v_scale`` ``[num_blocks, Hkv, block_size]``);
+    block_tables ``[B, blocks_per_row]`` int32; pos ``[B]`` int32.
+    With ``window`` a key is visible iff it is at most ``window - 1``
+    behind its query, and the table is a ring: logical block ``b`` of a
+    row is column ``b % blocks_per_row``, which has to be at least
+    :func:`window_blocks` wide. Returns ``[B, H, 1, D]`` in q's dtype.
     impl: None (auto — pallas on a TPU, xla elsewhere, and xla for more
     than one query per row), "pallas", "interpret" (Pallas interpreter,
     CPU-runnable), "xla" (the gather composite / parity oracle). Every
@@ -345,6 +404,17 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, k_scale=None,
                          "v_scale for a quantized pool (or neither)")
     if k_pool.dtype == jnp.int8 and k_scale is None:
         raise ValueError("int8 KV pool needs k_scale/v_scale arrays")
+    if q.shape[1] % k_pool.shape[1]:
+        raise ValueError(
+            f"paged_attention: {q.shape[1]} query heads do not divide "
+            f"into the pool's {k_pool.shape[1]} KV heads")
+    window = int(window) if window else None
+    if window is not None and block_tables.shape[1] < window_blocks(
+            window, k_pool.shape[2]):
+        raise ValueError(
+            f"paged_attention: a ring of {block_tables.shape[1]} blocks "
+            f"cannot hold a window of {window} at block size "
+            f"{k_pool.shape[2]}")
     reason = "requested" if impl else "backend"
     if not impl:
         impl = _dispatch.auto_impl()
@@ -358,12 +428,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, k_scale=None,
         if impl == "xla":
             return _xla_paged_attention(q, k_pool, v_pool, block_tables,
                                         pos, k_scale, v_scale,
-                                        float(scale))
+                                        float(scale), window)
 
         def kernel(q, k_pool, v_pool, tables, pos, k_scale, v_scale):
             return _pallas_paged_attention(
                 q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                float(scale), impl == "interpret")
+                float(scale), impl == "interpret", window)
 
         # rows stay whole: every row's table may name any pool block
         nhbd = (None, "heads", None, None)

@@ -739,11 +739,17 @@ def ring_attention(q, k, v, attn_bias=None, scale=0.0, mechanism="ring",
 
 
 def flash_attention(q, k, v, attn_bias=None, scale=0.0, causal=False,
-                    impl=None, block_q=None, block_k=None, name=None):
+                    impl=None, block_q=None, block_k=None, name=None,
+                    window=None, scope=None):
     """Fused blockwise attention (Pallas kernel on TPU; exact XLA composite
-    elsewhere). q/k/v: [B, n_head, S, d_head]; attn_bias: optional additive
-    key mask [B, 1, 1, S] (constant — no gradient flows to it). Never
-    materializes the [S, S] score matrix in HBM on the Pallas path."""
+    elsewhere). q: [B, n_head, S, d_head]; k/v the same or with fewer
+    heads (grouped queries: n_head a multiple of theirs); attn_bias:
+    optional additive key mask [B, 1, 1, S] (constant — no gradient
+    flows to it); ``window`` with ``causal`` keeps the last ``window``
+    keys of each query; ``scope`` is a ``jax.named_scope`` the op's
+    computation runs under, so that a trace tells the kinds of layer
+    apart. Never materializes the [S, S] score matrix in HBM on the
+    Pallas path."""
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     ins = {"Q": [q], "K": [k], "V": [v]}
@@ -754,7 +760,8 @@ def flash_attention(q, k, v, attn_bias=None, scale=0.0, causal=False,
         outputs={"Out": [out]},
         attrs={"scale": float(scale), "causal": bool(causal),
                "impl": impl or "",
-               "block_q": int(block_q or 0), "block_k": int(block_k or 0)},
+               "block_q": int(block_q or 0), "block_k": int(block_k or 0),
+               "window": int(window or 0), "scope": scope or ""},
         infer_shape=False)
     out.shape = tuple(q.shape or ())
     out.dtype = q.dtype
@@ -796,7 +803,7 @@ def kv_cached_attention(q, k_cache, v_cache, pos, scale=0.0, name=None):
 
 
 def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
-                         name=None):
+                         name=None, ring=False):
     """Append S new ``kv`` vectors [B, H, S, D] into the block-paged
     pool ``cache`` [num_blocks, H, block_size, D] at each row's own
     ``pos`` [B] int32, routed through the per-row block ``tables``
@@ -804,7 +811,8 @@ def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
     S vectors are real per row (chunked prefill's ragged tail; the rest
     route to the trash block). For an int8 pool pass its ``scale``
     array [num_blocks, H, block_size]; the op quantizes and returns
-    ``(updated_pool, updated_scale)``, else just the updated pool."""
+    ``(updated_pool, updated_scale)``, else just the updated pool.
+    ``ring`` says the table is a window layer's ring (one token a row)."""
     helper = LayerHelper("paged_kv_cache_write", name=name)
     out = helper.create_variable_for_type_inference(dtype=cache.dtype)
     ins = {"Cache": [cache], "KV": [kv], "Tables": [tables],
@@ -820,7 +828,7 @@ def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
         outs["OutScale"] = [out_scale]
     helper.append_op(
         type="paged_kv_cache_write", inputs=ins, outputs=outs,
-        attrs={}, infer_shape=False)
+        attrs={"ring": bool(ring)}, infer_shape=False)
     out.shape = tuple(cache.shape or ())
     out.dtype = cache.dtype
     if out_scale is not None:
@@ -831,14 +839,17 @@ def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
 
 
 def paged_attention(q, k_cache, v_cache, tables, pos, k_scale=None,
-                    v_scale=None, scale=0.0, impl=None, name=None):
+                    v_scale=None, scale=0.0, impl=None, name=None,
+                    window=None, scope=None):
     """Decode attention of S queries per row (``q`` [B, H, S, D] —
     S=1 decode, S>1 chunked prefill) over the block-paged KV pool
     ([num_blocks, H, block_size, D], int8 pools with their
     [num_blocks, H, block_size] scales), gathered through the per-row
     block ``tables`` and masked by per-row ``pos`` counters — the paged
     analogue of :func:`kv_cached_attention`. Fused Pallas gather+attend
-    on TPU for S=1; ``jnp.take`` reference elsewhere and for S>1."""
+    on TPU for S=1; ``jnp.take`` reference elsewhere and for S>1. The
+    pools may have fewer heads than ``q`` (grouped queries); ``window``
+    makes ``tables`` a ring and keeps each query's last ``window`` keys."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     ins = {"Q": [q], "K": [k_cache], "V": [v_cache],
@@ -848,11 +859,105 @@ def paged_attention(q, k_cache, v_cache, tables, pos, k_scale=None,
         ins["VScale"] = [v_scale]
     helper.append_op(
         type="paged_attention", inputs=ins, outputs={"Out": [out]},
-        attrs={"scale": float(scale), "impl": impl or ""},
+        attrs={"scale": float(scale), "impl": impl or "",
+               "window": int(window or 0), "scope": scope or ""},
         infer_shape=False)
     out.shape = tuple(q.shape or ())
     out.dtype = q.dtype
     return out
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over the last axis with a learned gain (float32
+    statistics, float32 result)."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    gain = helper.create_parameter(
+        helper.param_attr, shape=[int(input.shape[-1])], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        type="rms_norm", inputs={"X": [input], "Scale": [gain]},
+        outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)},
+        infer_shape=False)
+    out.shape = tuple(input.shape or ())
+    out.dtype = "float32"
+    return out
+
+
+def rotary_embedding(x, pos, inv_freq, attention_factor=1.0, name=None):
+    """Rotary position embedding of ``x`` [B, H, S, D] at positions
+    ``pos`` [B, S] int32: lane i pairs with lane i + D/2, angles
+    ``pos * inv_freq[i]`` (``inv_freq``: D/2 floats, from the model's
+    config), cos and sin scaled by ``attention_factor``."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op(
+        type="rotary_embedding", inputs={"X": [x], "Pos": [pos]},
+        outputs={"Out": [out]},
+        attrs={"inv_freq": [float(f) for f in inv_freq],
+               "attention_factor": float(attention_factor)},
+        infer_shape=False)
+    out.shape = tuple(x.shape or ())
+    out.dtype = x.dtype
+    return out
+
+
+def dense_acc32(input, size, dtype="float32", param_attr=None, name=None):
+    """``input`` [..., d] @ W [d, size] with no bias: W is held in
+    ``dtype``, the input is rounded to it, the product accumulates in
+    float32 and comes out float32."""
+    helper = LayerHelper("dense_acc32", param_attr=param_attr, name=name)
+    w = helper.create_parameter(
+        helper.param_attr, shape=[int(input.shape[-1]), int(size)],
+        dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        type="dense_acc32", inputs={"X": [input], "W": [w]},
+        outputs={"Out": [out]}, attrs={}, infer_shape=False)
+    out.shape = tuple(input.shape[:-1] or ()) + (int(size),)
+    out.dtype = "float32"
+    return out
+
+
+def routed_experts(input, num_experts, num_experts_per_tok,
+                   intermediate_size, norm_topk_prob=True, dtype="float32",
+                   valid=None, name=None, param_attr=None, impl=None):
+    """Top-k routed SwiGLU experts, dropless (ops/moe_ops.routed_experts):
+    a float32 router [d, E] (softmax over all experts, the k largest,
+    renormalised with ``norm_topk_prob``) and per-expert gate, up
+    [E, d, f] and down [E, f, d] matrices held in ``dtype``.
+    ``param_attr`` maps ``router``, ``gate``, ``up``, ``down`` to a
+    ParamAttr each; ``valid`` marks real tokens (padding routes
+    nowhere). Returns ``(out, counts)``: out like ``input`` in float32,
+    counts [E] int32 the assignments each expert got."""
+    helper = LayerHelper("routed_experts", name=name)
+    attr = param_attr or {}
+    d, E, f = int(input.shape[-1]), int(num_experts), int(intermediate_size)
+    router = helper.create_parameter(attr.get("router"), shape=[d, E],
+                                     dtype="float32")
+    w_gate = helper.create_parameter(attr.get("gate"), shape=[E, d, f],
+                                     dtype=dtype)
+    w_up = helper.create_parameter(attr.get("up"), shape=[E, d, f],
+                                   dtype=dtype)
+    w_down = helper.create_parameter(attr.get("down"), shape=[E, f, d],
+                                     dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype="float32")
+    counts = helper.create_variable_for_type_inference(dtype="int32")
+    ins = {"X": [input], "RouterW": [router], "WGate": [w_gate],
+           "WUp": [w_up], "WDown": [w_down]}
+    if valid is not None:
+        ins["Valid"] = [valid]
+    helper.append_op(
+        type="routed_experts", inputs=ins,
+        outputs={"Out": [out], "Counts": [counts]},
+        attrs={"top_k": int(num_experts_per_tok),
+               "norm_topk_prob": bool(norm_topk_prob), "impl": impl or ""},
+        infer_shape=False)
+    out.shape = tuple(input.shape or ())
+    out.dtype = "float32"
+    counts.shape = (E,)
+    counts.dtype = "int32"
+    return out, counts
 
 
 def row_gather(x, index, name=None):

@@ -8,7 +8,8 @@ bucketed prefill over the prompt (building every layer's
 step whose per-token cost is a cache append + read. All executables are
 AOT-compiled (``jit.lower().compile()``) into a serving
 ``ExecutableCache`` — length-bucketed prefill shapes stay bounded
-(power-of-two buckets, ``FLAGS_decode_bucket_min`` floor) and the cache's
+(power-of-two buckets and, for long prompts, their midpoints;
+``FLAGS_decode_bucket_min`` floor) and the cache's
 hit/miss/evict counters make compile traffic observable. Sampling
 (greedy / temperature / top-k, per ROW) is the ``sample_tokens`` op
 drawing from the framework RNG stream: a fixed seed reproduces the
@@ -26,7 +27,6 @@ import numpy as np
 from ..flags import flag
 from ..observability import tracing as _trace
 from ..observability import utilization as _util
-from . import gpt
 
 # fluid program construction mutates process-global state (the default
 # program pair swapped by ``program_guard`` plus the unique_name
@@ -48,12 +48,36 @@ class TPCompileGateError(RuntimeError):
     replicated serving fleet burns N chips for 1 chip's throughput."""
 
 
+class UnsupportedPathError(NotImplementedError):
+    """A serving path that is not built for the architecture being
+    served (``path`` names it: speculative verify, chunked prefill, the
+    dense KV bank, ``tp > 1``, a KV pool dtype). Raised when the path is
+    asked for, before any compile; the architecture's serving object
+    (``cfg.serving()``) decides what it has."""
+
+    def __init__(self, arch, path):
+        super().__init__(
+            f"{arch} has no {path} path: it serves through prefill and "
+            f"the paged decode step only")
+        self.arch, self.path = arch, path
+
+
+# from here up a prompt's padding is tens of milliseconds of prefill, so
+# the ladder of lengths also has the steps between two powers of two
+_HALF_STEPS_FROM = 2048
+
+
 def length_bucket(n, lo=1):
-    """Smallest power-of-two >= n (>= lo): bounded padding waste and a
-    bounded universe of compiled prefill shapes — the serving batcher's
+    """Smallest bucket >= n (>= lo): the powers of two and, from
+    ``_HALF_STEPS_FROM`` up, their midpoints (2048, 3072, 4096, 6144,
+    ...), which stay multiples of 1024 for the flash kernel's blocks.
+    Bounded padding waste (< 2x, < 1.5x for long prompts) and a bounded
+    universe of compiled prefill shapes — the serving batcher's
     bucketing policy, shared so prefill and batch buckets can't drift."""
     from ..serving.batching import next_bucket
-    return next_bucket(n, min_bucket=lo)
+    b = next_bucket(n, min_bucket=lo)
+    mid = b // 4 * 3
+    return mid if mid >= _HALF_STEPS_FROM and mid >= max(n, lo) else b
 
 
 def _sample_program_outs():
@@ -244,16 +268,19 @@ class GPTGenerator:
         self.cache = cache
         self.stats = stats
         self.tp = int(flag("serving_tp") if tp is None else tp)
+        # the architecture's own programs and pool layout come from the
+        # config (models/gpt.GPTServing, models/mellum.MellumServing)
+        self.arch = cfg.serving()
+        if self.tp > 1 and not self.arch.supports_tp:
+            raise UnsupportedPathError(self.arch.name, "tp > 1")
         self.mesh = self._init_tp_mesh() if self.tp > 1 else None
 
-        builders = {
-            "prefill": lambda: gpt.gpt_prefill(cfg, self.max_len),
-            "decode": lambda: gpt.gpt_decode_step(cfg, self.max_len),
-            "logits": lambda: gpt.gpt_logits(cfg),
+        builders = dict(self.arch.eager_builders(self.max_len))
+        builders.update({
             "sample": _sample_program_outs,
             "sample_temp": _sample_temp_program_outs,
             "sample_greedy": _greedy_program_outs,
-        }
+        })
         self._progs = {}
         with _PROG_BUILD_LOCK:
             for kind, build in builders.items():
@@ -280,8 +307,8 @@ class GPTGenerator:
     def _init_tp_mesh(self):
         """Build (and install as ambient) the tp mesh every generation
         executable compiles under — the SAME Megatron column/row scheme
-        training uses (gpt.apply_tp_sharding), so a trained tp
-        checkpoint serves without resharding."""
+        training uses (the architecture's ``apply_tp_sharding``), so a
+        trained tp checkpoint serves without resharding."""
         import jax
         from ..parallel.mesh import MeshConfig, make_mesh, set_mesh
         ndev = len(jax.devices())
@@ -289,10 +316,10 @@ class GPTGenerator:
             raise ValueError(
                 f"FLAGS_serving_tp={self.tp} exceeds the {ndev} visible "
                 f"device(s)")
-        if self.cfg.num_heads % self.tp:
+        if self.arch.kv_heads % self.tp:
             raise ValueError(
                 f"serving_tp={self.tp} must divide num_heads="
-                f"{self.cfg.num_heads} (the KV pool shards on the head "
+                f"{self.arch.kv_heads} (the KV pool shards on the head "
                 f"axis)")
         mesh = make_mesh(MeshConfig(tp=self.tp))
         set_mesh(mesh)
@@ -304,7 +331,7 @@ class GPTGenerator:
         sampler/acceptance programs)."""
         if self.mesh is not None and not kind.startswith("sample") \
                 and kind != "spec_accept":
-            gpt.apply_tp_sharding(main, self.cfg)
+            self.arch.apply_tp_sharding(main)
 
     def apply_pool_sharding(self, pool):
         """Shard a :class:`serving.kvpool.KVBlockPool`'s device arrays
@@ -384,12 +411,22 @@ class GPTGenerator:
             return [outs["tokens"].name, outs["accepted"].name]
         if "tokens" in outs:
             return [outs["tokens"].name]
+        # what an architecture returns beside logits and caches (the
+        # expert layers' assignment counts) comes last, in its own order
+        aux = [v.name for v in outs.get("aux", {}).values()]
         if "cache_vars" in outs:            # paged decode: pool arrays
             return ([outs["logits"].name]
-                    + [v.name for v in outs["cache_vars"]])
+                    + [v.name for v in outs["cache_vars"]] + aux)
         return ([outs["logits"].name]
                 + [v.name for v in outs.get("cache_k", ())]
-                + [v.name for v in outs.get("cache_v", ())])
+                + [v.name for v in outs.get("cache_v", ())] + aux)
+
+    def aux_of(self, kind, fetches):
+        """``{name: array}`` of what the ``kind`` program fetched beside
+        logits and caches (empty for GPT)."""
+        names = list(self._ensure_prog(kind)[1].get("aux", {}))
+        return dict(zip(names, fetches[len(fetches) - len(names):])) \
+            if names else {}
 
     def _ensure_prog(self, kind):
         """Program for ``kind``, building the lazily-declared ones on
@@ -399,32 +436,19 @@ class GPTGenerator:
         entry = self._progs.get(kind)
         if entry is not None:
             return entry
-        if not (kind.startswith("decode_paged_")
-                or kind.startswith("prefill_chunk_")
-                or kind.startswith("verify_paged_")
-                or kind in ("verify", "spec_accept")):
-            raise KeyError(f"unknown generation program kind {kind!r}")
         from ..framework.core import Program, program_guard
-        kv_dtype = kind.rsplit("_", 1)[-1]
         with _PROG_BUILD_LOCK:
             entry = self._progs.get(kind)
             if entry is not None:     # lost the build race to a peer
                 return entry
             main, startup = Program(), Program()
             with program_guard(main, startup):
-                if kind == "verify":
-                    outs = gpt.gpt_verify_step(self.cfg, self.max_len)
-                elif kind == "spec_accept":
+                if kind == "spec_accept":
                     outs = _spec_accept_program_outs()
-                elif kind.startswith("verify_paged_"):
-                    outs = gpt.gpt_verify_step_paged(self.cfg,
-                                                     kv_dtype=kv_dtype)
-                elif kind.startswith("decode_paged_"):
-                    outs = gpt.gpt_decode_step_paged(self.cfg,
-                                                     kv_dtype=kv_dtype)
                 else:
-                    outs = gpt.gpt_prefill_chunk_paged(self.cfg,
-                                                       kv_dtype=kv_dtype)
+                    # KeyError for a kind no architecture has, a typed
+                    # UnsupportedPathError for one this one lacks
+                    outs = self.arch.build(kind, self.max_len)
             self._annotate_tp(kind, main)
             self._progs[kind] = (main, outs)
         return self._progs[kind]
@@ -482,6 +506,18 @@ class GPTGenerator:
             state[n] = a
         self._fns[kind] = (jitted, state)
         return self._fns[kind]
+
+    def bind_params(self, device_params):
+        """Adopt arrays that are on the device already as the snapshot
+        of the parameters they name: no host round trip and no second
+        copy (a model that fills most of the chip has room for neither).
+        The caller keeps them out of any call that donates its
+        arguments. Names not given are still pulled from the scope at
+        first use."""
+        self._params.update(device_params)
+        for kind, (jitted, state) in list(self._fns.items()):
+            self._fns[kind] = (jitted, {
+                n: self._params[n] for n in state})
 
     def refresh_state(self):
         """Re-snapshot the scope's parameters onto the device (call after
@@ -592,11 +628,32 @@ class GPTGenerator:
             caches[f"cache_v_{i}"] = fetches[1 + n + i]
         return fetches[0], caches
 
-    def _run_prefill(self, tokens, pos_ids, last_pos, key):
+    def _run_prefill(self, tokens, pos_ids, last_pos, key, kv_dtype=None,
+                     want_aux=False):
+        """One bucketed prefill. ``kv_dtype`` is the pool's, for an
+        architecture whose prefill hands back keys and values in it;
+        ``want_aux`` adds :meth:`aux_of` as a fourth result."""
         feed = {"tokens": tokens, "pos_ids": pos_ids, "last_pos": last_pos}
-        fetches, key = self._invoke("prefill", "prefill", feed, key)
+        kind = self.arch.prefill_kind(kv_dtype or flag("kv_cache_dtype"))
+        fetches, key = self._invoke(kind, "prefill", feed, key)
         logits, caches = self._unpack_caches(fetches)
+        if want_aux:
+            return logits, caches, key, self.aux_of(kind, fetches)
         return logits, caches, key
+
+    def new_pool(self, slots, **kw):
+        """A :class:`serving.kvpool.KVBlockPool` laid out for this
+        architecture: its KV heads, head width and layer groups."""
+        from ..serving.kvpool import KVBlockPool
+        arch = self.arch
+        if kw.get("dtype") and kw["dtype"] not in arch.kv_dtypes:
+            raise UnsupportedPathError(arch.name,
+                                       f"{kw['dtype']} KV pool")
+        pool = KVBlockPool(
+            slots=slots, num_layers=self.cfg.num_layers,
+            num_heads=arch.kv_heads, d_head=arch.head_dim,
+            max_seq_len=self.max_len, groups=arch.kv_groups(), **kw)
+        return self.apply_pool_sharding(pool)
 
     def _run_decode(self, token, pos, caches, key, parent=None):
         feed = dict(caches)
@@ -822,6 +879,7 @@ class GPTGenerator:
             return self._generate_paged(
                 prompts, max_new_tokens, temperature, top_k, eos_id,
                 seed, key, kv_dtype)
+        self._ensure_prog("decode")     # refused by name where there is none
         prompts, lens, key = self._prep(prompts, max_new_tokens, seed,
                                         key)
         B = len(prompts)
@@ -863,31 +921,24 @@ class GPTGenerator:
         caches into a transient :class:`serving.kvpool.KVBlockPool`,
         then per-token paged decode steps with allocation-on-append.
         The pool is freed when generation ends."""
-        from ..serving.kvpool import KVBlockPool
         prompts, lens, key = self._prep(prompts, max_new_tokens, seed,
                                         key)
         B = len(prompts)
         tokens, pos_ids, last = self._pack_prompts(prompts)
         bb, s = tokens.shape
-        cfg = self.cfg
         kv_dtype = kv_dtype or flag("kv_cache_dtype")
         pool_key = (bb, kv_dtype, int(flag("kv_block_size")))
         pool = self._paged_pools.get(pool_key)
         if pool is None:
-            pool = KVBlockPool(
-                slots=bb, num_layers=cfg.num_layers,
-                num_heads=cfg.num_heads,
-                d_head=cfg.hidden_size // cfg.num_heads,
-                max_seq_len=self.max_len, dtype=kv_dtype,
-                name="offline")
-            self.apply_pool_sharding(pool)
+            pool = self.new_pool(bb, dtype=kv_dtype, name="offline")
             self._paged_pools[pool_key] = pool
         try:
             for r in range(B):
                 pool.alloc(r, lens[r])
             logits, row_caches, key = self._run_prefill(
-                tokens, pos_ids, last, key)
-            pool.scatter_prefill(list(range(B)), row_caches, s)
+                tokens, pos_ids, last, key, kv_dtype=kv_dtype)
+            pool.scatter_prefill(list(range(B)), row_caches, s,
+                                 lengths=lens)
 
             temp = np.full((bb,), float(temperature), np.float32)
             topk = np.full((bb,), int(top_k), np.int32)
@@ -938,7 +989,9 @@ class GPTGenerator:
         row's remaining budget; the dense path falls back to plain
         decode steps near the cache end (its fixed-span write cannot
         be trash-routed the way the paged ``limit`` input can)."""
-        from ..serving.kvpool import KVBlockPool
+        # an architecture with no verify program refuses here, by name
+        self._ensure_prog("verify_paged_" + (
+            kv_dtype or flag("kv_cache_dtype")) if paged else "verify")
         prompts, lens, key = self._prep(prompts, max_new_tokens, seed,
                                         key)
         if drafter is None:
@@ -953,13 +1006,7 @@ class GPTGenerator:
             pool_key = (bb, kv_dtype, int(flag("kv_block_size")))
             pool = self._paged_pools.get(pool_key)
             if pool is None:
-                pool = KVBlockPool(
-                    slots=bb, num_layers=cfg.num_layers,
-                    num_heads=cfg.num_heads,
-                    d_head=cfg.hidden_size // cfg.num_heads,
-                    max_seq_len=self.max_len, dtype=kv_dtype,
-                    name="offline")
-                self.apply_pool_sharding(pool)
+                pool = self.new_pool(bb, dtype=kv_dtype, name="offline")
                 self._paged_pools[pool_key] = pool
         try:
             caches = None

@@ -35,6 +35,9 @@ class GPTConfig:
     def base(cls):
         return cls()
 
+    def serving(self):
+        return GPTServing(self)
+
     @classmethod
     def tiny(cls):
         # 1 layer: the test suite compiles this config hundreds of
@@ -570,6 +573,71 @@ def gpt_verify_step_paged(cfg, kv_dtype="fp32", batch_size=-1,
     return {"feed_names": feed_names, "logits": logits,
             "cache_names": cache_names,
             "cache_vars": [by_name[n] for n in cache_names]}
+
+
+# ---- what the serving path asks an architecture for --------------------
+
+class GPTServing:
+    """GPT-2's serving programs, the layout of its keys and values in
+    the pool and the bytes a prefill hands back: the one place
+    ``GPTGenerator``, ``GenerationEngine`` and ``KVBlockPool`` take them
+    from (``models/mellum.MellumServing`` is the other architecture's)."""
+
+    name = "gpt"
+    kv_dtypes = ("fp32", "bf16", "int8")
+    supports_tp = True
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def eager_builders(self, max_len):
+        cfg = self.cfg
+        return {"prefill": lambda: gpt_prefill(cfg, max_len),
+                "decode": lambda: gpt_decode_step(cfg, max_len),
+                "logits": lambda: gpt_logits(cfg)}
+
+    def build(self, kind, max_len):
+        """The program of a lazily built ``kind`` (the paged decode
+        step, chunked prefill and the verify steps exist per KV-cache
+        dtype and most processes never touch them)."""
+        kv_dtype = kind.rsplit("_", 1)[-1]
+        if kind == "verify":
+            return gpt_verify_step(self.cfg, max_len)
+        if kind.startswith("verify_paged_"):
+            return gpt_verify_step_paged(self.cfg, kv_dtype=kv_dtype)
+        if kind.startswith("decode_paged_"):
+            return gpt_decode_step_paged(self.cfg, kv_dtype=kv_dtype)
+        if kind.startswith("prefill_chunk_"):
+            return gpt_prefill_chunk_paged(self.cfg, kv_dtype=kv_dtype)
+        raise KeyError(f"unknown generation program kind {kind!r}")
+
+    def prefill_kind(self, kv_dtype):
+        return "prefill"
+
+    def apply_tp_sharding(self, main):
+        apply_tp_sharding(main, self.cfg)
+
+    @property
+    def kv_heads(self):
+        return self.cfg.num_heads
+
+    @property
+    def head_dim(self):
+        return self.cfg.hidden_size // self.cfg.num_heads
+
+    def kv_groups(self):
+        return [{"name": "full", "window": None,
+                 "layers": list(range(self.cfg.num_layers))}]
+
+    def prefill_bytes(self, rows, seq, max_len, kv_elem_bytes):
+        """Device bytes one prefill of ``rows`` prompts holds at its
+        peak beyond the weights: every layer's dense float32 ``[rows, H,
+        max_len, D]`` key and value caches, whatever the prompts'
+        length, once as the prefill's result and once more in the
+        scatter that relays them into the pool, and the logits."""
+        cfg = self.cfg
+        return int(rows) * (2 * 2 * cfg.num_layers * cfg.hidden_size
+                            * int(max_len) * 4 + cfg.vocab_size * 4)
 
 
 # ---- tensor-parallel sharding annotation (Megatron-style over "tp") ----

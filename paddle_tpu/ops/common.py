@@ -147,3 +147,10 @@ def roi_batch_indices(ins, n_rois):
             ends, jnp.arange(n_rois, dtype=jnp.int32),
             side="right").astype(jnp.int32)
     return jnp.zeros((n_rois,), jnp.int32)
+
+
+def named(scope):
+    """``jax.named_scope(scope)``, or nothing where the op names none."""
+    import contextlib
+    import jax
+    return jax.named_scope(scope) if scope else contextlib.nullcontext()

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.registry import register_op
-from .common import x_of
+from .common import named, x_of
 
 _NEG_INF = -1e30   # additive mask value; -inf breaks softmax on all-masked rows
 
@@ -94,6 +94,10 @@ def paged_kv_cache_write(ctx, ins, attrs):
     optional Scale input [N, H, bs] is updated too (second output
     OutScale).
 
+    With the attribute ``ring`` the table is a window layer's ring:
+    position ``p`` lives in column ``(p // bs) % nblk`` (one token a row
+    only; the prefill scatter fills a ring from outside).
+
     One scatter covers the batch: slots own disjoint blocks and COW
     guarantees a written block has refcount 1, so the valid
     (block, offset) pairs are unique; rows whose table entry is the
@@ -112,10 +116,17 @@ def paged_kv_cache_write(ctx, ins, attrs):
     limit = ins.get("Limit")
 
     outs = {}
+    ring = bool(attrs.get("ring", False))
+    if ring and (S != 1 or limit):
+        raise NotImplementedError(
+            "paged_kv_cache_write: a ring table takes one token a row "
+            "(chunked prefill and the verify span are not built for "
+            "window layers)")
     if S == 1 and not limit:
         # single-token decode fast path (bitwise-identical to the
         # original op)
-        block_ids = tables[jnp.arange(B), pos // bs]        # [B]
+        col = (pos // bs) % tables.shape[1] if ring else pos // bs
+        block_ids = tables[jnp.arange(B), col]              # [B]
         offs = pos % bs                                     # [B]
         vec = kv[:, :, 0, :]                                # [B, H, D]
         if pool.dtype == jnp.int8:
@@ -161,8 +172,10 @@ def paged_attention_op(ctx, ins, attrs):
     Q [B, H, 1, D], K/V pools [N, H, bs, D] (+ KScale/VScale [N, H, bs]
     for int8), Tables [B, nblk] int32, Pos [B] int32 -> Out [B, H, 1, D].
     Dispatches to kernels/paged_attention (Pallas fused gather+attend on
-    TPU; jnp.take reference elsewhere — attrs["impl"] overrides). Under
-    a mesh the kernel runs per shard, heads over tp."""
+    TPU; jnp.take reference elsewhere — attrs["impl"] overrides). The
+    pools may have fewer heads than Q (grouped queries); attrs["window"]
+    (0 = none) makes Tables a ring and keeps the last ``window`` keys.
+    Under a mesh the kernel runs per shard, heads over tp."""
     from ..kernels.paged_attention import paged_attention as _kernel
 
     q = x_of(ins, "Q")
@@ -170,12 +183,14 @@ def paged_attention_op(ctx, ins, attrs):
     v = x_of(ins, "V")
     tables = x_of(ins, "Tables")
     pos = x_of(ins, "Pos")
-    out = _kernel(q, k, v, tables, pos,
-                  k_scale=x_of(ins, "KScale"),
-                  v_scale=x_of(ins, "VScale"),
-                  scale=float(attrs.get("scale", 0.0)) or None,
-                  impl=attrs.get("impl") or None,
-                  mesh=None if ctx.abstract else ctx.mesh)
+    with named(attrs.get("scope")):
+        out = _kernel(q, k, v, tables, pos,
+                      k_scale=x_of(ins, "KScale"),
+                      v_scale=x_of(ins, "VScale"),
+                      scale=float(attrs.get("scale", 0.0)) or None,
+                      impl=attrs.get("impl") or None,
+                      mesh=None if ctx.abstract else ctx.mesh,
+                      window=int(attrs.get("window", 0)) or None)
     return {"Out": out}
 
 

@@ -75,3 +75,36 @@ def switch_moe(ctx, ins, attrs):
     density_proxy = jnp.mean(gates, axis=0)       # mean gate prob / expert
     aux = jnp.mean(density * density_proxy) * (E * E)
     return {"Out": out, "AuxLoss": aux.reshape(())}
+
+
+@register_op("routed_experts", grad=False, infer_shape=False)
+def routed_experts(ctx, ins, attrs):
+    """Top-k routed SwiGLU experts with no capacity and no dropped token
+    (``kernels/moe_experts.py``). inputs: X [..., d] float32, RouterW
+    [d, E] float32, WGate, WUp [E, d, f] and WDown [E, f, d] in the dtype
+    the expert products run in (bfloat16 when served), optional Valid
+    [...] (bool or int: tokens that are padding route nowhere); attrs:
+    top_k, norm_topk_prob, impl ("" = auto). The router is float32
+    throughout: logits at ``precision=highest``, softmax over all E,
+    then the k largest, renormalised to sum 1 with ``norm_topk_prob``.
+    outputs: Out [..., d] float32; Counts [E] int32, the assignments
+    each expert got."""
+    from ..kernels.moe_experts import routed_experts as _experts
+
+    x = x_of(ins)
+    lead, d = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, d).astype(jnp.float32)
+    k = int(attrs["top_k"])
+    valid = ins.get("Valid")
+    valid = valid[0].reshape(-1).astype(bool) if valid else None
+    with jax.named_scope("moe/router"):
+        logits = jnp.dot(x2, x_of(ins, "RouterW").astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = jax.lax.top_k(probs, k)
+        if attrs.get("norm_topk_prob", True):
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    out, counts = _experts(x2, top_i, top_w, x_of(ins, "WGate"),
+                           x_of(ins, "WUp"), x_of(ins, "WDown"),
+                           valid=valid, impl=attrs.get("impl") or None)
+    return {"Out": out.reshape(lead + (d,)), "Counts": counts}

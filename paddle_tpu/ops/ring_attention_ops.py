@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..framework.registry import register_op
-from .common import x_of
+from .common import named, x_of
 
 _NEG_INF = -1e30
 
@@ -164,9 +164,11 @@ def flash_attention_op(ctx, ins, attrs):
     (operators/fused/multihead_matmul_op.cu). inputs: Q, K, V
     [B, H, S, D] (+ optional additive key Bias [B, 1, 1, S], treated as a
     constant mask); attrs: scale (default 1/sqrt(D)), causal, impl
-    ("" = auto: Pallas on TPU, XLA composite elsewhere). Under a mesh
-    the kernel runs per shard: batch over the data axes, heads over
-    tp."""
+    ("" = auto: Pallas on TPU, XLA composite elsewhere), window (0 =
+    none; with causal, key j is visible to query i iff i - window < j
+    <= i). K and V may have fewer heads than Q (grouped queries). Under
+    a mesh the kernel runs per shard: batch over the data axes, heads
+    over tp."""
     from ..kernels.flash_attention import flash_attention as _fa
 
     q = x_of(ins, "Q")
@@ -175,13 +177,16 @@ def flash_attention_op(ctx, ins, attrs):
     bias = ins.get("Bias")
     bias = bias[0] if bias else None
     scale = float(attrs.get("scale", 0.0)) or None
-    out = _fa(q, k, v, bias, scale=scale,
-              causal=bool(attrs.get("causal", False)),
-              impl=attrs.get("impl") or None,
-              block_q=int(attrs.get("block_q", 0)) or None,
-              block_k=int(attrs.get("block_k", 0)) or None,
-              mesh=None if ctx.abstract else ctx.mesh)
+    with named(attrs.get("scope")):
+        out = _fa(q, k, v, bias, scale=scale,
+                  causal=bool(attrs.get("causal", False)),
+                  impl=attrs.get("impl") or None,
+                  block_q=int(attrs.get("block_q", 0)) or None,
+                  block_k=int(attrs.get("block_k", 0)) or None,
+                  mesh=None if ctx.abstract else ctx.mesh,
+                  window=int(attrs.get("window", 0)) or None)
     return {"Out": out}
+
 
 
 @register_op("ulysses_attention", infer_shape=False)

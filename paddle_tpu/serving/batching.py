@@ -408,9 +408,14 @@ class RequestQueue:
         self._record_admission("admitted", rows=req.rows)
         return req
 
-    def get(self, timeout=None):
+    def get(self, timeout=None, accept=None):
         """Pop the oldest request of the HIGHEST populated class, or
-        None on timeout/close. Entries whose deadline expired (or were
+        None on timeout/close. ``accept(req)``, where given, is asked
+        about that request under the queue's lock: refused, it stays
+        where it was, first of its class, and None is returned, so a
+        request the caller has no room for yet is never outside the
+        queue (drain, close and the deadline sweep go on seeing it).
+        Entries whose deadline expired (or were
         abandoned) while queued are failed typed as they reach the
         front — a doomed request must not burn a micro-batch slot —
         and the pop continues to the next live entry. Cost is
@@ -418,7 +423,7 @@ class RequestQueue:
         (the full sweep runs on the put-when-full path, where the
         depth scan is already being paid)."""
         maybe_fail("serving.queue")
-        dead, out = [], None
+        dead, out, refused = [], None, False
         with self._cv:
             if not self._depth_locked():
                 self._cv.wait(timeout)
@@ -432,9 +437,13 @@ class RequestQueue:
                     if req.expired(now):
                         dead.append(req)
                         continue
-                    out = req
+                    if accept is not None and not accept(req):
+                        q.insert(0, req)
+                        refused = True
+                    else:
+                        out = req
                     break
-                if out is not None:
+                if out is not None or refused:
                     break
         self._fail_expired(dead)
         return out
@@ -596,6 +605,9 @@ class DecodeBatcher:
             spec_k = flag("decode_spec_k")
         self.spec_k = int(spec_k) \
             if getattr(engine, "pool", None) is not None else 0
+        if self.spec_k > 0 and hasattr(engine, "gen"):
+            # an architecture with no verify step refuses here, by name
+            engine.gen._ensure_prog(f"verify_paged_{engine.pool.dtype}")
         self._drafter = drafter         # lazy: make_drafter on first use
         self.brownout = brownout
         self._accept_window = deque(maxlen=64)   # (accepted, proposed)
@@ -937,13 +949,23 @@ class DecodeBatcher:
 
     def _admit_inner(self, epoch):
         take = self._admitting_reqs
+        # a round takes no more than its one prefill can hold beside the
+        # weights and the pool, by the engine's count: the request that
+        # would not fit stays in the queue and leads the next round
+        fit = getattr(self.engine, "prefill_fit", None)
+
+        def fits(req):
+            sizes = [r.prompt.size for r in take]
+            return fit(sizes + [req.prompt.size]) > len(sizes)
+
         while self._free and len(take) < len(self._free) \
                 and not self._stop.is_set() and self._epoch == epoch:
             # block briefly only when the bank is idle and nothing was
             # taken yet; once rows are decoding, admission must not
             # stall the step loop
             timeout = 0.05 if not (self._active or take) else 0
-            req = self.queue.get(timeout=timeout)
+            req = self.queue.get(
+                timeout=timeout, accept=fits if fit and take else None)
             if req is None:
                 break
             now = time.monotonic()
@@ -1336,8 +1358,12 @@ class DecodeBatcher:
         attrs["live"] = len(self._active)
         pool = getattr(self.engine, "pool", None)
         if pool is not None:
-            attrs["blocks_in_use"] = pool.blocks_in_use()
+            by_group = pool.blocks_in_use_by_group()
+            attrs["blocks_in_use"] = sum(by_group.values())
             attrs["blocks_total"] = pool.capacity_blocks
+            if "window" in by_group:
+                attrs["blocks_in_use_full"] = by_group["full"]
+                attrs["blocks_in_use_window"] = by_group["window"]
         try:
             with _trace.loop_span("engine/step") as stepped:
                 if drafts is not None:
@@ -1360,6 +1386,8 @@ class DecodeBatcher:
                     toks = self.engine.step(
                         self._tok, self._pos, self._temp,
                         self._topk, budget=self.watchdog_s or None)
+                    stepped.attrs.update(
+                        getattr(self.engine, "step_routing", None) or {})
         except Exception as exc:  # noqa: BLE001
             if self._epoch != epoch:
                 return False     # deposed mid-step: restart() owns

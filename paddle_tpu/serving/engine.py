@@ -417,6 +417,18 @@ class ServingEngine:
         return path
 
 
+def _free_device_bytes():
+    """Bytes the first device has free by its own count, less a tenth of
+    its memory kept for what an executable needs beside its results;
+    None where the backend keeps no count."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    limit, used = stats.get("bytes_limit"), stats.get("bytes_in_use")
+    if not limit or used is None:
+        return None
+    return max(int(0.9 * limit) - int(used), 0)
+
+
 class GenerationEngine:
     """Slot-batched autoregressive decoding primitives for the serving
     runtime, over a ``models.generation.GPTGenerator``.
@@ -459,28 +471,27 @@ class GenerationEngine:
         self.paged = bool(flag("kv_paged") if paged is None else paged)
         self.pool = None
         self.kernel_grid_steps = None
+        if not self.paged:
+            # an architecture with no dense-bank decode step refuses
+            # here, by name, before anything compiles
+            generator._ensure_prog("decode")
         if self.paged:
-            from .kvpool import KVBlockPool, _np_pool_dtype
+            from .kvpool import _np_pool_dtype
             from ..kernels.paged_attention import decode_grid
-            cfg = generator.cfg
-            self.pool = KVBlockPool(
-                slots=self.slots, num_layers=cfg.num_layers,
-                num_heads=cfg.num_heads,
-                d_head=cfg.hidden_size // cfg.num_heads,
-                max_seq_len=generator.max_len,
-                block_size=kv_block_size, num_blocks=kv_pool_blocks,
-                dtype=kv_dtype, name=pool_name,
+            # the architecture's own layout: its KV heads and head width,
+            # its layer groups (tensor-parallel serving: block arrays
+            # sharded on the head axis of the generator's tp mesh)
+            self.pool = generator.new_pool(
+                self.slots, block_size=kv_block_size,
+                num_blocks=kv_pool_blocks, dtype=kv_dtype, name=pool_name,
                 prefix_cache=prefix_cache)
-            if getattr(generator, "mesh", None) is not None:
-                # tensor-parallel serving: the pool's block arrays live
-                # sharded on the head axis of the generator's tp mesh
-                generator.apply_pool_sharding(self.pool)
             # what one paged_attention_decode call of a decode step
-            # launches (the engine/step span's grid_steps): the kernel's
-            # own function of the shapes a shard of it sees
+            # launches over the full layers' table (the engine/step
+            # span's grid_steps): the kernel's own function of the
+            # shapes a shard of it sees
             grid, _ = decode_grid(
                 self.slots,
-                cfg.num_heads // max(getattr(generator, "tp", 1), 1),
+                self.pool.num_heads // max(getattr(generator, "tp", 1), 1),
                 self.pool.block_size, self.pool.d_head,
                 _np_pool_dtype(self.pool.dtype), self.pool.blocks_per_row)
             self.kernel_grid_steps = math.prod(grid)
@@ -499,6 +510,7 @@ class GenerationEngine:
         self._caches = None        # lazy: zeros [slots, H, L, D] per layer
         self._insert_fn = None
         self.bank_lost = False     # see _drop_bank
+        self.step_routing = {}     # the last step's moe_* span attrs
 
     def _ensure_caches(self):
         self.bank_lost = False
@@ -632,7 +644,7 @@ class GenerationEngine:
         """Verify + load new HOST values for every generator parameter
         (building the parameter-bearing programs first if no traffic
         has). Raises without touching the live snapshot."""
-        for kind in ("prefill", "decode", "logits"):
+        for kind in self.gen.arch.eager_builders(self.max_len):
             self.gen._ensure_fn(kind)
         return load_param_snapshot(dirname, self.gen._params)
 
@@ -656,8 +668,16 @@ class GenerationEngine:
         Returns the first tokens as np int32 [len(requests)]."""
         maybe_fail("serving.prefill")
         self._ensure_caches()
-        t0 = time.perf_counter()
         n = len(requests)
+        fit = self.prefill_fit([r.prompt.size for r in requests])
+        if fit < n:
+            # more than one prefill can hold: what fits now, then the
+            # rest (DecodeBatcher asks prefill_fit before it takes a
+            # request off the queue, so its rounds never come here)
+            return np.concatenate([
+                self.admit(requests[:fit], slot_ids[:fit]),
+                self.admit(requests[fit:], slot_ids[fit:])])
+        t0 = time.perf_counter()
         span = _trace.loop_span
         with span("engine/pack", rows=n):
             tokens, pos_ids, last = self.gen._pack_prompts(
@@ -684,9 +704,11 @@ class GenerationEngine:
                     for sl in allocated:
                         self.pool.free_slot(sl)
                     raise
-        with span("generator/prefill", rows=n):
-            logits, row_caches, self._key = self.gen._run_prefill(
-                tokens, pos_ids, last, self._key)
+        with span("generator/prefill", rows=n) as prefilled:
+            logits, row_caches, self._key, aux = self.gen._run_prefill(
+                tokens, pos_ids, last, self._key,
+                kv_dtype=self.pool.dtype if self.pool is not None
+                else None, want_aux=True)
         with span("generator/sample", rows=n):
             toks, self._key = self.gen._run_sample(logits, temp, topk,
                                                    self._key)
@@ -695,8 +717,9 @@ class GenerationEngine:
             with span("pool/scatter", rows=n,
                       blocks=self.pool.blocks_for_tokens(tokens.shape[1])):
                 try:
-                    self.pool.scatter_prefill(list(slot_ids), row_caches,
-                                              tokens.shape[1])
+                    self.pool.scatter_prefill(
+                        list(slot_ids), row_caches, tokens.shape[1],
+                        lengths=[int(r.prompt.size) for r in requests])
                 except Exception:
                     # the donated device pool is lost (scatter dropped
                     # it); this batch's blocks go back, the batcher
@@ -716,11 +739,31 @@ class GenerationEngine:
                 self.pool.prefix_insert(req.prompt, slot)
         with span("engine/fetch", rows=n):
             out = np.asarray(toks)[:n]
+            prefilled.attrs.update(self._count_routing(aux))
         t1 = time.perf_counter()
         for req in requests:
             if getattr(req, "trace", None) is not None:
                 _trace.record_child("serving/prefill", t0, t1, req.trace)
         return out
+
+    def _count_routing(self, aux):
+        """Add an executable's ``[layers, experts]`` assignment counts
+        into the ``moe_*`` counters and return them as the attrs of the
+        span that ran it (the step's or the prefill's): assignments, the
+        fullest expert's load summed over the layers, and the experts
+        that got any. Nothing for an architecture that routes nothing."""
+        counts = aux.get("moe_counts")
+        if counts is None:
+            return {}
+        counts = np.asarray(counts)
+        routed = {"moe_tokens": int(counts.sum()),
+                  "moe_load_max": int(counts.max(axis=1).sum()),
+                  "moe_experts_hit": int((counts > 0).sum())}
+        if self.stats:
+            self.stats.bump("moe_assignments", routed["moe_tokens"])
+            self.stats.bump("moe_expert_load_max", routed["moe_load_max"])
+            self.stats.bump("moe_experts_hit", routed["moe_experts_hit"])
+        return routed
 
     # -- chunked (incremental) prefill ------------------------------------
     def incremental_prefill_enabled(self):
@@ -922,8 +965,9 @@ class GenerationEngine:
                 raise
             logits = adopt_decode_fetches(self.pool, fetches)
             self._key = new_key
+            aux = self.gen.aux_of(kind, fetches)
         else:
-            caches = self._caches
+            caches, aux = self._caches, {}
 
             def _decode():
                 return self.gen._run_decode(tok, posc, caches, key,
@@ -943,7 +987,36 @@ class GenerationEngine:
             logits, np.ascontiguousarray(temperature, dtype=np.float32),
             np.ascontiguousarray(top_k, dtype=np.int32), self._key)
         with _trace.loop_span("engine/fetch"):
-            return np.asarray(toks)
+            out = np.asarray(toks)
+            # the batcher puts them on its engine/step span
+            self.step_routing = self._count_routing(aux)
+            return out
+
+    def prefill_bytes(self, prompt_sizes):
+        """Device bytes a prefill of these prompts, admitted together,
+        holds beyond the weights and the pool (the architecture's own
+        count at the buckets the generator would pad them to)."""
+        from ..models.generation import length_bucket
+        rows = length_bucket(len(prompt_sizes))
+        seq = min(length_bucket(max(prompt_sizes), self.gen.bucket_min),
+                  self.max_len)
+        elem = {"fp32": 4, "bf16": 2, "int8": 1}[self.pool.dtype] \
+            if self.pool is not None else 4
+        return self.gen.arch.prefill_bytes(rows, seq, self.max_len, elem)
+
+    def prefill_fit(self, prompt_sizes):
+        """How many of these prompts, from the first on, one prefill can
+        take together in the device's free memory: at least one (alone a
+        prompt is admitted whatever its size, and fails by itself if the
+        chip cannot hold it), and all of them where the backend keeps no
+        count, as on the CPU. The one owner of that decision: ``admit``
+        splits by it and ``DecodeBatcher`` caps a round by it."""
+        n = len(prompt_sizes)
+        budget = _free_device_bytes() if n > 1 else None
+        if budget is not None:
+            while n > 1 and self.prefill_bytes(prompt_sizes[:n]) > budget:
+                n -= 1
+        return n
 
     def spec_step(self, tokens, pos, temperature, top_k, drafts,
                   num_draft, live, budget=None):

@@ -21,6 +21,18 @@ allocation. Block 0 is the reserved TRASH block: padded block-table
 entries point at it, so bucket-padded prefill scatters and stale free
 slots write garbage somewhere harmless that position masks never read.
 
+Layer groups: an architecture whose layers keep different spans of a row
+(full-attention layers every position, window layers the last
+``window``) gets one GROUP a kind, each with its own block ids, free
+list and per-slot table in the one pool. A window group's table is a
+ring of ``window_blocks`` columns: logical block ``b`` of a row lives in
+column ``b % ring``, so the block that fell wholly behind the window is
+the one the next append reuses (``window_blocks_recycled``) and a slot
+never holds more than the ring. Allocation, admission, the leak sweep
+and the counts cover every group; the prefix cache, copy-on-write and
+migration are the full group's alone and are off for a pool that has a
+window group.
+
 Device side: lazily-built jnp pool arrays (float32 / bfloat16 / int8
 with per-(block, head, slot) float32 scales — ``FLAGS_kv_cache_dtype``;
 at bandwidth-bound decode, halving cache bytes is ~2x tokens/s), a
@@ -180,12 +192,17 @@ def prompt_prefix_key(tokens, length=None):
 def decode_feed(pool, token, pos):
     """ONE paged decode step's feed dict: the pool's device arrays
     (donated into the call — XLA appends in place), this step's
-    token/pos vectors, and the host block tables. The one builder both
-    the offline generator loop and the serving engine use."""
+    token/pos vectors, and the host block tables (the window group's
+    ring as ``block_tables_window`` where the pool has one). The one
+    builder both the offline generator loop and the serving engine
+    use."""
     feed = dict(pool.arrays())
     feed["token"] = token
     feed["pos"] = pos
     feed["block_tables"] = np.ascontiguousarray(pool.tables)
+    if pool.window is not None:
+        feed["block_tables_window"] = np.ascontiguousarray(
+            pool.window.tables)
     return feed
 
 
@@ -198,6 +215,62 @@ def adopt_decode_fetches(pool, fetches):
     names = pool_feed_names(pool.num_layers, pool.quantized)
     pool.update_arrays({n: fetches[1 + i] for i, n in enumerate(names)})
     return fetches[0]
+
+
+class _WindowGroup:
+    """The window layers' share of a pool: block ids of its own, a LIFO
+    free list and a ring table a slot. Not thread-safe by itself: the
+    pool calls it under its lock."""
+
+    def __init__(self, slots, layers, window, block_size, num_blocks=None):
+        self.layers = list(layers)
+        self.window = int(window)
+        self.ring = _ceil_div(self.window, block_size) + 1
+        self.num_blocks = int(num_blocks or slots * self.ring + 1)
+        self.block_size = int(block_size)
+        self.tables = np.zeros((slots, self.ring), np.int32)
+        self.recycled = 0
+        self.reset()
+
+    def reset(self):
+        self.free = list(range(self.num_blocks - 1, 0, -1))
+        self.held = {}          # slot -> ring columns filled
+        self.logical = {}       # slot -> logical blocks covered so far
+        self.tables[:] = 0
+
+    @property
+    def capacity(self):
+        return self.num_blocks - 1
+
+    def in_use(self):
+        return self.capacity - len(self.free)
+
+    def need(self, slot, ntokens):
+        """Blocks ``slot`` lacks to cover ``ntokens``: never more than
+        the ring holds."""
+        want = min(_ceil_div(max(int(ntokens), 0), self.block_size),
+                   self.ring)
+        return max(want - self.held.get(slot, 0), 0)
+
+    def grow(self, slot, ntokens):
+        """Cover ``ntokens``: fill ring columns while any are empty,
+        then reuse the column whose block fell behind the window. The
+        caller has checked :meth:`need` against the free list."""
+        logical = _ceil_div(max(int(ntokens), 0), self.block_size)
+        have = self.held.get(slot, 0)
+        for col in range(have, min(logical, self.ring)):
+            self.tables[slot, col] = self.free.pop()
+        self.held[slot] = max(have, min(logical, self.ring))
+        before = self.logical.get(slot, 0)
+        self.recycled += max(logical - max(before, self.ring), 0)
+        self.logical[slot] = max(before, logical)
+
+    def release(self, slot):
+        n = self.held.pop(slot, 0)
+        self.logical.pop(slot, None)
+        self.free.extend(int(self.tables[slot, c]) for c in range(n))
+        self.tables[slot, :] = 0
+        return n
 
 
 class KVBlockPool:
@@ -217,7 +290,8 @@ class KVBlockPool:
 
     def __init__(self, *, slots, num_layers, num_heads, d_head,
                  max_seq_len, block_size=None, num_blocks=None,
-                 dtype=None, name="serving", prefix_cache=None):
+                 dtype=None, name="serving", prefix_cache=None,
+                 groups=None):
         self.slots = int(slots)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -241,6 +315,24 @@ class KVBlockPool:
                              "the reserved trash block)")
         self.name = str(name)
         self.quantized = self.dtype == "int8"
+        # layer groups (an architecture's kv_groups()): the full layers
+        # are this pool's own tables and free list; window layers, where
+        # there are any, a _WindowGroup beside them
+        groups = groups or [{"name": "full", "window": None,
+                             "layers": list(range(self.num_layers))}]
+        full = [g for g in groups if not g.get("window")]
+        windowed = [g for g in groups if g.get("window")]
+        if len(full) > 1 or len(windowed) > 1:
+            raise ValueError("KVBlockPool holds one full and one window "
+                             "group of layers at most")
+        self.full_layers = list(full[0]["layers"]) if full else []
+        self.window = None
+        if windowed:
+            if self.quantized:
+                raise ValueError("a window group has no int8 pool")
+            self.window = _WindowGroup(
+                self.slots, windowed[0]["layers"], windowed[0]["window"],
+                self.block_size)
 
         # host accounting (block 0 = trash, never allocated). LIFO free
         # list: recently-freed blocks are re-used first, which keeps the
@@ -261,9 +353,13 @@ class KVBlockPool:
         # order IS the LRU order (move_to_end on hit, popitem(False)
         # under pressure)
         self._prefix = OrderedDict()
+        # a cached prefix's window layers hold the END of the prompt it
+        # was cut from, not of the prefix: with a window group nothing
+        # is matched or deposited until a PR builds that
         self.prefix_enabled = bool(flag("kv_prefix_cache")
                                    if prefix_cache is None
-                                   else prefix_cache)
+                                   else prefix_cache) \
+            and self.window is None
         self.array_sharding = None     # NamedSharding under a tp mesh
         self._arrays = None            # lazy device pool
         self._scatter_fn = None
@@ -277,17 +373,18 @@ class KVBlockPool:
 
     @property
     def capacity_blocks(self):
-        """Allocatable blocks (trash excluded)."""
-        return self.num_blocks - 1
+        """Allocatable blocks of every group (trash excluded)."""
+        return self.num_blocks - 1 + (
+            self.window.capacity if self.window else 0)
 
-    def block_bytes(self):
-        """Device bytes per block across layers, K+V, scales included."""
+    def block_bytes(self, layers=None):
+        """Device bytes of one block across a group's ``layers`` (the
+        full group's by default), K+V, scales included."""
         elem = _ELEM_BYTES[self.dtype]
-        n = 2 * self.num_layers * self.num_heads * self.block_size \
-            * self.d_head * elem
+        nl = len(self.full_layers) if layers is None else len(layers)
+        n = 2 * nl * self.num_heads * self.block_size * self.d_head * elem
         if self.quantized:
-            n += 2 * self.num_layers * self.num_heads * self.block_size \
-                * 4
+            n += 2 * nl * self.num_heads * self.block_size * 4
         return n
 
     def dense_slot_bytes(self):
@@ -304,12 +401,14 @@ class KVBlockPool:
         the retryable ``Overloaded`` backpressure — backing off cannot
         make an impossible request fit."""
         need = self.blocks_for_tokens(ntokens)
-        if need > self.capacity_blocks:
+        if need > self.num_blocks - 1 or (
+                self.window and self.window.need(None, ntokens)
+                > self.window.capacity):
             raise BadRequestError(
                 f"request needs {need} KV blocks "
                 f"({ntokens} tokens at block_size={self.block_size}) "
                 f"but the pool's total capacity is "
-                f"{self.capacity_blocks} blocks — it can never be "
+                f"{self.num_blocks - 1} blocks — it can never be "
                 f"admitted; raise FLAGS_kv_pool_blocks")
 
     def admission_check(self, ntokens, pending_tokens=()):
@@ -326,6 +425,14 @@ class KVBlockPool:
             if need + pending > len(self._free):
                 self._evict_cold_locked(need + pending)
             free = len(self._free)
+            if self.window and need + pending <= free:
+                # the window group counts too: what it lacks is what
+                # the refusal reports
+                w = self.window
+                wneed = w.need(None, ntokens) + sum(
+                    w.need(None, t) for t in pending_tokens)
+                if wneed > len(w.free):
+                    need, pending, free = wneed, 0, len(w.free)
         if need + pending > free:
             _ALLOC_FAIL.inc(labels=(self.name,))
             _flightrec().record(
@@ -351,15 +458,23 @@ class KVBlockPool:
         with self._lock:
             have = self._slot_nblocks.get(slot, 0)
             add = need - have
+            w = self.window
             if add <= 0:
                 self._slot_tokens[slot] = max(
                     self._slot_tokens.get(slot, 0), int(ntokens))
+                if w:           # within a block: the ring has it too
+                    w.grow(slot, ntokens)
                 return 0
             if add > len(self._free):
                 self._evict_cold_locked(add)
             if add > len(self._free):
                 free_now = len(self._free)
+            elif w and w.need(slot, ntokens) > len(w.free):
+                # every group or none: nothing is held half
+                add, free_now = w.need(slot, ntokens), len(w.free)
             else:
+                if w:
+                    w.grow(slot, ntokens)
                 for j in range(have, need):
                     b = self._free.pop()
                     self._refs[b] = 1
@@ -401,6 +516,8 @@ class KVBlockPool:
             freed = self._release_blocks_locked(
                 int(self.tables[slot, j]) for j in range(n))
             self.tables[slot, :] = 0
+            if self.window:
+                freed += self.window.release(slot)
             self._update_gauges_locked()
         if freed:
             _FREED.inc(freed, labels=(self.name,))
@@ -426,8 +543,20 @@ class KVBlockPool:
         :meth:`cached_blocks` / ``kvpool_prefix_cached_blocks_count``
         and evict LRU under pressure."""
         with self._lock:
-            return self.capacity_blocks - len(self._free) \
-                - self._cached_only_locked()
+            return sum(self._in_use_by_group_locked().values())
+
+    def blocks_in_use_by_group(self):
+        """``{"full": n[, "window": m]}``: :meth:`blocks_in_use` a
+        group."""
+        with self._lock:
+            return self._in_use_by_group_locked()
+
+    def _in_use_by_group_locked(self):
+        out = {"full": self.num_blocks - 1 - len(self._free)
+               - self._cached_only_locked()}
+        if self.window:
+            out["window"] = self.window.in_use()
+        return out
 
     def cached_blocks(self):
         """Blocks held only by the prefix cache (evictable)."""
@@ -478,8 +607,11 @@ class KVBlockPool:
             dt = _np_pool_dtype(self.dtype)
             arrs = {}
             for i in range(self.num_layers):
-                arrs[f"cache_pk_{i}"] = jnp.zeros(shape, dt)
-                arrs[f"cache_pv_{i}"] = jnp.zeros(shape, dt)
+                # a window layer's arrays hold its group's blocks
+                n = self.window.num_blocks if self.window \
+                    and i in self.window.layers else self.num_blocks
+                arrs[f"cache_pk_{i}"] = jnp.zeros((n,) + shape[1:], dt)
+                arrs[f"cache_pv_{i}"] = jnp.zeros((n,) + shape[1:], dt)
             if self.quantized:
                 sshape = shape[:3]
                 for i in range(self.num_layers):
@@ -514,7 +646,10 @@ class KVBlockPool:
         """Free everything and drop the device pool — the engine
         restart / bank-lost path."""
         with self._lock:
-            freed = self.capacity_blocks - len(self._free)
+            freed = self.num_blocks - 1 - len(self._free)
+            if self.window:
+                freed += self.window.in_use()
+                self.window.reset()
             self._free = list(range(self.num_blocks - 1, 0, -1))
             self._slot_nblocks.clear()
             self._slot_tokens.clear()
@@ -737,54 +872,75 @@ class KVBlockPool:
             raise
 
     # -- prefill scatter --------------------------------------------------
-    def scatter_prefill(self, slot_ids, row_caches, bucket_len):
+    def scatter_prefill(self, slot_ids, row_caches, bucket_len,
+                        lengths=None):
         """Move freshly-prefilled dense row caches into the pool: rows
         ``slot_ids`` of the tables receive the first ``bucket_len``
         positions of ``row_caches[cache_{k,v}_i][:len(slot_ids)]``
-        (shape ``[bb, H, max_len, D]``), reshaped into blocks and
-        scattered through the block table in ONE donated jitted call.
-        Table entries past a row's allocation point at the trash block,
-        so bucket padding lands there. Quantizes on the way in for an
-        int8 pool. On ANY failure the donated pool arrays must be
-        presumed lost — callers reset the pool."""
+        (shape ``[bb, H, L, D]``, ``L`` the bank's length or the
+        bucket's), reshaped into blocks and scattered through the block
+        table in ONE donated jitted call. Table entries past a row's
+        allocation point at the trash block, so bucket padding lands
+        there. A window group's layers keep only what a row's ring
+        holds: of a prompt of ``lengths[r]`` tokens the last ``ring``
+        blocks, each into the column its logical index names. Quantizes
+        on the way in for an int8 pool. On ANY failure the donated pool
+        arrays must be presumed lost — callers reset the pool."""
         import jax
         import jax.numpy as jnp
 
         n = len(slot_ids)
         nblk = self.blocks_for_tokens(bucket_len)
+        slots = np.asarray(slot_ids, np.int32)
         tables = np.ascontiguousarray(
-            self.tables[np.asarray(slot_ids, np.int32), :nblk]
-        ).reshape(-1)                                     # [n*nblk]
+            self.tables[slots, :nblk]).reshape(-1)        # [n*nblk]
+        ring_src = ring_dst = None
+        if self.window is not None:
+            if lengths is None:
+                raise ValueError("a pool with a window group scatters a "
+                                 "prefill by the prompts' lengths")
+            w = self.window
+            last = (np.asarray(lengths, np.int64)[:n] - 1) // self.block_size
+            first = np.maximum(last - (w.ring - 1), 0)
+            logical = first[:, None] + np.arange(w.ring)[None, :]
+            ring_src = np.minimum(logical, nblk - 1).astype(np.int32)
+            ring_dst = np.where(
+                logical <= last[:, None],
+                w.tables[slots[:, None], logical % w.ring],
+                0).astype(np.int32).reshape(-1)           # [n*ring]
 
         if self._scatter_fn is None:
             from ..kernels.paged_attention import quantize_kv
             bs, quant = self.block_size, self.quantized
-            nl = self.num_layers
+            full = list(self.full_layers)
+            windowed = list(self.window.layers) if self.window else []
 
-            def scatter(pool, rows, tables_flat):
+            def blocks_of(src, m):
+                """[n, H, L, D] -> [n, m // n, H, bs, D]: the first
+                ``m // n`` blocks of every row. The covered length is
+                shape-determined (the jit retraces per (n, m) pair),
+                zero-padded past ``L``."""
+                n_rows = src.shape[0]
+                cover = (m // n_rows) * bs
+                take = min(cover, src.shape[2])
+                vals = src[:, :, :take]
+                if take < cover:
+                    pad = jnp.zeros(
+                        src.shape[:2] + (cover - take, src.shape[3]),
+                        src.dtype)
+                    vals = jnp.concatenate([vals, pad], axis=2)
+                vals = vals.reshape(n_rows, vals.shape[1], cover // bs,
+                                    bs, vals.shape[3])
+                return vals.transpose(0, 2, 1, 3, 4)
+
+            def scatter(pool, rows, tables_flat, ring_src, ring_dst):
                 out = dict(pool)
                 m = tables_flat.shape[0]
-                for i in range(nl):
+                for i in full:
                     for kind in ("k", "v"):
                         src = rows[f"cache_{kind}_{i}"]    # [n,H,L,D]
-                        n_rows = src.shape[0]
-                        # the covered length is shape-determined (the
-                        # jit retraces per (n, m) pair): m//n blocks of
-                        # bs slots per row, zero-padded past max_len
-                        cover = (m // n_rows) * bs
-                        take = min(cover, src.shape[2])
-                        vals = src[:, :, :take]
-                        if take < cover:
-                            pad = jnp.zeros(
-                                src.shape[:2] + (cover - take,
-                                                 src.shape[3]),
-                                src.dtype)
-                            vals = jnp.concatenate([vals, pad], axis=2)
-                        vals = vals.reshape(n_rows, vals.shape[1],
-                                            cover // bs, bs,
-                                            vals.shape[3])
-                        vals = vals.transpose(0, 2, 1, 3, 4).reshape(
-                            m, vals.shape[1], bs, vals.shape[4])
+                        vals = blocks_of(src, m)
+                        vals = vals.reshape((m,) + vals.shape[2:])
                         dst = out[f"cache_p{kind}_{i}"]
                         if quant:
                             q, sc = quantize_kv(vals)
@@ -796,13 +952,24 @@ class KVBlockPool:
                             out[f"cache_p{kind}_{i}"] = \
                                 dst.at[tables_flat].set(
                                     vals.astype(dst.dtype))
+                for i in windowed:
+                    for kind in ("k", "v"):
+                        vals = blocks_of(rows[f"cache_{kind}_{i}"], m)
+                        vals = jnp.take_along_axis(
+                            vals, ring_src[:, :, None, None, None], axis=1)
+                        dst = out[f"cache_p{kind}_{i}"]
+                        out[f"cache_p{kind}_{i}"] = dst.at[ring_dst].set(
+                            vals.reshape((-1,) + vals.shape[2:]).astype(
+                                dst.dtype))
                 return out
 
             self._scatter_fn = jax.jit(scatter, donate_argnums=(0,))
         rows = {name: a[:n] for name, a in row_caches.items()}
         try:
             self._arrays = self._scatter_fn(
-                self.arrays(), rows, jnp.asarray(tables, jnp.int32))
+                self.arrays(), rows, jnp.asarray(tables, jnp.int32),
+                None if ring_src is None else jnp.asarray(ring_src),
+                None if ring_dst is None else jnp.asarray(ring_dst))
         except Exception:
             self._arrays = None
             raise
@@ -826,6 +993,7 @@ class KVBlockPool:
         holds nothing. Single-driver like alloc/free — the decode loop
         is the only caller."""
         maybe_fail("serving.kv_export")
+        self._no_window("block migration")
         slot = int(slot)
         with self._lock:
             n = int(self._slot_nblocks.get(slot, 0))
@@ -856,6 +1024,12 @@ class KVBlockPool:
         _EXPORTED.inc(n, labels=(self.name,))
         return payload
 
+    def _no_window(self, what):
+        if self.window is not None:
+            raise BadRequestError(
+                f"KV pool {self.name!r} has a window group of layers: "
+                f"{what} is built for full-attention layers only")
+
     @staticmethod
     def payload_bytes(payload):
         """Total array bytes a migration payload carries (the wire-cost
@@ -873,6 +1047,7 @@ class KVBlockPool:
         scatter failure the blocks are returned and the device arrays
         presumed lost (the caller's bank-lost path applies)."""
         maybe_fail("serving.kv_import")
+        self._no_window("block migration")
         slot = int(slot)
         geom = self._validate_payload(payload)
         tokens, n = geom["tokens"], geom["nblocks"]
@@ -985,7 +1160,7 @@ class KVBlockPool:
     def _update_gauges_locked(self):
         lab = (self.name,)
         cached = self._cached_only_locked()
-        in_use = self.capacity_blocks - len(self._free) - cached
+        in_use = sum(self._in_use_by_group_locked().values())
         _BLOCKS_IN_USE.set(in_use, labels=lab)
         _CAPACITY.set(self.capacity_blocks, labels=lab)
         # occupancy counts SLOT load only: blocks held just by the
@@ -994,7 +1169,8 @@ class KVBlockPool:
         _OCCUPANCY.set(in_use / self.capacity_blocks
                        if self.capacity_blocks else 0.0, labels=lab)
         _SAVED.set(self.slots * self.dense_slot_bytes()
-                   - (in_use + cached) * self.block_bytes(), labels=lab)
+                   - self._bytes_of(self._in_use_by_group_locked(),
+                                    cached), labels=lab)
         _PREFIX_ENTRIES.set(len(self._prefix), labels=lab)
         _PREFIX_BLOCKS.set(cached, labels=lab)
 
@@ -1007,18 +1183,23 @@ class KVBlockPool:
         safe, merged into ``server.stats()`` under ``kvpool_*``)."""
         with self._lock:
             cached = self._cached_only_locked()
-            in_use = self.capacity_blocks - len(self._free) - cached
+            by_group = self._in_use_by_group_locked()
+            in_use = sum(by_group.values())
+            recycled = self.window.recycled if self.window else 0
             tokens = sum(self._slot_tokens.values())
             slots_held = sum(1 for n in self._slot_nblocks.values()
                              if n > 0)
             prefix_entries = len(self._prefix)
-        cap_tokens = in_use * self.block_size
+        cap_tokens = by_group["full"] * self.block_size
         return {
             "blocks": self.num_blocks,
             "block_size": self.block_size,
             "dtype": self.dtype,
             "capacity_blocks": self.capacity_blocks,
             "blocks_in_use": in_use,
+            "blocks_in_use_full": by_group["full"],
+            "blocks_in_use_window": by_group.get("window", 0),
+            "window_blocks_recycled": recycled,
             "blocks_free": self.capacity_blocks - in_use,
             "occupancy": round(in_use / self.capacity_blocks, 4)
             if self.capacity_blocks else 0.0,
@@ -1034,11 +1215,22 @@ class KVBlockPool:
             # load)
             "prefix_entries": prefix_entries,
             "evictable_blocks": cached,
-            "bytes_in_use": (in_use + cached) * self.block_bytes(),
-            "bytes_capacity": self.capacity_blocks * self.block_bytes(),
+            "bytes_in_use": self._bytes_of(by_group, cached),
+            "bytes_capacity": self._bytes_of(
+                {"full": self.num_blocks - 1,
+                 "window": self.window.capacity if self.window else 0}),
             "saved_vs_dense_bytes": self.slots * self.dense_slot_bytes()
-            - (in_use + cached) * self.block_bytes(),
+            - self._bytes_of(by_group, cached),
         }
+
+    def _bytes_of(self, by_group, cached=0):
+        """Device bytes of ``by_group``'s blocks (and the prefix
+        cache's, which are the full group's)."""
+        n = (by_group["full"] + cached) * self.block_bytes()
+        if self.window:
+            n += by_group.get("window", 0) * self.block_bytes(
+                self.window.layers)
+        return n
 
 
 def _ceil_div(a, b):

@@ -220,6 +220,10 @@ _COUNTER_KEYS = (
     "spec_drafted",         # draft tokens proposed across all rows
     "spec_accepted",        # draft tokens accepted by verification
     "spec_rejected",        # verify runs with >= 1 rejected draft
+    # -- routed expert layers (an executable's [layers, experts] counts) --
+    "moe_assignments",      # (token, expert) pairs routed, every layer
+    "moe_expert_load_max",  # the fullest expert's load, summed over layers
+    "moe_experts_hit",      # experts that got a token, summed over layers
 )
 
 

@@ -152,6 +152,18 @@ def test_generate_rejects_overlong_prompt(tiny_gen):
         gen.generate([np.zeros((0,), np.int32)], max_new_tokens=4)
 
 
+@pytest.mark.parametrize("n,lo,want", [
+    (1, 1, 1), (3, 1, 4), (17, 16, 32), (1024, 16, 1024),
+    (1025, 16, 2048), (2048, 16, 2048), (2049, 16, 3072),
+    (3073, 16, 4096), (4097, 16, 6144), (6144, 16, 6144),
+    (6145, 16, 8192), (8193, 16, 12288), (5, 3000, 3000)])
+def test_length_bucket_ladder(n, lo, want):
+    """Powers of two, and from 2048 up their midpoints too: a prompt of
+    4,097 tokens pads to 6,144 and not to 8,192, and no bucket under
+    2,048 (every GPT shape the tests and the benchmark warm) moved."""
+    assert length_bucket(n, lo) == want
+
+
 def test_generate_accepts_bare_prompt(tiny_gen):
     """A bare 1-D array (or flat list of ints) is ONE prompt — the shape
     the serving Client takes — not a batch of one-token prompts."""

@@ -140,7 +140,9 @@ def test_serving_stats_snapshot_keys_unchanged():
         "decode_slot_rows", "engine_failures", "watchdog_timeouts",
         "loop_restarts", "weight_reloads", "hedge_dedup_hits",
         "requests_cancelled", "kv_exports", "kv_imports",
-        "spec_steps", "spec_drafted", "spec_accepted", "spec_rejected"}
+        "spec_steps", "spec_drafted", "spec_accepted", "spec_rejected",
+        # PR 28: the routed expert layers' counts
+        "moe_assignments", "moe_expert_load_max", "moe_experts_hit"}
     derived = {"uptime_s", "throughput_rps", "mean_batch_size",
                "batch_occupancy", "tokens_per_s", "decode_occupancy",
                "queue_depth", "spec_accept_ratio"}

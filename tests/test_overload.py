@@ -237,6 +237,31 @@ def test_queue_evicts_expired_entries_typed():
     assert q3.get(timeout=0) is fresh
 
 
+def test_queue_get_leaves_a_refused_head_where_it_was():
+    """``get(accept=)`` asks about the request it would pop: refused, the
+    request stays first of its class (so drain, close and the deadline
+    sweep go on seeing it) and nothing behind it is served in its
+    place; an expired entry in front of it is still swept."""
+    q = RequestQueue(max_depth=8)
+    doomed = GenerationRequest([1], deadline_ms=15.0)
+    big = GenerationRequest([1, 2, 3])
+    small = GenerationRequest([1])
+    later = GenerationRequest([1], priority="batch")
+    for r in (doomed, big, small, later):
+        q.put(r)
+    time.sleep(0.04)
+    asked = []
+    assert q.get(timeout=0, accept=lambda r: asked.append(r)) is None
+    assert asked == [big] and len(q) == 3 and doomed.done()
+    assert q.get(timeout=0, accept=lambda r: True) is big
+    assert q.get(timeout=0) is small and q.get(timeout=0) is later
+    held = GenerationRequest([1])
+    q.put(held)
+    assert q.get(timeout=0, accept=lambda r: False) is None
+    q.close()                       # a refused request is still queued
+    assert isinstance(held.error, serving.ServerShutdownError)
+
+
 # -------------------------------------------------- deadline propagation
 
 def test_client_rejects_spent_budget_before_the_wire(tiny_gpt):

@@ -285,6 +285,33 @@ def test_drain_generation_greedy_parity():
         np.testing.assert_array_equal(got, want)
 
 
+def test_drain_waits_for_what_a_capped_round_left_in_the_queue(monkeypatch):
+    """Where the device's free memory caps a round at one prompt, the
+    prompts that did not fit stay in the queue; rows that end at their
+    first token leave nothing decoding, and drain() must still serve
+    every accepted request before it reports ``drained``."""
+    from paddle_tpu.serving import engine as engine_mod
+    cfg, _, _, _, gen = _tiny_gpt()
+    prompts = [RNG.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 9, 7, 6)]
+    ref = [gen.generate([p], max_new_tokens=1, seed=0, paged=True)[0]
+           for p in prompts]
+    monkeypatch.setattr(engine_mod, "_free_device_bytes", lambda: 1)
+    server = InferenceServer(generator=gen, decode_slots=4, kv_paged=True)
+    server.start(serve_network=False)
+    rows = []
+    real = server.gen_engine.admit
+    monkeypatch.setattr(server.gen_engine, "admit", lambda reqs, slots: (
+        rows.append(len(reqs)), real(reqs, slots))[1])
+    reqs = [server.submit_generate(p, max_new_tokens=1) for p in prompts]
+    report = server.drain(timeout=120)
+    assert report["drained"] and report["remaining"] == 0
+    assert rows == [1, 1, 1, 1]
+    for req, want in zip(reqs, ref):
+        got, = req.wait(timeout=1)
+        np.testing.assert_array_equal(got, want)
+
+
 # ------------------------------------------------------ supervised loops
 
 def test_supervisor_restarts_crashed_microbatcher(tmp_path,
