@@ -9,15 +9,18 @@ One process drives the main path once at the full width of GPT-base
 positions), the one model this repository both trains and serves, with
 seeded random weights:
 
-  kernels  both Pallas kernels compiled (never interpreted) and compared
-           with their in-file oracles; the paged decode kernel timed
-           alone at the shapes of the benchmark's serve cell
+  kernels  the Pallas kernels compiled (never interpreted) and compared
+           with their in-file oracles, the paged pair (paged_kv_append,
+           paged_attention_decode) over the pool's stored shape in its
+           three dtypes; the decode kernel and one append timed alone
+           at the shapes of the benchmark's serve cell
   train    gpt_pretrain at 8 x 2,048 + Adam + bf16 AMP through
            Executor.run and Executor.run_steps
   serve    GPTGenerator -> InferenceServer(kv_paged=True) -> six
            concurrent Client.generate calls over the loopback socket,
-           then one logits check of paged decode against a full
-           recompute
+           no pool-sized copy in the decode step's or the scatter's
+           optimised HLO, then one logits check of paged decode against
+           a full recompute
   mesh     only with four or more devices: the train step under
            with_data_parallel over every chip, and tp=2 paged generation
 
@@ -177,9 +180,9 @@ def phase_kernels(smoke):
     from paddle_tpu.flags import flag
     from paddle_tpu.kernels.flash_attention import (_xla_attention,
                                                     flash_attention)
-    from paddle_tpu.kernels.paged_attention import (_xla_paged_attention,
-                                                    paged_attention,
-                                                    quantize_kv)
+    from paddle_tpu.kernels.paged_attention import (
+        _xla_paged_attention, paged_attention, paged_kv_append, pool_packing,
+        quantize_kv, scales_to_stored, stored_shape, to_stored)
     from paddle_tpu.serving.kvpool import _DTYPES
 
     sz = smoke.sizes
@@ -243,7 +246,8 @@ def phase_kernels(smoke):
     assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
                for g in grads)
 
-    # ---- paged decode, every dtype FLAGS_kv_cache_dtype accepts
+    # ---- the paged pair over the pool's STORED shape, every dtype
+    # FLAGS_kv_cache_dtype accepts: one token appended a row, then read
     bs = int(flag("kv_block_size"))
     rows, positions = sz.paged_rows, sz.paged_positions
     nblk = positions // bs
@@ -252,25 +256,63 @@ def phase_kernels(smoke):
     qd = jnp.asarray(rng.normal(size=(rows, H, 1, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(2, rows, H, D)), jnp.float32)
     tables = jnp.asarray(
         rng.permutation(np.arange(1, N)).reshape(rows, nblk), jnp.int32)
     pos = jnp.asarray(sz.paged_pos, jnp.int32)
+    ids, offs = tables[jnp.arange(rows), pos // bs], pos % bs
     out["paged_block_size"] = bs
+    out["pool_packing"] = pool_packing(D, bs)
+    out["pool_stored_shape"] = list(stored_shape(N, H, bs, D))
+
+    def step(qd, pk, pv, new_k, new_v, ids, offs, tables, pos, ks, vs,
+             new_ks, new_vs):
+        """A decode layer's pool work: both appends, then the read."""
+        if ks is None:
+            pk = paged_kv_append(pk, new_k, ids, offs,
+                                 interpret=impl == "interpret")
+            pv = paged_kv_append(pv, new_v, ids, offs,
+                                 interpret=impl == "interpret")
+        else:
+            pk, ks = paged_kv_append(pk, new_k, ids, offs, ks, new_ks,
+                                     interpret=impl == "interpret")
+            pv, vs = paged_kv_append(pv, new_v, ids, offs, vs, new_vs,
+                                     interpret=impl == "interpret")
+        return paged_attention(qd, pk, pv, tables, pos, ks, vs, impl=impl,
+                               kv_heads=H), pk, pv, ks, vs
+
     for kv_dtype in _DTYPES:
         if kv_dtype == "int8":
             (pk, ks), (pv, vs) = quantize_kv(kp), quantize_kv(vp)
+            (nk, nks), (nv, nvs) = quantize_kv(new[0]), quantize_kv(new[1])
         else:
             dt = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
-            pk, pv, ks, vs = kp.astype(dt), vp.astype(dt), None, None
-        args = (qd, pk, pv, tables, pos, ks, vs)
-        fn = jax.jit(lambda *a: paged_attention(*a, impl=impl)).lower(
-            *args).compile()
+            pk, pv, nk, nv = (x.astype(dt) for x in (kp, vp, new[0], new[1]))
+            ks = vs = nks = nvs = None
+        # the oracle: the composite write and read on the logical shape
+        want_k = pk.at[ids, :, offs, :].set(nk)
+        want_v = pv.at[ids, :, offs, :].set(nv)
+        want_ks = None if ks is None else ks.at[ids, :, offs].set(nks)
+        want_vs = None if vs is None else vs.at[ids, :, offs].set(nvs)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a: _xla_paged_attention(*a, scale))(
+                qd, want_k, want_v, tables, pos, want_ks, want_vs)
+        args = (qd, to_stored(pk), to_stored(pv), nk, nv, ids, offs, tables,
+                pos, None if ks is None else scales_to_stored(ks, D),
+                None if vs is None else scales_to_stored(vs, D), nks, nvs)
+        fn = jax.jit(step).lower(*args).compile()
         t0 = time.perf_counter()
-        got = jax.block_until_ready(fn(*args))
+        got, got_k, got_v, got_ks, got_vs = jax.block_until_ready(fn(*args))
         out["smoke_timings_s"][f"paged_{kv_dtype}"] = round(
             time.perf_counter() - t0, 4)
-        with jax.default_matmul_precision("highest"):
-            ref = jax.jit(lambda *a: _xla_paged_attention(*a, scale))(*args)
+        # the append moves values, it computes none: every block but
+        # the trash block (no row writes it here) is bit for bit the
+        # composite's
+        for a, b in ((got_k, to_stored(want_k)), (got_v, to_stored(want_v))):
+            assert bool(jnp.all(a == b)), f"paged_kv_append {kv_dtype}"
+        if ks is not None:
+            for a, b in ((got_ks, want_ks), (got_vs, want_vs)):
+                assert bool(jnp.all(a == scales_to_stored(b, D))), kv_dtype
         # kernel and oracle read the same stored values and both
         # accumulate in fp32, so only the multiply differs: the kernel's
         # dot_generals ask for no precision, which lets the MXU take
@@ -381,18 +423,19 @@ def _grouped_window_kernels(sz):
 
 
 def _time_paged_decode(sz, calls=20):
-    """Device microseconds of one ``paged_attention_decode`` call at
-    ``sz.paged_timed``, positions log-uniform over its range: the kernel
-    alone, from the ``XLA Ops`` line of a profiler trace. A rehearsal
-    runs the same calls and has no device time to report."""
+    """Device microseconds of one ``paged_attention_decode`` call and of
+    one ``paged_kv_append`` call at ``sz.paged_timed`` over the stored
+    pool, positions log-uniform over its range: each kernel alone, from
+    the ``XLA Ops`` line of a profiler trace. A rehearsal runs the same
+    calls and has no device time to report."""
     import glob
     import statistics
     import tempfile
     import jax
     import jax.numpy as jnp
     from jax.profiler import ProfileData
-    from paddle_tpu.kernels.paged_attention import (decode_grid,
-                                                    paged_attention)
+    from paddle_tpu.kernels.paged_attention import (
+        decode_grid, paged_attention, paged_kv_append, stored_shape)
     t = sz.paged_timed
     B, H, nblk, bs, D = (t[k] for k in ("rows", "heads", "blocks", "block",
                                         "d_head"))
@@ -403,30 +446,48 @@ def _time_paged_decode(sz, calls=20):
     tables = np.zeros((B, nblk), np.int32)
     tables[np.arange(nblk) < live[:, None]] = \
         rng.permutation(np.arange(1, N))[:live.sum()]
-    args = (jnp.asarray(rng.normal(size=(B, H, 1, D)), jnp.float32),
-            jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.bfloat16),
-            jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.bfloat16),
-            jnp.asarray(tables), jnp.asarray(pos))
-    fn = jax.jit(lambda *a: paged_attention(*a, impl=sz.kernel_impl))
-    jax.block_until_ready(fn(*args))
+    shape = stored_shape(N, H, bs, D)
+    q = jnp.asarray(rng.normal(size=(B, H, 1, D)), jnp.float32)
+    pools = [jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+             for _ in range(2)]
+    new = jnp.asarray(rng.normal(size=(B, H, D)), jnp.bfloat16)
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+    ids, offs = tables[jnp.arange(B), pos // bs], pos % bs
+    interpret = sz.kernel_impl == "interpret"
+    read = jax.jit(lambda q, k, v, tables, pos: paged_attention(
+        q, k, v, tables, pos, impl=sz.kernel_impl, kv_heads=H))
+    append = jax.jit(lambda pool, new, ids, offs: paged_kv_append(
+        pool, new, ids, offs, interpret=interpret), donate_argnums=(0,))
+    jax.block_until_ready(read(q, *pools, tables, pos))
+    pools[0] = jax.block_until_ready(append(pools[0], new, ids, offs))
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             for _ in range(calls):
-                jax.block_until_ready(fn(*args))
+                jax.block_until_ready(read(q, *pools, tables, pos))
+                pools[0] = jax.block_until_ready(
+                    append(pools[0], new, ids, offs))
         path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                        "*.xplane.pb"))
-        us = [ev.duration_ns / 1e3
-              for plane in ProfileData.from_file(path).planes
-              if plane.name.startswith("/device:TPU")
-              for line in plane.lines if line.name == "XLA Ops"
-              for ev in line.events if "paged_attention_decode" in ev.name]
+        events = [ev for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/device:TPU")
+                  for line in plane.lines if line.name == "XLA Ops"
+                  for ev in line.events]
+    us = {name: [ev.duration_ns / 1e3 for ev in events if name in ev.name
+                 and "custom-call" in ev.name]
+          for name in ("paged_attention_decode", "paged_kv_append")}
     grid, G = decode_grid(B, H, bs, D, jnp.bfloat16, nblk)
+
+    def median(xs):
+        return statistics.median(xs) if xs else None
+
     return {"shape": {k: v for k, v in t.items() if k != "pos"},
+            "stored_shape": list(shape),
             "positions": [int(pos.min()), int(pos.max())],
             "live_blocks": int(live.sum()), "table_blocks": B * nblk,
             "grid_steps": math.prod(grid), "blocks_per_step": G,
-            "calls": len(us),
-            "device_us_per_call": statistics.median(us) if us else None}
+            "calls": len(us["paged_attention_decode"]),
+            "device_us_per_call": median(us["paged_attention_decode"]),
+            "append_device_us_per_call": median(us["paged_kv_append"])}
 
 
 # -------------------------------------------------------------------- train
@@ -623,6 +684,16 @@ def phase_serve(smoke):
         leaked = pool.blocks_in_use()
         assert leaked == 0, pool.stats()
         stats = server.stats()
+        # the pool keeps one layout from parameter to result: XLA:TPU
+        # left no pool-sized copy in the decode step or in the scatter
+        # (the interpreter's loops on the CPU copy as they please)
+        relayouts = stats["pool_relayouts"]
+        assert sz.rehearsal or (
+            relayouts.get(f"decode_paged_{pool.dtype}") == 0
+            and relayouts.get("scatter") == 0), relayouts
+        name, arr = next(iter(pool.arrays().items()))
+        pool_format = f"{name} {arr.dtype.name}{list(arr.shape)} " \
+                      f"{getattr(arr, 'format', None)}"
     finally:
         stopper = threading.Thread(target=server.stop)
         stopper.start()
@@ -637,6 +708,7 @@ def phase_serve(smoke):
            "generator_compiles": int(stats.get("compiles", 0)),
            "decode_steps": int(stats.get("decode_steps", 0)),
            "kv_cache_dtype": pool.dtype,
+           "pool_relayouts": relayouts, "pool_format": pool_format,
            "smoke_timings_s": {"request_walls_incl_compile": [
                walls[i] for i in range(len(prompts))]}}
     out["logits_check"] = _logits_check(sz, gen)

@@ -56,7 +56,8 @@ def per_shard(fn, mesh, args, dims, out_dims):
 
     ``dims`` names each argument's dimensions (``"batch"``, ``"heads"``
     or None, one tuple per argument; a None argument is passed through)
-    and ``out_dims`` the result's. A logical dimension shards only when
+    and ``out_dims`` the result's (a tuple of such tuples where ``fn``
+    returns several arrays). A logical dimension shards only when
     every array that carries it divides by its axes' size; with nothing
     to shard, without a mesh, or inside an enclosing ``shard_map`` (the
     hierarchical data-parallel path, where arrays are per-device
@@ -80,6 +81,8 @@ def per_shard(fn, mesh, args, dims, out_dims):
         return P(*(axes.get(l) for l in d))
 
     # a None argument is an empty pytree: its spec binds nothing
+    several = bool(out_dims) and isinstance(out_dims[0], tuple)
     return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(spec(d) for d in dims),
-        out_specs=spec(out_dims), check_vma=False)(*args)
+        out_specs=tuple(spec(d) for d in out_dims) if several
+        else spec(out_dims), check_vma=False)(*args)

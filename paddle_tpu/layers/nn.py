@@ -805,12 +805,13 @@ def kv_cached_attention(q, k_cache, v_cache, pos, scale=0.0, name=None):
 def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
                          name=None, ring=False):
     """Append S new ``kv`` vectors [B, H, S, D] into the block-paged
-    pool ``cache`` [num_blocks, H, block_size, D] at each row's own
+    pool ``cache`` (logically [num_blocks, H, block_size, D]; fed in
+    the stored shape of ``kernels/paged_attention``) at each row's own
     ``pos`` [B] int32, routed through the per-row block ``tables``
     [B, nblk] int32. Optional ``limit`` [B] int32 marks how many of the
     S vectors are real per row (chunked prefill's ragged tail; the rest
-    route to the trash block). For an int8 pool pass its ``scale``
-    array [num_blocks, H, block_size]; the op quantizes and returns
+    route to the trash block). For an int8 pool pass its stored
+    ``scale`` array; the op quantizes and returns
     ``(updated_pool, updated_scale)``, else just the updated pool.
     ``ring`` says the table is a window layer's ring (one token a row)."""
     helper = LayerHelper("paged_kv_cache_write", name=name)
@@ -840,16 +841,18 @@ def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
 
 def paged_attention(q, k_cache, v_cache, tables, pos, k_scale=None,
                     v_scale=None, scale=0.0, impl=None, name=None,
-                    window=None, scope=None):
+                    window=None, scope=None, kv_heads=None):
     """Decode attention of S queries per row (``q`` [B, H, S, D] —
     S=1 decode, S>1 chunked prefill) over the block-paged KV pool
-    ([num_blocks, H, block_size, D], int8 pools with their
-    [num_blocks, H, block_size] scales), gathered through the per-row
-    block ``tables`` and masked by per-row ``pos`` counters — the paged
-    analogue of :func:`kv_cached_attention`. Fused Pallas gather+attend
-    on TPU for S=1; ``jnp.take`` reference elsewhere and for S>1. The
-    pools may have fewer heads than ``q`` (grouped queries); ``window``
-    makes ``tables`` a ring and keeps each query's last ``window`` keys."""
+    (logically [num_blocks, Hkv, block_size, D]; fed in the stored shape
+    of ``kernels/paged_attention``, int8 pools with their stored
+    scales), gathered through the per-row block ``tables`` and masked by
+    per-row ``pos`` counters — the paged analogue of
+    :func:`kv_cached_attention`. Fused Pallas gather+attend on TPU for
+    S=1; ``jnp.take`` reference elsewhere and for S>1. The pools may
+    have fewer heads than ``q`` (grouped queries: ``kv_heads`` says how
+    many, H where it is left out); ``window`` makes ``tables`` a ring
+    and keeps each query's last ``window`` keys."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     ins = {"Q": [q], "K": [k_cache], "V": [v_cache],
@@ -860,7 +863,8 @@ def paged_attention(q, k_cache, v_cache, tables, pos, k_scale=None,
     helper.append_op(
         type="paged_attention", inputs=ins, outputs={"Out": [out]},
         attrs={"scale": float(scale), "impl": impl or "",
-               "window": int(window or 0), "scope": scope or ""},
+               "window": int(window or 0), "scope": scope or "",
+               "kv_heads": int(kv_heads or 0)},
         infer_shape=False)
     out.shape = tuple(q.shape or ())
     out.dtype = q.dtype

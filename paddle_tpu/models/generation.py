@@ -290,6 +290,7 @@ class GPTGenerator:
                 self._annotate_tp(kind, main)
                 self._progs[kind] = (main, outs)
         self._fns = {}      # kind -> (jitted, device_state)
+        self._unpack = {}   # kind -> its results back in fetch order
         self._params = {}   # param name -> device array, shared by kinds
         # (bucket_rows, kv_dtype, block_size) -> KVBlockPool reused
         # across generate(paged=True) calls: keeps the pool's jitted
@@ -297,6 +298,9 @@ class GPTGenerator:
         # recompiling/reallocating per call (blocks are still freed on
         # the way out of every call)
         self._paged_pools = {}
+        # program kind -> pool-sized copies in its optimised HLO (the
+        # worst of the kind's compiles): kvpool.count_pool_relayouts
+        self.pool_relayouts = {}
         # signature -> cost_analysis dict|False for the live MFU/HBM
         # gauges; LRU so an evicted entry recomputes instead of
         # freezing the gauges for a still-cached executable
@@ -335,18 +339,19 @@ class GPTGenerator:
 
     def apply_pool_sharding(self, pool):
         """Shard a :class:`serving.kvpool.KVBlockPool`'s device arrays
-        on the head axis of the tp mesh (dim 1 of the
-        ``[num_blocks, H, block_size, D]`` block arrays — the axis
-        ``apply_tp_sharding`` already splits qkv over, so the decode
-        step's cache append/read never crosses chips). No-op without a
-        mesh."""
+        on the head axis of the tp mesh (the stored block arrays
+        ``[num_blocks, H * block_size // f, f * D]`` are head-major in
+        dim 1, the int8 scales ``[num_blocks, f, H * block_size // f]``
+        in dim 2 — the axis ``apply_tp_sharding`` already splits qkv
+        over, so the decode step's cache append/read never crosses
+        chips). No-op without a mesh."""
         if self.mesh is None:
             return pool
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
         from ..serving.kvpool import pool_feed_names
-        val = NamedSharding(self.mesh, P(None, "tp", None, None))
-        sc = NamedSharding(self.mesh, P(None, "tp", None))
+        val = NamedSharding(self.mesh, P(None, "tp", None))
+        sc = NamedSharding(self.mesh, P(None, None, "tp"))
         pool.array_sharding = {
             n: (sc if ("pks" in n or "pvs" in n) else val)
             for n in pool_feed_names(pool.num_layers, pool.quantized)}
@@ -453,6 +458,21 @@ class GPTGenerator:
             self._progs[kind] = (main, outs)
         return self._progs[kind]
 
+    @staticmethod
+    def _cache_places(outs, feed_names, fetch_names):
+        """``{feed name: position in the fetch list}`` of every cache
+        the ``outs`` program is fed and hands back updated: the pool
+        arrays of a paged program, the dense bank's slabs of the others
+        (a prefill is fed none)."""
+        if "cache_vars" in outs:
+            names = list(outs["cache_names"])
+        else:
+            names = [f"cache_{kind}_{i}" for kind in "kv"
+                     for i in range(len(outs.get(f"cache_{kind}", ())))]
+        first = 1               # logits lead every cache-bearing fetch
+        return {n: first + i for i, n in enumerate(names)
+                if n in feed_names and first + i < len(fetch_names)}
+
     def _ensure_fn(self, kind):
         entry = self._fns.get(kind)
         if entry is not None:
@@ -469,14 +489,31 @@ class GPTGenerator:
 
         # only the decode step's KV caches are worth donating (XLA
         # aliases the cache append in place — no 2x cache traffic);
-        # everything else is a fresh host array every call
+        # everything else is a fresh host array every call. JAX pairs a
+        # donated argument with a result by shape and ORDER, and the
+        # caches arrive as a dict (sorted names: ..._1, ..._10, ..._2):
+        # results listed layer by layer would pair array 2 with array
+        # 10's buffer and XLA would copy every array across. So the
+        # caches go back the way they came, as a dict under the names
+        # they were fed by, and each is updated where it lies.
+        place = self._cache_places(outs, feed_names, fetch_names)
+        at = {i: n for n, i in place.items()}
+
         def run(state, caches, feed, base_key):
             env = dict(feed)
             env.update(caches)
             fetches, _, new_key = fn({}, state, env, base_key)
-            return fetches, new_key
+            return ([f for i, f in enumerate(fetches) if i not in at],
+                    {n: fetches[i] for n, i in place.items()}, new_key)
+
+        def unpack(rest, caches):
+            """The fetch list in ``fetch_names`` order again."""
+            rest = iter(rest)
+            return [caches[at[i]] if i in at else next(rest)
+                    for i in range(len(fetch_names))]
 
         jitted = jax.jit(run, donate_argnums=(1,))
+        self._unpack[kind] = unpack
         # one device snapshot per PARAMETER, shared by every kind's
         # state dict (prefill/decode/logits read the same weights — a
         # per-kind device_put would hold N identical copies in HBM)
@@ -596,6 +633,13 @@ class GPTGenerator:
                           feed_names=tuple(feed), cost=cost,
                           tag=f"generate_{kind}")
             self._tp_compile_gate(kind, compiled, feed)
+            from ..serving.kvpool import (count_pool_relayouts,
+                                          pool_element_counts)
+            pool_elems = pool_element_counts(caches)
+            if pool_elems:
+                self.pool_relayouts[kind] = max(
+                    self.pool_relayouts.get(kind, 0),
+                    count_pool_relayouts(compiled.as_text(), pool_elems))
             if self.stats:
                 self.stats.bump("compiles")
                 self.stats.hist["compile"].observe(dt)
@@ -605,7 +649,8 @@ class GPTGenerator:
         # are the one interval every consumer below takes
         with _trace.loop_span("generator/dispatch", parent, kind=kind,
                               stage=stage, compiled=fresh) as sent:
-            fetches, new_key = compiled(state, caches, rest, key)
+            fetched, kept, new_key = compiled(state, caches, rest, key)
+            fetches = self._unpack[kind](fetched, kept)
         with _trace.loop_span("generator/wait", parent, kind=kind,
                               stage=stage) as waited:
             jax.block_until_ready(fetches)

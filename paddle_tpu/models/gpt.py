@@ -341,16 +341,16 @@ def gpt_decode_step_paged(cfg, kv_dtype="fp32", batch_size=-1):
     feed_names = ["token", "pos", "block_tables"]
     pk_out, pv_out, ks_out, vs_out = [], [], [], []
     for i in range(cfg.num_layers):
-        pk = T.data(f"cache_pk_{i}", [-1, n_head, -1, d_head],
+        pk = T.data(f"cache_pk_{i}", [-1, -1, -1],
                     dtype=cache_dt)
-        pv = T.data(f"cache_pv_{i}", [-1, n_head, -1, d_head],
+        pv = T.data(f"cache_pv_{i}", [-1, -1, -1],
                     dtype=cache_dt)
         feed_names += [f"cache_pk_{i}", f"cache_pv_{i}"]
         kv_cache = {"k": pk, "v": pv, "mode": "paged", "tables": tables}
         if quantized:
-            pks = T.data(f"cache_pks_{i}", [-1, n_head, -1],
+            pks = T.data(f"cache_pks_{i}", [-1, -1, -1],
                          dtype="float32")
-            pvs = T.data(f"cache_pvs_{i}", [-1, n_head, -1],
+            pvs = T.data(f"cache_pvs_{i}", [-1, -1, -1],
                          dtype="float32")
             feed_names += [f"cache_pks_{i}", f"cache_pvs_{i}"]
             kv_cache["k_scale"], kv_cache["v_scale"] = pks, pvs
@@ -419,17 +419,17 @@ def gpt_prefill_chunk_paged(cfg, kv_dtype="fp32", batch_size=-1,
                   "block_tables"]
     pk_out, pv_out, ks_out, vs_out = [], [], [], []
     for i in range(cfg.num_layers):
-        pk = T.data(f"cache_pk_{i}", [-1, n_head, -1, d_head],
+        pk = T.data(f"cache_pk_{i}", [-1, -1, -1],
                     dtype=cache_dt)
-        pv = T.data(f"cache_pv_{i}", [-1, n_head, -1, d_head],
+        pv = T.data(f"cache_pv_{i}", [-1, -1, -1],
                     dtype=cache_dt)
         feed_names += [f"cache_pk_{i}", f"cache_pv_{i}"]
         kv_cache = {"k": pk, "v": pv, "mode": "paged", "tables": tables,
                     "limit": limit}
         if quantized:
-            pks = T.data(f"cache_pks_{i}", [-1, n_head, -1],
+            pks = T.data(f"cache_pks_{i}", [-1, -1, -1],
                          dtype="float32")
-            pvs = T.data(f"cache_pvs_{i}", [-1, n_head, -1],
+            pvs = T.data(f"cache_pvs_{i}", [-1, -1, -1],
                          dtype="float32")
             feed_names += [f"cache_pks_{i}", f"cache_pvs_{i}"]
             kv_cache["k_scale"], kv_cache["v_scale"] = pks, pvs
@@ -537,17 +537,17 @@ def gpt_verify_step_paged(cfg, kv_dtype="fp32", batch_size=-1,
                   "block_tables"]
     pk_out, pv_out, ks_out, vs_out = [], [], [], []
     for i in range(cfg.num_layers):
-        pk = T.data(f"cache_pk_{i}", [-1, n_head, -1, d_head],
+        pk = T.data(f"cache_pk_{i}", [-1, -1, -1],
                     dtype=cache_dt)
-        pv = T.data(f"cache_pv_{i}", [-1, n_head, -1, d_head],
+        pv = T.data(f"cache_pv_{i}", [-1, -1, -1],
                     dtype=cache_dt)
         feed_names += [f"cache_pk_{i}", f"cache_pv_{i}"]
         kv_cache = {"k": pk, "v": pv, "mode": "paged", "tables": tables,
                     "limit": limit}
         if quantized:
-            pks = T.data(f"cache_pks_{i}", [-1, n_head, -1],
+            pks = T.data(f"cache_pks_{i}", [-1, -1, -1],
                          dtype="float32")
-            pvs = T.data(f"cache_pvs_{i}", [-1, n_head, -1],
+            pvs = T.data(f"cache_pvs_{i}", [-1, -1, -1],
                          dtype="float32")
             feed_names += [f"cache_pks_{i}", f"cache_pvs_{i}"]
             kv_cache["k_scale"], kv_cache["v_scale"] = pks, pvs

@@ -224,7 +224,7 @@ def decoder_layer(cfg, x, idx, pos_ids, kv=None, valid=None):
             kv["v"], v, kv["tables"], kv["pos"], ring=bool(window))
         ctx = layers.nn.paged_attention(q, new_k, new_v, kv["tables"],
                                         kv["pos"], window=window,
-                                        scope=scope)
+                                        scope=scope, kv_heads=hkv)
     ctx = T.reshape(T.transpose(T.cast(ctx, "float32"), [0, 2, 1, 3]),
                     [0, 0, hq * d])
     x = M.elementwise_add(x, _proj(cfg, ctx, cfg.hidden_size,
@@ -298,7 +298,9 @@ def mellum_decode_step_paged(cfg, kv_dtype="bf16", batch_size=-1):
     """ONE paged decode step over the two-group pool. Feeds: token, pos
     [B] int32, ``block_tables`` [B, nblk] (the full layers' table) and
     ``block_tables_window`` [B, ring] (the window layers' ring), then
-    the pools ``cache_pk_<i>`` / ``cache_pv_<i>`` [N_group, Hkv, bs, D].
+    the pools ``cache_pk_<i>`` / ``cache_pv_<i>`` (logically [N_group,
+    Hkv, bs, D]; stored and fed as [N_group, Hkv * bs, D]: D = 128
+    fills the lanes).
     A row whose full table starts at the trash block is a free slot and
     routes to no expert. Fetches: logits, the updated pools in
     ``serving.kvpool.pool_feed_names`` order, then the counts."""
@@ -318,8 +320,8 @@ def mellum_decode_step_paged(cfg, kv_dtype="bf16", batch_size=-1):
     hkv, d = cfg.num_key_value_heads, cfg.head_dim
     by_name, counts = {}, []
     for i in range(cfg.num_hidden_layers):
-        pk = T.data(f"cache_pk_{i}", [-1, hkv, -1, d], dtype=cache_dt)
-        pv = T.data(f"cache_pv_{i}", [-1, hkv, -1, d], dtype=cache_dt)
+        pk = T.data(f"cache_pk_{i}", [-1, -1, d], dtype=cache_dt)
+        pv = T.data(f"cache_pv_{i}", [-1, -1, d], dtype=cache_dt)
         feed_names += [f"cache_pk_{i}", f"cache_pv_{i}"]
         x, npk, npv, c = decoder_layer(
             cfg, x, i, pos_ids, valid=live,

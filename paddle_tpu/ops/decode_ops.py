@@ -83,39 +83,47 @@ def kv_cached_attention(ctx, ins, attrs):
 @register_op("paged_kv_cache_write", grad=False, infer_shape=False)
 def paged_kv_cache_write(ctx, ins, attrs):
     """Append S new k/v vectors into a BLOCK-PAGED pool at each row's
-    own position. Cache [N, H, bs, D] (the shared pool), KV
-    [B, H, S, D], Tables [B, nblk] int32 (per-row block table), Pos [B]
-    int32 -> Out: pool with row b's vector i written at
-    ``(Tables[b, (Pos[b]+i)//bs], :, (Pos[b]+i)%bs)``. The optional
-    Limit input [B] int32 marks how many of the S vectors are REAL per
-    row (chunked prefill's ragged tail): positions at/past the limit
-    are routed to the reserved trash block 0 instead. With an int8 pool
-    the op quantizes (kernels/paged_attention.quantize_kv) and the
-    optional Scale input [N, H, bs] is updated too (second output
-    OutScale).
+    own position. Cache is the shared pool in its STORED shape
+    [N, H * bs // f, f * D] (kernels/paged_attention: logically
+    [N, H, bs, D]), KV [B, H, S, D], Tables [B, nblk] int32 (per-row
+    block table), Pos [B] int32 -> Out: pool with row b's vector i
+    written at logical ``(Tables[b, (Pos[b]+i)//bs], :, (Pos[b]+i)%bs)``.
+    The optional Limit input [B] int32 marks how many of the S vectors
+    are REAL per row (chunked prefill's ragged tail): positions at/past
+    the limit are routed to the reserved trash block 0 instead. With an
+    int8 pool the op quantizes (kernels/paged_attention.quantize_kv) and
+    the optional Scale input (stored [N, f, H * bs // f]) is updated too
+    (second output OutScale).
 
     With the attribute ``ring`` the table is a window layer's ring:
     position ``p`` lives in column ``(p // bs) % nblk`` (one token a row
     only; the prefill scatter fills a ring from outside).
 
-    One scatter covers the batch: slots own disjoint blocks and COW
-    guarantees a written block has refcount 1, so the valid
-    (block, offset) pairs are unique; rows whose table entry is the
-    trash block (free serving slots / past-limit padding) write garbage
-    nobody reads.
+    One token a row with no Limit (the decode step) is the Pallas call
+    ``paged_kv_append`` on a TPU: in place in the stored layout, no
+    table among its operands. Everything else, and every backend but
+    the TPU, takes the composite: one scatter over the logical view
+    covers the batch. Slots own disjoint blocks and COW guarantees a
+    written block has refcount 1, so the valid (block, offset) pairs
+    are unique; rows whose table entry is the trash block (free serving
+    slots / past-limit padding) write garbage nobody reads. Which one
+    ran is counted as ``attention_impl_total{op="paged_kv_append"}``.
     """
-    from ..kernels.paged_attention import quantize_kv
+    from ..kernels import _dispatch
+    from ..kernels.paged_attention import (
+        paged_kv_append, quantize_kv, scales_to_logical, scales_to_stored,
+        to_logical, to_stored)
 
     pool = x_of(ins, "Cache")
     kv = x_of(ins, "KV")
     tables = x_of(ins, "Tables").astype(jnp.int32)
     pos = x_of(ins, "Pos").astype(jnp.int32)
-    bs = pool.shape[2]
-    B = kv.shape[0]
-    S = kv.shape[2]
+    B, H, S, D = kv.shape
+    bs = pool.shape[1] * pool.shape[2] // (H * D)
     limit = ins.get("Limit")
+    quant = pool.dtype == jnp.int8
+    scale = x_of(ins, "Scale") if quant else None
 
-    outs = {}
     ring = bool(attrs.get("ring", False))
     if ring and (S != 1 or limit):
         raise NotImplementedError(
@@ -123,54 +131,70 @@ def paged_kv_cache_write(ctx, ins, attrs):
             "(chunked prefill and the verify span are not built for "
             "window layers)")
     if S == 1 and not limit:
-        # single-token decode fast path (bitwise-identical to the
-        # original op)
+        # the decode step: one (block, offset) a row
         col = (pos // bs) % tables.shape[1] if ring else pos // bs
         block_ids = tables[jnp.arange(B), col]              # [B]
         offs = pos % bs                                     # [B]
-        vec = kv[:, :, 0, :]                                # [B, H, D]
-        if pool.dtype == jnp.int8:
-            q, sc = quantize_kv(vec)
-            outs["Out"] = pool.at[block_ids, :, offs, :].set(q)
-            scale = x_of(ins, "Scale")
-            outs["OutScale"] = scale.at[block_ids, :, offs].set(sc)
+        vals = kv[:, :, 0, :]                               # [B, H, D]
+        impl, reason = _dispatch.auto_impl(), "backend"
+    else:
+        # multi-token path: per-(row, token) absolute positions, invalid
+        # (past-limit) entries routed to the trash block. Clip keeps the
+        # table gather in-bounds for padded rows whose pos+S would run
+        # past the row's table; those entries are invalid by
+        # construction.
+        steps = jnp.arange(S, dtype=jnp.int32)
+        qpos = pos[:, None] + steps[None, :]                # [B, S]
+        if limit:
+            valid = steps[None, :] < limit[0].astype(jnp.int32)[:, None]
         else:
-            outs["Out"] = pool.at[block_ids, :, offs, :].set(
-                vec.astype(pool.dtype))
-        return outs
+            valid = jnp.ones((B, S), dtype=bool)
+        safe = jnp.clip(qpos, 0, tables.shape[1] * bs - 1)
+        blk = jnp.take_along_axis(tables, safe // bs, axis=1)   # [B, S]
+        block_ids = jnp.where(valid, blk, 0).reshape(-1)    # [B*S]
+        offs = (safe % bs).reshape(-1)                      # [B*S]
+        vals = kv.transpose(0, 2, 1, 3).reshape(B * S, H, D)
+        impl, reason = "xla", "multi_token"
+    new_scale = None
+    if quant:
+        vals, new_scale = quantize_kv(vals)
 
-    # multi-token path: per-(row, token) absolute positions, invalid
-    # (past-limit) entries routed to the trash block. Clip keeps the
-    # table gather in-bounds for padded rows whose pos+S would run past
-    # the row's table; those entries are invalid by construction.
-    steps = jnp.arange(S, dtype=jnp.int32)
-    qpos = pos[:, None] + steps[None, :]                    # [B, S]
-    if limit:
-        valid = steps[None, :] < limit[0].astype(jnp.int32)[:, None]
-    else:
-        valid = jnp.ones((B, S), dtype=bool)
-    safe = jnp.clip(qpos, 0, tables.shape[1] * bs - 1)
-    blk = jnp.take_along_axis(tables, safe // bs, axis=1)   # [B, S]
-    block_ids = jnp.where(valid, blk, 0).reshape(-1)        # [B*S]
-    offs = (safe % bs).reshape(-1)                          # [B*S]
-    vals = kv.transpose(0, 2, 1, 3).reshape(B * S, kv.shape[1],
-                                            kv.shape[3])
-    if pool.dtype == jnp.int8:
-        q, sc = quantize_kv(vals)
-        outs["Out"] = pool.at[block_ids, :, offs, :].set(q)
-        scale = x_of(ins, "Scale")
-        outs["OutScale"] = scale.at[block_ids, :, offs].set(sc)
-    else:
-        outs["Out"] = pool.at[block_ids, :, offs, :].set(
-            vals.astype(pool.dtype))
-    return outs
+    with _dispatch.resolved("paged_kv_append", impl, reason):
+        if impl == "xla":
+            out = to_stored(to_logical(pool, H, D).at[
+                block_ids, :, offs, :].set(vals.astype(pool.dtype)))
+            if not quant:
+                return {"Out": out}
+            return {"Out": out, "OutScale": scales_to_stored(
+                scales_to_logical(scale, H).at[block_ids, :, offs].set(
+                    new_scale), D)}
+
+        def kernel(pool, vals, block_ids, offs, scale, new_scale):
+            return paged_kv_append(pool, vals.astype(pool.dtype), block_ids,
+                                   offs, scale, new_scale,
+                                   interpret=impl == "interpret")
+
+        # heads over tp: the stored rows and the scales' columns are
+        # head-major, a chip's shard a contiguous range of either
+        outs = _dispatch.per_shard(
+            kernel, None if ctx.abstract else ctx.mesh,
+            (pool, vals, block_ids, offs, scale, new_scale),
+            ((None, "heads", None), (None, "heads", None), (None,), (None,),
+             (None, None, "heads"), (None, "heads")),
+            ((None, "heads", None), (None, None, "heads")) if quant
+            else (None, "heads", None))
+    if quant:
+        return {"Out": outs[0], "OutScale": outs[1]}
+    return {"Out": outs}
 
 
 @register_op("paged_attention", grad=False, infer_shape=False)
 def paged_attention_op(ctx, ins, attrs):
     """Decode attention of one query per row over the block-paged pool:
-    Q [B, H, 1, D], K/V pools [N, H, bs, D] (+ KScale/VScale [N, H, bs]
-    for int8), Tables [B, nblk] int32, Pos [B] int32 -> Out [B, H, 1, D].
+    Q [B, H, 1, D], K/V pools in their stored shape [N, Hkv * bs // f,
+    f * D] (+ KScale/VScale [N, f, Hkv * bs // f] for int8; attrs
+    ["kv_heads"] is Hkv, 0 = H), Tables [B, nblk] int32, Pos [B] int32
+    -> Out [B, H, 1, D].
     Dispatches to kernels/paged_attention (Pallas fused gather+attend on
     TPU; jnp.take reference elsewhere — attrs["impl"] overrides). The
     pools may have fewer heads than Q (grouped queries); attrs["window"]
@@ -190,7 +214,8 @@ def paged_attention_op(ctx, ins, attrs):
                       scale=float(attrs.get("scale", 0.0)) or None,
                       impl=attrs.get("impl") or None,
                       mesh=None if ctx.abstract else ctx.mesh,
-                      window=int(attrs.get("window", 0)) or None)
+                      window=int(attrs.get("window", 0)) or None,
+                      kv_heads=int(attrs.get("kv_heads", 0)) or None)
     return {"Out": out}
 
 

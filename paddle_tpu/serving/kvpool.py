@@ -40,9 +40,27 @@ jitted bucketed prefill scatter (dense prefill row caches reshaped to
 blocks and scattered through the table in one donated call), and the
 paged decode programs' feed dict. The fused read path is
 ``kernels/paged_attention.py``.
+
+The stored-shape contract. A pool array is logically ``[num_blocks, H,
+block_size, D]``; the device array, every executable's parameter and
+result, is the same bytes as ``[num_blocks, H * block_size // f,
+f * D]`` with ``f = 128 // D`` slots of a head in one 128-lane row
+(``kernels/paged_attention.pool_packing``; int8 scales ``[num_blocks,
+f, H * block_size // f]``). That shape is tile-exact, so the runtime's
+layout, the append's, the prefill scatter's and the decode kernel's are
+one and no executable copies a pool array to relay it: every fresh
+compile of the scatter, the COW copy and the import counts the
+pool-sized ``copy`` instructions of its optimised HLO
+(:func:`count_pool_relayouts`; ``stats()["relayouts"]``, 0 is the aim,
+and the generator counts its programs' the same way). Blocks are the
+major dimension, so the block-row writers here index dimension 0 and
+are in place. The logical shape stays the contract of
+``export_slot``/``import_slot`` (the wire payload is unchanged), of
+:meth:`KVBlockPool.logical` and of the tests: host-side reshapes.
 """
 import hashlib
 import math
+import re
 import threading
 from collections import OrderedDict
 
@@ -160,6 +178,65 @@ def _np_pool_dtype(kv_dtype):
     import jax.numpy as jnp
     return {"fp32": jnp.float32, "bf16": jnp.bfloat16,
             "int8": jnp.int8}[kv_dtype]
+
+
+_COPY_RESULT = re.compile(r"= \w+\[([\d,]*)\]\S* copy\(")
+
+
+def count_pool_relayouts(hlo_text, element_counts):
+    """``copy`` instructions of an optimised HLO whose result has as
+    many elements as a pool array (``element_counts``): each is one
+    whole array relaid to suit an op's layout, where the stored shape
+    is meant to leave none. One scan of the text a fresh compile."""
+    counts = set(int(c) for c in element_counts)
+    return sum(
+        1 for dims in _COPY_RESULT.findall(hlo_text)
+        if math.prod(int(d) for d in dims.split(",") if d) in counts)
+
+
+def pool_element_counts(arrays):
+    """The element counts :func:`count_pool_relayouts` looks for: those
+    of the ``cache_p*`` arrays of a feed or of a pool."""
+    return {int(math.prod(a.shape)) for n, a in arrays.items()
+            if n.startswith("cache_p")}
+
+
+class _PoolJit:
+    """A jitted writer of the pool (its first argument, donated) that
+    compiles ahead of time for every new set of shapes and counts the
+    pool-sized copies XLA left in each executable."""
+
+    def __init__(self, fn):
+        import jax
+        self._jit = jax.jit(fn, donate_argnums=(0,))
+        self._compiled = {}
+        self.relayouts = 0      # the worst executable's
+
+    def __call__(self, pool, *args):
+        import jax
+        sharding = getattr(next(iter(pool.values())), "sharding", None)
+        if getattr(sharding, "mesh", None) is not None \
+                and sharding.mesh.size > 1:
+            # beside a pool split over a tp mesh an executable compiled
+            # ahead of time wants every other argument on that mesh too
+            from jax.sharding import NamedSharding, PartitionSpec
+            args = jax.device_put(
+                args, NamedSharding(sharding.mesh, PartitionSpec()))
+        return self.compiled(pool, *args)(pool, *args)
+
+    def compiled(self, pool, *args):
+        """The executable for these shapes (arrays or
+        ``ShapeDtypeStruct``s), compiled and counted at first sight."""
+        import jax
+        key = tuple((a.shape, str(a.dtype)) for a in
+                    jax.tree_util.tree_leaves((pool, args)))
+        compiled = self._compiled.get(key)
+        if compiled is None:
+            compiled = self._jit.lower(pool, *args).compile()
+            self._compiled[key] = compiled
+            self.relayouts = max(self.relayouts, count_pool_relayouts(
+                compiled.as_text(), pool_element_counts(pool)))
+        return compiled
 
 
 def pool_feed_names(num_layers, quantized):
@@ -602,8 +679,9 @@ class KVBlockPool:
         — see :func:`pool_feed_names` for the order contract."""
         if self._arrays is None:
             import jax.numpy as jnp
-            shape = (self.num_blocks, self.num_heads, self.block_size,
-                     self.d_head)
+            from ..kernels.paged_attention import stored_shape
+            shape = stored_shape(self.num_blocks, self.num_heads,
+                                 self.block_size, self.d_head)
             dt = _np_pool_dtype(self.dtype)
             arrs = {}
             for i in range(self.num_layers):
@@ -613,7 +691,7 @@ class KVBlockPool:
                 arrs[f"cache_pk_{i}"] = jnp.zeros((n,) + shape[1:], dt)
                 arrs[f"cache_pv_{i}"] = jnp.zeros((n,) + shape[1:], dt)
             if self.quantized:
-                sshape = shape[:3]
+                sshape = (shape[0], shape[2] // self.d_head, shape[1])
                 for i in range(self.num_layers):
                     # scale 1.0, not 0: a read of a never-written slot
                     # dequantizes 0 * 1.0 instead of hitting a 0-scale
@@ -621,9 +699,10 @@ class KVBlockPool:
                     arrs[f"cache_pvs_{i}"] = jnp.ones(sshape, jnp.float32)
             if self.array_sharding is not None:
                 # tp-mesh placement: blocks sharded on the head axis
-                # (dim 1), matching gpt.apply_tp_sharding's qkv split —
-                # each chip holds its own heads' cache bytes. Scale
-                # pools share the same head-axis split.
+                # (the stored rows are head-major: dim 1), matching
+                # gpt.apply_tp_sharding's qkv split — each chip holds
+                # its own heads' cache bytes. Scale pools share the
+                # same head split (their columns, dim 2).
                 import jax
                 arrs = {n: jax.device_put(a, self.array_sharding[n])
                         for n, a in arrs.items()}
@@ -634,6 +713,20 @@ class KVBlockPool:
         """Adopt the decode step's fetched (donated-in-place) pool
         arrays."""
         self._arrays = dict(new_arrays)
+
+    def logical(self, name, blocks=None):
+        """Pool array ``name`` (its rows ``blocks`` where given) in the
+        LOGICAL shape, on the host: ``[n, H, block_size, D]`` values,
+        ``[n, H, block_size]`` int8 scales. What :meth:`export_slot`
+        sends and what a test or a debugger reads; the executables never
+        see it."""
+        from ..kernels.paged_attention import scales_to_logical, to_logical
+        a = self.arrays()[name]
+        a = np.asarray(a if blocks is None else a[np.asarray(blocks,
+                                                             np.int32)])
+        if name.startswith(("cache_pks_", "cache_pvs_")):
+            return scales_to_logical(a, self.num_heads)
+        return to_logical(a, self.num_heads, self.d_head)
 
     def drop_device(self):
         """Forget the device arrays (a failed donated call may have
@@ -856,13 +949,12 @@ class KVBlockPool:
         copies every pool array's ``src`` rows into ``dst``. On failure
         the donated arrays must be presumed lost (drop_device
         semantics) — the caller's bank-lost path applies."""
-        import jax
         import jax.numpy as jnp
         if self._copy_fn is None:
             def cp(pool, src, dst):
                 return {n: a.at[dst].set(a[src])
                         for n, a in pool.items()}
-            self._copy_fn = jax.jit(cp, donate_argnums=(0,))
+            self._copy_fn = _PoolJit(cp)
         try:
             self._arrays = self._copy_fn(
                 self.arrays(), jnp.asarray(src_ids, jnp.int32),
@@ -872,6 +964,73 @@ class KVBlockPool:
             raise
 
     # -- prefill scatter --------------------------------------------------
+    def _scatter(self):
+        """The prefill scatter's donated jit (built once): ``(pool,
+        row_caches, tables [n, nblk], ring_src, ring_dst) -> pool``."""
+        if self._scatter_fn is None:
+            import jax.numpy as jnp
+            from ..kernels.paged_attention import (
+                quantize_kv, scales_to_stored, to_stored)
+            bs, quant, d_head = self.block_size, self.quantized, self.d_head
+            full = list(self.full_layers)
+            windowed = list(self.window.layers) if self.window else []
+
+            def blocks_of(src, n, nblk):
+                """[bb, H, L, D] -> [n, nblk, H, bs, D]: the first
+                ``nblk`` blocks of the first ``n`` rows (sliced here,
+                inside the jit, where the slice fuses with the gather:
+                outside it is a copy of every row cache). The covered
+                length is shape-determined (the jit retraces per
+                (n, nblk) pair), zero-padded past ``L``."""
+                cover = nblk * bs
+                take = min(cover, src.shape[2])
+                vals = src[:n, :, :take]
+                if take < cover:
+                    pad = jnp.zeros(
+                        (n, src.shape[1], cover - take, src.shape[3]),
+                        src.dtype)
+                    vals = jnp.concatenate([vals, pad], axis=2)
+                vals = vals.reshape(n, vals.shape[1], nblk, bs,
+                                    vals.shape[3])
+                return vals.transpose(0, 2, 1, 3, 4)
+
+            def scatter(pool, rows, tables, ring_src, ring_dst):
+                out = dict(pool)
+                n, nblk = tables.shape
+                m, tables_flat = n * nblk, tables.reshape(-1)
+                for i in full:
+                    for kind in ("k", "v"):
+                        src = rows[f"cache_{kind}_{i}"]    # [bb,H,L,D]
+                        vals = blocks_of(src, n, nblk)
+                        vals = vals.reshape((m,) + vals.shape[2:])
+                        dst = out[f"cache_p{kind}_{i}"]
+                        # whole blocks into rows of the stored array:
+                        # dimension 0 alone is indexed, so in place
+                        if quant:
+                            q, sc = quantize_kv(vals)
+                            out[f"cache_p{kind}_{i}"] = \
+                                dst.at[tables_flat].set(to_stored(q))
+                            skey = f"cache_p{kind}s_{i}"
+                            out[skey] = out[skey].at[tables_flat].set(
+                                scales_to_stored(sc, d_head))
+                        else:
+                            out[f"cache_p{kind}_{i}"] = \
+                                dst.at[tables_flat].set(
+                                    to_stored(vals.astype(dst.dtype)))
+                for i in windowed:
+                    for kind in ("k", "v"):
+                        vals = blocks_of(rows[f"cache_{kind}_{i}"], n, nblk)
+                        vals = jnp.take_along_axis(
+                            vals, ring_src[:, :, None, None, None], axis=1)
+                        dst = out[f"cache_p{kind}_{i}"]
+                        out[f"cache_p{kind}_{i}"] = dst.at[ring_dst].set(
+                            to_stored(vals.reshape(
+                                (-1,) + vals.shape[2:]).astype(dst.dtype)))
+                return out
+
+            self._scatter_fn = _PoolJit(scatter)
+        return self._scatter_fn
+
     def scatter_prefill(self, slot_ids, row_caches, bucket_len,
                         lengths=None):
         """Move freshly-prefilled dense row caches into the pool: rows
@@ -886,14 +1045,12 @@ class KVBlockPool:
         blocks, each into the column its logical index names. Quantizes
         on the way in for an int8 pool. On ANY failure the donated pool
         arrays must be presumed lost — callers reset the pool."""
-        import jax
         import jax.numpy as jnp
 
         n = len(slot_ids)
         nblk = self.blocks_for_tokens(bucket_len)
         slots = np.asarray(slot_ids, np.int32)
-        tables = np.ascontiguousarray(
-            self.tables[slots, :nblk]).reshape(-1)        # [n*nblk]
+        tables = np.ascontiguousarray(self.tables[slots, :nblk])
         ring_src = ring_dst = None
         if self.window is not None:
             if lengths is None:
@@ -909,65 +1066,10 @@ class KVBlockPool:
                 w.tables[slots[:, None], logical % w.ring],
                 0).astype(np.int32).reshape(-1)           # [n*ring]
 
-        if self._scatter_fn is None:
-            from ..kernels.paged_attention import quantize_kv
-            bs, quant = self.block_size, self.quantized
-            full = list(self.full_layers)
-            windowed = list(self.window.layers) if self.window else []
-
-            def blocks_of(src, m):
-                """[n, H, L, D] -> [n, m // n, H, bs, D]: the first
-                ``m // n`` blocks of every row. The covered length is
-                shape-determined (the jit retraces per (n, m) pair),
-                zero-padded past ``L``."""
-                n_rows = src.shape[0]
-                cover = (m // n_rows) * bs
-                take = min(cover, src.shape[2])
-                vals = src[:, :, :take]
-                if take < cover:
-                    pad = jnp.zeros(
-                        src.shape[:2] + (cover - take, src.shape[3]),
-                        src.dtype)
-                    vals = jnp.concatenate([vals, pad], axis=2)
-                vals = vals.reshape(n_rows, vals.shape[1], cover // bs,
-                                    bs, vals.shape[3])
-                return vals.transpose(0, 2, 1, 3, 4)
-
-            def scatter(pool, rows, tables_flat, ring_src, ring_dst):
-                out = dict(pool)
-                m = tables_flat.shape[0]
-                for i in full:
-                    for kind in ("k", "v"):
-                        src = rows[f"cache_{kind}_{i}"]    # [n,H,L,D]
-                        vals = blocks_of(src, m)
-                        vals = vals.reshape((m,) + vals.shape[2:])
-                        dst = out[f"cache_p{kind}_{i}"]
-                        if quant:
-                            q, sc = quantize_kv(vals)
-                            out[f"cache_p{kind}_{i}"] = \
-                                dst.at[tables_flat].set(q)
-                            skey = f"cache_p{kind}s_{i}"
-                            out[skey] = out[skey].at[tables_flat].set(sc)
-                        else:
-                            out[f"cache_p{kind}_{i}"] = \
-                                dst.at[tables_flat].set(
-                                    vals.astype(dst.dtype))
-                for i in windowed:
-                    for kind in ("k", "v"):
-                        vals = blocks_of(rows[f"cache_{kind}_{i}"], m)
-                        vals = jnp.take_along_axis(
-                            vals, ring_src[:, :, None, None, None], axis=1)
-                        dst = out[f"cache_p{kind}_{i}"]
-                        out[f"cache_p{kind}_{i}"] = dst.at[ring_dst].set(
-                            vals.reshape((-1,) + vals.shape[2:]).astype(
-                                dst.dtype))
-                return out
-
-            self._scatter_fn = jax.jit(scatter, donate_argnums=(0,))
-        rows = {name: a[:n] for name, a in row_caches.items()}
         try:
-            self._arrays = self._scatter_fn(
-                self.arrays(), rows, jnp.asarray(tables, jnp.int32),
+            self._arrays = self._scatter()(
+                self.arrays(), dict(row_caches),
+                jnp.asarray(tables, jnp.int32),
                 None if ring_src is None else jnp.asarray(ring_src),
                 None if ring_dst is None else jnp.asarray(ring_dst))
         except Exception:
@@ -1003,9 +1105,6 @@ class KVBlockPool:
             raise ValueError(
                 f"KV pool {self.name!r} slot {slot} holds no blocks — "
                 f"nothing to export")
-        import jax.numpy as jnp
-        arrs = self.arrays()
-        idx = jnp.asarray(ids, jnp.int32)
         payload = {
             "fmt": KV_WIRE_FMT, "pool_dtype": self.dtype,
             "block_size": self.block_size, "num_layers": self.num_layers,
@@ -1014,13 +1113,13 @@ class KVBlockPool:
         }
         for i in range(self.num_layers):
             for kind in ("k", "v"):
-                a = np.asarray(arrs[f"cache_p{kind}_{i}"][idx])
+                a = self.logical(f"cache_p{kind}_{i}", ids)
                 if self.dtype == "bf16":
                     a = a.view(np.uint16)
                 payload[f"{kind}_{i}"] = a
                 if self.quantized:
-                    payload[f"{kind}s_{i}"] = np.asarray(
-                        arrs[f"cache_p{kind}s_{i}"][idx])
+                    payload[f"{kind}s_{i}"] = self.logical(
+                        f"cache_p{kind}s_{i}", ids)
         _EXPORTED.inc(n, labels=(self.name,))
         return payload
 
@@ -1061,8 +1160,8 @@ class KVBlockPool:
         with self._lock:
             ids = np.zeros(n_pad, np.int32)        # trash-block padding
             ids[:n] = self.tables[slot, :n]
-        import jax
         import jax.numpy as jnp
+        from ..kernels.paged_attention import scales_to_stored, to_stored
         vals = {}
         try:
             pool_np = _np_pool_dtype(self.dtype)
@@ -1078,19 +1177,20 @@ class KVBlockPool:
                     a = np.ascontiguousarray(payload[f"{kind}_{i}"])
                     if self.dtype == "bf16":
                         a = a.view(pool_np)
-                    vals[f"cache_p{kind}_{i}"] = jnp.asarray(padded(a))
+                    vals[f"cache_p{kind}_{i}"] = jnp.asarray(
+                        to_stored(padded(a)))
                     if self.quantized:
                         vals[f"cache_p{kind}s_{i}"] = jnp.asarray(
-                            padded(np.ascontiguousarray(
+                            scales_to_stored(padded(np.ascontiguousarray(
                                 payload[f"{kind}s_{i}"],
-                                dtype=np.float32)))
+                                dtype=np.float32)), self.d_head))
             if self._import_fn is None:
                 def imp(pool, new_vals, idx):
                     out = dict(pool)
                     for name, v in new_vals.items():
                         out[name] = out[name].at[idx].set(v)
                     return out
-                self._import_fn = jax.jit(imp, donate_argnums=(0,))
+                self._import_fn = _PoolJit(imp)
             self._arrays = self._import_fn(self.arrays(), vals,
                                            jnp.asarray(ids, jnp.int32))
         except Exception:
@@ -1221,7 +1321,17 @@ class KVBlockPool:
                  "window": self.window.capacity if self.window else 0}),
             "saved_vs_dense_bytes": self.slots * self.dense_slot_bytes()
             - self._bytes_of(by_group, cached),
+            "relayouts": self.relayouts(),
         }
+
+    def relayouts(self):
+        """``{"scatter": n, "copy_blocks": n, "import": n}``: pool-sized
+        copies in the optimised HLO of this pool's own executables (the
+        worst of each kind's compiles; a kind that never ran is left
+        out). 0 says the stored layout served the writer as it lies."""
+        fns = (("scatter", self._scatter_fn), ("copy_blocks", self._copy_fn),
+               ("import", self._import_fn))
+        return {k: fn.relayouts for k, fn in fns if fn is not None}
 
     def _bytes_of(self, by_group, cached=0):
         """Device bytes of ``by_group``'s blocks (and the prefix

@@ -519,6 +519,13 @@ class InferenceServer:
             if self.gen_engine.pool is not None:
                 for k, v in self.gen_engine.pool.stats().items():
                     extra[f"kvpool_{k}"] = v
+                # pool-sized copies XLA left in each executable that
+                # takes the pool: the generator's by program kind, the
+                # pool's own writers beside them (0 everywhere is the
+                # stored layout doing its work)
+                extra["pool_relayouts"] = dict(
+                    self.gen_engine.gen.pool_relayouts,
+                    **extra.pop("kvpool_relayouts"))
         extra["state"] = self.state
         extra["weights_version"] = self._weights_version
         # level() (not snapshot's cached value): the ladder is

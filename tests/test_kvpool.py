@@ -5,15 +5,24 @@ through the serving decode bank with slot reuse, block frees on
 EOS/deadline/cancel (pool returns to empty), typed KVPoolExhaustedError
 backpressure at the door / admission / mid-decode, bf16+int8
 quantized-cache quality gates, the ``serving.kv_alloc`` chaos point,
-and Pallas-interpret vs XLA-reference kernel parity."""
+Pallas-interpret vs XLA-reference kernel parity, and the pool's stored
+shape with its one-token writer ``paged_kv_append``."""
+import importlib
+import types
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import serving
+from paddle_tpu.kernels import _dispatch
 from paddle_tpu.models import gpt
 from paddle_tpu.models.generation import GPTGenerator
-from paddle_tpu.serving.kvpool import KVBlockPool, KVPoolExhaustedError
+from paddle_tpu.ops import decode_ops
+from paddle_tpu.serving.kvpool import (KVBlockPool, KVPoolExhaustedError,
+                                       _np_pool_dtype, count_pool_relayouts,
+                                       pool_element_counts)
 
 
 def _pool(**kw):
@@ -204,10 +213,15 @@ def test_paged_attention_interpret_matches_xla_reference():
 # a step. H=16 takes the serve cell's tile (bs 16, D 64: G is 8 from the
 # score budget); H=2 takes a small one with the budget lowered to G = 4,
 # so both walk a table that is a multiple of G, one that is not, and one
-# narrower than the budget's G
+# narrower than the budget's G. H=16 and H=12 are D = 64 packed two
+# slots a lane row (the pool's stored shape); H=12 (GPT-base's heads, G
+# is 8 too) is fed STORED arrays and pads its two parities to 16 rows each
 _GRID_SHAPES = {2: dict(bs=4, D=16, score_lanes=4 * 2 * 4,
                         widths={"multiple": 8, "ragged": 6, "narrow": 2}),
                 16: dict(bs=16, D=64, score_lanes=None,
+                         widths={"multiple": 16, "ragged": 11,
+                                 "narrow": 4}),
+                12: dict(bs=16, D=64, score_lanes=None, stored=True,
                          widths={"multiple": 16, "ragged": 11,
                                  "narrow": 4})}
 
@@ -225,7 +239,7 @@ def _grid_positions(pattern, bs, nblk):
     return [mid, 0, nblk * bs - 1, mid], [1, 3]
 
 
-@pytest.mark.parametrize("H", [2, 16])
+@pytest.mark.parametrize("H", [2, 16, 12])
 @pytest.mark.parametrize("width", ["multiple", "ragged", "narrow"])
 @pytest.mark.parametrize("pattern", [
     "first_slot", "block_end", "block_start", "mid_block", "last_slot",
@@ -236,8 +250,8 @@ def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
     """The grouped grid through the Pallas interpreter against the
     gather composite: every pool type, positions at each edge of a block
     and of the table, alone and mixed in one batch, a free row (table
-    all trash block 0) beside live ones, and every way the table's
-    width meets G."""
+    all trash block 0) beside live ones, every way the table's width
+    meets G, and the pool fed logical or stored."""
     import importlib
     import jax.numpy as jnp
     # the package exports the function under the module's name
@@ -247,7 +261,7 @@ def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
     if shape["score_lanes"]:
         monkeypatch.setattr(pa, "_SCORE_LANES", shape["score_lanes"])
     (_, steps), G = pa.decode_grid(1, H, bs, D, jnp.float32, nblk)
-    assert G == {"multiple": nblk // 2, "ragged": 8 if H == 16 else 4,
+    assert G == {"multiple": nblk // 2, "ragged": 4 if H == 2 else 8,
                  "narrow": nblk}[width]
     assert steps == -(-nblk // G)
 
@@ -268,6 +282,11 @@ def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
         dt = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
         kp, vp, scales = kp.astype(dt), vp.astype(dt), {}
     ref = pa.paged_attention(q, kp, vp, tables, pos, impl="xla", **scales)
+    if shape.get("stored"):
+        assert pa.pool_packing(D, bs) == 2
+        kp, vp = pa.to_stored(kp), pa.to_stored(vp)
+        scales = {k: pa.scales_to_stored(v, D) for k, v in scales.items()}
+        scales["kv_heads"] = H
     out = pa.paged_attention(q, kp, vp, tables, pos, impl="interpret",
                              **scales)
     # a free row reads one slot of the trash block, like the oracle
@@ -536,3 +555,353 @@ def test_never_fitting_request_rejected_at_door_paged(tiny_gen,
                                    max_new_tokens=8)       # 29 tokens
     finally:
         server.stop()
+
+
+# ------------------------------------------------------- the stored shape
+# The pool's stored shape (``kernels/paged_attention``: logical ``[N, H,
+# bs, D]`` held as ``[N, H * bs // f, f * D]``): the one-token writer
+# ``paged_kv_append`` through the Pallas interpreter against the composite
+# write, the block-row writers and the migration round trip on the stored
+# arrays, and the count of pool-sized copies.
+
+# the package exports the function under the module's name
+pa = importlib.import_module("paddle_tpu.kernels.paged_attention")
+
+# f = 2: the serve cells' head width over a block of 16 (slots 2r and
+# 2r + 1 of a head share a lane row); f = 1: Mellum's, a row a slot
+_SHAPES = {2: dict(H=4, bs=16, D=64), 1: dict(H=2, bs=16, D=128)}
+_CTX = types.SimpleNamespace(abstract=False, mesh=None)
+
+
+def _write(pool, kv, tables, pos, scale=None, ring=False, limit=None):
+    ins = {"Cache": [pool], "KV": [kv], "Tables": [tables], "Pos": [pos]}
+    if scale is not None:
+        ins["Scale"] = [scale]
+    if limit is not None:
+        ins["Limit"] = [limit]
+    return decode_ops.paged_kv_cache_write(_CTX, ins, {"ring": ring})
+
+
+def _grew(before, op, impl, reason):
+    key = (op, impl, reason)
+    return _dispatch.resolved_counts().get(key, 0) - before.get(key, 0)
+
+
+def test_packing_follows_the_head_width_and_the_block():
+    assert pa.pool_packing(64, 16) == 2
+    assert pa.pool_packing(128, 16) == 1 and pa.pool_packing(256, 16) == 1
+    assert pa.pool_packing(32, 16) == 4
+    # what the packing cannot take keeps a row a slot: a width that does
+    # not divide the lanes, a block the lane row's slots do not divide
+    assert pa.pool_packing(48, 16) == 1 and pa.pool_packing(16, 4) == 1
+    assert pa.stored_shape(2049, 16, 16, 64) == (2049, 128, 128)
+    assert pa.stored_shape(16385, 4, 16, 128) == (16385, 64, 128)
+    assert pa.stored_shape(2081, 4, 16, 128) == (2081, 64, 128)
+
+
+@pytest.mark.parametrize("f", [1, 2])
+def test_stored_and_logical_views_are_the_same_bytes(f):
+    s = _SHAPES[f]
+    H, bs, D = s["H"], s["bs"], s["D"]
+    x = np.arange(3 * H * bs * D, dtype=np.float32).reshape(3, H, bs, D)
+    st = pa.to_stored(x)
+    assert st.shape == (3, H * bs // f, f * D)
+    assert np.array_equal(st.reshape(-1), x.reshape(-1))
+    assert np.array_equal(pa.to_logical(st, H, D), x)
+    # slot t of head h: row h * (bs // f) + t // f, lanes (t % f) * D ...
+    h, t = H - 1, bs - 1
+    row, lane = h * (bs // f) + t // f, (t % f) * D
+    assert np.array_equal(st[1, row, lane:lane + D], x[1, h, t])
+    sc = np.arange(3 * H * bs, dtype=np.float32).reshape(3, H, bs)
+    ss = pa.scales_to_stored(sc, D)
+    assert ss.shape == (3, f, H * bs // f)
+    assert ss[1, t % f, row] == sc[1, h, t]
+    assert np.array_equal(pa.scales_to_logical(ss, H), sc)
+    # the same helpers on device arrays
+    assert np.array_equal(np.asarray(pa.scales_to_logical(
+        pa.scales_to_stored(jnp.asarray(sc), D), H)), sc)
+
+
+@pytest.mark.parametrize("f", [1, 2])
+@pytest.mark.parametrize("trash_rows", [False, True])
+@pytest.mark.parametrize("offset", ["first", "second", "last_but_one",
+                                    "last"])
+@pytest.mark.parametrize("ring", [False, True])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_paged_kv_append_matches_the_composite_write(
+        monkeypatch, kv_dtype, ring, offset, trash_rows, f):
+    """The Pallas call (interpreter) against the scatter over the logical
+    view: every pool type with its scales, a plain table and a ring,
+    offsets at both ends of a block (both parities of a packed lane
+    row), free rows whose table names the trash block beside live ones."""
+    s = _SHAPES[f]
+    H, bs, D = s["H"], s["bs"], s["D"]
+    assert pa.pool_packing(D, bs) == f
+    B, nblk = 4, 3
+    N = B * nblk + 1
+    rng = np.random.default_rng(3)
+    off = {"first": 0, "second": 1, "last_but_one": bs - 2,
+           "last": bs - 1}[offset]
+    # rows at different blocks of their tables, all at the offset; a
+    # ring's positions lie past its width
+    base = np.array([0, 1, 2, 1]) + (nblk * 2 if ring else 0)
+    pos = jnp.asarray(base * bs + off, jnp.int32)
+    tables = rng.permutation(np.arange(1, N)).reshape(B, nblk)
+    if trash_rows:
+        tables[[1, 3]] = 0
+    tables = jnp.asarray(tables.astype(np.int32))
+    kv = jnp.asarray(rng.normal(size=(B, H, 1, D)), jnp.float32)
+    logical = jnp.asarray(rng.normal(size=(N, H, bs, D)), jnp.float32)
+    scale = None
+    if kv_dtype == "int8":
+        logical, scale = pa.quantize_kv(logical)
+        scale = pa.scales_to_stored(scale, D)
+    pool = pa.to_stored(logical.astype(_np_pool_dtype(kv_dtype)))
+
+    before = _dispatch.resolved_counts()
+    want = _write(pool, kv, tables, pos, scale, ring)       # cpu: composite
+    assert _grew(before, "paged_kv_append", "xla", "backend") == 1
+    monkeypatch.setattr(_dispatch, "auto_impl", lambda: "interpret")
+    got = _write(pool, kv, tables, pos, scale, ring)
+    assert _grew(before, "paged_kv_append", "interpret", "backend") == 1
+    # the trash block takes whichever free row wrote last: nobody reads it
+    for key in want:
+        assert got[key].shape == want[key].shape
+        assert got[key].dtype == want[key].dtype
+        assert np.array_equal(np.asarray(got[key][1:], np.float32),
+                              np.asarray(want[key][1:], np.float32)), key
+    assert np.all(np.isfinite(np.asarray(got["Out"][0], np.float32)))
+    # and the write landed: the live rows' vectors read back from the
+    # logical view where the table says
+    out = np.asarray(pa.to_logical(got["Out"], H, D), np.float32)
+    col = (base % nblk) if ring else base
+    for b in range(B):
+        blk = int(tables[b, col[b]])
+        if blk == 0:
+            continue
+        vec = np.asarray(kv[b, :, 0], np.float32)
+        if kv_dtype == "int8":
+            sc = np.asarray(pa.scales_to_logical(got["OutScale"], H))
+            vec_q, vec_s = pa.quantize_kv(kv[b, :, 0])
+            assert np.array_equal(out[blk, :, off], np.asarray(vec_q))
+            assert np.array_equal(sc[blk, :, off], np.asarray(vec_s))
+        else:
+            want_vec = np.asarray(jnp.asarray(vec).astype(
+                _np_pool_dtype(kv_dtype)), np.float32)
+            assert np.array_equal(out[blk, :, off], want_vec)
+
+
+@pytest.mark.parametrize("f", [1, 2])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_multi_token_write_takes_the_composite_and_says_so(monkeypatch,
+                                                           kv_dtype, f):
+    """Chunked prefill and the verify span (S > 1, a Limit) stay on the
+    scatter even where the kernel would run, counted as ``multi_token``,
+    and land where the one-token writes would."""
+    s = _SHAPES[f]
+    H, bs, D = s["H"], s["bs"], s["D"]
+    monkeypatch.setattr(_dispatch, "auto_impl", lambda: "interpret")
+    B, nblk, S = 2, 3, 5
+    N = B * nblk + 1
+    rng = np.random.default_rng(5)
+    tables = jnp.asarray(rng.permutation(np.arange(1, N)).reshape(B, nblk),
+                         jnp.int32)
+    pos = jnp.asarray([bs - 2, 3], jnp.int32)      # row 0 crosses a block
+    kv = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
+    shape = pa.stored_shape(N, H, bs, D)
+    pool = jnp.zeros(shape, _np_pool_dtype(kv_dtype))
+    scale = jnp.ones((N, f, shape[1]), jnp.float32) \
+        if kv_dtype == "int8" else None
+    before = _dispatch.resolved_counts()
+    limit = jnp.asarray([S, 3], jnp.int32)
+    got = _write(pool, kv, tables, pos, scale, limit=limit)
+    assert _grew(before, "paged_kv_append", "xla", "multi_token") == 1
+    one, one_scale = pool, scale
+    for b, n in ((0, S), (1, 3)):
+        for i in range(n):
+            step = _write(one, kv[b:b + 1, :, i:i + 1], tables[b:b + 1],
+                          pos[b:b + 1] + i, one_scale)
+            one, one_scale = step["Out"], step.get("OutScale")
+    assert np.array_equal(np.asarray(got["Out"][1:], np.float32),
+                          np.asarray(one[1:], np.float32))
+    if scale is not None:
+        assert np.array_equal(np.asarray(got["OutScale"][1:]),
+                              np.asarray(one_scale[1:]))
+
+
+def _filled_pool(kv_dtype, d_head, name, prefix_cache=False):
+    pool = KVBlockPool(slots=3, num_layers=2, num_heads=2, d_head=d_head,
+                       max_seq_len=64, block_size=16, dtype=kv_dtype,
+                       name=name, prefix_cache=prefix_cache)
+    return pool
+
+
+def _rows(rng, n, d_head, length=64):
+    return {f"cache_{k}_{i}": jnp.asarray(
+        rng.normal(size=(n, 2, length, d_head)), jnp.float32)
+        for i in range(2) for k in ("k", "v")}
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_scatter_prefill_fills_the_stored_blocks(kv_dtype, d_head):
+    """A prefill's dense rows land block by block in the stored arrays:
+    the logical view of each table entry is that slice of the row
+    (quantized for int8), and the device arrays keep the stored shape."""
+    pool = _filled_pool(kv_dtype, d_head, f"st_scatter_{kv_dtype}_{d_head}")
+    rng = np.random.default_rng(11)
+    rows = _rows(rng, 2, d_head)
+    lengths = [40, 16]
+    for slot, n in enumerate(lengths):
+        pool.alloc(slot, n)
+    pool.scatter_prefill([0, 1], rows, 48)
+    f = pa.pool_packing(d_head, 16)
+    assert pool.arrays()["cache_pk_0"].shape == (
+        pool.num_blocks, 2 * 16 // f, f * d_head)
+    assert pool.relayouts().keys() == {"scatter"}
+    for name in ("cache_pk_0", "cache_pv_1"):
+        got = pool.logical(name)
+        src = np.asarray(rows[name.replace("_p", "_")])
+        for slot, n in enumerate(lengths):
+            for blk in range(-(-n // 16)):
+                want = src[slot, :, blk * 16:(blk + 1) * 16]
+                have = got[pool.tables[slot, blk]]
+                if kv_dtype == "int8":
+                    q, sc = pa.quantize_kv(jnp.asarray(want))
+                    assert np.array_equal(have, np.asarray(q))
+                    # the jitted scatter's division differs by an ulp
+                    np.testing.assert_allclose(
+                        pool.logical(name.replace("_pk_", "_pks_").replace(
+                            "_pv_", "_pvs_"))[pool.tables[slot, blk]],
+                        np.asarray(sc), rtol=1e-6)
+                else:
+                    assert np.array_equal(
+                        np.asarray(have, np.float32),
+                        np.asarray(jnp.asarray(want).astype(
+                            _np_pool_dtype(kv_dtype)), np.float32))
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_copy_on_write_duplicates_whole_stored_blocks(kv_dtype, d_head):
+    """``_copy_blocks`` indexes the block dimension alone: the copies
+    are the sources bit for bit, scales included, and nothing else
+    moved."""
+    pool = _filled_pool(kv_dtype, d_head, f"st_cow_{kv_dtype}_{d_head}")
+    rng = np.random.default_rng(13)
+    pool.alloc(0, 48)
+    pool.scatter_prefill([0], _rows(rng, 1, d_head), 48)
+    before = {n: np.asarray(a, np.float32)
+              for n, a in pool.arrays().items()}
+    src = [int(b) for b in pool.tables[0, :2]]
+    dst = [b for b in range(1, pool.num_blocks) if b not in
+           set(int(x) for x in pool.tables[0, :3])][:2]
+    pool._copy_blocks(src, dst)
+    assert pool.relayouts().keys() == {"scatter", "copy_blocks"}
+    for name, a in pool.arrays().items():
+        a = np.asarray(a, np.float32)
+        assert a.shape == before[name].shape
+        assert np.array_equal(a[dst], before[name][src]), name
+        keep = [b for b in range(pool.num_blocks) if b not in dst]
+        assert np.array_equal(a[keep], before[name][keep]), name
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_export_import_round_trip_keeps_the_wire_payload(kv_dtype, d_head):
+    """The payload stays the logical ``[nblocks, H, block_size, D]``
+    (scales ``[nblocks, H, block_size]``) whatever the pool stores: an
+    exported slot imported elsewhere exports the same bytes again, and
+    reads back from the second pool's stored arrays."""
+    rng = np.random.default_rng(17)
+    src = _filled_pool(kv_dtype, d_head, f"st_exp_{kv_dtype}_{d_head}")
+    dst = _filled_pool(kv_dtype, d_head, f"st_imp_{kv_dtype}_{d_head}")
+    src.alloc(1, 40)
+    src.scatter_prefill([1], _rows(rng, 1, d_head), 48)
+    payload = src.export_slot(1)
+    assert payload["nblocks"] == 3 and payload["d_head"] == d_head
+    wire_dt = {"fp32": np.float32, "bf16": np.uint16,
+               "int8": np.int8}[kv_dtype]
+    for i in range(2):
+        for kind in "kv":
+            assert payload[f"{kind}_{i}"].shape == (3, 2, 16, d_head)
+            assert payload[f"{kind}_{i}"].dtype == wire_dt
+            if kv_dtype == "int8":
+                assert payload[f"{kind}s_{i}"].shape == (3, 2, 16)
+    dst.alloc(0, 5)                 # the importer's blocks differ
+    assert dst.import_slot(2, payload) == 3
+    assert dst.relayouts().keys() == {"import"}
+    again = dst.export_slot(2)
+    assert again.keys() == payload.keys()
+    for key, value in payload.items():
+        if isinstance(value, np.ndarray):
+            assert np.array_equal(again[key], value), key
+        else:
+            assert again[key] == value, key
+    got = dst.logical("cache_pk_1", dst.tables[2, :3])
+    want = payload["k_1"]
+    assert np.array_equal(got.view(np.uint16) if kv_dtype == "bf16"
+                          else got, want)
+
+
+def test_relayout_count_reads_pool_sized_copies_alone():
+    hlo = """
+  %copy.68 = bf16[2049,16,16,64]{3,1,2,0:T(8,128)(2,1)} copy(%pk.1), sharding={replicated}
+  %copy.70 = bf16[2049,128,128]{2,1,0:T(8,128)(2,1)} copy(%fusion.2), metadata={op_name="jit(layer)/scatter"}
+  %copy.66 = s32[32,64]{1,0:T(8,128)S(1)} copy(%broadcast_bitcast_fusion)
+  %copy.9 = f32[2049,2,128]{2,1,0:T(2,128)} copy(%scales)
+  %fusion.2 = bf16[2049,128,128]{2,1,0:T(8,128)(2,1)} fusion(%copy.68), kind=kLoop
+  %copy-start.1 = (bf16[2049,128,128]{2,1,0}, u32[]) copy-start(%x)
+"""
+    arrays = {"cache_pk_0": np.zeros((2049, 128, 128), np.int8),
+              "token": np.zeros((2049 * 128 * 128,), np.int8)}
+    assert pool_element_counts(arrays) == {2049 * 128 * 128}
+    # both shapes of the one array count (a copy may relay to either)
+    assert count_pool_relayouts(hlo, pool_element_counts(arrays)) == 2
+    arrays["cache_pks_0"] = np.zeros((2049, 2, 128), np.float32)
+    assert count_pool_relayouts(hlo, pool_element_counts(arrays)) == 3
+    assert count_pool_relayouts(hlo, set()) == 0
+
+
+def test_generator_counts_relayouts_by_program_kind():
+    """A paged decode step's fresh compile is scanned once and the count
+    stands under its program kind; programs that take no pool array are
+    not listed. (The CPU's executable copies as it pleases: the count is
+    read, not its value; 0 on the chip is ``chip_smoke.py``'s assert.)"""
+    cfg = gpt.GPTConfig.tiny()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.gpt_logits(cfg)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+    gen = GPTGenerator(cfg, scope, max_len=32)
+    out = gen.generate(np.array([[1, 2, 3]], np.int32), max_new_tokens=3,
+                       paged=True, kv_dtype="bf16")
+    assert np.asarray(out).shape[-1] >= 3
+    assert list(gen.pool_relayouts) == ["decode_paged_bf16"]
+    assert isinstance(gen.pool_relayouts["decode_paged_bf16"], int)
+    pool, = gen._paged_pools.values()
+    assert pool.stats()["relayouts"].keys() == {"scatter"}
+
+
+def test_donated_caches_come_back_under_the_names_they_went_in_by():
+    """JAX pairs a donated argument with a result by shape and order, and
+    a dict of caches flattens in sorted order (``_10`` before ``_2``):
+    the generator hands the caches back as a dict too, so array i is
+    updated in array i's buffer at any depth."""
+    names = [f"cache_p{k}_{i}" for k in "kv" for i in range(12)]
+    outs = {"cache_vars": [object()] * 24, "cache_names": names}
+    feeds = ["token", "pos", "block_tables"] + names
+    fetch = ["logits"] + [f"v{i}" for i in range(24)] + ["aux"]
+    place = GPTGenerator._cache_places(outs, feeds, fetch)
+    assert place == {n: 1 + i for i, n in enumerate(names)}
+    # the dense bank: all k then all v, as _fetch_names lists them
+    outs = {"cache_k": [0] * 3, "cache_v": [0] * 3}
+    dense = [f"cache_{k}_{i}" for k in "kv" for i in range(3)]
+    place = GPTGenerator._cache_places(outs, ["token", "pos"] + dense,
+                                       ["logits"] + dense)
+    assert place == {n: 1 + i for i, n in enumerate(dense)}
+    # a prefill is fed no cache: nothing to pair
+    assert GPTGenerator._cache_places(
+        outs, ["tokens", "pos_ids", "last_pos"], ["logits"] + dense) == {}

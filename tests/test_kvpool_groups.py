@@ -50,10 +50,13 @@ def test_a_window_slot_never_holds_more_than_its_ring_and_recycles():
 
 def test_arrays_are_sized_by_their_layer_group():
     pool = _pool()
-    shapes = {n: a.shape for n, a in pool.arrays().items()}
+    shapes = {n: pool.logical(n).shape for n in pool.arrays()}
     assert shapes["cache_pk_3"] == (4 * 32 + 1, 2, 4, 8)
     for i in (0, 1, 2):
         assert shapes[f"cache_pv_{i}"] == (4 * 5 + 1, 2, 4, 8)
+    # stored: [N, H * bs // f, f * D]; D = 8 would pack 16 slots a lane
+    # row, which a block of 4 does not hold, so a row a slot
+    assert pool.arrays()["cache_pk_3"].shape == (4 * 32 + 1, 8, 8)
     assert pool.capacity_blocks == 4 * 32 + 4 * 5
     st = pool.stats()
     assert st["bytes_capacity"] == (128 * 1 + 20 * 3) * 2 * 2 * 4 * 8 * 2
@@ -120,21 +123,20 @@ def test_scatter_keeps_the_windows_last_blocks():
     for slot, n in enumerate(lengths):
         pool.alloc(slot, n)
     pool.scatter_prefill([0, 1], rows, 32, lengths=lengths)
-    arrays = pool.arrays()
+    arrays = {n: pool.logical(n).astype(np.float32)
+              for n in ("cache_pk_3", "cache_pv_1")}
     for slot, n in enumerate(lengths):
         last = (n - 1) // 4
         for blk in range(last + 1):
             src = np.asarray(rows["cache_k_3"][slot, :, blk * 4:blk * 4 + 4]
                              .astype(jnp.float32))
-            got = np.asarray(arrays["cache_pk_3"][pool.tables[slot, blk]]
-                             .astype(jnp.float32))
+            got = arrays["cache_pk_3"][pool.tables[slot, blk]]
             np.testing.assert_array_equal(got, src)
             if blk > last - pool.window.ring:
                 src = np.asarray(rows["cache_v_1"][
                     slot, :, blk * 4:blk * 4 + 4].astype(jnp.float32))
-                got = np.asarray(arrays["cache_pv_1"][
+                got = arrays["cache_pv_1"][
                     pool.window.tables[slot, blk % pool.window.ring]]
-                    .astype(jnp.float32))
                 np.testing.assert_array_equal(got, src)
     with pytest.raises(ValueError, match="lengths"):
         pool.scatter_prefill([0, 1], rows, 32)

@@ -1,4 +1,4 @@
-"""Both Pallas kernels must lower for the TPU platform, checked on the CPU.
+"""The Pallas kernels must lower for the TPU platform, checked on the CPU.
 
 ``jax.jit(f).trace(*specs).lower(lowering_platforms=("tpu",))`` runs the
 Pallas-to-Mosaic lowering with no TPU and no libtpu: block-shape rules,
@@ -19,8 +19,11 @@ import pytest
 
 from paddle_tpu.kernels import _dispatch
 from paddle_tpu.kernels.flash_attention import flash_attention
-from paddle_tpu.kernels.paged_attention import paged_attention, quantize_kv
-from paddle_tpu.serving.kvpool import _DTYPES, _np_pool_dtype
+from paddle_tpu.kernels.paged_attention import (paged_attention,
+                                                paged_kv_append,
+                                                quantize_kv, stored_shape)
+from paddle_tpu.serving.kvpool import (_DTYPES, _np_pool_dtype,
+                                       count_pool_relayouts)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -129,13 +132,18 @@ def _kernel_names(fn, specs):
 
 @pytest.mark.parametrize("name", [
     "flash_attention_fwd", "flash_attention_bwd", "flash_attention_bwd_dq",
-    "flash_attention_bwd_dkv", "paged_attention_decode"])
+    "flash_attention_bwd_dkv", "paged_attention_decode", "paged_kv_append"])
 def test_each_kernel_lowers_under_its_own_name(name):
     """``pl.pallas_call(name=...)`` becomes ``kernel_name`` in the
     ``tpu_custom_call`` config, so a device trace can tell the flash
-    forward from its backward calls and both from the paged kernel."""
+    forward from its backward calls, both from the paged kernel, and the
+    pool's writer from its reader."""
     if name == "paged_attention_decode":
         found = _kernel_names(_paged_fn, _paged_specs("bf16", 16))
+    elif name == "paged_kv_append":
+        found = _kernel_names(_decode_layer(), _decode_layer_specs(
+            8, H, H, D, 128, 1025))
+        assert found == {"paged_attention_decode", "paged_kv_append"}
     else:
         # the short bucket takes the fused backward, the training length
         # the dq and dkv pair
@@ -145,6 +153,93 @@ def test_each_kernel_lowers_under_its_own_name(name):
         found = _kernel_names(fn, _flash_specs(seq, False, jnp.bfloat16))
     assert name in found
     assert not found & {"kern", "dq_kern", "dkv_kern"}
+
+
+def _decode_layer(window=None, kv_heads=None):
+    """What one layer of a paged decode step does to the pool: both
+    appends, then the read, over the STORED arrays."""
+    def layer(pk, pv, q, k, v, tables, pos):
+        bs = pk.shape[1] * pk.shape[2] // (k.shape[1] * k.shape[2])
+        col = pos // bs
+        if window is not None:
+            col = col % tables.shape[1]
+        ids, offs = tables[jnp.arange(tables.shape[0]), col], pos % bs
+        pk = paged_kv_append(pk, k.astype(pk.dtype), ids, offs)
+        pv = paged_kv_append(pv, v.astype(pv.dtype), ids, offs)
+        return paged_attention(q, pk, pv, tables, pos, impl="pallas",
+                               window=window, kv_heads=kv_heads), pk, pv
+    return layer
+
+
+def _decode_layer_specs(rows, hq, hkv, d, nblk, blocks, sharding=None,
+                        block=16):
+    pool = _spec(stored_shape(blocks, hkv, block, d), jnp.bfloat16, sharding)
+    new = _spec((rows, hkv, d), jnp.float32, sharding)
+    return [pool, pool, _spec((rows, hq, 1, d), jnp.float32, sharding), new,
+            new, _spec((rows, nblk), jnp.int32, sharding),
+            _spec((rows,), jnp.int32, sharding)]
+
+
+# (rows, query heads, KV heads, head width, table width, blocks, window)
+_SERVE_LAYERS = {
+    "gpt2-medium": (32, 16, 16, 64, 64, 2049, None),
+    "mellum_full": (32, 32, 4, 128, 512, 16385, None),
+    "mellum_window": (32, 32, 4, 128, 65, 2081, 1024)}
+
+
+# test_benchmark.py reads a process-wide counter, and pytest-xdist hands
+# out files largest first: this file stays smaller than it (30 cases), so
+# it never runs ahead of it on the same worker
+@pytest.mark.parametrize("kv_dtype,d_head", [
+    ("fp32", 64), ("bf16", 64), ("int8", 64), ("bf16", 128)])
+def test_paged_kv_append_lowers_for_tpu(kv_dtype, d_head):
+    """The pool's one-token writer in every pool type packed two slots a
+    lane row (D = 64), and a row a slot (D = 128, Mellum's bf16), under
+    its own name and with no table among its operands."""
+    import re
+    rows, heads, block, blocks = 8, 12, 16, 65
+    shape = stored_shape(blocks, heads, block, d_head)
+    dt = _np_pool_dtype(kv_dtype)
+    specs = [_spec(shape, dt), _spec((rows, heads, d_head), dt),
+             _spec((rows,), jnp.int32), _spec((rows,), jnp.int32)]
+    if kv_dtype == "int8":
+        specs += [_spec((blocks, shape[2] // d_head, shape[1]), jnp.float32),
+                  _spec((rows, heads), jnp.float32)]
+    lowered = jax.jit(paged_kv_append).trace(*specs).lower(
+        lowering_platforms=("tpu",))
+    assert re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()) == [
+        "paged_kv_append"]
+    call, = [line for line in lowered.compiler_ir(
+        dialect="hlo").as_hlo_text().splitlines() if "custom-call(" in line
+        and 'custom_call_target="tpu_custom_call"' in line]
+    assert not re.search(r"s32\[\d+,\d+\]", call)
+
+
+def test_decode_layer_keeps_one_call_with_the_serve_cells_table():
+    """``paged_attention_roofline.serve`` finds the decode kernel by
+    ``tpu_custom_call`` and the ``s32[32,64]`` table among its operands:
+    in a whole decode layer at gpt2-medium's shapes exactly one call has
+    it, and it is not an append."""
+    import re
+    rows, hq, hkv, d, nblk, blocks, window = _SERVE_LAYERS["gpt2-medium"]
+    lowered = jax.jit(_decode_layer(window, hkv)).trace(
+        *_decode_layer_specs(rows, hq, hkv, d, nblk, blocks)).lower(
+        lowering_platforms=("tpu",))
+    # the kernels are jitted, so the module holds each body once and the
+    # layer calls the append's twice (XLA inlines them; the compile-only
+    # v5e test counts the inlined calls)
+    text = lowered.as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) == [
+        "paged_attention_decode", "paged_kv_append"]
+    assert text.count("call @paged_kv_append(") == 2
+    calls = [line for line in lowered.compiler_ir(
+        dialect="hlo").as_hlo_text().splitlines() if "custom-call(" in line
+        and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    with_table = [c for c in calls if "s32[32,64]" in c]
+    assert len(with_table) == 1
+    # the decode call reads the appends' results: two stored pools
+    assert with_table[0].count("bf16[2049,128,128]") >= 2
 
 
 def test_composite_fallbacks_are_counted(monkeypatch):
@@ -244,3 +339,15 @@ def test_kernels_compile_for_a_compile_only_v5e():
         for block in (16, 128):
             jax.jit(_paged_fn).lower(
                 *_paged_specs(kv_dtype, block, sharding=on)).compile()
+    # one decode layer at each serve configuration's pool shapes, the
+    # pool donated: the stored layout is the runtime's, the append's and
+    # the reader's, so XLA:TPU leaves no copy of a whole pool array
+    for label, (rows, hq, hkv, d, nblk, blocks, window) in \
+            _SERVE_LAYERS.items():
+        specs = _decode_layer_specs(rows, hq, hkv, d, nblk, blocks, on)
+        text = jax.jit(_decode_layer(window, hkv), donate_argnums=(0, 1)) \
+            .lower(*specs).compile().as_text()
+        assert text.count("paged_kv_append") >= 2, label
+        assert count_pool_relayouts(
+            text, {int(np.prod(specs[0].shape))}) == 0, label
+        assert "bf16[%d,%d,%d]{2,1,0" % specs[0].shape in text, label
