@@ -764,8 +764,7 @@ def _bench_serving_tp(tp=2):
         for width in (1, tp):
             gen = GPTGenerator(cfg, scope, max_len=96, bucket_min=8,
                                tp=width)
-            out = gen.generate(prompt, max_new_tokens=new_tokens,
-                               paged=True)
+            out = gen.generate(prompt, max_new_tokens=new_tokens)
             if ref is None:
                 ref = out[0]
             elif not np.array_equal(out[0], ref):
@@ -773,7 +772,7 @@ def _bench_serving_tp(tp=2):
                     "tp greedy decode diverged from single-chip")
             t0 = time.perf_counter()
             for _ in range(reps):
-                gen.generate(prompt, max_new_tokens=new_tokens, paged=True)
+                gen.generate(prompt, max_new_tokens=new_tokens)
             dt = (time.perf_counter() - t0) / reps
             rows[str(width)] = {
                 "tokens_per_sec": round(new_tokens / dt, 2),
@@ -823,8 +822,7 @@ def _bench_prefix_prefill():
     warm_prompt = rng.integers(1, cfg.vocab_size,
                                prompt_len).astype(np.int32)
     prompt = rng.integers(1, cfg.vocab_size, prompt_len).astype(np.int32)
-    engine = GenerationEngine(gen, slots=2, paged=True,
-                              prefix_cache=True,
+    engine = GenerationEngine(gen, slots=2, prefix_cache=True,
                               pool_name="bench_prefix")
 
     def prefill_once(slot, p):
@@ -1658,30 +1656,32 @@ def bench_decode():
             "first_call_ms": round(compile_plus_first_ms, 1),
         }
 
-    # paged + quantized rows (block-paged KV pool, serving/kvpool +
+    # one row for each KV pool dtype (serving/kvpool +
     # kernels/paged_attention) at the longest prompt: fp32 is the
-    # bitwise greedy-parity row, bf16/int8 the bandwidth-multiplier
-    # rows (cache bytes per token is the decode roofline)
+    # bitwise greedy-parity row against full recompute, bf16/int8 the
+    # bandwidth-multiplier rows (cache bytes per token is the decode
+    # roofline); "vs_dense" in the keys is the records' old name for
+    # that reference
     seq = max(seqs)
     prompt = [rng.integers(1, cfg.vocab_size, seq).astype(np.int32)]
-    dense_out = gen.generate(prompt, max_new_tokens=new_tokens)
+    ref_out = gen.generate_naive(prompt, max_new_tokens=new_tokens)
     paged = {}
     for kv_dtype in ("fp32", "bf16", "int8"):
         t0 = time.perf_counter()
         out = gen.generate(prompt, max_new_tokens=new_tokens,
-                           paged=True, kv_dtype=kv_dtype)
+                           kv_dtype=kv_dtype)
         first_ms = (time.perf_counter() - t0) * 1e3
-        n = min(len(out[0]), len(dense_out[0]))
+        n = min(len(out[0]), len(ref_out[0]))
         match = float(np.mean(np.asarray(out[0][:n])
-                              == np.asarray(dense_out[0][:n]))) \
+                              == np.asarray(ref_out[0][:n]))) \
             if n else 1.0
         if kv_dtype == "fp32":
-            assert np.array_equal(out[0], dense_out[0]), \
-                "paged fp32 greedy decode diverged from the dense bank"
+            assert np.array_equal(out[0], ref_out[0]), \
+                "paged fp32 greedy decode diverged from full recompute"
         reps = 2
         t0 = time.perf_counter()
         for _ in range(reps):
-            gen.generate(prompt, max_new_tokens=new_tokens, paged=True,
+            gen.generate(prompt, max_new_tokens=new_tokens,
                          kv_dtype=kv_dtype)
         dt_p = (time.perf_counter() - t0) / reps
         paged[kv_dtype] = {
@@ -1718,7 +1718,7 @@ def bench_decode():
         spec_base = None
         for k in (0, 2, 4, 8):
             out = gen.generate(spec_prompt, max_new_tokens=spec_new,
-                               paged=True, spec_k=k)
+                               spec_k=k)
             if spec_base is None:
                 spec_base = out
             else:
@@ -1731,7 +1731,7 @@ def bench_decode():
             for _ in range(3):          # best-of: shields the 2x gate
                 t0 = time.perf_counter()   # from scheduler noise
                 gen.generate(spec_prompt, max_new_tokens=spec_new,
-                             paged=True, spec_k=k)
+                             spec_k=k)
                 dts.append(time.perf_counter() - t0)
             dt_s = min(dts)
             drafted = spec_stats.counter("spec_drafted") - c0[0]
@@ -2003,7 +2003,7 @@ def bench_fleet():
     def mksrv(name):
         gen = GPTGenerator(cfg, scope, max_len=max_len, bucket_min=8)
         return serving.InferenceServer(
-            generator=gen, decode_slots=slots, kv_paged=True,
+            generator=gen, decode_slots=slots,
             kv_pool_name=name).start()
 
     def warm(reps):
@@ -2297,7 +2297,7 @@ def bench_overload():
     for i in range(3):
         gen = GPTGenerator(cfg, scope, max_len=24, bucket_min=8)
         srv = serving.InferenceServer(
-            generator=gen, decode_slots=slots, kv_paged=True,
+            generator=gen, decode_slots=slots,
             kv_pool_name=f"ovl{i}", queue_depth=4).start()
         srv.brownout.batch_token_cap = 4
         # sticky recovery: once the overload window breaches, the

@@ -16,7 +16,7 @@ seeded random weights:
            at the shapes of the benchmark's serve cell
   train    gpt_pretrain at 8 x 2,048 + Adam + bf16 AMP through
            Executor.run and Executor.run_steps
-  serve    GPTGenerator -> InferenceServer(kv_paged=True) -> six
+  serve    GPTGenerator -> InferenceServer -> six
            concurrent Client.generate calls over the loopback socket,
            no pool-sized copy in the decode step's or the scatter's
            optimised HLO, then one logits check of paged decode against
@@ -642,7 +642,7 @@ def phase_serve(smoke):
     # the loop watchdog "must exceed the worst-case first-shape compile"
     # (flags.py): a cold GPT-base prefill or decode compile is not a hung
     # chip call, and nothing here relies on a warmup having run
-    server = InferenceServer(generator=gen, kv_paged=True, decode_slots=8,
+    server = InferenceServer(generator=gen, decode_slots=8,
                              loop_watchdog_s=600.0).start()
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
@@ -800,8 +800,7 @@ def phase_mesh(smoke):
         prompt = rng.integers(1, cfg.vocab_size,
                               sz.check_prompt).astype(np.int32)
         t0 = time.perf_counter()
-        toks, = gen.generate([prompt], max_new_tokens=sz.new_tokens,
-                             paged=True)
+        toks, = gen.generate([prompt], max_new_tokens=sz.new_tokens)
         out["smoke_timings_s"]["tp2_generate_incl_compile"] = round(
             time.perf_counter() - t0, 2)
         assert toks.shape == (sz.new_tokens,), toks.shape

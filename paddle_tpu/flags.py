@@ -206,13 +206,10 @@ _DEFS = {
     # snapshot)
     "decode_spec_mode": ("ngram", str, None),
     # -- paged KV cache (serving/kvpool, kernels/paged_attention) --
-    # opt-in block-paged decode memory: KV caches live in a shared
-    # block pool with per-slot block tables (vLLM/PagedAttention)
-    # instead of the dense [slots, H, max_len, D] bank; blocks allocate
-    # on append and free on EOS/deadline/cancel, so concurrency is
-    # bounded by actual tokens. 0 keeps the dense bank (the parity
-    # baseline)
-    "kv_paged": (False, bool, None),
+    # decode memory is block-paged: KV caches live in a shared block
+    # pool with per-slot block tables (vLLM/PagedAttention); blocks
+    # allocate on append and free on EOS/deadline/cancel, so
+    # concurrency is bounded by actual tokens.
     # KV-cache element type: fp32 (bitwise baseline), bf16 (half the
     # cache bytes), int8 (quarter, with per-(block, head, slot) float32
     # scales) — at bandwidth-bound decode, cache bytes ARE tokens/s

@@ -174,8 +174,8 @@ def _first_block(pos, window, bs):
 def _xla_paged_attention(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
                          scale, window=None):
     """Gather-then-attend composite: per-row ``jnp.take`` of the row's
-    blocks, per-row position mask, fp32 softmax — identical math to
-    ``ops.decode_ops.kv_cached_attention`` over the gathered layout.
+    blocks, per-row position mask (key slot j visible to query i iff
+    j <= pos[b] + i), fp32 softmax.
     Runs anywhere (CPU CI) and is the kernel's parity oracle. Grouped
     queries fold against the KV heads they share; with a ``window`` the
     table is a ring (logical block ``b`` at column ``b % width``) and a

@@ -784,24 +784,6 @@ def kv_cache_write(cache, kv, pos, name=None):
     return out
 
 
-def kv_cached_attention(q, k_cache, v_cache, pos, scale=0.0, name=None):
-    """Causal attention of fresh queries ``q`` [B, H, S, D] over KV
-    caches [B, H, max_len, D], masked by per-row position counters
-    ``pos`` [B] int32 (key slot j visible to query i iff
-    j <= pos[b] + i). Rows at different positions share one executable —
-    the decode-batch fast path of autoregressive generation."""
-    helper = LayerHelper("kv_cached_attention", name=name)
-    out = helper.create_variable_for_type_inference(dtype=q.dtype)
-    helper.append_op(
-        type="kv_cached_attention",
-        inputs={"Q": [q], "K": [k_cache], "V": [v_cache], "Pos": [pos]},
-        outputs={"Out": [out]}, attrs={"scale": float(scale)},
-        infer_shape=False)
-    out.shape = tuple(q.shape or ())
-    out.dtype = q.dtype
-    return out
-
-
 def paged_kv_cache_write(cache, kv, tables, pos, scale=None, limit=None,
                          name=None, ring=False):
     """Append S new ``kv`` vectors [B, H, S, D] into the block-paged
@@ -847,8 +829,8 @@ def paged_attention(q, k_cache, v_cache, tables, pos, k_scale=None,
     (logically [num_blocks, Hkv, block_size, D]; fed in the stored shape
     of ``kernels/paged_attention``, int8 pools with their stored
     scales), gathered through the per-row block ``tables`` and masked by
-    per-row ``pos`` counters — the paged analogue of
-    :func:`kv_cached_attention`. Fused Pallas gather+attend on TPU for
+    per-row ``pos`` counters (key slot j visible to query i iff
+    j <= pos[b] + i). Fused Pallas gather+attend on TPU for
     S=1; ``jnp.take`` reference elsewhere and for S>1. The pools may
     have fewer heads than ``q`` (grouped queries: ``kv_heads`` says how
     many, H where it is left out); ``window`` makes ``tables`` a ring

@@ -1,11 +1,12 @@
 """Autoregressive generation driver: the prefill/decode split over the
-KV-cached GPT graphs (models/gpt.py gpt_prefill / gpt_decode_step).
+KV-cached GPT graphs (models/gpt.py gpt_prefill / gpt_decode_step_paged).
 
 Naive generation re-runs the full forward for every new token — N tokens
 cost N O(S^2) recomputes. ``GPTGenerator.generate`` instead runs ONE
-bucketed prefill over the prompt (building every layer's
-``[B, H, max_len, D]`` KV cache), then loops a single compiled decode
-step whose per-token cost is a cache append + read. All executables are
+bucketed prefill over the prompt, scatters every layer's fresh keys and
+values into a block-paged pool (``serving/kvpool``), then loops a single
+compiled decode step whose per-token cost is a cache append + read. All
+executables are
 AOT-compiled (``jit.lower().compile()``) into a serving
 ``ExecutableCache`` — length-bucketed prefill shapes stay bounded
 (power-of-two buckets and, for long prompts, their midpoints;
@@ -50,8 +51,8 @@ class TPCompileGateError(RuntimeError):
 
 class UnsupportedPathError(NotImplementedError):
     """A serving path that is not built for the architecture being
-    served (``path`` names it: speculative verify, chunked prefill, the
-    dense KV bank, ``tp > 1``, a KV pool dtype). Raised when the path is
+    served (``path`` names it: speculative verify, chunked prefill,
+    ``tp > 1``, a KV pool dtype). Raised when the path is
     asked for, before any compile; the architecture's serving object
     (``cfg.serving()``) decides what it has."""
 
@@ -293,7 +294,7 @@ class GPTGenerator:
         self._unpack = {}   # kind -> its results back in fetch order
         self._params = {}   # param name -> device array, shared by kinds
         # (bucket_rows, kv_dtype, block_size) -> KVBlockPool reused
-        # across generate(paged=True) calls: keeps the pool's jitted
+        # across generate() calls: keeps the pool's jitted
         # prefill-scatter closure and device arrays warm instead of
         # recompiling/reallocating per call (blocks are still freed on
         # the way out of every call)
@@ -462,13 +463,8 @@ class GPTGenerator:
     def _cache_places(outs, feed_names, fetch_names):
         """``{feed name: position in the fetch list}`` of every cache
         the ``outs`` program is fed and hands back updated: the pool
-        arrays of a paged program, the dense bank's slabs of the others
-        (a prefill is fed none)."""
-        if "cache_vars" in outs:
-            names = list(outs["cache_names"])
-        else:
-            names = [f"cache_{kind}_{i}" for kind in "kv"
-                     for i in range(len(outs.get(f"cache_{kind}", ())))]
+        arrays of a paged program (a prefill is fed none)."""
+        names = list(outs.get("cache_names", ()))
         first = 1               # logits lead every cache-bearing fetch
         return {n: first + i for i, n in enumerate(names)
                 if n in feed_names and first + i < len(fetch_names)}
@@ -700,15 +696,6 @@ class GPTGenerator:
             max_seq_len=self.max_len, groups=arch.kv_groups(), **kw)
         return self.apply_pool_sharding(pool)
 
-    def _run_decode(self, token, pos, caches, key, parent=None):
-        feed = dict(caches)
-        feed["token"] = token
-        feed["pos"] = pos
-        fetches, key = self._invoke("decode", "decode", feed, key,
-                                    parent=parent)
-        logits, caches = self._unpack_caches(fetches)
-        return logits, caches, key
-
     def _run_decode_paged(self, token, pos, pool, key):
         """One decode step over the block-paged KV pool: feeds the
         pool's device arrays (donated — XLA appends in place) plus the
@@ -752,18 +739,6 @@ class GPTGenerator:
             pool.drop_device()
             raise
         return adopt_decode_fetches(pool, fetches), key
-
-    def _run_verify(self, tokens, pos, pos_ids, caches, key):
-        """One speculative verify step over the DENSE per-slot caches:
-        score all S = K+1 fed positions in one pass. Same donated-cache
-        discipline as the decode step."""
-        feed = dict(caches)
-        feed["tokens"] = np.asarray(tokens, np.int32)
-        feed["pos"] = np.asarray(pos, np.int32)
-        feed["pos_ids"] = np.asarray(pos_ids, np.int32)
-        fetches, key = self._invoke("verify", "decode", feed, key)
-        logits, caches = self._unpack_caches(fetches)
-        return logits, caches, key
 
     def _run_verify_paged(self, tokens, pos_ids, start_pos, limit, pool,
                           key, rows=None):
@@ -884,22 +859,23 @@ class GPTGenerator:
                 done[r] = True
 
     def generate(self, prompts, max_new_tokens=32, temperature=0.0,
-                 top_k=0, eos_id=None, seed=None, key=None, paged=None,
+                 top_k=0, eos_id=None, seed=None, key=None,
                  kv_dtype=None, spec_k=None, spec_mode=None,
                  drafter=None):
-        """KV-cached generation: one bucketed prefill, then one compiled
-        decode step per token. ``prompts`` is a list of 1-D int token
-        arrays (ragged lengths fine — rows are right-padded to the
-        bucket and tracked by per-row position counters). Returns a list
-        of 1-D int32 arrays of NEW tokens (prompt excluded; generation
-        stops at ``eos_id``, which is not included).
+        """KV-cached generation: one bucketed prefill (compute-bound and
+        flash-fused), a jitted scatter of the fresh row caches into a
+        transient :class:`serving.kvpool.KVBlockPool`, then one compiled
+        paged decode step per token with allocation-on-append; the
+        pool's blocks are freed when generation ends. ``prompts`` is a
+        list of 1-D int token arrays (ragged lengths fine — rows are
+        right-padded to the bucket and tracked by per-row position
+        counters). Returns a list of 1-D int32 arrays of NEW tokens
+        (prompt excluded; generation stops at ``eos_id``, which is not
+        included).
 
-        ``paged`` (None -> ``FLAGS_kv_paged``) routes the decode loop
-        through a transient block-paged KV pool (``serving/kvpool``)
-        instead of the dense ``[B, H, max_len, D]`` bank — same prefill,
-        same sampler, same RNG chain, greedy output token-for-token
-        identical. ``kv_dtype`` (None -> ``FLAGS_kv_cache_dtype``)
-        selects the paged pool's element type (fp32/bf16/int8).
+        ``kv_dtype`` (None -> ``FLAGS_kv_cache_dtype``) selects the
+        pool's element type (fp32/bf16/int8); over an fp32 pool greedy
+        output is token for token ``generate_naive``'s.
 
         ``spec_k`` (None -> ``FLAGS_decode_spec_k``; 0 disables) turns
         on speculative decoding: a drafter proposes up to K tokens per
@@ -911,72 +887,19 @@ class GPTGenerator:
         default drafter ('ngram' prompt-lookup / 'model' shared-weight
         draft GPT); ``drafter`` overrides it with any object exposing
         ``draft(ctx_tokens, k)``."""
-        if paged is None:
-            paged = bool(flag("kv_paged"))
         if spec_k is None:
             spec_k = int(flag("decode_spec_k"))
         if int(spec_k) > 0:
             return self._generate_spec(
                 prompts, max_new_tokens, temperature, top_k, eos_id,
-                seed, key, paged, kv_dtype, int(spec_k), spec_mode,
-                drafter)
-        if paged:
-            return self._generate_paged(
-                prompts, max_new_tokens, temperature, top_k, eos_id,
-                seed, key, kv_dtype)
-        self._ensure_prog("decode")     # refused by name where there is none
-        prompts, lens, key = self._prep(prompts, max_new_tokens, seed,
-                                        key)
-        B = len(prompts)
-        tokens, pos_ids, last = self._pack_prompts(prompts)
-        bb = tokens.shape[0]
-
-        logits, caches, key = self._run_prefill(tokens, pos_ids, last,
-                                                key)
-        temp = np.full((bb,), float(temperature), np.float32)
-        topk = np.full((bb,), int(top_k), np.int32)
-        tok, key = self._run_sample(logits, temp, topk, key)
-        tok_h = np.asarray(tok)
-
-        outs = [[] for _ in range(B)]
-        done = np.zeros(B, bool)
-        # pos[r] = cache slot the NEXT fed token lands in
-        pos = np.zeros((bb,), np.int32)
-        pos[:B] = np.asarray(lens, np.int32)
-        self._emit(tok_h, outs, done, eos_id, max_new_tokens)
-
-        while not done.all():
-            logits, caches, key = self._run_decode(tok, pos, caches, key)
-            tok, key = self._run_sample(logits, temp, topk, key)
-            tok_h = np.asarray(tok)
-            pos[:B] = np.where(done, pos[:B], pos[:B] + 1)
-            self._emit(tok_h, outs, done, eos_id, max_new_tokens)
-            if self.stats:
-                self.stats.bump("decode_steps")
-        if self.stats:
-            self.stats.bump("tokens_generated",
-                            int(sum(len(o) for o in outs)))
-        return [np.asarray(o, np.int32) for o in outs]
-
-    def _generate_paged(self, prompts, max_new_tokens, temperature,
-                        top_k, eos_id, seed, key, kv_dtype=None):
-        """The block-paged decode loop behind ``generate(paged=True)``:
-        one dense bucketed prefill (unchanged — prefill is compute-bound
-        and already flash-fused), a jitted scatter of the fresh row
-        caches into a transient :class:`serving.kvpool.KVBlockPool`,
-        then per-token paged decode steps with allocation-on-append.
-        The pool is freed when generation ends."""
+                seed, key, kv_dtype, int(spec_k), spec_mode, drafter)
         prompts, lens, key = self._prep(prompts, max_new_tokens, seed,
                                         key)
         B = len(prompts)
         tokens, pos_ids, last = self._pack_prompts(prompts)
         bb, s = tokens.shape
         kv_dtype = kv_dtype or flag("kv_cache_dtype")
-        pool_key = (bb, kv_dtype, int(flag("kv_block_size")))
-        pool = self._paged_pools.get(pool_key)
-        if pool is None:
-            pool = self.new_pool(bb, dtype=kv_dtype, name="offline")
-            self._paged_pools[pool_key] = pool
+        pool = self._offline_pool(bb, kv_dtype)
         try:
             for r in range(B):
                 pool.alloc(r, lens[r])
@@ -992,6 +915,7 @@ class GPTGenerator:
 
             outs = [[] for _ in range(B)]
             done = np.zeros(B, bool)
+            # pos[r] = cache slot the NEXT fed token lands in
             pos = np.zeros((bb,), np.int32)
             pos[:B] = np.asarray(lens, np.int32)
             self._emit(tok_h, outs, done, eos_id, max_new_tokens)
@@ -1012,31 +936,41 @@ class GPTGenerator:
                                 int(sum(len(o) for o in outs)))
             return [np.asarray(o, np.int32) for o in outs]
         finally:
-            # free every block and the device arrays, but KEEP the
-            # pool instance (its compiled prefill-scatter closure is
-            # the expensive part — the next call rebuilds zero arrays
-            # without retracing); one cached pool per (bucket, dtype,
-            # block size) must not pin dense-bank-equivalent HBM
-            # between calls
-            for r in range(bb):
-                pool.free_slot(r)
-            pool.drop_device()
+            self._release_offline_pool(pool)
+
+    def _offline_pool(self, rows, kv_dtype):
+        """The transient pool of one ``generate()`` call, one cached per
+        (bucket rows, dtype, block size)."""
+        pool_key = (rows, kv_dtype, int(flag("kv_block_size")))
+        pool = self._paged_pools.get(pool_key)
+        if pool is None:
+            pool = self.new_pool(rows, dtype=kv_dtype, name="offline")
+            self._paged_pools[pool_key] = pool
+        return pool
+
+    @staticmethod
+    def _release_offline_pool(pool):
+        # free every block and the device arrays, but KEEP the pool
+        # instance (its compiled prefill-scatter closure is the
+        # expensive part — the next call rebuilds zero arrays without
+        # retracing); a cached pool must not pin its HBM between calls
+        for r in range(pool.slots):
+            pool.free_slot(r)
+        pool.drop_device()
 
     def _generate_spec(self, prompts, max_new_tokens, temperature,
-                       top_k, eos_id, seed, key, paged, kv_dtype,
-                       spec_k, spec_mode, drafter):
-        """The speculative decode loop behind ``generate(spec_k=K)``,
-        dense and paged: draft up to K tokens per row host-side, verify
-        all K+1 positions in ONE model pass (the whole win — a verify
-        pass costs about one decode step, both bandwidth-bound), keep
-        the accepted prefix plus the correction/bonus token via
-        rejection sampling. Per-row draft counts are capped to the
-        row's remaining budget; the dense path falls back to plain
-        decode steps near the cache end (its fixed-span write cannot
-        be trash-routed the way the paged ``limit`` input can)."""
+                       top_k, eos_id, seed, key, kv_dtype, spec_k,
+                       spec_mode, drafter):
+        """The speculative decode loop behind ``generate(spec_k=K)``:
+        draft up to K tokens per row host-side, verify all K+1
+        positions in ONE model pass (the whole win — a verify pass
+        costs about one decode step, both bandwidth-bound), keep the
+        accepted prefix plus the correction/bonus token via rejection
+        sampling. Per-row draft counts are capped to the row's
+        remaining budget."""
+        kv_dtype = kv_dtype or flag("kv_cache_dtype")
         # an architecture with no verify program refuses here, by name
-        self._ensure_prog("verify_paged_" + (
-            kv_dtype or flag("kv_cache_dtype")) if paged else "verify")
+        self._ensure_prog(f"verify_paged_{kv_dtype}")
         prompts, lens, key = self._prep(prompts, max_new_tokens, seed,
                                         key)
         if drafter is None:
@@ -1045,25 +979,13 @@ class GPTGenerator:
         tokens, pos_ids, last = self._pack_prompts(prompts)
         bb, s = tokens.shape
         cfg = self.cfg
-        pool = None
-        if paged:
-            kv_dtype = kv_dtype or flag("kv_cache_dtype")
-            pool_key = (bb, kv_dtype, int(flag("kv_block_size")))
-            pool = self._paged_pools.get(pool_key)
-            if pool is None:
-                pool = self.new_pool(bb, dtype=kv_dtype, name="offline")
-                self._paged_pools[pool_key] = pool
+        pool = self._offline_pool(bb, kv_dtype)
         try:
-            caches = None
-            if paged:
-                for r in range(B):
-                    pool.alloc(r, lens[r])
-                logits, row_caches, key = self._run_prefill(
-                    tokens, pos_ids, last, key)
-                pool.scatter_prefill(list(range(B)), row_caches, s)
-            else:
-                logits, caches, key = self._run_prefill(
-                    tokens, pos_ids, last, key)
+            for r in range(B):
+                pool.alloc(r, lens[r])
+            logits, row_caches, key = self._run_prefill(
+                tokens, pos_ids, last, key)
+            pool.scatter_prefill(list(range(B)), row_caches, s)
 
             temp = np.full((bb,), float(temperature), np.float32)
             topk = np.full((bb,), int(top_k), np.int32)
@@ -1094,39 +1016,19 @@ class GPTGenerator:
                                    np.int32).ravel()[:kr]
                     nd[r] = d.size
                     draft[r, :d.size] = d
-                if not paged and int(pos[:B][~done].max()) + S \
-                        > self.max_len:
-                    # dense tail: the fixed-span cache write would
-                    # clamp into valid entries — plain steps finish the
-                    # last few tokens (greedy stays bitwise: same
-                    # argmax, key-independent)
-                    logits, caches, key = self._run_decode(
-                        tok_h, pos, caches, key)
-                    tok, key = self._run_sample(logits, temp, topk, key)
-                    tok_h = np.asarray(tok).astype(np.int32)
-                    pos[:B] = np.where(done, pos[:B], pos[:B] + 1)
-                    self._emit(tok_h, outs, done, eos_id,
-                               max_new_tokens)
-                    if self.stats:
-                        self.stats.bump("decode_steps")
-                    continue
                 feed_toks = np.zeros((bb, S), np.int32)
                 feed_toks[:, 0] = tok_h
                 feed_toks[:, 1:] = draft
                 span_pos = np.clip(
                     pos[:, None] + np.arange(S, dtype=np.int32)[None, :],
                     0, cfg.max_position - 1)
-                if paged:
-                    limit = np.zeros((bb,), np.int32)
-                    for r in range(B):
-                        if not done[r]:
-                            limit[r] = int(nd[r]) + 1
-                            pool.alloc(r, int(pos[r]) + int(nd[r]) + 1)
-                    logits, key = self._run_verify_paged(
-                        feed_toks, span_pos, pos, limit, pool, key)
-                else:
-                    logits, caches, key = self._run_verify(
-                        feed_toks, pos, span_pos, caches, key)
+                limit = np.zeros((bb,), np.int32)
+                for r in range(B):
+                    if not done[r]:
+                        limit[r] = int(nd[r]) + 1
+                        pool.alloc(r, int(pos[r]) + int(nd[r]) + 1)
+                logits, key = self._run_verify_paged(
+                    feed_toks, span_pos, pos, limit, pool, key)
                 out_toks, acc, key = self._run_spec_accept(
                     logits, draft, temp, topk, nd, key)
                 out_h = np.asarray(out_toks)
@@ -1161,10 +1063,7 @@ class GPTGenerator:
                                 int(sum(len(o) for o in outs)))
             return [np.asarray(o, np.int32) for o in outs]
         finally:
-            if pool is not None:
-                for r in range(bb):
-                    pool.free_slot(r)
-                pool.drop_device()
+            self._release_offline_pool(pool)
 
     def generate_naive(self, prompts, max_new_tokens=32, temperature=0.0,
                        top_k=0, eos_id=None, seed=None, key=None):

@@ -83,10 +83,6 @@ def decoder_layer(cfg, x, idx, is_test, kv_cache=None, pos=None):
       the flash path (prompt rows start at position 0, so attention runs
       over the length BUCKET, not the whole cache). Returns
       ``(x, new_k_cache, new_v_cache)``.
-    - ``mode: "decode"``: the incremental step — append this token's k/v
-      at each row's own position, then attend the query over the full
-      cache with the per-row position mask (O(max_len) read instead of an
-      O(S^2) recompute). Returns ``(x, new_k_cache, new_v_cache)``.
     - ``mode: "paged"`` with ``tables`` [B, nblk] int32: the
       block-paged incremental step — k/v caches are a SHARED pool
       ``[num_blocks, H, block_size, D]`` routed through per-row block
@@ -134,10 +130,7 @@ def decoder_layer(cfg, x, idx, is_test, kv_cache=None, pos=None):
     else:
         new_k = layers.nn.kv_cache_write(kv_cache["k"], k, pos)
         new_v = layers.nn.kv_cache_write(kv_cache["v"], v, pos)
-        if kv_cache.get("mode", "decode") == "prefill":
-            ctx = layers.nn.flash_attention(q, k, v, causal=True)
-        else:
-            ctx = layers.nn.kv_cached_attention(q, new_k, new_v, pos)
+        ctx = layers.nn.flash_attention(q, k, v, causal=True)
     ctx = T.reshape(T.transpose(ctx, [0, 2, 1, 3]), [0, 0, h])
     attn_out = _fc(cfg, ctx, h, f"{pre}_att_out")
     attn_out = layers.dropout(attn_out, cfg.dropout, is_test=is_test,
@@ -195,7 +188,7 @@ def gpt_pretrain(cfg, batch_size, seq_len, is_test=False):
             "loss": loss, "checkpoints": checkpoints}
 
 
-# ---- inference graphs: full-forward logits, prefill, cached decode ----
+# ---- inference graphs: full-forward logits, prefill, paged decode ----
 # (the generation driver over these lives in models/generation.py)
 
 def _tied_next_logits(cfg, x, last_pos):
@@ -273,51 +266,16 @@ def gpt_prefill(cfg, max_len, batch_size=-1, seq_len=-1):
             "logits": logits, "cache_k": cache_k, "cache_v": cache_v}
 
 
-def gpt_decode_step(cfg, max_len, batch_size=-1):
-    """ONE incremental decode step: embed the current token at each
-    row's own position, append its k/v into every layer's cache
-    (position-indexed dynamic_update_slice), attend over the cache with
-    the per-row position mask, emit next-token logits. Rows at different
-    positions share this one executable — per-token cost is an O(max_len)
-    cache-append + read instead of an O(S^2) full recompute.
-
-    Feeds: token [B] int32, pos [B] int32 (cache index this token is
-    written to), cache_k_<i>/cache_v_<i> [B, H, max_len, D]."""
-    token = T.data("token", [batch_size], dtype="int32")
-    pos = T.data("pos", [batch_size], dtype="int32")
-    n_head, d_head = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    emb = layers.embedding(token, size=[cfg.vocab_size, cfg.hidden_size],
-                           param_attr=_param(cfg, "word_embedding"))
-    pemb = layers.embedding(pos, size=[cfg.max_position, cfg.hidden_size],
-                            param_attr=_param(cfg, "pos_embedding"))
-    x = M.elementwise_add(emb, pemb)                     # [B, H]
-    x = T.reshape(x, [-1, 1, cfg.hidden_size])           # [B, 1, H]
-    feed_names = ["token", "pos"]
-    cache_k, cache_v = [], []
-    for i in range(cfg.num_layers):
-        ck_in = T.data(f"cache_k_{i}",
-                       [batch_size, n_head, max_len, d_head])
-        cv_in = T.data(f"cache_v_{i}",
-                       [batch_size, n_head, max_len, d_head])
-        feed_names += [f"cache_k_{i}", f"cache_v_{i}"]
-        x, ck, cv = decoder_layer(
-            cfg, x, i, True,
-            kv_cache={"k": ck_in, "v": cv_in, "mode": "decode"}, pos=pos)
-        cache_k.append(ck)
-        cache_v.append(cv)
-    zero = T.fill_constant_batch_size_like(token, [-1], "int32", 0)
-    logits = _tied_next_logits(cfg, x, zero)             # S=1: gather at 0
-    return {"feed_names": feed_names, "logits": logits,
-            "cache_k": cache_k, "cache_v": cache_v}
-
-
 def gpt_decode_step_paged(cfg, kv_dtype="fp32", batch_size=-1):
-    """ONE block-paged incremental decode step: like
-    :func:`gpt_decode_step`, but every layer's KV cache is the SHARED
-    block pool ``[num_blocks, H, block_size, D]`` (``serving/kvpool``)
-    routed through a per-row block table — append via
-    ``paged_kv_cache_write``, read via the fused ``paged_attention``
-    kernel. All pool dims are dynamic, so one program covers every pool
+    """ONE block-paged incremental decode step: embed the current token
+    at each row's own position, append its k/v into every layer's
+    SHARED block pool ``[num_blocks, H, block_size, D]``
+    (``serving/kvpool``) through a per-row block table
+    (``paged_kv_cache_write``), attend over the row's blocks with the
+    fused ``paged_attention`` kernel, emit next-token logits. Rows at
+    different positions share this one executable — per-token cost is a
+    cache append + read instead of an O(S^2) full recompute. All pool
+    dims are dynamic, so one program covers every pool
     size; ``kv_dtype`` picks the cache element type (``int8`` adds the
     per-(block, head, slot) float32 scale pools to the feed/fetch set).
 
@@ -457,58 +415,19 @@ def gpt_prefill_chunk_paged(cfg, kv_dtype="fp32", batch_size=-1,
             "cache_vars": [by_name[n] for n in cache_names]}
 
 
-def gpt_verify_step(cfg, max_len, batch_size=-1, span_len=-1):
-    """ONE speculative VERIFY step over the dense per-slot caches:
-    score S = K+1 positions per row (the current token plus K draft
-    tokens) in a single pass — the k/v of every fed token are appended
-    at ``pos[b]..pos[b]+S-1`` via the same dynamic_update_slice write
-    as :func:`gpt_decode_step`, and each query i attends keys
-    ``<= pos[b]+i`` (prefill-style causal masking over the cache), so
-    ``logits[:, i]`` is exactly what a sequential decode step would
-    emit after accepting the first i fed tokens. Rejected positions
-    leave garbage k/v beyond the accepted point; the caller re-writes
-    them on the next step before any mask admits them.
-
-    Feeds: tokens [B, S] int32, pos [B] int32 (write start = each
-    row's current position), pos_ids [B, S] int32 (absolute positions,
-    host-clipped to max_position), cache_k_<i>/cache_v_<i>
-    [B, H, max_len, D]. Fetches: logits [B, S, V] + updated caches."""
-    tokens = T.data("tokens", [batch_size, span_len], dtype="int32")
-    pos = T.data("pos", [batch_size], dtype="int32")
-    pos_ids = T.data("pos_ids", [batch_size, span_len], dtype="int32")
-    n_head, d_head = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    emb = layers.embedding(tokens, size=[cfg.vocab_size, cfg.hidden_size],
-                           param_attr=_param(cfg, "word_embedding"))
-    pemb = layers.embedding(pos_ids, size=[cfg.max_position,
-                                           cfg.hidden_size],
-                            param_attr=_param(cfg, "pos_embedding"))
-    x = M.elementwise_add(emb, pemb)                     # [B, S, H]
-    feed_names = ["tokens", "pos", "pos_ids"]
-    cache_k, cache_v = [], []
-    for i in range(cfg.num_layers):
-        ck_in = T.data(f"cache_k_{i}",
-                       [batch_size, n_head, max_len, d_head])
-        cv_in = T.data(f"cache_v_{i}",
-                       [batch_size, n_head, max_len, d_head])
-        feed_names += [f"cache_k_{i}", f"cache_v_{i}"]
-        x, ck, cv = decoder_layer(
-            cfg, x, i, True,
-            kv_cache={"k": ck_in, "v": cv_in, "mode": "decode"}, pos=pos)
-        cache_k.append(ck)
-        cache_v.append(cv)
-    logits = _tied_span_logits(cfg, x)                   # [B, S, V]
-    return {"feed_names": feed_names, "logits": logits,
-            "cache_k": cache_k, "cache_v": cache_v}
-
-
 def gpt_verify_step_paged(cfg, kv_dtype="fp32", batch_size=-1,
                           span_len=-1):
-    """ONE speculative VERIFY step over the shared block pool: the
-    paged analogue of :func:`gpt_verify_step`, built exactly like a
-    chunked-prefill pass (:func:`gpt_prefill_chunk_paged` — same
-    block-table gather, same per-row position masks, same trash-block
-    routing for past-``limit`` padding) except that logits come back
-    for EVERY position, not just the row's last real one. ``limit``
+    """ONE speculative VERIFY step over the shared block pool: score
+    S = K+1 positions per row (the current token plus K draft tokens)
+    in a single pass, so ``logits[:, i]`` is exactly what a sequential
+    decode step would emit after accepting the first i fed tokens.
+    Built exactly like a chunked-prefill pass
+    (:func:`gpt_prefill_chunk_paged` — same block-table gather, same
+    per-row position masks, same trash-block routing for
+    past-``limit`` padding) except that logits come back for EVERY
+    position, not just the row's last real one. Rejected positions
+    leave garbage k/v beyond the accepted point; the caller re-writes
+    them on the next step before any mask admits them. ``limit``
     [B] carries each row's real span (k_b drafts + 1), so rows may
     speculate at different depths inside one executable; a row's
     padding positions write to the trash block and its logits there
@@ -593,7 +512,6 @@ class GPTServing:
     def eager_builders(self, max_len):
         cfg = self.cfg
         return {"prefill": lambda: gpt_prefill(cfg, max_len),
-                "decode": lambda: gpt_decode_step(cfg, max_len),
                 "logits": lambda: gpt_logits(cfg)}
 
     def build(self, kind, max_len):
@@ -601,8 +519,6 @@ class GPTServing:
         step, chunked prefill and the verify steps exist per KV-cache
         dtype and most processes never touch them)."""
         kv_dtype = kind.rsplit("_", 1)[-1]
-        if kind == "verify":
-            return gpt_verify_step(self.cfg, max_len)
         if kind.startswith("verify_paged_"):
             return gpt_verify_step_paged(self.cfg, kv_dtype=kv_dtype)
         if kind.startswith("decode_paged_"):

@@ -362,9 +362,7 @@ class MellumServing:
         if kind.startswith("decode_paged_"):
             return mellum_decode_step_paged(self.cfg, kv_dtype=kv_dtype)
         for prefix, path in (("prefill_chunk", "chunked prefill"),
-                             ("verify", "speculative verify"),
-                             ("decode", "dense KV bank"),
-                             ("prefill", "dense KV bank")):
+                             ("verify", "speculative verify")):
             if kind.startswith(prefix):
                 raise UnsupportedPathError(self.name, path)
         raise KeyError(f"unknown generation program kind {kind!r}")

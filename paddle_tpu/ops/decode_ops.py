@@ -46,40 +46,6 @@ def kv_cache_write(ctx, ins, attrs):
     return {"Out": jax.vmap(row)(cache, kv, pos)}
 
 
-@register_op("kv_cached_attention", grad=False, infer_shape=False)
-def kv_cached_attention(ctx, ins, attrs):
-    """Causal attention of S fresh queries over a KV cache, masked by
-    per-row position counters. Q [B, H, S, D]; K/V caches [B, H, L, D];
-    Pos [B] int32 (absolute position of the FIRST query token, i.e. the
-    cache index its k/v was just written to). Key slot j is visible to
-    query i iff j <= pos[b] + i — rows at different positions share one
-    executable, and stale/garbage cache entries beyond a row's position
-    are never attended.
-
-    Scores/softmax accumulate in float32 (flash-kernel convention);
-    the output is cast back to Q's dtype. Decode (S=1) is a cache-wide
-    read per token: bandwidth-bound by design.
-    """
-    q = x_of(ins, "Q")
-    k = x_of(ins, "K")
-    v = x_of(ins, "V")
-    pos = x_of(ins, "Pos").astype(jnp.int32)
-    scale = float(attrs.get("scale", 0.0)) or float(q.shape[-1]) ** -0.5
-
-    scores = jnp.einsum("bhsd,bhld->bhsl", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    L = k.shape[2]
-    S = q.shape[2]
-    key_idx = jnp.arange(L, dtype=jnp.int32)[None, None, :]     # [1,1,L]
-    qry_pos = pos[:, None, None] + jnp.arange(S, dtype=jnp.int32)[None, :,
-                                                                  None]
-    mask = key_idx <= qry_pos                                    # [B,S,L]
-    scores = jnp.where(mask[:, None, :, :], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhsl,bhld->bhsd", probs, v.astype(jnp.float32))
-    return {"Out": out.astype(q.dtype)}
-
-
 @register_op("paged_kv_cache_write", grad=False, infer_shape=False)
 def paged_kv_cache_write(ctx, ins, attrs):
     """Append S new k/v vectors into a BLOCK-PAGED pool at each row's

@@ -27,15 +27,15 @@ clipper/ORCA adaptive-batching tradition:
   ``InferenceServer(generator=...)`` and the server also speaks
   ``op: "generate"`` — requests join a fixed bank of decode slots
   (``FLAGS_decode_slots``) stepped one token at a time by a single
-  compiled KV-cached decode executable (ORCA-style continuous
+  compiled paged decode executable (ORCA-style continuous
   batching: per-row position counters, token-level deadlines, slot
   reuse the moment a row finishes); ``stats()`` adds prefill/decode/
   sample histograms, ``tokens_per_s`` and ``decode_occupancy``
 
-- paged KV cache (``FLAGS_kv_paged``): the dense per-slot decode bank
-  becomes a shared block-paged ``kvpool.KVBlockPool``
-  (vLLM/PagedAttention) — per-slot block tables, allocation on append,
-  frees on EOS/deadline/cancel, typed
+- paged KV cache: the decode bank's keys and values live in one
+  shared block-paged ``kvpool.KVBlockPool`` (vLLM/PagedAttention),
+  the only KV store behind serving — per-slot block tables,
+  allocation on append, frees on EOS/deadline/cancel, typed
   ``KVPoolExhaustedError`` backpressure, optional bf16/int8 cache
   (``FLAGS_kv_cache_dtype``) read by the fused
   ``kernels.paged_attention`` decode kernel; ``stats()`` adds

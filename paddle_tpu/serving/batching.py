@@ -592,19 +592,18 @@ class DecodeBatcher:
         self.slots = engine.slots
         self.stats = stats
         self.watchdog_s = float(watchdog_s)
-        # speculative decoding (FLAGS_decode_spec_k > 0, paged pool
-        # only): between steps each live row proposes up to spec_k
-        # draft tokens (drafter; FLAGS_decode_spec_mode picks the
-        # default) verified in ONE span pass through the pool —
-        # rejection sampling keeps the output distribution exact. The
+        # speculative decoding (FLAGS_decode_spec_k > 0): between
+        # steps each live row proposes up to spec_k draft tokens
+        # (drafter; FLAGS_decode_spec_mode picks the default) verified
+        # in ONE span pass through the pool — rejection sampling keeps
+        # the output distribution exact. The
         # draft depth is a LOAD knob: a windowed acceptance rate adapts
         # it globally (low acceptance = wasted verify compute) and the
         # brownout ladder shrinks it per-row for degraded classes
         # before their admission degrades.
         if spec_k is None:
             spec_k = flag("decode_spec_k")
-        self.spec_k = int(spec_k) \
-            if getattr(engine, "pool", None) is not None else 0
+        self.spec_k = int(spec_k)
         if self.spec_k > 0 and hasattr(engine, "gen"):
             # an architecture with no verify step refuses here, by name
             engine.gen._ensure_prog(f"verify_paged_{engine.pool.dtype}")
@@ -1356,14 +1355,13 @@ class DecodeBatcher:
         self._steps += 1
         attrs["step"] = self._steps
         attrs["live"] = len(self._active)
-        pool = getattr(self.engine, "pool", None)
-        if pool is not None:
-            by_group = pool.blocks_in_use_by_group()
-            attrs["blocks_in_use"] = sum(by_group.values())
-            attrs["blocks_total"] = pool.capacity_blocks
-            if "window" in by_group:
-                attrs["blocks_in_use_full"] = by_group["full"]
-                attrs["blocks_in_use_window"] = by_group["window"]
+        pool = self.engine.pool
+        by_group = pool.blocks_in_use_by_group()
+        attrs["blocks_in_use"] = sum(by_group.values())
+        attrs["blocks_total"] = pool.capacity_blocks
+        if "window" in by_group:
+            attrs["blocks_in_use_full"] = by_group["full"]
+            attrs["blocks_in_use_window"] = by_group["window"]
         try:
             with _trace.loop_span("engine/step") as stepped:
                 if drafts is not None:
@@ -1374,15 +1372,14 @@ class DecodeBatcher:
                         self._topk, drafts, nd, live_mask,
                         budget=self.watchdog_s or None)
                 else:
-                    if pool is not None:
-                        # the paged kernel's grid against the blocks it
-                        # has to read: over slots * blocks_per_row, how
-                        # much of the table it no longer walks
-                        stepped.attrs["grid_steps"] = getattr(
-                            self.engine, "kernel_grid_steps", None)
-                        stepped.attrs["live_blocks"] = int(np.sum(
-                            self._pos[list(self._active)]
-                            // pool.block_size + 1))
+                    # the paged kernel's grid against the blocks it
+                    # has to read: over slots * blocks_per_row, how
+                    # much of the table it no longer walks
+                    stepped.attrs["grid_steps"] = \
+                        self.engine.kernel_grid_steps
+                    stepped.attrs["live_blocks"] = int(np.sum(
+                        self._pos[list(self._active)]
+                        // pool.block_size + 1))
                     toks = self.engine.step(
                         self._tok, self._pos, self._temp,
                         self._topk, budget=self.watchdog_s or None)

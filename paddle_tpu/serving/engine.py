@@ -433,15 +433,17 @@ class GenerationEngine:
     """Slot-batched autoregressive decoding primitives for the serving
     runtime, over a ``models.generation.GPTGenerator``.
 
-    The engine owns a fixed bank of ``slots`` generation rows whose KV
-    caches live on the device as ONE ``[slots, H, max_len, D]`` buffer
-    per layer, stepped by a single compiled decode executable
-    (``FLAGS_decode_slots``). The ``DecodeBatcher`` drives it:
+    The engine owns a fixed bank of ``slots`` generation rows
+    (``FLAGS_decode_slots``) whose KV caches live on the device in one
+    shared block pool (``kvpool.KVBlockPool``: per-slot block tables,
+    blocks allocated on append — concurrency bounded by actual tokens,
+    not ``slots * max_len``), stepped by a single compiled paged decode
+    executable. The ``DecodeBatcher`` drives it:
 
     - ``admit(requests, slot_ids)``: bucketed prefill over the new
       prompts, per-row sampling of their first tokens, and a jitted
-      scatter of the fresh row caches into the slot bank (slot reuse —
-      a finished row's stale cache is simply overwritten).
+      scatter of the fresh row caches into the slots' blocks (a
+      finished row's blocks went back to the pool at release).
     - ``step(tokens, pos, temperature, top_k)``: one decode + sample
       over the whole bank; rows at different positions (and with
       different sampling configs) share the executable.
@@ -452,49 +454,36 @@ class GenerationEngine:
     """
 
     def __init__(self, generator, *, slots=None, stats=None, seed=0,
-                 paged=None, kv_dtype=None, kv_block_size=None,
+                 kv_dtype=None, kv_block_size=None,
                  kv_pool_blocks=None, pool_name="serving",
                  prefix_cache=None):
         import jax
+        from .kvpool import _np_pool_dtype
+        from ..kernels.paged_attention import decode_grid
         self.gen = generator
         self.slots = int(slots or flag("decode_slots"))
         self.stats = stats if stats is not None else generator.stats
-        # block-paged decode memory (FLAGS_kv_paged / paged=True): the
-        # slot bank becomes a shared KVBlockPool with per-slot block
-        # tables — concurrency bounded by actual tokens, not
-        # slots * max_len. None/False keeps the dense bank (the parity
-        # baseline). ``pool_name`` labels the pool's kvpool_* gauge
-        # series — fleet replicas sharing one process must not clobber
-        # each other's occupancy. ``prefix_cache`` (None ->
-        # FLAGS_kv_prefix_cache) turns on block-granular prompt-prefix
-        # reuse across requests.
-        self.paged = bool(flag("kv_paged") if paged is None else paged)
-        self.pool = None
-        self.kernel_grid_steps = None
-        if not self.paged:
-            # an architecture with no dense-bank decode step refuses
-            # here, by name, before anything compiles
-            generator._ensure_prog("decode")
-        if self.paged:
-            from .kvpool import _np_pool_dtype
-            from ..kernels.paged_attention import decode_grid
-            # the architecture's own layout: its KV heads and head width,
-            # its layer groups (tensor-parallel serving: block arrays
-            # sharded on the head axis of the generator's tp mesh)
-            self.pool = generator.new_pool(
-                self.slots, block_size=kv_block_size,
-                num_blocks=kv_pool_blocks, dtype=kv_dtype, name=pool_name,
-                prefix_cache=prefix_cache)
-            # what one paged_attention_decode call of a decode step
-            # launches over the full layers' table (the engine/step
-            # span's grid_steps): the kernel's own function of the
-            # shapes a shard of it sees
-            grid, _ = decode_grid(
-                self.slots,
-                self.pool.num_heads // max(getattr(generator, "tp", 1), 1),
-                self.pool.block_size, self.pool.d_head,
-                _np_pool_dtype(self.pool.dtype), self.pool.blocks_per_row)
-            self.kernel_grid_steps = math.prod(grid)
+        # the architecture's own layout: its KV heads and head width,
+        # its layer groups (tensor-parallel serving: block arrays
+        # sharded on the head axis of the generator's tp mesh).
+        # ``pool_name`` labels the pool's kvpool_* gauge series — fleet
+        # replicas sharing one process must not clobber each other's
+        # occupancy. ``prefix_cache`` (None -> FLAGS_kv_prefix_cache)
+        # turns on block-granular prompt-prefix reuse across requests.
+        self.pool = generator.new_pool(
+            self.slots, block_size=kv_block_size,
+            num_blocks=kv_pool_blocks, dtype=kv_dtype, name=pool_name,
+            prefix_cache=prefix_cache)
+        # what one paged_attention_decode call of a decode step
+        # launches over the full layers' table (the engine/step
+        # span's grid_steps): the kernel's own function of the
+        # shapes a shard of it sees
+        grid, _ = decode_grid(
+            self.slots,
+            self.pool.num_heads // max(getattr(generator, "tp", 1), 1),
+            self.pool.block_size, self.pool.d_head,
+            _np_pool_dtype(self.pool.dtype), self.pool.blocks_per_row)
+        self.kernel_grid_steps = math.prod(grid)
         # a generator WITHOUT its own sink adopts the server's (stage
         # histograms land in server.stats()), and a sink a PREVIOUS
         # engine bound is rebound to the live server (else a reused
@@ -507,67 +496,30 @@ class GenerationEngine:
             generator._stats_adopted = True
         self.max_len = generator.max_len
         self._key = jax.random.PRNGKey(int(seed))
-        self._caches = None        # lazy: zeros [slots, H, L, D] per layer
-        self._insert_fn = None
         self.bank_lost = False     # see _drop_bank
         self.step_routing = {}     # the last step's moe_* span attrs
 
     def _ensure_caches(self):
         self.bank_lost = False
-        if self.pool is not None:
-            self.pool.arrays()       # lazy device-side pool build
-            return
-        if self._caches is not None:
-            return
-        import jax.numpy as jnp
-        cfg = self.gen.cfg
-        d_head = cfg.hidden_size // cfg.num_heads
-        shape = (self.slots, cfg.num_heads, self.max_len, d_head)
-        self._caches = {}
-        for i in range(cfg.num_layers):
-            self._caches[f"cache_k_{i}"] = jnp.zeros(shape, jnp.float32)
-            self._caches[f"cache_v_{i}"] = jnp.zeros(shape, jnp.float32)
-
-    def _insert(self, row_caches, slot_ids):
-        """Scatter freshly prefilled row caches into the slot bank (one
-        jitted executable; jax's shape cache handles the (n, bucket)
-        universe)."""
-        import jax
-        import jax.numpy as jnp
-        maybe_fail("serving.slot_insert")
-        if self._insert_fn is None:
-            def ins(dst, src, idx):
-                return {name: dst[name].at[idx].set(src[name][:idx.shape[0]])
-                        for name in dst}
-            self._insert_fn = jax.jit(ins, donate_argnums=(0,))
-        idx = jnp.asarray(slot_ids, jnp.int32)
-        try:
-            self._caches = self._insert_fn(self._caches, row_caches, idx)
-        except Exception:
-            self._drop_bank()
-            raise
+        self.pool.arrays()           # lazy device-side pool build
 
     def _drop_bank(self):
-        """A failed donated call may have invalidated the slot bank's
-        buffers: drop it (the next admission rebuilds zeros) and flag
-        the loss so the DecodeBatcher fails every active row instead of
-        letting them silently decode against a fresh zero cache. Paged
-        mode drops the pool's DEVICE arrays only — the host block
-        accounting survives, and the failed rows return their blocks
-        through the batcher's release path."""
-        self._caches = None
-        if self.pool is not None:
-            self.pool.drop_device()
+        """A failed donated call may have invalidated the pool's
+        buffers: drop its DEVICE arrays (the next admission rebuilds
+        zeros) and flag the loss so the DecodeBatcher fails every
+        active row instead of letting them silently decode against a
+        fresh zero cache. The host block accounting survives, and the
+        failed rows return their blocks through the batcher's release
+        path."""
+        self.pool.drop_device()
         self.bank_lost = True
 
     def reset(self):
         """Forget the slot bank without flagging a loss — the restart
         path: a replaced decode loop starts from an empty bank (its rows
         were already failed by the supervisor), so the stale caches are
-        garbage, not state. Paged mode frees every block too."""
-        self._caches = None
-        if self.pool is not None:
-            self.pool.reset()
+        garbage, not state. Every block is freed too."""
+        self.pool.reset()
         self.bank_lost = False
 
     # -- paged-pool admission / lifecycle hooks ---------------------------
@@ -577,7 +529,7 @@ class GenerationEngine:
         prefill compile: an overlong request raises
         :class:`batching.BadRequestError` (the wire maps it to
         ``etype: "BadRequest"`` — retrying without fixing the input
-        cannot help), and in paged mode so does a request the pool
+        cannot help), and so does a request the pool
         could NEVER hold even empty; a request whose prompt blocks are
         merely not free RIGHT NOW (unless ``static_only``) raises the
         retryable :class:`kvpool.KVPoolExhaustedError` instead,
@@ -590,19 +542,16 @@ class GenerationEngine:
                 f"prompt ({prompt_len} tokens) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the decode cache length "
                 f"{self.max_len}")
-        if self.pool is not None:
-            self.pool.check_fits(prompt_len + max_new_tokens)
-            if not static_only:
-                # +1: the first decode append may open a fresh block
-                self.pool.admission_check(
-                    prompt_len + 1, [int(t) + 1 for t in pending_tokens])
+        self.pool.check_fits(prompt_len + max_new_tokens)
+        if not static_only:
+            # +1: the first decode append may open a fresh block
+            self.pool.admission_check(
+                prompt_len + 1, [int(t) + 1 for t in pending_tokens])
 
     def release_slot(self, slot):
         """Return a finished slot's KV blocks to the pool (EOS /
-        deadline / cancel / error — the continuous-batching reclaim).
-        Dense mode: no-op (the bank row is simply overwritten)."""
-        if self.pool is not None:
-            self.pool.free_slot(slot)
+        deadline / cancel / error — the continuous-batching reclaim)."""
+        self.pool.free_slot(slot)
 
     def prepare_step(self, active_pos, widths=None):
         """Allocation-on-append before a decode step: grow each live
@@ -615,9 +564,7 @@ class GenerationEngine:
         speculative write lands, even for draft positions that may be
         rejected. Returns ``{slot: exc}`` for rows the pool could not
         grow — the batcher sheds exactly those rows (typed) while the
-        rest of the bank keeps decoding. Dense mode returns ``{}``."""
-        if self.pool is None:
-            return {}
+        rest of the bank keeps decoding."""
         shed = {}
         for slot, p in active_pos.items():
             w = max(int(widths.get(slot, 1)) if widths else 1, 1)
@@ -634,9 +581,7 @@ class GenerationEngine:
 
     def reclaim_leaks(self, live_slots):
         """Leak sweep: free blocks held by slots not in ``live_slots``
-        (flight-recorded per leaking slot). Dense mode: 0."""
-        if self.pool is None:
-            return 0
+        (flight-recorded per leaking slot)."""
         return self.pool.reclaim_leaks(live_slots)
 
     # -- hot weight reload ------------------------------------------------
@@ -689,48 +634,44 @@ class GenerationEngine:
                 temp[r] = req.temperature
                 topk[r] = req.top_k
 
-        if self.pool is not None:
-            # allocate each row's prompt blocks BEFORE the prefill (the
-            # scatter routes through the tables); a mid-batch failure
-            # rolls this batch's allocations back untouched
-            allocated = []
-            with span("pool/alloc", rows=n):
-                try:
-                    for req, slot in zip(requests, slot_ids):
-                        self.pool.free_slot(slot)   # stale holder (if any)
-                        self.pool.alloc(slot, int(req.prompt.size))
-                        allocated.append(slot)
-                except Exception:
-                    for sl in allocated:
-                        self.pool.free_slot(sl)
-                    raise
+        # allocate each row's prompt blocks BEFORE the prefill (the
+        # scatter routes through the tables); a mid-batch failure
+        # rolls this batch's allocations back untouched
+        allocated = []
+        with span("pool/alloc", rows=n):
+            try:
+                for req, slot in zip(requests, slot_ids):
+                    self.pool.free_slot(slot)   # stale holder (if any)
+                    self.pool.alloc(slot, int(req.prompt.size))
+                    allocated.append(slot)
+            except Exception:
+                for sl in allocated:
+                    self.pool.free_slot(sl)
+                raise
         with span("generator/prefill", rows=n) as prefilled:
             logits, row_caches, self._key, aux = self.gen._run_prefill(
                 tokens, pos_ids, last, self._key,
-                kv_dtype=self.pool.dtype if self.pool is not None
-                else None, want_aux=True)
+                kv_dtype=self.pool.dtype, want_aux=True)
         with span("generator/sample", rows=n):
             toks, self._key = self.gen._run_sample(logits, temp, topk,
                                                    self._key)
-        if self.pool is not None:
-            # (rows, blocks) is the pair the scatter's jit retraces on
-            with span("pool/scatter", rows=n,
-                      blocks=self.pool.blocks_for_tokens(tokens.shape[1])):
-                try:
-                    self.pool.scatter_prefill(
-                        list(slot_ids), row_caches, tokens.shape[1],
-                        lengths=[int(r.prompt.size) for r in requests])
-                except Exception:
-                    # the donated device pool is lost (scatter dropped
-                    # it); this batch's blocks go back, the batcher
-                    # fails the other active rows via bank_lost
-                    for sl in slot_ids:
-                        self.pool.free_slot(sl)
-                    self.bank_lost = True
-                    raise
-        else:
-            self._insert(row_caches, list(slot_ids))
-        if self.pool is not None and self.pool.prefix_enabled:
+        # (rows, blocks) is the pair the scatter's jit retraces on
+        with span("pool/scatter", rows=n,
+                  blocks=self.pool.blocks_for_tokens(tokens.shape[1])):
+            try:
+                maybe_fail("serving.slot_insert")
+                self.pool.scatter_prefill(
+                    list(slot_ids), row_caches, tokens.shape[1],
+                    lengths=[int(r.prompt.size) for r in requests])
+            except Exception:
+                # the donated device pool is lost (scatter dropped
+                # it); this batch's blocks go back, the batcher
+                # fails the other active rows via bank_lost
+                for sl in slot_ids:
+                    self.pool.free_slot(sl)
+                self.bank_lost = True
+                raise
+        if self.pool.prefix_enabled:
             # deposit the freshly prefilled prompt blocks into the
             # prefix index (refcounted co-ownership — they outlive the
             # slot's EOS until evicted LRU); later requests sharing the
@@ -767,15 +708,14 @@ class GenerationEngine:
 
     # -- chunked (incremental) prefill ------------------------------------
     def incremental_prefill_enabled(self):
-        """Chunked prompt ingestion (Orca/Sarathi-style): on when the
-        paged pool exists AND either ``FLAGS_prefill_chunk_tokens``
+        """Chunked prompt ingestion (Orca/Sarathi-style): on when
+        either ``FLAGS_prefill_chunk_tokens``
         bounds the per-round prompt slice (long prompts stop stalling
         the decode bank's token cadence) or the prefix cache is on (the
         incremental path is what turns a cached-prefix hit into skipped
         prefill compute)."""
-        return self.pool is not None and (
-            int(flag("prefill_chunk_tokens")) > 0
-            or self.pool.prefix_enabled)
+        return (int(flag("prefill_chunk_tokens")) > 0
+                or self.pool.prefix_enabled)
 
     def start_prefill(self, req, slot):
         """Begin incremental prefill of ``req`` into ``slot``: reclaim
@@ -866,14 +806,8 @@ class GenerationEngine:
     # -- disaggregated prefill/decode (KV-block migration) ----------------
     def export_slot(self, slot):
         """Serialize ``slot``'s KV blocks for cross-replica migration
-        (the prefill half of the disaggregated split). Paged mode only:
-        the block table is what makes in-flight KV state a well-defined,
-        movable unit — the dense bank has no such boundary."""
-        from .batching import BadRequestError
-        if self.pool is None:
-            raise BadRequestError(
-                "KV export requires the paged pool (FLAGS_kv_paged / "
-                "paged=True) — the dense bank's rows are not migratable")
+        (the prefill half of the disaggregated split): the block table
+        is what makes in-flight KV state a well-defined, movable unit."""
         return self.pool.export_slot(slot)
 
     def admit_imported(self, requests, slot_ids):
@@ -883,11 +817,6 @@ class GenerationEngine:
         the first tokens (carried in the payloads, sampled prefill-side)
         as np int32 [len(requests)]; on failure nothing stays allocated
         and a donated-array loss flags ``bank_lost``."""
-        from .batching import BadRequestError
-        if self.pool is None:
-            raise BadRequestError(
-                "KV import requires the paged pool (FLAGS_kv_paged / "
-                "paged=True) on the decode replica")
         self._ensure_caches()
         t0 = time.perf_counter()
         imported = []
@@ -928,9 +857,10 @@ class GenerationEngine:
         ``budget`` (seconds) runs the decode call under
         ``resilience.run_with_watchdog``: a hung chip call raises
         WatchdogTimeout instead of wedging the decode loop. The worker
-        only COMPUTES — state (caches, RNG key) is assigned on this
-        thread after it returns, so an abandoned overbudget worker can
-        never resurrect a bank this thread already dropped."""
+        only COMPUTES — the feed is built here and state (pool arrays,
+        RNG key) is adopted on this thread after it returns, so an
+        abandoned overbudget worker can never resurrect a pool this
+        thread already dropped."""
         maybe_fail("serving.decode_step")
         self._ensure_caches()
         # the caller's span (the batcher's engine/step): the watchdog's
@@ -940,49 +870,26 @@ class GenerationEngine:
         posc = np.ascontiguousarray(pos, dtype=np.int32)
         key = self._key
 
-        if self.pool is not None:
-            # paged decode: the worker only COMPUTES (feed built here,
-            # pool state adopted on this thread after it returns), so an
-            # abandoned overbudget worker can never resurrect a pool
-            # this thread already dropped — mirroring the dense path
-            from .kvpool import adopt_decode_fetches, decode_feed
-            feed = decode_feed(self.pool, tok, posc)
-            kind = f"decode_paged_{self.pool.dtype}"
+        from .kvpool import adopt_decode_fetches, decode_feed
+        feed = decode_feed(self.pool, tok, posc)
+        kind = f"decode_paged_{self.pool.dtype}"
 
-            def _decode_paged():
-                return self.gen._invoke(kind, "decode", feed, key,
-                                        parent=span)
+        def _decode_paged():
+            return self.gen._invoke(kind, "decode", feed, key,
+                                    parent=span)
 
-            try:
-                if budget:
-                    fetches, new_key = run_with_watchdog(
-                        _decode_paged, budget,
-                        what="serving decode step")
-                else:
-                    fetches, new_key = _decode_paged()
-            except Exception:
-                self._drop_bank()  # pool arrays were donated in
-                raise
-            logits = adopt_decode_fetches(self.pool, fetches)
-            self._key = new_key
-            aux = self.gen.aux_of(kind, fetches)
-        else:
-            caches, aux = self._caches, {}
-
-            def _decode():
-                return self.gen._run_decode(tok, posc, caches, key,
-                                            parent=span)
-
-            try:
-                if budget:
-                    logits, new_caches, new_key = run_with_watchdog(
-                        _decode, budget, what="serving decode step")
-                else:
-                    logits, new_caches, new_key = _decode()
-            except Exception:
-                self._drop_bank()  # caches were donated into the call
-                raise
-            self._caches, self._key = new_caches, new_key
+        try:
+            if budget:
+                fetches, new_key = run_with_watchdog(
+                    _decode_paged, budget, what="serving decode step")
+            else:
+                fetches, new_key = _decode_paged()
+        except Exception:
+            self._drop_bank()  # pool arrays were donated in
+            raise
+        logits = adopt_decode_fetches(self.pool, fetches)
+        self._key = new_key
+        aux = self.gen.aux_of(kind, fetches)
         toks, self._key = self.gen._run_sample(
             logits, np.ascontiguousarray(temperature, dtype=np.float32),
             np.ascontiguousarray(top_k, dtype=np.int32), self._key)
@@ -1000,8 +907,7 @@ class GenerationEngine:
         rows = length_bucket(len(prompt_sizes))
         seq = min(length_bucket(max(prompt_sizes), self.gen.bucket_min),
                   self.max_len)
-        elem = {"fp32": 4, "bf16": 2, "int8": 1}[self.pool.dtype] \
-            if self.pool is not None else 4
+        elem = {"fp32": 4, "bf16": 2, "int8": 1}[self.pool.dtype]
         return self.gen.arch.prefill_bytes(rows, seq, self.max_len, elem)
 
     def prefill_fit(self, prompt_sizes):
@@ -1021,8 +927,7 @@ class GenerationEngine:
     def spec_step(self, tokens, pos, temperature, top_k, drafts,
                   num_draft, live, budget=None):
         """One speculative verify + accept step over the whole slot
-        bank (paged pool only — the dense bank's fixed-span cache write
-        clamps near the row end, so the batcher never routes it here).
+        bank.
 
         ``drafts`` is np int32 [slots, K] (drafter proposals per row),
         ``num_draft`` np int32 [slots] counts the real drafts per row
@@ -1037,11 +942,6 @@ class GenerationEngine:
         Same watchdog discipline as :meth:`step`: the worker only
         computes; pool adoption and key assignment happen on this
         thread after it returns."""
-        if self.pool is None:
-            raise ValueError(
-                "speculative decoding requires the paged KV pool "
-                "(FLAGS_kv_paged / paged=True) — the dense bank has no "
-                "trash-routed multi-token write")
         maybe_fail("serving.decode_step")
         self._ensure_caches()
         span = _trace.current_loop()    # as in step()

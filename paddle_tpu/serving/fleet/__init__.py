@@ -25,7 +25,7 @@ Quick start::
     from paddle_tpu import serving
     from paddle_tpu.serving import fleet
 
-    reps = [serving.InferenceServer(generator=mkgen(), kv_paged=True,
+    reps = [serving.InferenceServer(generator=mkgen(),
                                     kv_pool_name=f"rep{i}").start()
             for i in range(3)]
     router = fleet.Router([r.endpoint for r in reps]).start()

@@ -1,10 +1,11 @@
 """Block-paged KV-cache pool: decode memory priced by ACTUAL tokens.
 
-The dense decode bank (``GenerationEngine``'s ``[slots, H, max_len, D]``
-buffer per layer) charges every slot ``max_len`` HBM whatever its real
-length — BENCHMARKS.md shows decode is bandwidth-bound against exactly
-that buffer. This module is the vLLM/PagedAttention alternative (Kwon et
-al. 2023): one device-resident pool of fixed-size blocks
+A dense decode bank (a ``[slots, H, max_len, D]`` buffer per layer)
+charges every slot ``max_len`` HBM whatever its real length, and decode
+is bandwidth-bound against exactly that buffer. This module is the
+vLLM/PagedAttention store (Kwon et al. 2023) that ``GenerationEngine``
+and ``GPTGenerator.generate`` keep keys and values in: one
+device-resident pool of fixed-size blocks
 (``[num_blocks, H, block_size, D]`` per layer, K and V) shared across
 slots, a per-slot block table, blocks allocated on append and returned
 on EOS/deadline/cancel, so concurrent generations are bounded by the

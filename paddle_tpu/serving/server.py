@@ -26,7 +26,7 @@ Wire protocol (all values inside the typed wire universe):
     request  {"op": "ping"}    -> {"ok": True}
     request  {"op": "health"}  -> {"ok": True, "health": {state, queue
                                    depths, loop liveness, weights_version,
-                                   kvpool_occupancy (paged)}}
+                                   kvpool_occupancy}}
     request  {"op": "cancel", "rid": str} -> {"ok": True, "cancelled": bool}
     request  {"op": "prefill", "tokens": ...} -> {"ok": True, "kv": {...}}
                                   (disaggregated split, prefill half:
@@ -139,6 +139,13 @@ class InferenceServer:
                  allow_insecure=False, kv_paged=None,
                  kv_pool_name="serving", slo_rules=None,
                  **config_overrides):
+        if kv_paged not in (None, True):
+            # the keyword is kept, inert, for the frozen benchmark
+            # drivers that still pass kv_paged=True (ROADMAP Queue 3)
+            raise ValueError(
+                "kv_paged=False: PR 31 removed the dense KV bank — the "
+                "paged pool is the one KV store behind serving; drop "
+                "the keyword")
         self.config = config or ServingConfig(**config_overrides)
         self.stats_sink = ServingStats()
         if engine is None and (model_dir is not None
@@ -169,7 +176,6 @@ class InferenceServer:
             self.gen_engine = GenerationEngine(generator,
                                                slots=decode_slots,
                                                stats=self.stats_sink,
-                                               paged=kv_paged,
                                                pool_name=kv_pool_name)
             self.gen_queue = RequestQueue(
                 max_depth=self.config.queue_depth, stats=self.stats_sink)
@@ -445,8 +451,8 @@ class InferenceServer:
 
         Requests that could NEVER run are refused typed AT THE DOOR,
         before any queue wait or prefill compile: an overlong prompt
-        (prompt + max_new_tokens > the decode cache length) and, in
-        paged mode, a request bigger than the whole KV pool both raise
+        (prompt + max_new_tokens > the decode cache length) and a
+        request bigger than the whole KV pool both raise
         :class:`BadRequestError` (wire ``etype: "BadRequest"`` —
         retrying cannot help)."""
         if self.gen_queue is None:
@@ -455,12 +461,6 @@ class InferenceServer:
         ntokens = np.asarray(tokens).size
         self.gen_engine.admission_check(
             ntokens, max_new_tokens, static_only=True)
-        if (export_kv or kv is not None) \
-                and self.gen_engine.pool is None:
-            raise BadRequestError(
-                "disaggregated prefill/decode requires the paged KV "
-                "pool (FLAGS_kv_paged / kv_paged=True) — the dense "
-                "bank's rows are not migratable")
         if kv is not None:
             # door check: the migrated payload must describe exactly
             # this prompt's prefill (position arithmetic depends on it)
@@ -516,16 +516,15 @@ class InferenceServer:
             extra["decode_free_slots"] = len(self.decode_batcher._free)
             for k, v in self.gen_engine.gen.cache.stats().items():
                 extra[f"decode_cache_{k}"] = v
-            if self.gen_engine.pool is not None:
-                for k, v in self.gen_engine.pool.stats().items():
-                    extra[f"kvpool_{k}"] = v
-                # pool-sized copies XLA left in each executable that
-                # takes the pool: the generator's by program kind, the
-                # pool's own writers beside them (0 everywhere is the
-                # stored layout doing its work)
-                extra["pool_relayouts"] = dict(
-                    self.gen_engine.gen.pool_relayouts,
-                    **extra.pop("kvpool_relayouts"))
+            for k, v in self.gen_engine.pool.stats().items():
+                extra[f"kvpool_{k}"] = v
+            # pool-sized copies XLA left in each executable that
+            # takes the pool: the generator's by program kind, the
+            # pool's own writers beside them (0 everywhere is the
+            # stored layout doing its work)
+            extra["pool_relayouts"] = dict(
+                self.gen_engine.gen.pool_relayouts,
+                **extra.pop("kvpool_relayouts"))
         extra["state"] = self.state
         extra["weights_version"] = self._weights_version
         # level() (not snapshot's cached value): the ladder is
@@ -576,19 +575,18 @@ class InferenceServer:
                 # windowed acceptance next to the load signals
                 h.update(self.decode_batcher.spec_snapshot())
             pool = self.gen_engine.pool
-            if pool is not None:
-                # the router's least-loaded dispatch reads this: live
-                # kvpool occupancy next to the queue depths, one cheap
-                # probe instead of a full stats()/metrics scrape
-                cap = pool.capacity_blocks
-                # blocks_in_use excludes cache-only blocks: a pool full
-                # of EVICTABLE prefix blocks reads as empty to the
-                # dispatch score (those blocks are reclaimable capacity
-                # that doubles as cache value), with the evictable
-                # count alongside for the affinity-aware observer
-                h["kvpool_occupancy"] = round(
-                    pool.blocks_in_use() / cap, 4) if cap else 0.0
-                h["kvpool_evictable_blocks"] = pool.cached_blocks()
+            # the router's least-loaded dispatch reads this: live
+            # kvpool occupancy next to the queue depths, one cheap
+            # probe instead of a full stats()/metrics scrape
+            cap = pool.capacity_blocks
+            # blocks_in_use excludes cache-only blocks: a pool full
+            # of EVICTABLE prefix blocks reads as empty to the
+            # dispatch score (those blocks are reclaimable capacity
+            # that doubles as cache value), with the evictable
+            # count alongside for the affinity-aware observer
+            h["kvpool_occupancy"] = round(
+                pool.blocks_in_use() / cap, 4) if cap else 0.0
+            h["kvpool_evictable_blocks"] = pool.cached_blocks()
         return h
 
     def reload_weights(self, path, timeout=120.0):
@@ -1356,7 +1354,7 @@ class Client:
         the prompt on this (prefill) replica and return the serialized
         KV payload — ``first_token``/``prompt_tokens`` plus the slot's
         block arrays — ready to pass to another replica's
-        :meth:`generate` as ``kv=``. Requires the server's paged pool."""
+        :meth:`generate` as ``kv=``."""
         msg = {
             "op": "prefill",
             "tokens": np.asarray(tokens, dtype=np.int32).ravel(),
@@ -1452,7 +1450,7 @@ class Client:
     def health(self, timeout=_UNSET):
         """The server's lifecycle/liveness snapshot (state, queue
         depths, loop heartbeats + restarts, weights_version, kvpool
-        occupancy when paged). ``timeout`` (seconds) overrides the
+        occupancy). ``timeout`` (seconds) overrides the
         client's socket default for this one call — the router's
         health probes pass ``FLAGS_router_probe_timeout_s`` so a hung
         replica (stalled accept loop included) fails the probe fast

@@ -111,9 +111,10 @@ def test_prefill_logits_parity_across_buckets(tiny_gen):
 # ---------------------------------------------------------------------------
 
 def test_kv_cache_write_position_invariants(tiny_gen):
-    """A decode step must change each row's caches ONLY at that row's
-    own position (vmapped dynamic_update_slice), and cache shapes must
-    stay [B, H, max_len, D] throughout."""
+    """A paged decode step (``decode_paged_fp32``) must change each
+    row's keys and values ONLY at that row's own position — the block
+    its table names for it, at the offset inside — and nowhere else in
+    the pool; prefill row caches stay [B, H, max_len, D]."""
     cfg, _, gen = tiny_gen
     import jax
     key = jax.random.PRNGKey(1)
@@ -126,22 +127,31 @@ def test_kv_cache_write_position_invariants(tiny_gen):
                               (2, bucket)).copy()
     last = np.array([4, 8], np.int32)
     _, caches, key = gen._run_prefill(toks, pos_ids, last, key)
-    before = {n: np.asarray(a) for n, a in caches.items()}
+    d_head = cfg.hidden_size // cfg.num_heads
+    for a in caches.values():
+        assert a.shape == (2, cfg.num_heads, gen.max_len, d_head)
 
+    pool = gen.new_pool(2, dtype="fp32", block_size=4, name="invariants")
     pos = np.array([5, 9], np.int32)          # per-row write positions
     tok = np.array([3, 4], np.int32)
-    _, caches2, _ = gen._run_decode(tok, pos, caches, key)
-    d_head = cfg.hidden_size // cfg.num_heads
-    for i in range(cfg.num_layers):
-        for kind in ("k", "v"):
-            a = before[f"cache_{kind}_{i}"]
-            b = np.asarray(caches2[f"cache_{kind}_{i}"])
-            assert b.shape == (2, cfg.num_heads, gen.max_len, d_head)
-            changed = np.any(a != b, axis=(1, 3))          # [B, max_len]
-            for r, p in enumerate(pos):
-                assert changed[r, p], (i, kind, r)
-                others = np.delete(changed[r], p)
-                assert not others.any(), (i, kind, r)
+    for r, p in enumerate(prompts):
+        pool.alloc(r, int(p.size))
+    pool.scatter_prefill([0, 1], caches, bucket,
+                         lengths=[int(p.size) for p in prompts])
+    for r in range(2):
+        pool.ensure(r, int(pos[r]))
+    names = list(pool.arrays())
+    before = {n: pool.logical(n) for n in names}
+    gen._run_decode_paged(tok, pos, pool, key)
+    assert len(names) == 2 * cfg.num_layers
+    for n in names:
+        b = pool.logical(n)                   # [blocks, H, block, D]
+        assert b.shape == before[n].shape
+        changed = np.any(before[n] != b, axis=(1, 3))   # [blocks, block]
+        want = np.zeros_like(changed)
+        for r, p in enumerate(pos):
+            want[pool.tables[r, p // 4], p % 4] = True
+        np.testing.assert_array_equal(changed, want, err_msg=n)
 
 
 def test_generate_rejects_overlong_prompt(tiny_gen):

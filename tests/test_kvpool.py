@@ -1,6 +1,6 @@
 """Paged KV-cache subsystem (serving/kvpool + kernels/paged_attention +
 the paged decode wiring): free-list allocator invariants (alloc/free/
-exhaustion/leak sweep), paged==dense bitwise greedy parity offline and
+exhaustion/leak sweep), paged==naive bitwise greedy parity offline and
 through the serving decode bank with slot reuse, block frees on
 EOS/deadline/cancel (pool returns to empty), typed KVPoolExhaustedError
 backpressure at the door / admission / mid-decode, bf16+int8
@@ -60,12 +60,10 @@ def _prompts(cfg, lens, seed=3):
 
 @pytest.fixture
 def paged_flags():
-    """Route serving through the paged pool for one test; always
-    restored (the dense bank stays the suite-wide default)."""
+    """Restore the pool's flags after a test that sets them."""
     from paddle_tpu.flags import set_flags
-    set_flags({"kv_paged": True})
     yield
-    set_flags({"kv_paged": False, "kv_cache_dtype": "fp32",
+    set_flags({"kv_cache_dtype": "fp32",
                "kv_pool_blocks": 0, "kv_block_size": 16})
 
 
@@ -332,41 +330,42 @@ def test_paged_attention_input_validation():
 # ---------------------------------------------------------------------------
 
 def test_paged_generate_bitwise_greedy_parity(tiny_gen):
-    """generate(paged=True) over the block pool must be token-for-token
-    identical to the dense-bank fast path (itself gated against naive
-    full recompute in test_decode.py), across ragged lengths."""
+    """generate() over the fp32 block pool must be token-for-token
+    identical to naive full recompute, across ragged lengths, with the
+    pool's dtype named (test_decode.py: left to ``FLAGS_kv_cache_dtype``)."""
     cfg, _, gen = tiny_gen
     prompts = _prompts(cfg, (5, 9, 12))
-    dense = gen.generate(prompts, max_new_tokens=14, seed=0)
-    paged = gen.generate(prompts, max_new_tokens=14, seed=0, paged=True)
-    for a, b in zip(dense, paged):
+    naive = gen.generate_naive(prompts, max_new_tokens=14, seed=0)
+    paged = gen.generate(prompts, max_new_tokens=14, seed=0,
+                         kv_dtype="fp32")
+    for a, b in zip(naive, paged):
         np.testing.assert_array_equal(a, b)
         assert b.dtype == np.int32
 
 
 def test_quantized_cache_greedy_quality_gate(tiny_gen):
     """bf16/int8 pools generate full-length outputs whose greedy tokens
-    stay in high agreement with the fp32 dense reference (cache
+    stay in high agreement with the full-recompute reference (cache
     quantization perturbs logits but must not derail generation)."""
     cfg, _, gen = tiny_gen
     prompts = _prompts(cfg, (5, 9, 12))
-    dense = gen.generate(prompts, max_new_tokens=14, seed=0)
+    naive = gen.generate_naive(prompts, max_new_tokens=14, seed=0)
     for kv_dtype, floor in (("bf16", 0.9), ("int8", 0.75)):
         outs = gen.generate(prompts, max_new_tokens=14, seed=0,
-                            paged=True, kv_dtype=kv_dtype)
+                            kv_dtype=kv_dtype)
         agree = []
-        for ref, out in zip(dense, outs):
+        for ref, out in zip(naive, outs):
             assert out.shape == ref.shape and out.dtype == np.int32
             agree.append(float(np.mean(out == ref)))
         assert np.mean(agree) >= floor, (kv_dtype, agree)
 
 
 def test_offline_paged_pool_is_transient(tiny_gen):
-    """The offline paged loop frees its pool on the way out — the
+    """The offline loop frees its pool on the way out — the
     'offline' gauge series reads 0 blocks in use after generate()."""
     from paddle_tpu.serving.kvpool import _BLOCKS_IN_USE
     cfg, _, gen = tiny_gen
-    gen.generate(_prompts(cfg, (6,)), max_new_tokens=4, paged=True)
+    gen.generate(_prompts(cfg, (6,)), max_new_tokens=4)
     assert _BLOCKS_IN_USE.value(labels=("offline",)) == 0
 
 
@@ -379,8 +378,8 @@ def test_chaos_kv_alloc_point_offline(tiny_gen, fault_points):
     prompts = _prompts(cfg, (6,))
     with chaos("serving.kv_alloc", times=1):
         with pytest.raises(FaultInjected):
-            gen.generate(prompts, max_new_tokens=4, paged=True)
-    out = gen.generate(prompts, max_new_tokens=4, paged=True)
+            gen.generate(prompts, max_new_tokens=4)
+    out = gen.generate(prompts, max_new_tokens=4)
     assert out[0].shape == (4,)
 
 
@@ -388,17 +387,17 @@ def test_chaos_kv_alloc_point_offline(tiny_gen, fault_points):
 # serving: parity through the decode bank, frees, typed shed
 # ---------------------------------------------------------------------------
 
-def test_serving_paged_parity_slot_reuse_and_drain(tiny_gen,
-                                                   paged_flags):
+def test_serving_paged_parity_slot_reuse_and_drain(tiny_gen):
     """More requests than slots through the paged decode bank: every
-    request matches the dense greedy reference (slot reuse re-routes a
+    request matches the full-recompute greedy reference (slot reuse re-routes a
     fresh row's blocks through a just-freed slot's table row), stats
     surface kvpool_*, and the pool returns to EMPTY when all rows
     finished — the free-on-EOS invariant after a soak."""
     cfg, _, gen = tiny_gen
     prompts = _prompts(cfg, (5, 9, 12, 7, 4), seed=17)
-    ref = gen.generate(prompts, max_new_tokens=9, seed=0)
+    ref = gen.generate_naive(prompts, max_new_tokens=9, seed=0)
 
+    # no keyword, no flag: a server serves through a pool
     server = serving.InferenceServer(generator=gen, decode_slots=2)
     server.start(serve_network=False)
     try:
@@ -409,6 +408,7 @@ def test_serving_paged_parity_slot_reuse_and_drain(tiny_gen,
         for got, want in zip(outs, ref):
             np.testing.assert_array_equal(got, want)
         st = server.stats()
+        assert "decode_paged_fp32" in st["pool_relayouts"]
         assert st["kvpool_blocks_in_use"] == 0       # pool drained
         assert st["kvpool_capacity_blocks"] > 0
         assert st["decode_free_slots"] == 2
@@ -418,7 +418,7 @@ def test_serving_paged_parity_slot_reuse_and_drain(tiny_gen,
         server.stop()
 
 
-def test_paged_deadline_and_cancel_free_blocks(tiny_gen, paged_flags):
+def test_paged_deadline_and_cancel_free_blocks(tiny_gen):
     """A row that dies mid-generation (token-level deadline, client
     cancel) returns its blocks immediately — driven synchronously so
     the expiry point is deterministic."""
@@ -428,7 +428,7 @@ def test_paged_deadline_and_cancel_free_blocks(tiny_gen, paged_flags):
                                              RequestCancelledError,
                                              RequestQueue)
     cfg, _, gen = tiny_gen
-    engine = serving.GenerationEngine(gen, slots=2, paged=True)
+    engine = serving.GenerationEngine(gen, slots=2)
     batcher = DecodeBatcher(RequestQueue(max_depth=8), engine)
     pool = engine.pool
 
@@ -454,7 +454,7 @@ def test_paged_deadline_and_cancel_free_blocks(tiny_gen, paged_flags):
         req2.wait(timeout=0.1)
 
 
-def test_pool_exhaustion_typed_shed_and_recovery(tiny_gen, paged_flags):
+def test_pool_exhaustion_typed_shed_and_recovery(tiny_gen):
     """A request whose blocks are not free RIGHT NOW is shed typed at
     admission (KVPoolExhaustedError is ServerOverloadedError: the
     client backs off), the rows already decoding are untouched, and the
@@ -465,7 +465,7 @@ def test_pool_exhaustion_typed_shed_and_recovery(tiny_gen, paged_flags):
     cfg, _, gen = tiny_gen
     # 5 allocatable blocks of 8 tokens: one 32-token prompt (4 blocks
     # + 1 decode-growth block) fills the pool exactly
-    engine = serving.GenerationEngine(gen, slots=2, paged=True,
+    engine = serving.GenerationEngine(gen, slots=2,
                                       kv_block_size=8, kv_pool_blocks=6)
     batcher = DecodeBatcher(RequestQueue(max_depth=8), engine)
     big = GenerationRequest(_prompts(cfg, (32,), seed=5)[0],
@@ -491,14 +491,14 @@ def test_pool_exhaustion_typed_shed_and_recovery(tiny_gen, paged_flags):
     assert retry.slot is not None
 
 
-def test_exhaustion_flight_recorded(tiny_gen, paged_flags):
+def test_exhaustion_flight_recorded(tiny_gen):
     """Shed admissions leave a kv_pool_exhausted event in the flight
     recorder (+ the kvpool_alloc_failures_total counter) so debug_dump
     explains them."""
     from paddle_tpu.observability.recorder import flight_recorder
     from paddle_tpu.serving.kvpool import _ALLOC_FAIL
     cfg, _, gen = tiny_gen
-    engine = serving.GenerationEngine(gen, slots=2, paged=True,
+    engine = serving.GenerationEngine(gen, slots=2,
                                       kv_block_size=8, kv_pool_blocks=6)
     fails0 = _ALLOC_FAIL.value(labels=("serving",))
     with pytest.raises(KVPoolExhaustedError):
@@ -540,7 +540,7 @@ def test_overlong_prompt_rejected_at_door_over_wire(tiny_gen):
 
 def test_never_fitting_request_rejected_at_door_paged(tiny_gen,
                                                       paged_flags):
-    """Paged mode adds the pool-capacity door check: a request bigger
+    """The pool-capacity door check: a request bigger
     than the WHOLE pool is refused as a terminal BadRequest at submit
     (retry can never help at this pool size) — distinct from the
     transient wait-and-retry Overloaded shed."""
@@ -877,7 +877,7 @@ def test_generator_counts_relayouts_by_program_kind():
         exe.run(startup)
     gen = GPTGenerator(cfg, scope, max_len=32)
     out = gen.generate(np.array([[1, 2, 3]], np.int32), max_new_tokens=3,
-                       paged=True, kv_dtype="bf16")
+                       kv_dtype="bf16")
     assert np.asarray(out).shape[-1] >= 3
     assert list(gen.pool_relayouts) == ["decode_paged_bf16"]
     assert isinstance(gen.pool_relayouts["decode_paged_bf16"], int)
@@ -896,12 +896,8 @@ def test_donated_caches_come_back_under_the_names_they_went_in_by():
     fetch = ["logits"] + [f"v{i}" for i in range(24)] + ["aux"]
     place = GPTGenerator._cache_places(outs, feeds, fetch)
     assert place == {n: 1 + i for i, n in enumerate(names)}
-    # the dense bank: all k then all v, as _fetch_names lists them
+    # a prefill hands row caches back and is fed none: nothing to pair
     outs = {"cache_k": [0] * 3, "cache_v": [0] * 3}
-    dense = [f"cache_{k}_{i}" for k in "kv" for i in range(3)]
-    place = GPTGenerator._cache_places(outs, ["token", "pos"] + dense,
-                                       ["logits"] + dense)
-    assert place == {n: 1 + i for i, n in enumerate(dense)}
-    # a prefill is fed no cache: nothing to pair
+    rows = [f"cache_{k}_{i}" for k in "kv" for i in range(3)]
     assert GPTGenerator._cache_places(
-        outs, ["tokens", "pos_ids", "last_pos"], ["logits"] + dense) == {}
+        outs, ["tokens", "pos_ids", "last_pos"], ["logits"] + rows) == {}

@@ -45,8 +45,7 @@ def served():
     with fluid.scope_guard(scope):
         fluid.Executor().run(startup)
     gen = GPTGenerator(cfg, scope, max_len=32, bucket_min=8)
-    server = serving.InferenceServer(generator=gen, decode_slots=2,
-                                     kv_paged=True)
+    server = serving.InferenceServer(generator=gen, decode_slots=2)
     server.start(serve_network=False)
     t0 = time.perf_counter()
     try:

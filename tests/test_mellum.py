@@ -159,8 +159,7 @@ def test_inference_server_serves_it_past_the_window():
     import time
     cfg, gen, params = _generator("float32")
     prompts = [_tokens(1, n, seed=n)[0] for n in (21, 9, 30, 14)]
-    server = serving.InferenceServer(generator=gen, kv_paged=True,
-                                     decode_slots=2)
+    server = serving.InferenceServer(generator=gen, decode_slots=2)
     t0 = time.perf_counter()
     server.start(serve_network=False)
     try:
@@ -194,18 +193,14 @@ def test_inference_server_serves_it_past_the_window():
 def test_the_paths_it_is_not_built_for_refuse_by_name():
     cfg, gen, _ = _generator("float32")
     prompt = _tokens(1, 6)[0]
-    with pytest.raises(generation.UnsupportedPathError, match="dense KV bank"):
-        gen.generate([prompt], max_new_tokens=2, paged=False)
     with pytest.raises(generation.UnsupportedPathError,
                        match="speculative verify"):
-        gen.generate([prompt], max_new_tokens=2, paged=True, spec_k=2)
+        gen.generate([prompt], max_new_tokens=2, spec_k=2)
     with pytest.raises(generation.UnsupportedPathError, match="tp > 1"):
         GPTGenerator(cfg, fluid.Scope(), max_len=32, tp=2)
     with pytest.raises(generation.UnsupportedPathError, match="int8 KV pool"):
         gen.new_pool(2, dtype="int8")
-    with pytest.raises(generation.UnsupportedPathError, match="dense KV bank"):
-        serving.GenerationEngine(gen, slots=2, paged=False)
-    engine = serving.GenerationEngine(gen, slots=2, paged=True)
+    engine = serving.GenerationEngine(gen, slots=2)
     with pytest.raises(generation.UnsupportedPathError,
                        match="chunked prefill"):
         set_flags({"prefill_chunk_tokens": 4})

@@ -106,7 +106,7 @@ def test_a_round_admits_only_what_its_prefill_fits(monkeypatch):
     with fluid.scope_guard(scope):
         exe.run(startup)
     gen = GPTGenerator(cfg, scope, max_len=48)
-    engine = serving.GenerationEngine(gen, slots=8, paged=True)
+    engine = serving.GenerationEngine(gen, slots=8)
     one = engine.prefill_bytes([5])
     # GPT-2's count: the dense float32 caches twice over and the logits
     assert one == 2 * 2 * cfg.num_layers * cfg.hidden_size * 48 * 4 \

@@ -52,8 +52,8 @@ def _mksrv(tiny_gpt, name, **kw):
     cfg, scope = tiny_gpt
     kw.setdefault("decode_slots", 2)
     gen = GPTGenerator(cfg, scope, max_len=48, bucket_min=8)
-    return InferenceServer(generator=gen, kv_paged=True,
-                          kv_pool_name=name, **kw).start()
+    return InferenceServer(generator=gen, kv_pool_name=name,
+                           **kw).start()
 
 
 def _prompt(cfg, n=4, seed=3):

@@ -568,7 +568,7 @@ def test_zoo_programs_verify_clean():
                  if hasattr(v, "name")][:1]))
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
-        dec = gpt.gpt_decode_step(gcfg, 16, batch_size=2)
+        dec = gpt.gpt_decode_step_paged(gcfg, batch_size=2)
     zoo.append(("gpt-decode", main,
                 [v.name for v in dec.values()
                  if hasattr(v, "name")][:1]))

@@ -55,7 +55,7 @@ def _mkgen(tiny_gpt, max_len=48):
 
 def _mksrv(tiny_gpt, name, **kw):
     kw.setdefault("decode_slots", 2)
-    return InferenceServer(generator=_mkgen(tiny_gpt), kv_paged=True,
+    return InferenceServer(generator=_mkgen(tiny_gpt),
                            kv_pool_name=name, **kw).start()
 
 
@@ -195,17 +195,17 @@ def test_disaggregated_split_matches_colocated_bitwise(tiny_gpt):
         dec.stop()
 
 
-def test_prefill_requires_paged_pool(tiny_gpt):
-    """The dense bank has no migratable unit: the prefill wire op is
-    refused typed at the door."""
-    srv = InferenceServer(generator=_mkgen(tiny_gpt), decode_slots=2,
-                          kv_paged=False).start()
-    try:
-        with Client(srv.endpoint) as c:
-            with pytest.raises(BadRequestError):
-                c.prefill(_prompts(tiny_gpt[0], (6,))[0])
-    finally:
-        srv.stop()
+def test_dense_bank_keyword_is_refused(tiny_gpt):
+    """The paged pool is the one KV store: ``kv_paged=False`` is refused
+    at construction, by name; the kept keyword's other values do
+    nothing."""
+    with pytest.raises(ValueError, match="kv_paged=False"):
+        InferenceServer(generator=_mkgen(tiny_gpt), decode_slots=2,
+                        kv_paged=False)
+    for kept in (None, True):
+        srv = InferenceServer(generator=_mkgen(tiny_gpt), decode_slots=2,
+                              kv_paged=kept)
+        assert srv.gen_engine.pool is not None
 
 
 # ------------------------------------------------------- router tier

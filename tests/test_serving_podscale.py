@@ -74,22 +74,20 @@ def _run_bank(engine, prompts, n_new=6):
 
 def test_tp_generate_bitwise_parity(tiny_gpt, podscale_flags):
     """tp=2 sharded generation (conftest's virtual 8-device mesh) is
-    bitwise identical to single-chip greedy decode, dense AND paged —
-    tensor parallelism is a throughput lever, never a numerics one."""
+    bitwise identical to single-chip greedy decode, which is naive
+    full recompute's token for token — tensor parallelism is a
+    throughput lever, never a numerics one."""
     cfg, scope = tiny_gpt
     prompts = _prompts(cfg, [11, 7])
     gen1 = GPTGenerator(cfg, scope, max_len=48, bucket_min=8, tp=1)
-    ref_dense = gen1.generate(prompts, max_new_tokens=8, seed=0,
-                              paged=False)
-    ref_paged = gen1.generate(prompts, max_new_tokens=8, seed=0,
-                              paged=True)
+    ref_naive = gen1.generate_naive(prompts, max_new_tokens=8, seed=0)
+    ref_paged = gen1.generate(prompts, max_new_tokens=8, seed=0)
     gen2 = GPTGenerator(cfg, scope, max_len=48, bucket_min=8, tp=2)
     assert gen2.mesh is not None
-    tp_dense = gen2.generate(prompts, max_new_tokens=8, seed=0,
-                             paged=False)
-    tp_paged = gen2.generate(prompts, max_new_tokens=8, seed=0,
-                             paged=True)
-    for a, b in zip(ref_dense + ref_paged, tp_dense + tp_paged):
+    tp_naive = gen2.generate_naive(prompts, max_new_tokens=8, seed=0)
+    tp_paged = gen2.generate(prompts, max_new_tokens=8, seed=0)
+    for a, b in zip(ref_naive + ref_paged + ref_naive,
+                    tp_naive + tp_paged + tp_paged):
         np.testing.assert_array_equal(a, b)
 
 
@@ -112,8 +110,7 @@ def test_tp_compile_gate_refuses_replicated_build(tiny_gpt,
                         lambda self, kind, main: None)
     bad = GPTGenerator(cfg, scope, max_len=48, bucket_min=8, tp=2)
     with pytest.raises(TPCompileGateError, match="replicated large"):
-        bad.generate(_prompts(cfg, [8]), max_new_tokens=2, seed=0,
-                     paged=False)
+        bad.generate(_prompts(cfg, [8]), max_new_tokens=2, seed=0)
 
 
 # ---------------------------------------------------------------------------
@@ -129,13 +126,13 @@ def test_chunked_prefill_matches_monolithic(tiny_gpt, podscale_flags):
     cfg, scope = tiny_gpt
     gen = GPTGenerator(cfg, scope, max_len=48, bucket_min=8)
     prompts = _prompts(cfg, [11, 7, 13], seed=1)
-    eng_a = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng_a = serving.GenerationEngine(gen, slots=4,
                                      pool_name="pod_mono")
     base = _run_bank(eng_a, prompts)
     assert eng_a.pool.blocks_in_use() == 0
 
     set_flags({"FLAGS_prefill_chunk_tokens": 4})
-    eng_b = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng_b = serving.GenerationEngine(gen, slots=4,
                                      pool_name="pod_chunk",
                                      prefix_cache=True)
     assert eng_b.incremental_prefill_enabled()
@@ -155,7 +152,7 @@ def test_chunked_prefill_matches_monolithic(tiny_gpt, podscale_flags):
     # prefix-only incremental mode (chunk flag 0): one whole-prompt
     # chunk after the cached prefix — same outputs
     set_flags({"FLAGS_prefill_chunk_tokens": 0})
-    eng_c = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng_c = serving.GenerationEngine(gen, slots=4,
                                      pool_name="pod_pfx",
                                      prefix_cache=True)
     assert eng_c.incremental_prefill_enabled()
@@ -180,13 +177,13 @@ def test_cow_divergence_keeps_shared_prefix_bitwise(tiny_gpt,
     pB = np.concatenate(
         [head, rng.integers(1, cfg.vocab_size, 5).astype(np.int32)])
 
-    eng_ref = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng_ref = serving.GenerationEngine(gen, slots=4,
                                        kv_block_size=4,
                                        pool_name="pod_cowref")
     ref = _run_bank(eng_ref, [pA]) + _run_bank(eng_ref, [pB])
 
     set_flags({"FLAGS_prefill_chunk_tokens": 4})
-    eng = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng = serving.GenerationEngine(gen, slots=4,
                                    kv_block_size=4, pool_name="pod_cow",
                                    prefix_cache=True)
     outA = _run_bank(eng, [pA])      # inserts exact-11 + aligned-8
@@ -291,7 +288,7 @@ def test_router_prefix_affinity(tiny_gpt, podscale_flags):
 
     def mksrv(name):
         g = GPTGenerator(cfg, scope, max_len=48, bucket_min=8)
-        return InferenceServer(generator=g, kv_paged=True,
+        return InferenceServer(generator=g,
                                decode_slots=2,
                                kv_pool_name=name).start()
 

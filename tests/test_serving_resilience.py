@@ -294,10 +294,10 @@ def test_drain_waits_for_what_a_capped_round_left_in_the_queue(monkeypatch):
     cfg, _, _, _, gen = _tiny_gpt()
     prompts = [RNG.integers(1, cfg.vocab_size, n).astype(np.int32)
                for n in (5, 9, 7, 6)]
-    ref = [gen.generate([p], max_new_tokens=1, seed=0, paged=True)[0]
+    ref = [gen.generate([p], max_new_tokens=1, seed=0)[0]
            for p in prompts]
     monkeypatch.setattr(engine_mod, "_free_device_bytes", lambda: 1)
-    server = InferenceServer(generator=gen, decode_slots=4, kv_paged=True)
+    server = InferenceServer(generator=gen, decode_slots=4)
     server.start(serve_network=False)
     rows = []
     real = server.gen_engine.admit

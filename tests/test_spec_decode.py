@@ -1,6 +1,6 @@
 """Speculative decoding on the paged KV bank: rejection sampling
 preserves the output distribution exactly (op-level marginal check +
-bitwise greedy parity spec-on vs spec-off, dense AND paged AND tp=2),
+bitwise greedy parity spec-on vs spec-off vs full recompute, and tp=2),
 multi-token block-pool appends stay COW/refcount-correct under
 prefix-cache sharing (a 256-verify-step sweep with partial rejections
 leaks zero blocks), and the draft depth behaves as a load knob (the
@@ -44,7 +44,7 @@ def tiny_gpt():
 def spec_flags():
     """Flags this file mutates, always restored — plus the ambient mesh
     (GPTGenerator(tp=2) installs one globally)."""
-    keys = ("decode_spec_k", "decode_spec_mode", "kv_paged",
+    keys = ("decode_spec_k", "decode_spec_mode",
             "kv_prefix_cache", "prefill_chunk_tokens")
     saved = {k: flag(k) for k in keys}
     prev_mesh = get_mesh()
@@ -126,21 +126,19 @@ def test_spec_accept_greedy_semantics(tiny_gpt):
 # end-to-end parity (offline generator)
 # ---------------------------------------------------------------------------
 
-def test_spec_greedy_bitwise_parity_dense_and_paged(tiny_gpt,
-                                                    spec_flags):
+def test_spec_greedy_bitwise_parity_with_full_recompute(tiny_gpt,
+                                                        spec_flags):
     """Greedy generation with speculation on is BITWISE the
-    non-speculative output on both backends — for high-acceptance
-    (repetitive) and low-acceptance (random) prompts alike."""
+    non-speculative output, and both are naive full recompute's token
+    for token — for high-acceptance (repetitive) and low-acceptance
+    (random) prompts alike."""
     cfg, _scope, gen = tiny_gpt
     prompts = [_repetitive_prompt(12)] + _prompts(cfg, [9, 7])
-    for paged in (False, True):
-        ref = gen.generate(prompts, max_new_tokens=10, seed=0,
-                           paged=paged, spec_k=0)
-        for k in (2, 4):
-            spec = gen.generate(prompts, max_new_tokens=10, seed=0,
-                                paged=paged, spec_k=k)
-            for a, b in zip(ref, spec):
-                np.testing.assert_array_equal(a, b)
+    ref = gen.generate_naive(prompts, max_new_tokens=10, seed=0)
+    for k in (0, 2, 4):
+        spec = gen.generate(prompts, max_new_tokens=10, seed=0, spec_k=k)
+        for a, b in zip(ref, spec):
+            np.testing.assert_array_equal(a, b)
 
 
 def test_spec_greedy_parity_tp2(tiny_gpt, spec_flags):
@@ -149,33 +147,27 @@ def test_spec_greedy_parity_tp2(tiny_gpt, spec_flags):
     paged pool — the verify program shards like prefill."""
     cfg, scope, gen = tiny_gpt
     prompts = [_repetitive_prompt(11), _prompts(cfg, [8])[0]]
-    ref = gen.generate(prompts, max_new_tokens=8, seed=0, paged=True,
-                       spec_k=0)
+    ref = gen.generate(prompts, max_new_tokens=8, seed=0, spec_k=0)
     gen2 = GPTGenerator(cfg, scope, max_len=48, bucket_min=8, tp=2)
     assert gen2.mesh is not None
-    spec = gen2.generate(prompts, max_new_tokens=8, seed=0, paged=True,
-                         spec_k=4)
+    spec = gen2.generate(prompts, max_new_tokens=8, seed=0, spec_k=4)
     for a, b in zip(ref, spec):
         np.testing.assert_array_equal(a, b)
 
 
 def test_spec_stochastic_seeded_equivalence(tiny_gpt, spec_flags):
     """Seeded stochastic speculative sampling is reproducible call-over
-    -call and backend-agnostic (dense == paged): the whole span's
-    randomness comes from the one program-invocation key chain."""
+    -call: the whole span's randomness comes from the one
+    program-invocation key chain."""
     cfg, _scope, gen = tiny_gpt
     prompts = [_repetitive_prompt(10)] + _prompts(cfg, [8])
-    outs = {}
-    for paged in (False, True):
-        a = gen.generate(prompts, max_new_tokens=8, temperature=0.9,
-                         top_k=8, seed=11, paged=paged, spec_k=4)
-        b = gen.generate(prompts, max_new_tokens=8, temperature=0.9,
-                         top_k=8, seed=11, paged=paged, spec_k=4)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(x, y)
-        outs[paged] = a
-    for x, y in zip(outs[False], outs[True]):
+    a = gen.generate(prompts, max_new_tokens=8, temperature=0.9,
+                     top_k=8, seed=11, spec_k=4)
+    b = gen.generate(prompts, max_new_tokens=8, temperature=0.9,
+                     top_k=8, seed=11, spec_k=4)
+    for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
+        assert x.shape == (8,)
 
 
 def test_ngram_drafter_and_registry():
@@ -217,14 +209,14 @@ def test_spec_cow_fires_before_speculative_write_on_shared_blocks(
     cached prompt replays bitwise afterwards and nothing leaks."""
     cfg, _scope, gen = tiny_gpt
     prompt = _repetitive_prompt(11)       # odd length: unaligned tail
-    eng_ref = serving.GenerationEngine(gen, slots=2, paged=True,
+    eng_ref = serving.GenerationEngine(gen, slots=2,
                                        kv_block_size=4,
                                        pool_name="spec_cowref")
     ref, _ = _run_spec_bank(
         eng_ref, [GenerationRequest(prompt, max_new_tokens=8)], spec_k=0)
 
     set_flags({"FLAGS_prefill_chunk_tokens": 0})
-    eng = serving.GenerationEngine(gen, slots=2, paged=True,
+    eng = serving.GenerationEngine(gen, slots=2,
                                    kv_block_size=4,
                                    pool_name="spec_cow",
                                    prefix_cache=True)
@@ -250,7 +242,7 @@ def test_spec_partial_rejection_leaks_zero_blocks_256_steps(tiny_gpt,
     sweeper finds nothing."""
     cfg, _scope, gen = tiny_gpt
     st = ServingStats()
-    eng = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng = serving.GenerationEngine(gen, slots=4,
                                    kv_block_size=4,
                                    pool_name="spec_sweep",
                                    prefix_cache=True, stats=st)
@@ -285,7 +277,7 @@ def test_spec_server_stats_health_and_flight_events(tiny_gpt,
     cfg, scope, _gen = tiny_gpt
     prompt = _repetitive_prompt(10)
 
-    set_flags({"FLAGS_kv_paged": True, "FLAGS_decode_spec_k": 4})
+    set_flags({"FLAGS_decode_spec_k": 4})
     srv = serving.InferenceServer(
         generator=GPTGenerator(cfg, scope, max_len=48, bucket_min=8),
         decode_slots=2, kv_pool_name="spec_srv")
@@ -360,7 +352,7 @@ def test_brownout_shrinks_batch_drafting_keeps_interactive(tiny_gpt,
     breached = [1]
     bc = BrownoutController(lambda: breached[0], enabled=True,
                             escalate_s=60.0, recover_s=0.0)
-    eng = serving.GenerationEngine(gen, slots=4, paged=True,
+    eng = serving.GenerationEngine(gen, slots=4,
                                    pool_name="spec_bo")
     b = DecodeBatcher(RequestQueue(max_depth=8), eng, spec_k=4,
                       brownout=bc)
