@@ -687,9 +687,13 @@ def phase_serve(smoke):
         # the pool keeps one layout from parameter to result: XLA:TPU
         # left no pool-sized copy in the decode step or in the scatter
         # (the interpreter's loops on the CPU copy as they please)
+        # the decode step is keyed by its executable: the decode kind
+        # and the pick inside it (decode_paged_fp32+sample_greedy)
         relayouts = stats["pool_relayouts"]
+        decode_kinds = [k for k in relayouts
+                        if k.startswith(f"decode_paged_{pool.dtype}+")]
         assert sz.rehearsal or (
-            relayouts.get(f"decode_paged_{pool.dtype}") == 0
+            decode_kinds and all(relayouts[k] == 0 for k in decode_kinds)
             and relayouts.get("scatter") == 0), relayouts
         name, arr = next(iter(pool.arrays().items()))
         pool_format = f"{name} {arr.dtype.name}{list(arr.shape)} " \
@@ -707,6 +711,7 @@ def phase_serve(smoke):
            "blocks_in_use_after": leaked,
            "generator_compiles": int(stats.get("compiles", 0)),
            "decode_steps": int(stats.get("decode_steps", 0)),
+           "decode_steps_ahead": int(stats.get("decode_steps_ahead", 0)),
            "kv_cache_dtype": pool.dtype,
            "pool_relayouts": relayouts, "pool_format": pool_format,
            "smoke_timings_s": {"request_walls_incl_compile": [

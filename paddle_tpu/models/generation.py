@@ -20,6 +20,7 @@ token sequence bitwise.
 sampler, no cache ops) — the A/B half of ``bench.py --config decode``
 and the greedy-parity reference in tests.
 """
+import collections
 import threading
 import time
 
@@ -28,6 +29,7 @@ import numpy as np
 from ..flags import flag
 from ..observability import tracing as _trace
 from ..observability import utilization as _util
+from ..resilience import await_ready
 
 # fluid program construction mutates process-global state (the default
 # program pair swapped by ``program_guard`` plus the unique_name
@@ -114,6 +116,27 @@ def _greedy_program_outs():
     logits = T.data("logits", [-1, -1], dtype="float32")
     toks = T.cast(T.argmax(logits, axis=-1), "int32")
     return {"feed_names": ["logits"], "tokens": toks}
+
+
+def pick_for(temperature, top_k):
+    """``(kind, feed beside the logits)`` of the cheapest pick program
+    that covers a batch, from its host-side sampling vectors: argmax
+    when every row is greedy, the sort-free sampler when no row
+    restricts top-k, the full sampler otherwise. All three advance the
+    RNG key once and draw a sampled row's token from the same stream,
+    so which one runs changes no row's token."""
+    if np.all(np.asarray(temperature) <= 0.0):
+        return "sample_greedy", {}
+    if np.all(np.asarray(top_k) <= 0):
+        return "sample_temp", {"temperature": temperature}
+    return "sample", {"temperature": temperature, "top_k": top_k}
+
+
+# a compiled call that was sent and not waited for yet: its results
+# (device arrays that become ready when the device has run it), the
+# advanced RNG key, and what GPTGenerator._await accounts for it by
+_Sent = collections.namedtuple(
+    "_Sent", "kind stage fetches key sig compiled t0")
 
 
 def _spec_accept_program_outs():
@@ -438,7 +461,9 @@ class GPTGenerator:
         """Program for ``kind``, building the lazily-declared ones on
         first use (the paged decode step exists per KV-cache dtype —
         ``decode_paged_fp32|bf16|int8`` — and most processes never
-        touch them)."""
+        touch them). A decode step that picks its own token
+        (``<decode kind>+<pick kind>``) is its decode program."""
+        kind = kind.partition("+")[0]
         entry = self._progs.get(kind)
         if entry is not None:
             return entry
@@ -474,6 +499,7 @@ class GPTGenerator:
         if entry is not None:
             return entry
         import jax
+        import jax.numpy as jnp
         from ..framework.lowering import analyze_block_io, build_block_fn
 
         main, outs = self._ensure_prog(kind)
@@ -482,6 +508,21 @@ class GPTGenerator:
         state_in, _ = analyze_block_io(main, 0, feed_names)
         fn = build_block_fn(main, 0, feed_names, fetch_names, state_in, [],
                             mesh=self.mesh)
+        # ``<decode kind>+<pick kind>``: the decode step and the pick
+        # that followed it as a second call, in one executable. The two
+        # programs run as they did apart (the pick on the key the step
+        # advanced, the key advanced again), so a seeded request draws
+        # the tokens it drew from two calls; what comes back first is
+        # int32 [rows] tokens, not the logits. Its token input is the
+        # last step's result where it lies, but for the rows the host
+        # has a newer token for (``token_from_host``).
+        pick = kind.partition("+")[2]
+        if pick:
+            pick_main, pick_outs = self._ensure_prog(pick)
+            pick_feeds = list(pick_outs["feed_names"])
+            pick_fn = build_block_fn(
+                pick_main, 0, pick_feeds, [pick_outs["tokens"].name],
+                [], [], mesh=self.mesh)
 
         # only the decode step's KV caches are worth donating (XLA
         # aliases the cache append in place — no 2x cache traffic);
@@ -498,7 +539,16 @@ class GPTGenerator:
         def run(state, caches, feed, base_key):
             env = dict(feed)
             env.update(caches)
+            if pick:
+                env["token"] = jnp.where(env.pop("token_from_host"),
+                                         env["token"],
+                                         env.pop("token_prev"))
             fetches, _, new_key = fn({}, state, env, base_key)
+            if pick:
+                picked, _, new_key = pick_fn(
+                    {}, {}, dict({n: env[n] for n in pick_feeds[1:]},
+                                 logits=fetches[0]), new_key)
+                fetches = [picked[0]] + list(fetches[1:])
             return ([f for i, f in enumerate(fetches) if i not in at],
                     {n: fetches[i] for n, i in place.items()}, new_key)
 
@@ -587,10 +637,17 @@ class GPTGenerator:
             ((f"__program__/{kind}", (), "meta"),)
             + feed_signature(feed)))
 
-    def _invoke(self, kind, stage, feed, key, parent=None):
-        """Run the ``kind`` executable on ``feed``. ``parent`` is the
-        loop span that caused the call, for a caller on another thread
-        than the span's (the decode step under its watchdog)."""
+    def _invoke(self, kind, stage, feed, key):
+        """Run the ``kind`` executable on ``feed`` and wait for it."""
+        sent = self._dispatch(kind, stage, feed, key)
+        self._await(sent)
+        return sent.fetches, sent.key
+
+    def _dispatch(self, kind, stage, feed, key):
+        """Send the ``kind`` executable on ``feed`` and return at once
+        (a fresh signature compiles first): a :class:`_Sent` whose
+        results the device fills in. Whoever needs them ready, or
+        wants the call accounted for, hands it to :meth:`_await`."""
         import jax
         jitted, state = self._ensure_fn(kind)
         sig = self._signature(kind, feed)
@@ -640,23 +697,37 @@ class GPTGenerator:
                 self.stats.bump("compiles")
                 self.stats.hist["compile"].observe(dt)
         # two spans, so that a trace tells the host's dispatch from the
-        # wait for the device (the per-token loop is serial anyway —
-        # the next step needs this token); their two outer clock reads
-        # are the one interval every consumer below takes
-        with _trace.loop_span("generator/dispatch", parent, kind=kind,
-                              stage=stage, compiled=fresh) as sent:
+        # wait for the device; the first's start and the second's end
+        # are the one interval every consumer in _await takes
+        with _trace.loop_span("generator/dispatch", kind=kind,
+                              stage=stage, compiled=fresh) as sending:
             fetched, kept, new_key = compiled(state, caches, rest, key)
             fetches = self._unpack[kind](fetched, kept)
-        with _trace.loop_span("generator/wait", parent, kind=kind,
-                              stage=stage) as waited:
-            jax.block_until_ready(fetches)
+        return _Sent(kind, stage, fetches, new_key, sig, compiled,
+                     sending.t0)
+
+    def _await(self, sent, deadline=None, budget=None):
+        """Wait until the device has run ``sent`` and account for it
+        (stage histogram, MFU gauge) from its dispatch to now: with a
+        step dispatched ahead that is the latency a reader saw, not the
+        device's time. ``deadline`` (a ``perf_counter`` instant,
+        ``budget`` seconds after the caller began) bounds the wait:
+        past it, WatchdogTimeout, and no thread is spent on it."""
+        import jax
+        with _trace.loop_span("generator/wait", kind=sent.kind,
+                              stage=sent.stage) as waited:
+            if deadline is None:
+                jax.block_until_ready(sent.fetches)
+            else:
+                # an executable's results become ready together
+                await_ready(sent.fetches[0].is_ready, deadline, budget,
+                            f"serving {sent.stage} step")
         dt = waited.t1 - sent.t0
-        cost = _util.cost_for(self._exec_costs, sig, compiled)
+        cost = _util.cost_for(self._exec_costs, sent.sig, sent.compiled)
         if cost:
-            _util.observe_execution(stage, cost, dt)
+            _util.observe_execution(sent.stage, cost, dt)
         if self.stats:
-            self.stats.hist[stage].observe(dt)
-        return fetches, new_key
+            self.stats.hist[sent.stage].observe(dt)
 
     # -- stage runners ----------------------------------------------------
     def _unpack_caches(self, fetches):
@@ -783,23 +854,12 @@ class GPTGenerator:
         return fetches[0], key
 
     def _run_sample(self, logits, temperature, top_k, key):
-        # cheapest program that covers the batch: argmax when every row
-        # is greedy, sort-free sampler when no row restricts top-k,
-        # full sampler otherwise (all variants advance the RNG key once,
-        # so mixing them keeps the key chain aligned)
-        if np.all(np.asarray(temperature) <= 0.0):
-            fetches, key = self._invoke("sample_greedy", "sample",
-                                        {"logits": logits}, key)
-            return fetches[0], key
-        if np.all(np.asarray(top_k) <= 0):
-            feed = {"logits": logits, "temperature": temperature}
-            fetches, key = self._invoke("sample_temp", "sample", feed,
-                                        key)
-            return fetches[0], key
-        feed = {"logits": logits, "temperature": temperature,
-                "top_k": top_k}
-        fetches, key = self._invoke("sample", "sample", feed, key)
+        kind, feed = pick_for(temperature, top_k)
+        fetches, key = self._invoke(kind, "sample",
+                                    dict(feed, logits=logits), key)
         return fetches[0], key
+
+    pick_for = staticmethod(pick_for)
 
     # -- public API -------------------------------------------------------
     def _prep(self, prompts, max_new_tokens, seed, key):

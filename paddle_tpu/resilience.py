@@ -626,6 +626,24 @@ def run_with_watchdog(fn, budget_secs, *args, what=None, **kwargs):
     return box.get("result")
 
 
+def await_ready(ready, deadline, budget_secs, what, poll_s=1e-4):
+    """Wait on the calling thread until ``ready()`` is true, asking
+    every ``poll_s`` seconds; raise WatchdogTimeout once
+    ``time.perf_counter()`` passes ``deadline``. The watchdog of a wait
+    for a device result (``jax.Array.is_ready``): no thread, and a
+    stall that ate the budget before the wait began counts, because
+    the deadline is asked first."""
+    while True:
+        if time.perf_counter() > deadline:
+            _flightrec().record("watchdog", what=str(what),
+                                budget_s=float(budget_secs))
+            raise WatchdogTimeout(
+                f"{what} exceeded its {budget_secs}s wall-clock budget")
+        if ready():
+            return
+        time.sleep(poll_s)
+
+
 # --------------------------------------------------------------------------
 # fault injection (test hook)
 # --------------------------------------------------------------------------

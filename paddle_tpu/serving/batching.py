@@ -27,7 +27,7 @@ doomed micro-batch (``serving_expired_in_queue_total``).
 """
 import threading
 import time
-from collections import deque
+from collections import deque, namedtuple
 
 import numpy as np
 
@@ -572,6 +572,12 @@ class SwapHandle:
         return self.pause_ms
 
 
+# a decode step that was sent and whose tokens are unread: ``sent`` is
+# what GenerationEngine.collect_step reads, ``rows`` the {slot: request}
+# that took part, ``t_sent`` when its engine/step span began
+_Flight = namedtuple("_Flight", "sent rows t_sent")
+
+
 class DecodeBatcher:
     """Continuous batching over a fixed bank of decode slots
     (ORCA-style iteration-level scheduling): one thread pulls
@@ -580,7 +586,17 @@ class DecodeBatcher:
     between steps, finished rows (EOS / max_new_tokens / deadline) free
     their slot immediately for the next admission. Per-row state
     (position counter, current token, sampling config, done) lives
-    here; the device-side slot caches live in the GenerationEngine."""
+    here; the device-side slot caches live in the GenerationEngine.
+
+    The loop runs one step ahead of its own reading: a step picks its
+    tokens on the device and the next step takes them from there, so a
+    round sends step t + 1 and then reads and delivers step t while
+    the device runs t + 1. A row that ends at t on its ``eos_id``, a
+    deadline, a cancel or a shed is found one step late: its row of
+    t + 1 is computed and dropped. Rounds that cannot run ahead read
+    their step in place: a round with speculative drafts (the drafter
+    reads the newest tokens on the host) and a round in which a
+    chunked prefill advances."""
 
     def __init__(self, queue, engine, stats=None, watchdog_s=None,
                  spec_k=None, drafter=None, brownout=None):
@@ -616,7 +632,14 @@ class DecodeBatcher:
         self._free = list(range(self.slots))
         self._active = {}                       # slot -> request
         self._tok = np.zeros((self.slots,), np.int32)
+        # rows whose next token the host has and the device has not
+        # (admitted since the last step): they enter a step by _tok
+        self._from_host = np.ones((self.slots,), bool)
+        # the position the next step SENT writes: a plain step moves it
+        # on as it is sent, a speculative one as it is delivered
         self._pos = np.zeros((self.slots,), np.int32)
+        self._flight = None             # the step sent and not read yet
+        self._t_tokens = 0.0            # when the last step's were read
         self._temp = np.zeros((self.slots,), np.float32)
         self._topk = np.zeros((self.slots,), np.int32)
         # supervision handles: the loop stamps `heartbeat` every
@@ -724,6 +747,7 @@ class DecodeBatcher:
         self._prefilling = []
         self._free = list(range(self.slots))
         self._admitting = 0
+        self._flight = None     # its rows were failed above
         self.engine.reset()
         with self._swap_lock:
             sw, self._swap = self._swap, None
@@ -886,7 +910,10 @@ class DecodeBatcher:
         takes every ACTIVE row's caches with it — fail those rows too
         rather than letting them silently decode against a rebuilt zero
         bank."""
-        if getattr(self.engine, "bank_lost", False) and self._active:
+        if not getattr(self.engine, "bank_lost", False):
+            return
+        self._flight = None     # a step of a pool that is gone
+        if self._active:
             for req in list(self._active.values()):
                 self._finish(req, ServingError(
                     f"decode slot bank lost to an engine failure "
@@ -962,7 +989,8 @@ class DecodeBatcher:
             # block briefly only when the bank is idle and nothing was
             # taken yet; once rows are decoding, admission must not
             # stall the step loop
-            timeout = 0.05 if not (self._active or take) else 0
+            timeout = 0.05 if not (self._active or take
+                                   or self._flight) else 0
             req = self.queue.get(
                 timeout=timeout, accept=fits if fit and take else None)
             if req is None:
@@ -1102,13 +1130,19 @@ class DecodeBatcher:
                 if getattr(req, "export_kv", False):
                     self._finish_export(req, slot, int(tok))
                     continue
-                req.slot = slot
-                self._active[slot] = req
-                self._pos[slot] = req.prompt.size
-                self._temp[slot] = req.temperature
-                self._topk[slot] = req.top_k
-                self._tok[slot] = tok
-                self._deliver_token(req, int(tok))
+                self._join(req, slot, int(tok))
+
+    def _join(self, req, slot, tok):
+        """A prefilled (or imported) request joins the decode bank at
+        ``slot`` with its first token, which the host holds."""
+        req.slot = slot
+        self._active[slot] = req
+        self._pos[slot] = req.prompt.size
+        self._temp[slot] = req.temperature
+        self._topk[slot] = req.top_k
+        self._tok[slot] = tok
+        self._from_host[slot] = True
+        self._deliver_token(req, tok)
 
     def _fail_deposed(self, take):
         """The loop was restarted while this (now deposed) thread held
@@ -1171,13 +1205,7 @@ class DecodeBatcher:
         if getattr(req, "export_kv", False):
             self._finish_export(req, slot, int(tok))
             return
-        req.slot = slot
-        self._active[slot] = req
-        self._pos[slot] = req.prompt.size
-        self._temp[slot] = req.temperature
-        self._topk[slot] = req.top_k
-        self._tok[slot] = tok
-        self._deliver_token(req, int(tok))
+        self._join(req, slot, int(tok))
 
     def _finish_export(self, req, slot, tok):
         """Deliver a prefill-only request (disaggregated split): the
@@ -1269,6 +1297,7 @@ class DecodeBatcher:
             # state now) must not touch anything.
             if self._epoch == epoch:
                 self._admitting = 0
+                self._flight = None     # its rows fail here, unread
                 release = getattr(self.engine, "release_slot", None)
                 for slot, req in list(self._active.items()):
                     if not req.done():
@@ -1295,15 +1324,18 @@ class DecodeBatcher:
 
     def _round(self, epoch, attrs):
         """One iteration of the loop: admit, advance a chunked prefill,
-        step the bank, deliver. ``attrs`` are the ``serving/round``
-        span's: left empty, the iteration did nothing and is not
-        recorded. Returns False once the loop is deposed."""
+        send the bank's next step, read and deliver the one in flight.
+        ``attrs`` are the ``serving/round`` span's: left empty, the
+        iteration did nothing and is not recorded. Returns False once
+        the loop is deposed."""
         sw = self._swap
+        idle = not (self._active or self._prefilling or self._flight)
         if sw is not None:
             # a pending swap stops admission so the bank drains;
             # in-flight rows (decoding OR mid chunked-prefill)
-            # keep running on the old weights
-            if not self._active and not self._prefilling:
+            # keep running on the old weights, and a step still in
+            # flight is read (below) before the swap applies
+            if idle:
                 sw.apply()
                 with self._swap_lock:
                     if self._swap is sw:
@@ -1313,118 +1345,31 @@ class DecodeBatcher:
             admitted = self._admit(epoch)
             if admitted:
                 attrs["admitted"] = admitted
-        if not self._active and not self._prefilling:
+            idle = not (self._active or self._prefilling or self._flight)
+        if idle:
             return True
         self._check_deadlines(time.monotonic())
+        # what the loop holds decides whether this round may run ahead:
+        # a drafter reads the newest tokens on the host, and a chunked
+        # prefill advances against a pool no step is writing
+        in_place = self.spec_k > 0 or bool(self._prefilling)
+        if in_place and self._flight is not None:
+            if not self._step(epoch, attrs, {}, in_place):
+                return self._epoch == epoch
         if self._prefilling:
             attrs["prefilling"] = len(self._prefilling)
             self._advance_prefill(epoch)
         if self._epoch != epoch:
             return False
-        if not self._active:
+        if not self._active and self._flight is None:
             return True
-        # paged pool: allocation-on-append for the live rows;
-        # rows the pool cannot grow are shed TYPED while the
-        # rest of the bank keeps decoding (their freed blocks
-        # unblock the next step's growth)
-        # speculative rows draft BEFORE the allocation pass so
-        # the whole verify span [pos, pos + nd + 1) is covered
-        # by blocks (and COW-duplicated when shared) up front
-        drafts = nd = None
-        if self.spec_k > 0 and self._active:
-            drafts, nd = self._propose_drafts(self.spec_k)
-        prep = getattr(self.engine, "prepare_step", None)
-        if prep is not None:
-            with _trace.loop_span("serving/prepare_step") as prepared:
-                widths = None
-                if nd is not None:
-                    widths = {slot: int(nd[slot]) + 1
-                              for slot in self._active}
-                shed = prep({slot: int(self._pos[slot])
-                             for slot in self._active},
-                            widths=widths)
-                self._shed_rows(shed)
-                prepared.attrs["shed"] = len(shed)
-            if not self._active:
-                return True
-        # per-token spans for TRACED rows only (sampled at the
-        # client edge): untraced traffic pays one list-comp over
-        # <= slots entries per step
-        traced = [r for r in self._active.values()
-                  if r.trace is not None]
-        self._steps += 1
-        attrs["step"] = self._steps
-        attrs["live"] = len(self._active)
-        pool = self.engine.pool
-        by_group = pool.blocks_in_use_by_group()
-        attrs["blocks_in_use"] = sum(by_group.values())
-        attrs["blocks_total"] = pool.capacity_blocks
-        if "window" in by_group:
-            attrs["blocks_in_use_full"] = by_group["full"]
-            attrs["blocks_in_use_window"] = by_group["window"]
-        try:
-            with _trace.loop_span("engine/step") as stepped:
-                if drafts is not None:
-                    live_mask = np.zeros((self.slots,), bool)
-                    live_mask[list(self._active)] = True
-                    out, acc = self.engine.spec_step(
-                        self._tok, self._pos, self._temp,
-                        self._topk, drafts, nd, live_mask,
-                        budget=self.watchdog_s or None)
-                else:
-                    # the paged kernel's grid against the blocks it
-                    # has to read: over slots * blocks_per_row, how
-                    # much of the table it no longer walks
-                    stepped.attrs["grid_steps"] = \
-                        self.engine.kernel_grid_steps
-                    stepped.attrs["live_blocks"] = int(np.sum(
-                        self._pos[list(self._active)]
-                        // pool.block_size + 1))
-                    toks = self.engine.step(
-                        self._tok, self._pos, self._temp,
-                        self._topk, budget=self.watchdog_s or None)
-                    stepped.attrs.update(
-                        getattr(self.engine, "step_routing", None) or {})
-        except Exception as exc:  # noqa: BLE001
-            if self._epoch != epoch:
-                return False     # deposed mid-step: restart() owns
-            self.consecutive_failures += 1      # the row state
-            if self.stats:
-                self.stats.bump("engine_failures")
-                if isinstance(exc, WatchdogTimeout):
-                    self.stats.bump("watchdog_timeouts")
-            for req in list(self._active.values()):
-                self._finish(req, exc)
-            return True
-        if self._epoch != epoch:
-            # deposed while blocked in the step (hung chip call
-            # that eventually returned): the restarted loop owns
-            # _active/_free now — do not touch them
-            return False
-        self.consecutive_failures = 0
-        for r in traced:
-            _trace.record_child("serving/decode", stepped.t0,
-                                stepped.t1, r.trace)
-        if self.stats:
-            # inter-token latency: the WHOLE step's wall time
-            # (decode + sample + any stall), the signal the SLO
-            # monitor's default p99 rule evaluates windowed
-            self.stats.hist["token"].observe(stepped.t1 - stepped.t0)
-            self.stats.observe_decode_step(attrs["live"], self.slots)
-        with _trace.loop_span("serving/deliver") as delivered:
-            before = len(self._active)
-            if drafts is not None:
-                self._deliver_spec(out, acc, nd)
-            else:
-                for slot in list(self._active):
-                    req = self._active[slot]
-                    if req.done():      # abandoned by its waiter
-                        self._finish(req)
-                        continue
-                    self._pos[slot] += 1
-                    self._tok[slot] = toks[slot]
-                    self._deliver_token(req, int(toks[slot]))
-            delivered.attrs["finished"] = before - len(self._active)
+        if self.spec_k > 0:
+            alive = self._spec_round(epoch, attrs)
+        else:
+            alive = self._step(epoch, attrs, self._prepare_rows(),
+                               in_place)
+        if not alive:
+            return self._epoch == epoch
         # periodic paged-pool leak sweep: blocks held by slots
         # no longer active are a bug — reclaim + flight-record
         # them instead of bleeding capacity
@@ -1435,6 +1380,190 @@ class DecodeBatcher:
             if sweep is not None:
                 sweep(list(self._active)
                       + [st["slot"] for st in self._prefilling])
+        return True
+
+    def _prepare_rows(self):
+        """The rows of the next step, ``{slot: request}``, with blocks
+        under the positions they write. A row whose last token is due
+        from the step in flight by its own ``max_new_tokens`` takes no
+        part. Allocation on append: a row the pool cannot grow is shed
+        TYPED, before its step is sent, while the rest of the bank
+        keeps decoding (its freed blocks unblock the next step's
+        growth)."""
+        flying = self._flight.rows if self._flight is not None else {}
+        rows = {slot: req for slot, req in self._active.items()
+                if len(req.out_tokens) + (flying.get(slot) is req)
+                < req.max_new_tokens}
+        if rows:
+            with _trace.loop_span("serving/prepare_step") as prepared:
+                shed = self.engine.prepare_step(
+                    {slot: int(self._pos[slot]) for slot in rows})
+                self._shed_rows(shed)
+                prepared.attrs["shed"] = len(shed)
+            for slot in shed:
+                del rows[slot]
+        return rows
+
+    def _count_failure(self, exc):
+        self.consecutive_failures += 1
+        if self.stats:
+            self.stats.bump("engine_failures")
+            if isinstance(exc, WatchdogTimeout):
+                self.stats.bump("watchdog_timeouts")
+
+    def _round_attrs(self, attrs, live):
+        """What a ``serving/round`` that sends a step says of it."""
+        self._steps += 1
+        attrs["step"] = self._steps
+        attrs["live"] = live
+        pool = self.engine.pool
+        by_group = pool.blocks_in_use_by_group()
+        attrs["blocks_in_use"] = sum(by_group.values())
+        attrs["blocks_total"] = pool.capacity_blocks
+        if "window" in by_group:
+            attrs["blocks_in_use_full"] = by_group["full"]
+            attrs["blocks_in_use_window"] = by_group["window"]
+
+    def _step(self, epoch, attrs, rows, in_place):
+        """Send ``rows`` as the bank's next step (none where empty),
+        then read and deliver the step that was in flight: the device
+        runs the one while the host reads the other. With nothing in
+        flight the step just sent stays in flight, or is read at once
+        where the round is ``in_place``. One ``engine/step`` span covers
+        the send and the read. Returns False when the step failed (its
+        rows were failed) or the loop was deposed meanwhile."""
+        old, self._flight = self._flight, None
+        if not rows and old is None:
+            return True
+        new = landed = None
+        try:
+            with _trace.loop_span(
+                    "engine/step",
+                    ahead=int(bool(rows) and old is not None)) as stepped:
+                if rows:
+                    slots = list(rows)
+                    self._round_attrs(attrs, len(rows))
+                    # the paged kernel's grid against the blocks it
+                    # has to read: over slots * blocks_per_row, how
+                    # much of the table it no longer walks
+                    stepped.attrs["grid_steps"] = \
+                        self.engine.kernel_grid_steps
+                    stepped.attrs["live_blocks"] = int(np.sum(
+                        self._pos[slots]
+                        // self.engine.pool.block_size + 1))
+                    new = _Flight(self.engine.dispatch_step(
+                        self._tok, self._pos, self._temp, self._topk,
+                        from_host=self._from_host), rows, stepped.t0)
+                    self._pos[slots] += 1
+                    self._from_host[slots] = False
+                    if old is not None and self.stats:
+                        self.stats.bump("decode_steps_ahead")
+                landed = old or (new if in_place else None)
+                if landed is not None:
+                    toks = self.engine.collect_step(
+                        landed.sent, budget=self.watchdog_s or None)
+                    stepped.attrs.update(self.engine.step_routing or {})
+        except Exception as exc:  # noqa: BLE001
+            if self._epoch != epoch:
+                return False     # deposed mid-step: restart() owns
+            self._count_failure(exc)            # the row state
+            # the rows of the step that failed get its error; the pool
+            # went with it (bank_lost), so a step sent after it is
+            # dropped unread and whoever else is live fails typed
+            failed = landed.rows if landed is not None else rows
+            for slot, req in failed.items():
+                if self._active.get(slot) is req:
+                    self._finish(req, exc)
+            self._fail_active_if_bank_lost(exc)
+            return False
+        if self._epoch != epoch:
+            # deposed while blocked in the step (hung chip call
+            # that eventually returned): the restarted loop owns
+            # _active/_free now — do not touch them
+            return False
+        if landed is not new:
+            self._flight = new
+        if landed is None:
+            return True
+        attrs["collected"] = len(landed.rows)
+        self.consecutive_failures = 0
+        # from the last step's tokens to this step's (from its own send
+        # where nothing was in flight before it): the inter-token
+        # latency, any stall included, that the SLO monitor's default
+        # p99 rule evaluates windowed
+        t0 = max(landed.t_sent, self._t_tokens)
+        self._t_tokens = stepped.t1
+        for req in landed.rows.values():
+            if req.trace is not None:
+                # per-token spans for TRACED rows only (sampled at the
+                # client edge)
+                _trace.record_child("serving/decode", t0, stepped.t1,
+                                    req.trace)
+        if self.stats:
+            self.stats.hist["token"].observe(stepped.t1 - t0)
+            self.stats.observe_decode_step(len(landed.rows), self.slots)
+        toks = toks.tolist()
+        with _trace.loop_span("serving/deliver") as delivered:
+            before = len(self._active)
+            for slot, req in landed.rows.items():
+                if self._active.get(slot) is not req:
+                    continue    # ended since its step was sent
+                if req.done():      # abandoned by its waiter
+                    self._finish(req)
+                    continue
+                self._tok[slot] = toks[slot]
+                self._deliver_token(req, toks[slot])
+            delivered.attrs["finished"] = before - len(self._active)
+        return True
+
+    def _spec_round(self, epoch, attrs):
+        """A round of speculative decoding, read in place: draft,
+        cover the verify spans with blocks, verify, deliver the runs.
+        Returns False when the step failed or the loop was deposed."""
+        # speculative rows draft BEFORE the allocation pass so
+        # the whole verify span [pos, pos + nd + 1) is covered
+        # by blocks (and COW-duplicated when shared) up front
+        drafts, nd = self._propose_drafts(self.spec_k)
+        with _trace.loop_span("serving/prepare_step") as prepared:
+            shed = self.engine.prepare_step(
+                {slot: int(self._pos[slot]) for slot in self._active},
+                widths={slot: int(nd[slot]) + 1
+                        for slot in self._active})
+            self._shed_rows(shed)
+            prepared.attrs["shed"] = len(shed)
+        if not self._active:
+            return True
+        traced = [r for r in self._active.values()
+                  if r.trace is not None]
+        self._round_attrs(attrs, len(self._active))
+        try:
+            with _trace.loop_span("engine/step", ahead=0) as stepped:
+                live_mask = np.zeros((self.slots,), bool)
+                live_mask[list(self._active)] = True
+                out, acc = self.engine.spec_step(
+                    self._tok, self._pos, self._temp,
+                    self._topk, drafts, nd, live_mask,
+                    budget=self.watchdog_s or None)
+        except Exception as exc:  # noqa: BLE001
+            if self._epoch != epoch:
+                return False     # deposed mid-step: restart() owns
+            self._count_failure(exc)            # the row state
+            for req in list(self._active.values()):
+                self._finish(req, exc)
+            return False
+        if self._epoch != epoch:
+            return False        # deposed while blocked in the step
+        self.consecutive_failures = 0
+        for r in traced:
+            _trace.record_child("serving/decode", stepped.t0,
+                                stepped.t1, r.trace)
+        if self.stats:
+            self.stats.hist["token"].observe(stepped.t1 - stepped.t0)
+            self.stats.observe_decode_step(attrs["live"], self.slots)
+        with _trace.loop_span("serving/deliver") as delivered:
+            before = len(self._active)
+            self._deliver_spec(out, acc, nd)
+            delivered.attrs["finished"] = before - len(self._active)
         return True
 
     def _shed_rows(self, shed):

@@ -18,12 +18,12 @@ import numpy as np
 
 from .batching import next_bucket
 from .cache import ExecutableCache, feed_signature
+from .kvpool import adopt_decode_fetches, decode_feed
 from .metrics import record_class_done
 from ..flags import flag
 from ..observability import tracing as _trace
 from ..observability import utilization as _util
-from ..resilience import (CheckpointCorruptError, maybe_fail,
-                          run_with_watchdog)
+from ..resilience import CheckpointCorruptError, maybe_fail
 from ..utils.lru import LRUCache
 
 SIGNATURE_FILE = "_serving_signatures.json"
@@ -444,9 +444,14 @@ class GenerationEngine:
       prompts, per-row sampling of their first tokens, and a jitted
       scatter of the fresh row caches into the slots' blocks (a
       finished row's blocks went back to the pool at release).
-    - ``step(tokens, pos, temperature, top_k)``: one decode + sample
-      over the whole bank; rows at different positions (and with
-      different sampling configs) share the executable.
+    - ``dispatch_step(tokens, pos, temperature, top_k, from_host)``
+      sends one decode step that picks its own tokens over the whole
+      bank and returns at once; ``collect_step(sent)`` reads them. The
+      tokens stay on the device from one step to the next, so the
+      batcher sends step t + 1 before it has read step t.
+      ``step(tokens, pos, temperature, top_k)`` is the two in place.
+      Rows at different positions (and with different sampling
+      configs) share the executable.
 
     All methods are single-caller by design — the DecodeBatcher thread
     is the only driver (the chip is the bottleneck resource; concurrency
@@ -498,6 +503,9 @@ class GenerationEngine:
         self._key = jax.random.PRNGKey(int(seed))
         self.bank_lost = False     # see _drop_bank
         self.step_routing = {}     # the last step's moe_* span attrs
+        # int32 [slots] on the device: what the last dispatched step
+        # picked, the next step's tokens for the rows that were in it
+        self._prev_tokens = None
 
     def _ensure_caches(self):
         self.bank_lost = False
@@ -512,6 +520,7 @@ class GenerationEngine:
         failed rows return their blocks through the batcher's release
         path."""
         self.pool.drop_device()
+        self._prev_tokens = None
         self.bank_lost = True
 
     def reset(self):
@@ -520,6 +529,7 @@ class GenerationEngine:
         were already failed by the supervisor), so the stale caches are
         garbage, not state. Every block is freed too."""
         self.pool.reset()
+        self._prev_tokens = None
         self.bank_lost = False
 
     # -- paged-pool admission / lifecycle hooks ---------------------------
@@ -849,55 +859,80 @@ class GenerationEngine:
         return first
 
     def step(self, tokens, pos, temperature, top_k, budget=None):
-        """One decode + sample over the whole slot bank. ``tokens``/
-        ``pos``/``temperature``/``top_k`` are np arrays of length
-        ``slots`` (free slots carry harmless stale values — their rows
-        are never read). Returns sampled np int32 tokens [slots].
+        """One decode + pick over the whole slot bank, sent and read in
+        place. ``tokens``/``pos``/``temperature``/``top_k`` are np
+        arrays of length ``slots`` (free slots carry harmless stale
+        values — their rows are never read). Returns sampled np int32
+        tokens [slots]. The executable and its signature are the ones
+        the decode loop runs through :meth:`dispatch_step`, so calling
+        this warms them. ``budget`` (seconds) bounds the wait, see
+        :meth:`collect_step`."""
+        return self.collect_step(
+            self.dispatch_step(tokens, pos, temperature, top_k), budget)
 
-        ``budget`` (seconds) runs the decode call under
-        ``resilience.run_with_watchdog``: a hung chip call raises
-        WatchdogTimeout instead of wedging the decode loop. The worker
-        only COMPUTES — the feed is built here and state (pool arrays,
-        RNG key) is adopted on this thread after it returns, so an
-        abandoned overbudget worker can never resurrect a pool this
-        thread already dropped."""
-        maybe_fail("serving.decode_step")
+    def dispatch_step(self, tokens, pos, temperature, top_k,
+                      from_host=None):
+        """Send one decode step over the whole slot bank and return at
+        once with what :meth:`collect_step` reads. The step picks its
+        own tokens (the pick program that ``temperature`` and ``top_k``
+        call for, inside the decode executable) and they stay on the
+        device: the next step takes them from there, but for the rows
+        whose ``from_host`` is set (bool [slots]; None: all), which
+        take ``tokens`` (a row admitted since the last step, whose
+        first token the prefill's pick made). Every host vector and
+        both block tables are copied here, so the caller may change
+        them while the step is in flight; the pool's arrays and the RNG
+        key are the step's results from now on, and whatever is sent
+        after it (the next step, an admission's scatter) runs behind it
+        on the device."""
         self._ensure_caches()
-        # the caller's span (the batcher's engine/step): the watchdog's
-        # worker thread is inside none, so the parent goes with the call
-        span = _trace.current_loop()
-        tok = np.ascontiguousarray(tokens, dtype=np.int32)
-        posc = np.ascontiguousarray(pos, dtype=np.int32)
-        key = self._key
-
-        from .kvpool import adopt_decode_fetches, decode_feed
-        feed = decode_feed(self.pool, tok, posc)
-        kind = f"decode_paged_{self.pool.dtype}"
-
-        def _decode_paged():
-            return self.gen._invoke(kind, "decode", feed, key,
-                                    parent=span)
-
+        gen, slots = self.gen, self.slots
+        pick, pick_feed = gen.pick_for(
+            np.array(temperature, dtype=np.float32),
+            np.array(top_k, dtype=np.int32))
+        feed = decode_feed(self.pool, np.array(tokens, dtype=np.int32),
+                           np.array(pos, dtype=np.int32))
+        feed["token_prev"] = self._prev_tokens \
+            if self._prev_tokens is not None else np.zeros(slots, np.int32)
+        feed["token_from_host"] = np.ones(slots, bool) \
+            if from_host is None or self._prev_tokens is None \
+            else np.array(from_host, dtype=bool)
+        feed.update(pick_feed)
         try:
-            if budget:
-                fetches, new_key = run_with_watchdog(
-                    _decode_paged, budget, what="serving decode step")
-            else:
-                fetches, new_key = _decode_paged()
+            sent = gen._dispatch(f"decode_paged_{self.pool.dtype}+{pick}",
+                                 "decode", feed, self._key)
         except Exception:
             self._drop_bank()  # pool arrays were donated in
             raise
-        logits = adopt_decode_fetches(self.pool, fetches)
-        self._key = new_key
-        aux = self.gen.aux_of(kind, fetches)
-        toks, self._key = self.gen._run_sample(
-            logits, np.ascontiguousarray(temperature, dtype=np.float32),
-            np.ascontiguousarray(top_k, dtype=np.int32), self._key)
-        with _trace.loop_span("engine/fetch"):
-            out = np.asarray(toks)
-            # the batcher puts them on its engine/step span
-            self.step_routing = self._count_routing(aux)
-            return out
+        self._prev_tokens = adopt_decode_fetches(self.pool, sent.fetches)
+        self._key = sent.key
+        # what collect_step reads, on its way to the host already
+        self._prev_tokens.copy_to_host_async()
+        for a in gen.aux_of(sent.kind, sent.fetches).values():
+            a.copy_to_host_async()
+        return sent
+
+    def collect_step(self, sent, budget=None):
+        """Read a dispatched step's tokens: np int32 [slots]. The one
+        place the decode loop waits for the chip, so the watchdog
+        stands here: with ``budget`` (seconds) a wait longer than that,
+        a stall at the chaos point included, raises WatchdogTimeout
+        instead of wedging the loop, from a clock asked between polls
+        of the result and no thread. On any failure the pool arrays,
+        donated into the step, are presumed lost."""
+        deadline = time.perf_counter() + budget if budget else None
+        try:
+            maybe_fail("serving.decode_step")
+            self.gen._await(sent, deadline, budget)
+            with _trace.loop_span("engine/fetch"):
+                out = np.asarray(sent.fetches[0])
+                # the batcher puts them on its engine/step span
+                self.step_routing = self._count_routing(
+                    self.gen.aux_of(sent.kind, sent.fetches))
+        except Exception:
+            self._drop_bank()
+            raise
+        return out
 
     def prefill_bytes(self, prompt_sizes):
         """Device bytes a prefill of these prompts, admitted together,
@@ -939,12 +974,12 @@ class GenerationEngine:
         (accepted drafts, then the correction/bonus token), all drawn
         from the target distribution by rejection sampling.
 
-        Same watchdog discipline as :meth:`step`: the worker only
-        computes; pool adoption and key assignment happen on this
-        thread after it returns."""
+        Sent and read in place (the drafter needs the newest tokens
+        on the host); ``budget`` bounds the wait for the verify pass as
+        in :meth:`collect_step`."""
+        deadline = time.perf_counter() + budget if budget else None
         maybe_fail("serving.decode_step")
         self._ensure_caches()
-        span = _trace.current_loop()    # as in step()
         tok = np.ascontiguousarray(tokens, dtype=np.int32)
         posc = np.ascontiguousarray(pos, dtype=np.int32)
         drafts = np.ascontiguousarray(drafts, dtype=np.int32)
@@ -961,24 +996,14 @@ class GenerationEngine:
                                  0).astype(np.int32)
         feed["block_tables"] = np.ascontiguousarray(self.pool.tables)
         kind = f"verify_paged_{self.pool.dtype}"
-        key = self._key
-
-        def _verify():
-            return self.gen._invoke(kind, "decode", feed, key,
-                                    parent=span)
-
         try:
-            if budget:
-                fetches, new_key = run_with_watchdog(
-                    _verify, budget, what="serving spec verify step")
-            else:
-                fetches, new_key = _verify()
+            sent = self.gen._dispatch(kind, "decode", feed, self._key)
+            logits = adopt_decode_fetches(self.pool, sent.fetches)
+            self._key = sent.key
+            self.gen._await(sent, deadline, budget)
         except Exception:
             self._drop_bank()  # pool arrays were donated in
             raise
-        from .kvpool import adopt_decode_fetches
-        logits = adopt_decode_fetches(self.pool, fetches)
-        self._key = new_key
         out, acc, self._key = self.gen._run_spec_accept(
             logits, drafts,
             np.ascontiguousarray(temperature, dtype=np.float32),

@@ -270,17 +270,17 @@ def prompt_prefix_key(tokens, length=None):
 def decode_feed(pool, token, pos):
     """ONE paged decode step's feed dict: the pool's device arrays
     (donated into the call — XLA appends in place), this step's
-    token/pos vectors, and the host block tables (the window group's
-    ring as ``block_tables_window`` where the pool has one). The one
-    builder both the offline generator loop and the serving engine
-    use."""
+    token/pos vectors, and a copy of the host block tables as they are
+    now (the window group's ring as ``block_tables_window`` where the
+    pool has one): the pool may change its tables while the step is in
+    flight. The one builder both the offline generator loop and the
+    serving engine use."""
     feed = dict(pool.arrays())
     feed["token"] = token
     feed["pos"] = pos
-    feed["block_tables"] = np.ascontiguousarray(pool.tables)
+    feed["block_tables"] = pool.tables.copy()
     if pool.window is not None:
-        feed["block_tables_window"] = np.ascontiguousarray(
-            pool.window.tables)
+        feed["block_tables_window"] = pool.window.tables.copy()
     return feed
 
 

@@ -203,6 +203,7 @@ _COUNTER_KEYS = (
     "generate_requests",
     "tokens_generated",
     "decode_steps",
+    "decode_steps_ahead",  # sent while the last step's tokens were unread
     "decode_rows",        # live generation rows stepped
     "decode_slot_rows",   # slot capacity across steps
     # -- disaggregated prefill/decode (fleet KV migration) --
@@ -283,8 +284,10 @@ class ServingStats:
               # generation pipeline stages (KV-cached decoding):
               # prefill = prompt ingestion forward, decode = one
               # incremental step over the slot batch, sample = the
-              # next-token selection executable, token = one WHOLE
-              # decode-loop step (engine.step wall: decode + sample +
+              # next-token selection executable (an admission's: a
+              # decode step picks inside its own executable), token =
+              # from one decode step's tokens to the next's (from its
+              # own send where nothing was in flight: decode + pick +
               # host work — the inter-token latency the SLO monitor's
               # default p99 rule watches; a stall anywhere in the step
               # lands here even if the compiled call itself was fast),

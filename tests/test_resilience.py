@@ -607,6 +607,31 @@ def test_run_with_watchdog_times_out_and_passes_results():
                           5.0)
 
 
+@pytest.mark.parametrize("ready_after,deadline_in,times_out", [
+    (0.0, 5.0, False),      # ready at the first asking
+    (0.05, 5.0, False),     # ready after some polls
+    (10.0, 0.1, True),      # never ready inside the budget
+    (0.0, -0.01, True),     # the budget was spent before the wait began
+])
+def test_await_ready_polls_on_the_callers_thread(ready_after, deadline_in,
+                                                 times_out):
+    from paddle_tpu.resilience import await_ready
+    t0 = time.perf_counter()
+    threads = threading.active_count()
+
+    def ready():
+        assert threading.active_count() == threads      # no thread
+        return time.perf_counter() - t0 >= ready_after
+
+    if times_out:
+        with pytest.raises(WatchdogTimeout, match="a wait"):
+            await_ready(ready, t0 + deadline_in, 0.1, "a wait")
+        assert time.perf_counter() - t0 < 2.0
+    else:
+        await_ready(ready, t0 + deadline_in, 5.0, "a wait")
+        assert time.perf_counter() - t0 >= ready_after
+
+
 def test_watchdog_context_aborts_overbudget_block():
     t0 = time.monotonic()
     with pytest.raises(WatchdogTimeout, match="budget"):

@@ -491,13 +491,17 @@ def test_reload_weights_generation_inflight_old_new(tmp_path):
     server.start(serve_network=False)
     try:
         server.submit_generate(p1, max_new_tokens=2).wait(timeout=120)
-        long_req = server.submit_generate(p1, max_new_tokens=40)
-        assert _wait_until(
-            lambda: server.decode_batcher.inflight() > 0
-            or long_req.done(), timeout=10)
-        assert not long_req.done(), "generation finished before the " \
-            "reload could land mid-flight — lengthen max_new_tokens"
-        report = server.reload_weights(ck2, timeout=120)
+        # the loop runs 40 steps of a tiny model in a few milliseconds:
+        # a short stall at every step's chaos point keeps the generation
+        # in flight until the reload has been asked for
+        with chaos("serving.decode_step", p=1.0, delay=0.01):
+            long_req = server.submit_generate(p1, max_new_tokens=40)
+            assert _wait_until(
+                lambda: server.decode_batcher.inflight() > 0
+                or long_req.done(), timeout=10)
+            assert not long_req.done(), "generation finished before " \
+                "the reload could land mid-flight"
+            report = server.reload_weights(ck2, timeout=120)
         assert report["weights_version"] == 2
         assert report["swap_pause_ms"] >= 0.0
         got_long, = long_req.wait(timeout=60)
