@@ -21,6 +21,12 @@ seeded random weights:
            no pool-sized copy in the decode step's or the scatter's
            optimised HLO, then one logits check of paged decode against
            a full recompute
+  loop     the looped block of benchmark configuration ouro-2.6b (its
+           published widths, four passes, 2 of 48 layers) through
+           InferenceServer: no pool-sized copy in its decode step (the
+           append runs inside the loop of passes) or its scatter, and a
+           logits check of prefill then paged decode against the
+           family's plain float32 reference
   mesh     only with four or more devices: the train step under
            with_data_parallel over every chip, and tp=2 paged generation
 
@@ -75,6 +81,8 @@ class Sizes:
             self.new_tokens = 4
             self.check_prompt, self.check_steps = 9, 2
             self.kernel_impl = "interpret"
+            self.loop = dict(layers=None, slots=2, prompt_lens=(5, 11, 18),
+                             new_tokens=4)
         else:
             self.cfg = gpt.GPTConfig.base()
             self.batch, self.seq = 8, 2048
@@ -96,6 +104,10 @@ class Sizes:
             self.new_tokens = 32
             self.check_prompt, self.check_steps = 100, 3
             self.kernel_impl = "pallas"
+            # benchmark configuration ouro-2.6b at its published widths
+            # and four passes, 2 of its 48 layers: 8 cache layers
+            self.loop = dict(layers=2, slots=4,
+                             prompt_lens=(33, 70, 100, 128), new_tokens=24)
         self.max_len = self.cfg.max_position
 
 
@@ -576,23 +588,22 @@ def _startup_scope(cfg):
     return scope
 
 
-def _logits_check(sz, gen):
+def _logits_check(sz, gen, reference=None, tol=5e-2):
     """Next-token logits after prefill plus a few paged decode steps
-    against a full recompute of the same prefix. Logits, not tokens:
-    with random weights and reduced-precision fp32 matmuls an argmax can
-    flip on rounding with nothing wrong."""
+    against a full recompute of the same prefix (the program's own
+    cache-less forward, or ``reference(seq)`` where one is given).
+    Logits, not tokens: with random weights and reduced-precision fp32
+    matmuls an argmax can flip on rounding with nothing wrong."""
     import jax
-    from paddle_tpu.serving.kvpool import KVBlockPool
-    cfg = sz.cfg
+    cfg = gen.cfg
     rng = np.random.default_rng(1)
     seq = list(rng.integers(1, cfg.vocab_size, sz.check_prompt))
     key = jax.random.PRNGKey(0)
-    pool = KVBlockPool(slots=1, num_layers=cfg.num_layers,
-                       num_heads=cfg.num_heads,
-                       d_head=cfg.hidden_size // cfg.num_heads,
-                       max_seq_len=gen.max_len, name="smoke_check")
+    pool = gen.new_pool(1, name="smoke_check")
 
     def recompute(seq):
+        if reference is not None:
+            return np.asarray(reference(np.asarray(seq, np.int32)))
         t, p, last = gen._pack_prompts([np.asarray(seq, np.int32)])
         logits, _ = gen._run_logits(t, p, last, key)
         return np.asarray(logits)[0]
@@ -615,7 +626,7 @@ def _logits_check(sz, gen):
         # 0.5 (unit-variance hidden state against N(0, 0.02) tied
         # embeddings), so 5e-2 is a tenth of one: rounding stays under
         # it, a stale, misplaced or masked-out key does not.
-        assert diffs[-1] <= 5e-2, (step, diffs)
+        assert diffs[-1] <= tol, (step, diffs)
         if step == sz.check_steps:
             break
         tok = int(np.argmax(ref))
@@ -719,6 +730,78 @@ def phase_serve(smoke):
     out["logits_check"] = _logits_check(sz, gen)
     out["peak_bytes_in_use"] = _peak_bytes()
     return out
+
+
+# --------------------------------------------------------------------- loop
+
+def phase_loop(smoke):
+    """The looped block (``models/ouro.py``: a stack of layers run four
+    times over shared weights, a cache a (pass, layer) pair, the passes
+    of a weight layer in one pool array) through ``InferenceServer``:
+    the decode step and the scatter leave no pool-sized copy though the
+    append runs inside the loop of passes, and prefill then decode agree
+    a step with the family's plain float32 reference."""
+    import jax.numpy as jnp
+    from benchmark.families import ouro as fam
+    from paddle_tpu.flags import flag, set_flags
+    from paddle_tpu.serving import InferenceServer
+    sz, loop = smoke.sizes, smoke.sizes.loop
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", "ouro-2.6b.json")) as fh:
+        config = json.load(fh)
+    if loop["layers"]:
+        config["num_hidden_layers"] = loop["layers"]
+    fsz = fam.Sizes(config, rehearsal=sz.rehearsal)
+    kv_dtype = flag("kv_cache_dtype")
+    try:
+        gen = fam.build_generator(
+            fsz, {"kv_cache_dtype": "bf16",
+                  "max_len": 64 if sz.rehearsal else 320}, seed=33)
+        server = InferenceServer(generator=gen, decode_slots=loop["slots"],
+                                 loop_watchdog_s=600.0)
+        server.start(serve_network=False)
+        try:
+            rng = np.random.default_rng(0)
+            prompts = [rng.integers(1, fsz.vocab_size, n).astype(np.int32)
+                       for n in loop["prompt_lens"]]
+            reqs = [server.submit_generate(
+                p, max_new_tokens=loop["new_tokens"]) for p in prompts]
+            outs = [r.wait(timeout=1200)[0] for r in reqs]
+            stats = server.stats()
+            pool = server.gen_engine.pool
+            assert pool.blocks_in_use() == 0, pool.stats()
+        finally:
+            server.stop()
+        assert all(o.shape == (loop["new_tokens"],) for o in outs)
+        assert stats["kv_cache_layers"] == fsz.cache_layers \
+            == pool.passes * pool.num_arrays, stats["kv_cache_layers"]
+        relayouts = stats["pool_relayouts"]
+        decode_kinds = [k for k in relayouts
+                        if k.startswith(f"decode_paged_{pool.dtype}+")]
+        assert sz.rehearsal or (
+            decode_kinds and all(relayouts[k] == 0 for k in decode_kinds)
+            and relayouts.get("scatter") == 0), relayouts
+        params = fam.init_params(fsz, 33)
+
+        def reference(seq):
+            return fam.reference_logits(fsz, params,
+                                        jnp.asarray(seq)[None])[0, -1]
+
+        # bfloat16 weights and activations against float32 at
+        # precision=highest through 8 block applications: some 1e-2 of
+        # logits whose standard deviation is 0.9; a stale or misplaced
+        # key, or a pass reading another pass's cache, moves them by
+        # tenths
+        check = _logits_check(sz, gen, reference=reference, tol=0.1)
+    finally:
+        set_flags({"kv_cache_dtype": kv_dtype})
+    return {"layers": fsz.num_hidden_layers, "ut_steps": fsz.total_ut_steps,
+            "kv_cache_layers": int(stats["kv_cache_layers"]),
+            "pool_arrays": pool.num_arrays,
+            "loop_passes": int(stats.get("loop_passes", 0)),
+            "decode_steps": int(stats.get("decode_steps", 0)),
+            "replies": len(outs), "pool_relayouts": relayouts,
+            "logits_check": check, "peak_bytes_in_use": _peak_bytes()}
 
 
 # --------------------------------------------------------------------- mesh
@@ -869,6 +952,7 @@ def main(argv=None):
     smoke.run_phase("kernels", phase_kernels)
     smoke.run_phase("train", phase_train)
     smoke.run_phase("serve", phase_serve)
+    smoke.run_phase("loop", phase_loop)
     if smoke.device["count"] >= 4:
         smoke.run_phase("mesh", phase_mesh)
 

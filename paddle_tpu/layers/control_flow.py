@@ -385,10 +385,15 @@ class StaticRNN:
         rnn.update_memory(h_prev, h)
         rnn.step_output(h)
     out = rnn()                        # [T, B, H]
+
+    ``rnn.final_states()`` are the memories after the last step, in the
+    order they were declared; ``scope`` is a ``jax.named_scope`` the loop
+    runs under (the HLO's metadata then tells its body apart).
     """
 
-    def __init__(self, name=None):
+    def __init__(self, name=None, scope=None):
         self.helper = LayerHelper("static_rnn", name=name)
+        self._scope = scope or ""
         self._block = None
         self._step_inputs = []    # (outer var, inner var)
         self._memories = []       # [pre_var, post_var|None, boot_var]
@@ -503,7 +508,7 @@ class StaticRNN:
                                 for rec in self._memories],
                    "p_names": reads,
                    "step_outputs": [o.name for o in self._step_outputs],
-                   "is_reverse": False},
+                   "is_reverse": False, "scope": self._scope},
             infer_shape=False)
         self._outputs = outs
         self._final_states = finals
@@ -512,6 +517,10 @@ class StaticRNN:
         assert self._outputs is not None, "finish `with rnn.step():` first"
         return self._outputs[0] if len(self._outputs) == 1 \
             else list(self._outputs)
+
+    def final_states(self):
+        assert self._outputs is not None, "finish `with rnn.step():` first"
+        return list(self._final_states)
 
 
 # ---- LoDTensorArray helpers (reference layers/control_flow.py:1280) ----

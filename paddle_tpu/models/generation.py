@@ -378,7 +378,7 @@ class GPTGenerator:
         sc = NamedSharding(self.mesh, P(None, None, "tp"))
         pool.array_sharding = {
             n: (sc if ("pks" in n or "pvs" in n) else val)
-            for n in pool_feed_names(pool.num_layers, pool.quantized)}
+            for n in pool_feed_names(pool.num_arrays, pool.quantized)}
         return pool
 
     def _tp_wire_budget(self, feed):
@@ -730,10 +730,11 @@ class GPTGenerator:
             self.stats.hist[sent.stage].observe(dt)
 
     # -- stage runners ----------------------------------------------------
-    def _unpack_caches(self, fetches):
+    def _unpack_caches(self, kind, fetches):
         """Fetch layout of the cache-bearing programs (_fetch_names):
-        logits at 0, then cache_k_0..n-1, then cache_v_0..n-1."""
-        n = self.cfg.num_layers
+        logits at 0, then cache_k_0..n-1, then cache_v_0..n-1, as many
+        as the architecture's ``kind`` program hands back."""
+        n = len(self._ensure_prog(kind)[1]["cache_k"])
         caches = {}
         for i in range(n):
             caches[f"cache_k_{i}"] = fetches[1 + i]
@@ -748,23 +749,26 @@ class GPTGenerator:
         feed = {"tokens": tokens, "pos_ids": pos_ids, "last_pos": last_pos}
         kind = self.arch.prefill_kind(kv_dtype or flag("kv_cache_dtype"))
         fetches, key = self._invoke(kind, "prefill", feed, key)
-        logits, caches = self._unpack_caches(fetches)
+        logits, caches = self._unpack_caches(kind, fetches)
         if want_aux:
             return logits, caches, key, self.aux_of(kind, fetches)
         return logits, caches, key
 
     def new_pool(self, slots, **kw):
         """A :class:`serving.kvpool.KVBlockPool` laid out for this
-        architecture: its KV heads, head width and layer groups."""
+        architecture: its KV heads, head width and layer groups, which
+        name its cache layers (more than its weight layers where a
+        stack is run several times)."""
         from ..serving.kvpool import KVBlockPool
         arch = self.arch
         if kw.get("dtype") and kw["dtype"] not in arch.kv_dtypes:
             raise UnsupportedPathError(arch.name,
                                        f"{kw['dtype']} KV pool")
+        groups = arch.kv_groups()
         pool = KVBlockPool(
-            slots=slots, num_layers=self.cfg.num_layers,
+            slots=slots, num_layers=sum(len(g["layers"]) for g in groups),
             num_heads=arch.kv_heads, d_head=arch.head_dim,
-            max_seq_len=self.max_len, groups=arch.kv_groups(), **kw)
+            max_seq_len=self.max_len, groups=groups, **kw)
         return self.apply_pool_sharding(pool)
 
     def _run_decode_paged(self, token, pos, pool, key):

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.registry import register_op
-from .common import x_of
+from .common import named, x_of
 
 
 def block_writes(program, block_idx):
@@ -189,7 +189,8 @@ def recurrent_op(ctx, ins, attrs):
     P=[outer vars read inside the step (weights etc.)]
     attrs: sub_block; step_input_vars (inner names for X slices); memories
     [(pre_name, post_name)] aligned with Boot; p_names (inner names for P);
-    step_outputs (in-block names); is_reverse.
+    step_outputs (in-block names); is_reverse; scope (a
+    ``jax.named_scope`` around the loop, where given).
     Outputs "Out": stacked step outputs, time-major.
     """
     sub = attrs["sub_block"]
@@ -217,7 +218,9 @@ def recurrent_op(ctx, ins, attrs):
         return new_carry, ys
 
     # lax.scan(reverse=True) already returns ys position-aligned with xs
-    final_carry, stacked = jax.lax.scan(body, carry0, xs, reverse=reverse)
+    with named(attrs.get("scope")):
+        final_carry, stacked = jax.lax.scan(body, carry0, xs,
+                                            reverse=reverse)
     out = {"Out": list(stacked)}
     if memories:
         out["FinalStates"] = list(final_carry)

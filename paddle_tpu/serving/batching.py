@@ -1448,6 +1448,7 @@ class DecodeBatcher:
                     # much of the table it no longer walks
                     stepped.attrs["grid_steps"] = \
                         self.engine.kernel_grid_steps
+                    stepped.attrs.update(self.engine.loop_attrs)
                     stepped.attrs["live_blocks"] = int(np.sum(
                         self._pos[slots]
                         // self.engine.pool.block_size + 1))

@@ -503,6 +503,14 @@ class GenerationEngine:
         self._key = jax.random.PRNGKey(int(seed))
         self.bank_lost = False     # see _drop_bank
         self.step_routing = {}     # the last step's moe_* span attrs
+        # what every executable of this architecture runs a token: passes
+        # over its weights (more than 1 where a stack of layers is run
+        # several times) and the pool's cache layers (more than its
+        # weight layers there); on the engine/step, generator/prefill
+        # and pool/scatter spans
+        self.loop_attrs = {
+            "ut_steps": int(getattr(generator.arch, "ut_steps", 1)),
+            "cache_layers": self.pool.num_layers}
         # int32 [slots] on the device: what the last dispatched step
         # picked, the next step's tokens for the rows that were in it
         self._prev_tokens = None
@@ -658,7 +666,8 @@ class GenerationEngine:
                 for sl in allocated:
                     self.pool.free_slot(sl)
                 raise
-        with span("generator/prefill", rows=n) as prefilled:
+        with span("generator/prefill", rows=n,
+                  **self.loop_attrs) as prefilled:
             logits, row_caches, self._key, aux = self.gen._run_prefill(
                 tokens, pos_ids, last, self._key,
                 kv_dtype=self.pool.dtype, want_aux=True)
@@ -667,7 +676,8 @@ class GenerationEngine:
                                                    self._key)
         # (rows, blocks) is the pair the scatter's jit retraces on
         with span("pool/scatter", rows=n,
-                  blocks=self.pool.blocks_for_tokens(tokens.shape[1])):
+                  blocks=self.pool.blocks_for_tokens(tokens.shape[1]),
+                  cache_layers=self.pool.num_layers):
             try:
                 maybe_fail("serving.slot_insert")
                 self.pool.scatter_prefill(
@@ -690,22 +700,33 @@ class GenerationEngine:
                 self.pool.prefix_insert(req.prompt, slot)
         with span("engine/fetch", rows=n):
             out = np.asarray(toks)[:n]
-            prefilled.attrs.update(self._count_routing(aux))
+            prefilled.attrs.update(self._count_routing(aux, rows=n))
         t1 = time.perf_counter()
         for req in requests:
             if getattr(req, "trace", None) is not None:
                 _trace.record_child("serving/prefill", t0, t1, req.trace)
         return out
 
-    def _count_routing(self, aux):
-        """Add an executable's ``[layers, experts]`` assignment counts
-        into the ``moe_*`` counters and return them as the attrs of the
-        span that ran it (the step's or the prefill's): assignments, the
-        fullest expert's load summed over the layers, and the experts
-        that got any. Nothing for an architecture that routes nothing."""
+    def _count_routing(self, aux, rows=None):
+        """Count one executable that ran (a step read, a prefill of
+        ``rows`` real rows): ``loop_passes`` by the passes it made over
+        the weights, and its ``[layers, experts]`` assignment counts
+        into the ``moe_*`` counters. Returns what the span that ran it
+        gains: ``exit_pass_mean`` where the executable hands back each
+        row's ``exit_pass`` (the mean over live rows, whose pass is not
+        0); the assignments, the fullest expert's load summed over the
+        layers and the experts that got any where it routes."""
+        if self.stats:
+            self.stats.bump("loop_passes", self.loop_attrs["ut_steps"])
+        looped = {}
+        if "exit_pass" in aux:
+            left = np.asarray(aux["exit_pass"])[:rows]
+            left = left[left > 0]
+            if left.size:
+                looped["exit_pass_mean"] = float(left.mean())
         counts = aux.get("moe_counts")
         if counts is None:
-            return {}
+            return looped
         counts = np.asarray(counts)
         routed = {"moe_tokens": int(counts.sum()),
                   "moe_load_max": int(counts.max(axis=1).sum()),

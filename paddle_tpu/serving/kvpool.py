@@ -34,6 +34,17 @@ and the counts cover every group; the prefix cache, copy-on-write and
 migration are the full group's alone and are off for a pool that has a
 window group.
 
+Cache layers need not be weight layers. A group may say ``passes``: its
+``layers`` are then ``passes`` cache layers a weight layer (a stack run
+several times over the same weights, a cache a (pass, layer) pair: cache
+layer ``u * arrays + i`` is pass ``u`` of weight layer ``i``), all under
+the one block table, and the pool keeps a weight layer's passes in ONE
+array of ``passes * num_blocks`` blocks, pass ``u``'s block ``b`` at
+``u * num_blocks + b`` (each pass has a trash block of its own there).
+A block id then stands for that block in every cache layer, as it does
+with one pass; the executables add the offset. The prefix cache and
+migration are off for such a pool too.
+
 Device side: lazily-built jnp pool arrays (float32 / bfloat16 / int8
 with per-(block, head, slot) float32 scales — ``FLAGS_kv_cache_dtype``;
 at bandwidth-bound decode, halving cache bytes is ~2x tokens/s), a
@@ -290,7 +301,7 @@ def adopt_decode_fetches(pool, fetches):
     contract (logits first, then :func:`pool_feed_names` order) lives
     HERE, next to the feed-order contract, so the two callers cannot
     drift."""
-    names = pool_feed_names(pool.num_layers, pool.quantized)
+    names = pool_feed_names(pool.num_arrays, pool.quantized)
     pool.update_arrays({n: fetches[1 + i] for i, n in enumerate(names)})
     return fetches[0]
 
@@ -404,6 +415,13 @@ class KVBlockPool:
             raise ValueError("KVBlockPool holds one full and one window "
                              "group of layers at most")
         self.full_layers = list(full[0]["layers"]) if full else []
+        # cache layers a weight layer, kept in one array (module docstring)
+        self.passes = int(full[0].get("passes", 1)) if full else 1
+        if self.passes > 1 and (windowed or self.quantized
+                                or self.num_layers % self.passes):
+            raise ValueError("a group of several passes is the pool's "
+                             "only group, whole passes, and not int8")
+        self.num_arrays = self.num_layers // self.passes
         self.window = None
         if windowed:
             if self.quantized:
@@ -437,7 +455,7 @@ class KVBlockPool:
         self.prefix_enabled = bool(flag("kv_prefix_cache")
                                    if prefix_cache is None
                                    else prefix_cache) \
-            and self.window is None
+            and self.window is None and self.passes == 1
         self.array_sharding = None     # NamedSharding under a tp mesh
         self._arrays = None            # lazy device pool
         self._scatter_fn = None
@@ -685,15 +703,17 @@ class KVBlockPool:
                                  self.block_size, self.d_head)
             dt = _np_pool_dtype(self.dtype)
             arrs = {}
-            for i in range(self.num_layers):
-                # a window layer's arrays hold its group's blocks
+            for i in range(self.num_arrays):
+                # a window layer's arrays hold its group's blocks, an
+                # array of several passes every pass's
                 n = self.window.num_blocks if self.window \
-                    and i in self.window.layers else self.num_blocks
+                    and i in self.window.layers \
+                    else self.passes * self.num_blocks
                 arrs[f"cache_pk_{i}"] = jnp.zeros((n,) + shape[1:], dt)
                 arrs[f"cache_pv_{i}"] = jnp.zeros((n,) + shape[1:], dt)
             if self.quantized:
                 sshape = (shape[0], shape[2] // self.d_head, shape[1])
-                for i in range(self.num_layers):
+                for i in range(self.num_arrays):
                     # scale 1.0, not 0: a read of a never-written slot
                     # dequantizes 0 * 1.0 instead of hitting a 0-scale
                     arrs[f"cache_pks_{i}"] = jnp.ones(sshape, jnp.float32)
@@ -973,7 +993,8 @@ class KVBlockPool:
             from ..kernels.paged_attention import (
                 quantize_kv, scales_to_stored, to_stored)
             bs, quant, d_head = self.block_size, self.quantized, self.d_head
-            full = list(self.full_layers)
+            passes, per_pass = self.passes, self.num_blocks
+            full = [i for i in self.full_layers if i < self.num_arrays]
             windowed = list(self.window.layers) if self.window else []
 
             def blocks_of(src, n, nblk):
@@ -999,11 +1020,21 @@ class KVBlockPool:
                 out = dict(pool)
                 n, nblk = tables.shape
                 m, tables_flat = n * nblk, tables.reshape(-1)
+                # several passes in an array: [U,bb,H,L,D] row caches,
+                # pass u's blocks at the table's ids + u * per_pass. One
+                # pass keeps the path it had, with no leading axis: the
+                # scatters the other cells warm lower to the text they
+                # lowered to (an add and a concatenate of one part would
+                # move their compile-cache keys)
+                at = tables_flat if passes == 1 else jnp.concatenate(
+                    [tables_flat + u * per_pass for u in range(passes)])
                 for i in full:
                     for kind in ("k", "v"):
                         src = rows[f"cache_{kind}_{i}"]    # [bb,H,L,D]
-                        vals = blocks_of(src, n, nblk)
-                        vals = vals.reshape((m,) + vals.shape[2:])
+                        vals = blocks_of(src, n, nblk) if passes == 1 \
+                            else jnp.concatenate([blocks_of(src[u], n, nblk)
+                                                  for u in range(passes)])
+                        vals = vals.reshape((passes * m,) + vals.shape[2:])
                         dst = out[f"cache_p{kind}_{i}"]
                         # whole blocks into rows of the stored array:
                         # dimension 0 alone is indexed, so in place
@@ -1016,7 +1047,7 @@ class KVBlockPool:
                                 scales_to_stored(sc, d_head))
                         else:
                             out[f"cache_p{kind}_{i}"] = \
-                                dst.at[tables_flat].set(
+                                dst.at[at].set(
                                     to_stored(vals.astype(dst.dtype)))
                 for i in windowed:
                     for kind in ("k", "v"):
@@ -1038,10 +1069,11 @@ class KVBlockPool:
         ``slot_ids`` of the tables receive the first ``bucket_len``
         positions of ``row_caches[cache_{k,v}_i][:len(slot_ids)]``
         (shape ``[bb, H, L, D]``, ``L`` the bank's length or the
-        bucket's), reshaped into blocks and scattered through the block
-        table in ONE donated jitted call. Table entries past a row's
-        allocation point at the trash block, so bucket padding lands
-        there. A window group's layers keep only what a row's ring
+        bucket's; with several passes an array ``[U, bb, H, L, D]``,
+        every cache layer of weight layer ``i``), reshaped into blocks
+        and scattered through the block table in ONE donated jitted
+        call. Table entries past a row's allocation point at the trash
+        block, so bucket padding lands there. A window group's layers keep only what a row's ring
         holds: of a prompt of ``lengths[r]`` tokens the last ``ring``
         blocks, each into the column its logical index names. Quantizes
         on the way in for an int8 pool. On ANY failure the donated pool
@@ -1129,6 +1161,10 @@ class KVBlockPool:
             raise BadRequestError(
                 f"KV pool {self.name!r} has a window group of layers: "
                 f"{what} is built for full-attention layers only")
+        if self.passes > 1:
+            raise BadRequestError(
+                f"KV pool {self.name!r} keeps {self.passes} cache layers "
+                f"a weight layer: {what} is built for one")
 
     @staticmethod
     def payload_bytes(payload):

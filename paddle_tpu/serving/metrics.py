@@ -225,6 +225,8 @@ _COUNTER_KEYS = (
     "moe_assignments",      # (token, expert) pairs routed, every layer
     "moe_expert_load_max",  # the fullest expert's load, summed over layers
     "moe_experts_hit",      # experts that got a token, summed over layers
+    # -- a stack of layers run several times over the same weights --
+    "loop_passes",          # passes over the weights: steps and prefills
 )
 
 

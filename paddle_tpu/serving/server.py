@@ -518,6 +518,9 @@ class InferenceServer:
                 extra[f"decode_cache_{k}"] = v
             for k, v in self.gen_engine.pool.stats().items():
                 extra[f"kvpool_{k}"] = v
+            # more than the model's weight layers where a stack is run
+            # several times, a cache a (pass, layer) pair
+            extra["kv_cache_layers"] = self.gen_engine.pool.num_layers
             # pool-sized copies XLA left in each executable that
             # takes the pool: the generator's by program kind, the
             # pool's own writers beside them (0 everywhere is the

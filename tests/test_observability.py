@@ -144,7 +144,9 @@ def test_serving_stats_snapshot_keys_unchanged():
         # PR 28: the routed expert layers' counts
         "moe_assignments", "moe_expert_load_max", "moe_experts_hit",
         # PR 32: decode steps sent before the last step's tokens were read
-        "decode_steps_ahead"}
+        "decode_steps_ahead",
+        # PR 33: passes over the weights, steps and prefills alike
+        "loop_passes"}
     derived = {"uptime_s", "throughput_rps", "mean_batch_size",
                "batch_occupancy", "tokens_per_s", "decode_occupancy",
                "queue_depth", "spec_accept_ratio"}
