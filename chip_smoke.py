@@ -2,6 +2,7 @@
 """The quickest proof that paddle_tpu still starts on the chip.
 
     python chip_smoke.py                 # needs a TPU; exits 2 without one
+    python chip_smoke.py kernels         # that phase alone (any of them)
     python chip_smoke.py --rehearse-cpu  # CPU dry run, never a pass
 
 One process drives the main path once at the full width of GPT-base
@@ -13,7 +14,11 @@ seeded random weights:
            with their in-file oracles, the paged pair (paged_kv_append,
            paged_attention_decode) over the pool's stored shape in its
            three dtypes; the decode kernel and one append timed alone
-           at the shapes of the benchmark's serve cell
+           at the shapes of the benchmark's serve cell, and the decode
+           kernel at every serve cell's decode shape under a bank of
+           free slots, of whole tables and of the cell's own mix (a
+           table on stderr: microseconds a call and a walked step, the
+           share of the bandwidth bound), the mix against the oracle
   train    gpt_pretrain at 8 x 2,048 + Adam + bf16 AMP through
            Executor.run and Executor.run_steps
   serve    GPTGenerator -> InferenceServer -> six
@@ -72,6 +77,11 @@ class Sizes:
             self.paged_pos = (0, 37)
             self.paged_timed = dict(rows=4, heads=2, blocks=8, block=4,
                                     d_head=16, pos=(3, 20))
+            self.paged_micro = (
+                dict(name="f2", rows=4, hq=2, hkv=2, d=64, block=4, table=8,
+                     mixes={"some": (3, 1, 5)}),
+                dict(name="ring", rows=4, hq=4, hkv=2, d=128, block=4,
+                     table=6, window=16, mixes={"some": (4, 5, 20)}))
             # (query heads, KV heads, head width, window, block, expert
             # width in, hidden, experts, experts a token, prefill length)
             self.gqa = dict(hq=4, hkv=2, d=8, window=8, block=4, hidden=32,
@@ -95,6 +105,20 @@ class Sizes:
             # 16, bf16 pool, contexts of 48 to 320 tokens
             self.paged_timed = dict(rows=32, heads=16, blocks=64, block=16,
                                     d_head=64, pos=(48, 320))
+            # one decode call of every serve cell, as the cell's decode
+            # step feeds it: mixes are (live rows, fewest, most blocks of
+            # context a live row); the other rows are free slots
+            self.paged_micro = (
+                dict(name="gpt2-medium", rows=32, hq=16, hkv=16, d=64,
+                     block=16, table=64,
+                     mixes={"chat": (31, 2, 20), "long_decode": (8, 48, 64)}),
+                dict(name="ouro", rows=16, hq=16, hkv=16, d=128, block=16,
+                     table=20, mixes={"reason": (16, 2, 20)}),
+                dict(name="mellum-full", rows=32, hq=32, hkv=4, d=128,
+                     block=16, table=512, mixes={"code": (32, 64, 400)}),
+                dict(name="mellum-window", rows=32, hq=32, hkv=4, d=128,
+                     block=16, table=96, window=1024,
+                     mixes={"code": (32, 64, 400)}))
             # the shapes of mellum2-12b-a2.5b: 32 query heads over 4 KV
             # heads of 128, window 1024, 64 experts of 896 top-8
             self.gqa = dict(hq=32, hkv=4, d=128, window=1024, block=16,
@@ -336,6 +360,7 @@ def phase_kernels(smoke):
                                    rtol=0, atol=5e-2)
         out["max_abs_err"][f"paged_{kv_dtype}"] = _max_err(got, ref)
     out["paged_timed"] = _time_paged_decode(sz)
+    out["paged_micro"] = _paged_microbench(sz)
     out["grouped_window"] = _grouped_window_kernels(sz)
     return out
 
@@ -434,20 +459,45 @@ def _grouped_window_kernels(sz):
     return {"shape": g, "max_abs_err": err, "second_call_s": secs}
 
 
+def _kernel_events(run, names):
+    """``run()`` under a profiler trace: for each of ``names`` the device
+    microseconds of its custom calls on the ``XLA Ops`` line, in the
+    order they ran (empty without a device plane: a rehearsal)."""
+    import glob
+    import tempfile
+    import jax
+    from jax.profiler import ProfileData
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            run()
+        path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        events = sorted(
+            ((ev.start_ns, ev.name, ev.duration_ns / 1e3)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/device:TPU")
+             for line in plane.lines if line.name == "XLA Ops"
+             for ev in line.events if "custom-call" in ev.name))
+    return {name: [us for _, ev, us in events if name in ev]
+            for name in names}
+
+
+def _median(xs):
+    import statistics
+    return statistics.median(xs) if xs else None
+
+
 def _time_paged_decode(sz, calls=20):
     """Device microseconds of one ``paged_attention_decode`` call and of
     one ``paged_kv_append`` call at ``sz.paged_timed`` over the stored
     pool, positions log-uniform over its range: each kernel alone, from
     the ``XLA Ops`` line of a profiler trace. A rehearsal runs the same
     calls and has no device time to report."""
-    import glob
-    import statistics
-    import tempfile
     import jax
     import jax.numpy as jnp
-    from jax.profiler import ProfileData
     from paddle_tpu.kernels.paged_attention import (
-        decode_grid, paged_attention, paged_kv_append, stored_shape)
+        blocks_per_step, paged_attention, paged_kv_append, row_steps,
+        stored_shape)
     t = sz.paged_timed
     B, H, nblk, bs, D = (t[k] for k in ("rows", "heads", "blocks", "block",
                                         "d_head"))
@@ -472,34 +522,141 @@ def _time_paged_decode(sz, calls=20):
         pool, new, ids, offs, interpret=interpret), donate_argnums=(0,))
     jax.block_until_ready(read(q, *pools, tables, pos))
     pools[0] = jax.block_until_ready(append(pools[0], new, ids, offs))
-    with tempfile.TemporaryDirectory() as trace_dir:
-        with jax.profiler.trace(trace_dir):
-            for _ in range(calls):
-                jax.block_until_ready(read(q, *pools, tables, pos))
-                pools[0] = jax.block_until_ready(
-                    append(pools[0], new, ids, offs))
-        path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                       "*.xplane.pb"))
-        events = [ev for plane in ProfileData.from_file(path).planes
-                  if plane.name.startswith("/device:TPU")
-                  for line in plane.lines if line.name == "XLA Ops"
-                  for ev in line.events]
-    us = {name: [ev.duration_ns / 1e3 for ev in events if name in ev.name
-                 and "custom-call" in ev.name]
-          for name in ("paged_attention_decode", "paged_kv_append")}
-    grid, G = decode_grid(B, H, bs, D, jnp.bfloat16, nblk)
 
-    def median(xs):
-        return statistics.median(xs) if xs else None
+    def run():
+        for _ in range(calls):
+            jax.block_until_ready(read(q, *pools, tables, pos))
+            pools[0] = jax.block_until_ready(
+                append(pools[0], new, ids, offs))
 
+    us = _kernel_events(run, ("paged_attention_decode", "paged_kv_append"))
+    G = blocks_per_step(H, bs, D, jnp.bfloat16, nblk)
     return {"shape": {k: v for k, v in t.items() if k != "pos"},
             "stored_shape": list(shape),
             "positions": [int(pos.min()), int(pos.max())],
             "live_blocks": int(live.sum()), "table_blocks": B * nblk,
-            "grid_steps": math.prod(grid), "blocks_per_step": G,
+            "grid_steps": B, "blocks_per_step": G,
+            "kernel_steps": int(row_steps(np.asarray(pos), bs, G,
+                                          nblk)[2].sum()),
             "calls": len(us["paged_attention_decode"]),
-            "device_us_per_call": median(us["paged_attention_decode"]),
-            "append_device_us_per_call": median(us["paged_kv_append"])}
+            "device_us_per_call": _median(us["paged_attention_decode"]),
+            "append_device_us_per_call": _median(us["paged_kv_append"])}
+
+
+def _paged_microbench(sz, chain=8, reps=5):
+    """``paged_attention_decode`` alone at each shape of
+    ``sz.paged_micro`` (the serve cells' decode steps, bf16 pool) under
+    three kinds of bank: every row a free slot (``pos`` 0, a table of
+    trash), every row the table's whole width, and the cell's own mix of
+    live rows and free slots. An executable runs ``chain`` calls back to
+    back, as a decode step runs its layers' (each call's query is the
+    last one's result added to the first query). A row a case: device
+    microseconds a call (median over ``reps`` runs of the chain, from a
+    profiler trace; None in a rehearsal) and of a chain's first call
+    (the device was idle before it), microseconds a walked step (a step
+    for every G live blocks of a row), the HBM time of the live blocks'
+    bytes and their share of the call. The mix is also compared with
+    the oracle over its live rows."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import (
+        _xla_paged_attention, blocks_per_step, paged_attention, row_steps,
+        to_stored)
+    from paddle_tpu.observability import utilization
+    rng = np.random.default_rng(0)
+    cases, runs = [], []
+    for t in sz.paged_micro:
+        B, hq, hkv, D, bs, nblk = (t[k] for k in (
+            "rows", "hq", "hkv", "d", "block", "table"))
+        window = t.get("window")
+        N = B * nblk + 1
+        keys = jax.random.split(jax.random.PRNGKey(len(cases)), 3)
+        kp, vp = (jax.random.normal(k, (N, hkv, bs, D), jnp.bfloat16)
+                  for k in keys[:2])
+        q = jax.random.normal(keys[2], (B, hq, 1, D), jnp.float32)
+        ks, vs = to_stored(kp), to_stored(vp)
+        def read(q, k, v, tables, pos, window=window, hkv=hkv):
+            return paged_attention(q, k, v, tables, pos, impl=sz.kernel_impl,
+                                   kv_heads=hkv, window=window)
+
+        def chained(q, *args, read=read):
+            out = read(q, *args)
+            for _ in range(chain - 1):
+                out = read(q + out, *args)
+            return out
+
+        read, chained = jax.jit(read), jax.jit(chained)
+        G = blocks_per_step(hkv, bs, D, jnp.bfloat16, nblk, window)
+        whole = max(t["mixes"].values(), key=lambda m: m[2])[2] \
+            if window else nblk
+        mixes = {"free": (0, 0, 0), "whole": (B, whole, whole), **t["mixes"]}
+        for mix, (n_live, lo, hi) in mixes.items():
+            ctx = np.zeros(B, np.int64)
+            ctx[:n_live] = rng.integers(lo, hi + 1, n_live)
+            pos = np.maximum(ctx * bs - 1 - rng.integers(0, bs, B), 0)
+            pos[n_live:] = 0
+            held = np.minimum(ctx, nblk)       # columns a row has blocks in
+            tables = np.zeros((B, nblk), np.int32)
+            tables[np.arange(nblk) < held[:, None]] = \
+                rng.permutation(np.arange(1, N))[:held.sum()]
+            _, live, steps = (np.where(ctx > 0, x, 0)
+                              for x in row_steps(pos, bs, G, nblk, window))
+            args = (q, ks, vs, jnp.asarray(tables),
+                    jnp.asarray(pos, jnp.int32))
+            got = jax.block_until_ready(read(*args))
+            err = None
+            if mix in t["mixes"]:
+                with jax.default_matmul_precision("highest"):
+                    ref = jax.jit(lambda q, k, v, tables, pos, window=window:
+                                  _xla_paged_attention(
+                                      q, k, v, tables, pos, None, None,
+                                      float(D) ** -0.5, window))(
+                        q, kp, vp, *args[3:])
+                # bf16 MXU passes against a float32 oracle (phase_kernels)
+                np.testing.assert_allclose(
+                    np.asarray(got)[:n_live], np.asarray(ref)[:n_live],
+                    rtol=0, atol=5e-2, err_msg=f"{t['name']} {mix}")
+                err = _max_err(got[:n_live], ref[:n_live])
+            nbytes = int(live.sum()) * 2 * hkv * bs * D * 2
+            cases.append({
+                "shape": t["name"], "mix": mix, "live_rows": int(n_live),
+                "live_blocks": int(live.sum()), "blocks_per_step": G,
+                "walked_steps": int(steps.sum()),
+                "bytes": nbytes, "max_abs_err": err})
+            jax.block_until_ready(chained(*args))
+            runs.append((chained, args))
+
+    def run():
+        for chained, args in runs:
+            for _ in range(reps):
+                jax.block_until_ready(chained(*args))
+
+    us = _kernel_events(run, ("paged_attention_decode",))[
+        "paged_attention_decode"]
+    peak = utilization.hbm_peak()
+    calls = chain * reps
+    if us:
+        assert len(us) == calls * len(cases), (len(us), len(cases))
+    for i, case in enumerate(cases):
+        mine = us[i * calls:(i + 1) * calls]
+        t = _median(mine)
+        case["device_us_first_call"] = _median(mine[::chain])
+        nbytes = case.pop("bytes")
+        hbm_us = nbytes / peak * 1e6 if peak else None
+        case.update(
+            device_us_per_call=t,
+            us_per_walked_step=None if not t or not case["walked_steps"]
+            else round(t / case["walked_steps"], 3),
+            hbm_us=None if hbm_us is None else round(hbm_us, 2),
+            bandwidth_bound_share=None if not t or hbm_us is None
+            else round(hbm_us / t, 4))
+    cols = ("shape", "mix", "live_rows", "live_blocks", "blocks_per_step",
+            "walked_steps", "device_us_per_call", "device_us_first_call",
+            "us_per_walked_step", "hbm_us", "bandwidth_bound_share")
+    print("\n".join(["paged_attention_decode alone:", "  ".join(cols)] + [
+        "  ".join(str(case[c]) for c in cols) for case in cases]),
+          file=sys.stderr, flush=True)
+    return cases
 
 
 # -------------------------------------------------------------------- train
@@ -908,13 +1065,23 @@ def phase_mesh(smoke):
 
 # --------------------------------------------------------------------- main
 
+_PHASES = {"kernels": phase_kernels, "train": phase_train,
+           "serve": phase_serve, "loop": phase_loop, "mesh": phase_mesh}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument(
         "--rehearse-cpu", action="store_true",
         help="dry run on the CPU at GPTConfig.tiny() with interpreted "
              "kernels; every line says rehearsal and none says pass")
+    ap.add_argument(
+        "phases", nargs="*", metavar="phase",
+        help=f"run these alone, of {', '.join(_PHASES)} (default: all; "
+             f"mesh needs four devices)")
     args = ap.parse_args(argv)
+    if set(args.phases) - set(_PHASES):
+        ap.error(f"no such phase: {sorted(set(args.phases) - set(_PHASES))}")
 
     import jax
     platform = jax.devices()[0].platform
@@ -949,12 +1116,10 @@ def main(argv=None):
     print(json.dumps({"phase": "start", **smoke.device, **smoke.versions,
                       "rehearsal_not_a_chip_run": sizes.rehearsal,
                       "compile_cache_dir": cache.directory}), flush=True)
-    smoke.run_phase("kernels", phase_kernels)
-    smoke.run_phase("train", phase_train)
-    smoke.run_phase("serve", phase_serve)
-    smoke.run_phase("loop", phase_loop)
-    if smoke.device["count"] >= 4:
-        smoke.run_phase("mesh", phase_mesh)
+    for name, fn in _PHASES.items():
+        if name in (args.phases or _PHASES) and (
+                name != "mesh" or smoke.device["count"] >= 4):
+            smoke.run_phase(name, fn)
 
     failed = sorted(n for n, s in smoke.results.items() if s == "fail")
     if sizes.rehearsal:

@@ -4,29 +4,36 @@ The decode half of the flash-attention story (kernels/flash_attention.py
 fused prefill): one query token per row attends over that row's KV cache
 stored as BLOCKS of a shared pool (vLLM/PagedAttention, Kwon et al.
 2023) instead of a dense per-slot ``[B, H, max_len, D]`` bank. The
-block-table gather IS the kernel's index map — each grid step's
-``BlockSpec``s resolve their blocks from a scalar-prefetched table, so
-the gather and the attention read are one fused pass over VMEM-resident
-blocks and the ``[B, max_len]`` dense cache is never materialized (decode
-is bandwidth-bound: bytes streamed per token IS the token rate).
+block-table gather IS the kernel's walk: the pool stays in HBM and each
+row's live blocks come to VMEM by DMA as the row's table names them, so
+the gather and the attention read are one fused pass and the
+``[B, max_len]`` dense cache is never materialized (decode is
+bandwidth-bound: bytes streamed per token IS the token rate).
 
 Two implementations, same math:
 
-- ``pallas``: grid ``(B, cdiv(blocks_per_row, G))``. A step fetches G
-  consecutive table entries of a row as G whole ``[H, block_size, D]``
-  tiles of K and of V (G pool operands each, 256 KB and more a step at
-  serving shapes) and folds all H heads of them at once into the row's
-  online-softmax state (m, l, acc in VMEM scratch carried across the
-  row's steps). G follows from H, block_size, D, the pool's dtype and
-  the table's width (:func:`blocks_per_step`). Past a row's last live
-  block ``pos[b] // block_size`` every operand's block index stands
-  still (:func:`_live_tables`), so a dead step costs neither a DMA nor
-  a fold — table padding rides the same skip. int8 blocks are
-  dequantized against their per-slot scales as they leave VMEM.
-  ``interpret`` runs the SAME kernel through the Pallas interpreter on
-  CPU. What a step costs on a v5e (PERF.md, PR 27): 0.04 us for each
-  operand whose index map reads the table, whether it moves or not;
-  the tiles' bytes and the fold are a tenth of that at 12% live.
+- ``pallas``: grid ``(B,)``, a grid step a row, the block table and
+  ``pos`` scalar-prefetched, the pool arrays left in HBM. The kernel
+  reads the row's trip count from ``pos`` (:func:`row_steps`: a step
+  for every G of the blocks up to ``pos[b] // block_size``, from the
+  first block a window still reaches) and runs a loop of that many
+  steps. A step waits for G table entries of the row as G whole
+  ``[H, block_size, D]`` tiles of K and of V (256 KB and more at
+  serving shapes) in one half of a double buffer, starts the next
+  step's copies into the other half, and folds all H heads of its
+  tiles at once into the row's online-softmax state (m, l, acc: the
+  loop's carry). A row's last step starts the first copies of the next
+  row that holds blocks, so the pipe does not drain between rows. G
+  follows from H, block_size, D, the pool's dtype and the table's width
+  (:func:`blocks_per_step`). What is not live is not visited: a row
+  whose first live table entry is block 0 (the pool's trash block: a
+  free slot) costs an empty grid step and reads zeros, and a table's
+  tail past ``pos`` costs nothing, so a call's time follows the blocks
+  it reads. int8 blocks are dequantized against their per-slot scales
+  as they leave VMEM. ``interpret`` runs the SAME kernel through the
+  Pallas interpreter on CPU. What it replaced (PERF.md, PR 27 to 35): a
+  grid of ``(B, table width / G)`` steps of 2 + 2G BlockSpec operands,
+  each 0.04 to 0.09 us a step whether it moved or not.
 - ``xla``: a ``jnp.take``-based gather + masked softmax composite — the
   CPU-CI path and the parity oracle the kernel is tested against.
 
@@ -39,8 +46,11 @@ shares (absmax / 127 per head-token, zero-scale guarded).
 
 Layout: q ``[B, H, 1, D]`` (single decode step per row), block tables
 ``[B, blocks_per_row]`` int32 (entries past a row's allocation point at
-the reserved trash block — masked by ``pos``), pos ``[B]`` int32 (index
-of the query's own slot: key slot j is visible iff ``j <= pos[b]``).
+the reserved trash block 0 — masked by ``pos``; a row whose first live
+entry is block 0 holds nothing: the kernel gives it zeros, the
+composite whatever the trash block holds, and nobody reads either), pos
+``[B]`` int32 (index of the query's own slot: key slot j is visible iff
+``j <= pos[b]``).
 
 The pool's STORED shape (PERF.md 7.5, PR 29). Logically a pool array is
 ``[num_blocks, H, block_size, D]``; what the runtime holds, the append
@@ -167,8 +177,10 @@ def window_blocks(window, bs):
 
 
 def _first_block(pos, window, bs):
-    """The first logical block a row at ``pos`` still reads."""
-    return jnp.maximum(pos - (window - 1), 0) // bs
+    """The first logical block a row at ``pos`` still reads (plain
+    integers, numpy and jax arrays and a kernel's scalars alike)."""
+    first = (pos - (window - 1)) // bs
+    return first * (first > 0)
 
 
 def _xla_paged_attention(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
@@ -241,13 +253,16 @@ def _tile_bytes(H, bs, D, dtype):
         * _LANES * itemsize
 
 
-def blocks_per_step(H, bs, D, dtype, nblk):
-    """G, the consecutive table entries of a row that one grid step
-    fetches and folds: the largest power of two no wider than the table
-    whose K and V tiles, double-buffered, sit in ``_VMEM_BUDGET`` and
-    whose scores fit ``_SCORE_LANES`` (1 where a single block already
-    exceeds either)."""
+def blocks_per_step(H, bs, D, dtype, nblk, window=None):
+    """G, the consecutive table entries of a row that one step of the
+    kernel's walk fetches and folds: the largest power of two no wider
+    than the table (than the :func:`window_blocks` a ``window`` row can
+    read of its ring) whose K and V tiles, double-buffered, sit in
+    ``_VMEM_BUDGET`` and whose scores fit ``_SCORE_LANES`` (1 where a
+    single block already exceeds either)."""
     tile = _tile_bytes(H, bs, D, dtype)
+    if window is not None:
+        nblk = min(nblk, window_blocks(window, bs))
     g = 1
     while (2 * g <= nblk and 8 * g * tile <= _VMEM_BUDGET
            and 2 * g * H * bs <= _SCORE_LANES):
@@ -255,37 +270,19 @@ def blocks_per_step(H, bs, D, dtype, nblk):
     return g
 
 
-def decode_grid(B, H, bs, D, dtype, nblk):
-    """``(grid, G)`` of one ``paged_attention_decode`` call: a step for
-    every G table entries of every row."""
-    g = blocks_per_step(H, bs, D, dtype, nblk)
-    return (B, pl.cdiv(nblk, g)), g
-
-
-def _live_tables(tables, pos, bs, G, steps, window=None):
-    """``[B, steps * G]``: the block each (row, step, operand) fetches.
-    Entry (b, j * G + i) is ``tables[b, j * G + i]`` up to the row's last
-    live block ``pos[b] // bs``; past it, a live step's tail repeats that
-    last block (fetched again, masked in the fold) and a dead step
-    repeats the last live step's entries, so every operand's block index
-    stands still and Pallas elides the fetch. Computed here, once a
-    decode step, because an index map pays for its arithmetic at every
-    (operand, grid step): 0.09 us with the clamp inside it, 0.04 us as
-    one table read. With a ``window`` entry 0 is the row's first block
-    still in reach (:func:`_first_block`) and the table a ring."""
-    if window is None:
-        lo = 0
-        last = jnp.clip(pos // bs, 0, tables.shape[1] - 1)           # [B]
-    else:
-        lo = _first_block(pos, window, bs)
-        last = pos // bs - lo                      # live blocks less one
-    step = jnp.minimum(jnp.arange(steps, dtype=jnp.int32)[None, :],
-                       (last // G)[:, None])                   # [B, steps]
-    col = step[:, :, None] * G + jnp.arange(G, dtype=jnp.int32)
-    col = jnp.minimum(col, last[:, None, None]).reshape(-1, steps * G)
-    if window is not None:
-        col = (lo[:, None] + col) % tables.shape[1]
-    return jnp.take_along_axis(tables, col, axis=1)
+def row_steps(pos, bs, G, nblk, window=None):
+    """``(first, live, steps)`` of a row at ``pos`` in one
+    ``paged_attention_decode`` call: the first block it reads (0, or the
+    first a ``window`` still reaches), the blocks it reads (up to
+    ``pos // bs``, of a table of ``nblk`` columns) and the steps its
+    walk takes, one for every G of them. The kernel's own trip count of
+    a row that holds blocks, written for plain integers, numpy arrays
+    and the kernel's scalars alike; the ``engine/step`` span sums it
+    over a step's rows as ``kernel_steps``."""
+    first = 0 if window is None else _first_block(pos, window, bs)
+    live = pos // bs + 1 - first
+    live = live - (live - nblk) * (live > nblk)        # min(live, nblk)
+    return first, live, (live + G - 1) // G
 
 
 def _slot_of(H, bs, G, rep=1, f=1, rows=None):
@@ -309,13 +306,24 @@ def _slot_of(H, bs, G, rep=1, f=1, rows=None):
          for p in range(f)], axis=0).astype(np.int32)
 
 
-def _paged_kernel(*refs, scale, bs, G, f, D, quant, window=None):
-    """Grid step (b, j) folds table entries ``[j * G, (j + 1) * G)`` of
-    row b, all H heads at once, into the row's online-softmax state. The
-    gather already happened in the index maps: ``refs`` hold G key
-    tiles, G value tiles of the stored ``[H * bs // f, f * D]`` each
-    (and with ``quant`` G + G scale tiles ``[f, H * bs // f]``), then
-    the output and the (m, l, acc) scratch.
+def _paged_kernel(tables_ref, pos_ref, q_ref, slot_of_ref, *refs, scale, bs,
+                  G, f, D, quant, window, count_folds):
+    """Grid step b is row b: the kernel walks the row's live blocks
+    itself. The pools (``refs``: K, V and with ``quant`` their scale
+    arrays) stay in HBM; step j of the row waits for table entries
+    ``[j * G, (j + 1) * G)`` counted from the row's first live block, G
+    stored ``[H * bs // f, f * D]`` tiles of K and of V (and G + G scale
+    tiles ``[f, H * bs // f]``) in one half of the ``[2, G, ...]`` VMEM
+    buffers, starts the next step's copies into the other half and
+    folds all H heads at once into the row's online-softmax state, the
+    loop's carry. The row's last step starts the first copies of the
+    next row that holds blocks, so the pipe stays full from row to row;
+    ``flight_ref`` (SMEM: the half the next step reads, and whether its
+    copies are under way) hands that over. Only live blocks are copied:
+    the tail of a row's last step keeps what the buffer held (V and its
+    scales are cleared once a call, so a masked probability of 0 never
+    meets a NaN). A row whose first live table entry is block 0, the
+    pool's trash block, holds nothing and costs a grid step of nothing.
 
     All heads fold in two matrix products. The query comes laid out f
     times (``[f * Hq, f * D]``: row ``p * Hq + h`` holds q[h] in the
@@ -326,98 +334,146 @@ def _paged_kernel(*refs, scale, bs, G, f, D, quant, window=None):
     exactly zero off a head's own columns, times the value rows are the
     weighted sums, of which row ``p * Hq + h`` is read in parity p's
     lanes alone. Each (parity, head) row keeps an online softmax of its
-    own (running max and sum in lane 0 of ``(f * Hq, 128)`` VMEM tiles,
-    the flash kernel's idiom: Mosaic stores vectors to VMEM, never
-    scalars) and the f parities of a head merge when the row ends. int8
+    own and the f parities of a head merge when the row ends. int8
     tiles stay unscaled: a key's scale multiplies its score and a
     value's its probability, column by column."""
-    # scalar prefetch: the live table (read by the index maps alone),
-    # pos and, with a window, the position of the table's first slot
-    pos_ref, first_ref = refs[1], (refs[2] if window is not None else None)
-    refs = refs[3 if window is not None else 2:]
-    q_ref, slot_of_ref, refs = refs[0], refs[1], refs[2:]
-    k_refs, v_refs, refs = refs[:G], refs[G:2 * G], refs[2 * G:]
-    ks_refs = vs_refs = None
-    if quant:
-        ks_refs, vs_refs, refs = refs[:G], refs[G:2 * G], refs[2 * G:]
-    out_ref, m_sc, l_sc, acc_sc = refs
-    b, j = pl.program_id(0), pl.program_id(1)
+    n = 4 if quant else 2
+    hbm, out_ref, refs = refs[:n], refs[n], refs[n + 1:]
+    folds_ref = None
+    if count_folds:
+        folds_ref, refs = refs[0], refs[1:]
+    bufs, (sems, flight_ref) = refs[:n], refs[n:]
+    b, B = pl.program_id(0), pl.num_programs(0)
+    nblk = tables_ref.shape[1]
     Hq = q_ref.shape[1] // f
 
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+    def span(r):
+        """Row r's first live block, its live blocks (0 where it holds
+        none) and its steps."""
+        first, live, steps = row_steps(pos_ref[r], bs, G, nblk, window)
+        held = tables_ref[r, first % nblk] != 0
+        return first, jnp.where(held, live, 0), jnp.where(held, steps, 0)
 
-    # the query's position counted from the table's first slot
-    p = pos_ref[b] if window is None else pos_ref[b] - first_ref[b]
+    def copies(act, r, first, live, j, half):
+        """``act`` ("start" or "wait") the copy of every live tile of
+        row r's step j into (out of) ``half``."""
+        for i in range(G):
+            @pl.when(j * G + i < live)
+            def _():
+                col = first + j * G + i
+                block = tables_ref[r, col if window is None else col % nblk]
+                for n, (src, dst) in enumerate(zip(hbm, bufs)):
+                    getattr(pltpu.make_async_copy(
+                        src.at[block], dst.at[half, i],
+                        sems.at[half, n]), act)()
 
-    def tiles(refs):
-        return jnp.concatenate([ref[0].astype(jnp.float32) for ref in refs],
-                               axis=0)                   # [G*H*bs/f, f*D]
+    first, live, steps = span(b)
 
-    def column_scales(refs):
-        """[f * Hq, G * H * bs / f]: the scale of the key (or value)
-        that each score column holds for each parity's rows."""
-        sc = jnp.concatenate([ref[0] for ref in refs], axis=1)
-        return jnp.concatenate(
-            [jnp.broadcast_to(sc[i:i + 1], (Hq, sc.shape[1]))
-             for i in range(f)], axis=0)
+    @pl.when(b == 0)
+    def _reset():
+        flight_ref[0] = 0
+        flight_ref[1] = 0
+        for buf in bufs[1::2]:          # V, and the values' scales
+            buf[...] = jnp.zeros_like(buf)
 
-    # dead-step skip: step j covers key slots [j*G*bs, (j+1)*G*bs);
-    # nothing there is visible once j*G*bs > pos[b], and nothing was
-    # fetched for it (_live_tables). Block-table padding (trash block 0)
-    # only ever appears PAST a row's allocation, so the same predicate
-    # and slot_of's compare keep garbage out of the state.
-    @pl.when(j * (G * bs) <= p)
-    def _fold():
-        s = jax.lax.dot_general(
-            q_ref[0].astype(jnp.float32), tiles(k_refs),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [f*Hq, G*H*bs/f]
-        if quant:
-            s = s * column_scales(ks_refs)
-        rel = p - j * (G * bs)          # the query's slot in this step
-        keep = slot_of_ref[...] <= rel
-        if window is not None:
-            keep = keep & (slot_of_ref[...] > rel - window)
-        s = jnp.where(keep, s, _NEG_INF)
-        m_prev = m_sc[:, :1]                                  # [f*Hq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        # a parity that has no visible key yet keeps m at _NEG_INF and
-        # would read exp(0) off its masked columns
-        pr = jnp.where(keep, jnp.exp(s - m_new), 0.0) if f > 1 \
-            else jnp.exp(s - m_new)
-        l_sc[:, :1] = l_sc[:, :1] * corr + jnp.sum(pr, axis=-1,
-                                                   keepdims=True)
-        m_sc[:, :1] = m_new
-        if quant:
-            pr = pr * column_scales(vs_refs)
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            pr, tiles(v_refs), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [f*Hq, f*D]
+    @pl.when(steps == 0)
+    def _free_slot():
+        out_ref[0] = jnp.zeros_like(out_ref[0])
+        if folds_ref is not None:
+            folds_ref[b] = 0
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        if f == 1:
-            l = l_sc[:, :1]
-            out_ref[0] = acc_sc[:] / jnp.where(l == 0.0, 1.0, l)
-            return
-        # merge a head's f parities: each weighs exp(m_p - max m); its
-        # sums stand in its own lanes, the caller adds the lane groups
-        ms = [m_sc[i * Hq:(i + 1) * Hq, :1] for i in range(f)]
-        m_all = functools.reduce(jnp.maximum, ms)
-        lane_group = jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, acc_sc.shape[1]), 1) // D
-        l = jnp.zeros((Hq, 1), jnp.float32)
-        acc = jnp.zeros((Hq, acc_sc.shape[1]), jnp.float32)
-        for i in range(f):
-            w = jnp.exp(ms[i] - m_all)
-            l = l + l_sc[i * Hq:(i + 1) * Hq, :1] * w
-            acc = acc + jnp.where(lane_group == i,
-                                  acc_sc[i * Hq:(i + 1) * Hq, :] * w, 0.0)
+    @pl.when(steps > 0)
+    def _row():
+        half0 = flight_ref[0]
+
+        @pl.when(flight_ref[1] == 0)
+        def _first_of_the_call():
+            copies("start", b, first, live, 0, half0)
+
+        # the next row that holds blocks (B: none)
+        nxt = jax.lax.while_loop(
+            lambda r: (r < B) & (span(jnp.minimum(r, B - 1))[2] == 0),
+            lambda r: r + 1, b + 1)
+        nxt_first, nxt_live, _ = span(jnp.minimum(nxt, B - 1))
+        # the query's position counted from the row's first live slot
+        p = pos_ref[b] - first * bs
+        qw = q_ref[0].astype(jnp.float32)
+
+        def rows_of(buf, half):
+            return buf[half].astype(jnp.float32).reshape(
+                G * buf.shape[2], buf.shape[3])           # [G*H*bs/f, f*D]
+
+        def column_scales(buf, half):
+            """[f * Hq, G * H * bs / f]: the scale of the key (or value)
+            that each score column holds for each parity's rows."""
+            tiles = buf[half]              # [G, f, R padded to the lanes]
+            sc = jnp.concatenate([tiles[i, :, :bufs[0].shape[2]]
+                                  for i in range(G)], axis=1)
+            return jnp.concatenate(
+                [jnp.broadcast_to(sc[i:i + 1], (Hq, sc.shape[1]))
+                 for i in range(f)], axis=0)
+
+        def fold(j, carry):
+            m_prev, l_prev, acc, folds = carry
+            half = (half0 + j) % 2
+
+            @pl.when(j + 1 < steps)
+            def _next_step():
+                copies("start", b, first, live, j + 1, 1 - half)
+
+            @pl.when((j + 1 == steps) & (nxt < B))
+            def _next_row():
+                copies("start", nxt, nxt_first, nxt_live, 0, 1 - half)
+
+            copies("wait", b, first, live, j, half)
+            s = jax.lax.dot_general(
+                qw, rows_of(bufs[0], half), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quant:                                   # [f*Hq, G*H*bs/f]
+                s = s * column_scales(bufs[2], half)
+            rel = p - j * (G * bs)          # the query's slot in this step
+            keep = slot_of_ref[...] <= rel
+            if window is not None:
+                keep = keep & (slot_of_ref[...] > rel - window)
+            s = jnp.where(keep, s, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # a parity that has no visible key yet keeps m at _NEG_INF
+            # and would read exp(0) off its masked columns
+            pr = jnp.where(keep, jnp.exp(s - m_new), 0.0) if f > 1 \
+                else jnp.exp(s - m_new)
+            l_new = l_prev * corr + jnp.sum(pr, axis=-1, keepdims=True)
+            if quant:
+                pr = pr * column_scales(bufs[3], half)
+            acc = acc * corr + jax.lax.dot_general(
+                pr, rows_of(bufs[1], half), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [f*Hq, f*D]
+            return m_new, l_new, acc, folds + 1
+
+        m, l, acc, folds = jax.lax.fori_loop(0, steps, fold, (
+            jnp.full((f * Hq, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((f * Hq, 1), jnp.float32),
+            jnp.zeros((f * Hq, f * D), jnp.float32), jnp.int32(0)))
+        flight_ref[0] = (half0 + steps) % 2
+        flight_ref[1] = (nxt < B).astype(jnp.int32)
+        if folds_ref is not None:
+            folds_ref[b] = folds
+        if f > 1:
+            # merge a head's f parities: each weighs exp(m_p - max m);
+            # its sums stand in its own lanes, the caller adds the lane
+            # groups
+            ms = [m[i * Hq:(i + 1) * Hq] for i in range(f)]
+            m_all = functools.reduce(jnp.maximum, ms)
+            lane_group = jax.lax.broadcasted_iota(
+                jnp.int32, (Hq, f * D), 1) // D
+            l_all = jnp.zeros((Hq, 1), jnp.float32)
+            acc_all = jnp.zeros((Hq, f * D), jnp.float32)
+            for i in range(f):
+                w = jnp.exp(ms[i] - m_all)
+                l_all = l_all + l[i * Hq:(i + 1) * Hq] * w
+                acc_all = acc_all + jnp.where(
+                    lane_group == i, acc[i * Hq:(i + 1) * Hq] * w, 0.0)
+            l, acc = l_all, acc_all
         out_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
 
 
@@ -425,11 +481,14 @@ def _paged_kernel(*refs, scale, bs, G, f, D, quant, window=None):
 # lowering of the call (24 reads and 48 appends in gpt2-medium's decode
 # step: 5.7 s of lowering without it, 1.2 with; XLA inlines the calls)
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "rep",
-                                             "window"))
+                                             "window", "count_folds"))
 def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
-                            v_scale, scale, interpret, rep, window=None):
+                            v_scale, scale, interpret, rep, window=None,
+                            count_folds=False):
     """The kernel over STORED pools ``[N, H * bs // f, f * D]`` (scales
-    ``[N, f, H * bs // f]``); ``rep`` query heads share a KV head."""
+    ``[N, f, H * bs // f]``); ``rep`` query heads share a KV head. With
+    ``count_folds`` (the tests' alone) it also returns the folds each
+    row made, ``[B]`` int32."""
     B, Hq, S, D = q.shape
     if S != 1:
         raise ValueError(
@@ -447,11 +506,7 @@ def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
             f"double-buffered take {4 * tile} of the kernel's "
             f"{_VMEM_BUDGET}: lower kv_block_size")
     quant = k_scale is not None
-    # a window row reads at most window_blocks of its ring, whatever the
-    # ring's width
-    nblk = tables.shape[1] if window is None \
-        else min(tables.shape[1], window_blocks(window, bs))
-    grid, G = decode_grid(B, H, bs, D, k_pool.dtype, nblk)
+    G = blocks_per_step(H, bs, D, k_pool.dtype, tables.shape[1], window)
     # f parities of Hp rows each; sublane-aligned where the merge slices
     Hp = Hq if f == 1 else pl.cdiv(Hq, 8) * 8
     slot_of = _slot_of(H, bs, G, rep, f, Hp)
@@ -460,53 +515,49 @@ def _pallas_paged_attention(q, k_pool, v_pool, tables, pos, k_scale,
     qw = (jnp.eye(f, dtype=q.dtype)[None, :, None, :, None]
           * qw[:, None, :, None, :]).reshape(B, f * Hp, C)
 
-    # index maps see the grid indices THEN the scalar-prefetch refs: the
-    # i-th pool operand's block for (b, j) is whatever the row's live
-    # table names at j * G + i — the fused gather
-    def row(b, j, *scalars):
+    def row(b, *scalars):
         return (b, 0, 0)
 
-    def block(i):
-        return lambda b, j, live, *_: (live[b, j * G + i], 0, 0)
-
-    def pool_specs(pool):
-        return [pl.BlockSpec((1,) + pool.shape[1:], block(i))
-                for i in range(G)]
-
-    in_specs = [pl.BlockSpec((1, f * Hp, C), row),
-                pl.BlockSpec(slot_of.shape, lambda b, j, *scalars: (0, 0))]
-    args = [qw, slot_of]
-    # whole stored tiles, and for int8 the block's whole [f, H*bs/f]
-    # scale tile: a block's last two dims divide (8, 128) or equal the
-    # array's
-    for pool in (k_pool, v_pool) + ((k_scale, v_scale) if quant else ()):
-        in_specs += pool_specs(pool)
-        args += [pool] * G
-
-    pos = pos.astype(jnp.int32)
-    scalars = [_live_tables(tables.astype(jnp.int32), pos, bs, G, grid[1],
-                            window), pos]
-    if window is not None:
-        scalars.append(_first_block(pos, window, bs) * bs)
+    pools = (k_pool, v_pool)
+    if quant:
+        # Mosaic copies whole lane tiles out of HBM: a scale tile whose
+        # rows are no multiple of 128 wide (12 heads) goes in padded
+        pad = ((0, 0), (0, 0), (0, -R % _LANES))
+        pools += (jnp.pad(k_scale, pad), jnp.pad(v_scale, pad))
+    out_shape = [jax.ShapeDtypeStruct((B, Hp, C), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, Hp, C), row)]
+    if count_folds:
+        out_shape.append(jax.ShapeDtypeStruct((B,), jnp.int32))
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hp, C), row),
-        scratch_shapes=[pltpu.VMEM((f * Hp, _LANES), jnp.float32),
-                        pltpu.VMEM((f * Hp, _LANES), jnp.float32),
-                        pltpu.VMEM((f * Hp, C), jnp.float32)],
+        num_scalar_prefetch=2,           # the block table as it is, and pos
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, f * Hp, C), row),
+                  pl.BlockSpec(slot_of.shape, lambda b, *scalars: (0, 0))]
+        + [pl.BlockSpec(memory_space=pltpu.HBM)] * len(pools),
+        out_specs=out_specs,
+        # both halves of a step's tiles a pool array, a DMA semaphore a
+        # (half, array), and what one row hands the next
+        scratch_shapes=[pltpu.VMEM((2, G) + pool.shape[1:], pool.dtype)
+                        for pool in pools]
+        + [pltpu.SemaphoreType.DMA((2, len(pools))),
+           pltpu.SMEM((2,), jnp.int32)],
     )
-    out = pl.pallas_call(
+    out, *folds = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=bs, G=G, f=f, D=D,
-                          quant=quant, window=window),
+                          quant=quant, window=window,
+                          count_folds=count_folds),
         name="paged_attention_decode", grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hp, C), jnp.float32),
+        out_shape=out_shape,
+        # a row's first copies may be started by the row before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*scalars, *args)
+    )(tables.astype(jnp.int32), pos.astype(jnp.int32), qw, slot_of, *pools)
     # a head's weighted sum: its f lane groups added
     out = out[:, :Hq].reshape(B, Hq, f, D).sum(axis=2)
-    return out.reshape(B, Hq, 1, D).astype(q.dtype)
+    out = out.reshape(B, Hq, 1, D).astype(q.dtype)
+    return (out, folds[0]) if count_folds else out
 
 
 # ---------------------------------------------------------- the one writer

@@ -1443,15 +1443,15 @@ class DecodeBatcher:
                 if rows:
                     slots = list(rows)
                     self._round_attrs(attrs, len(rows))
-                    # the paged kernel's grid against the blocks it
-                    # has to read: over slots * blocks_per_row, how
-                    # much of the table it no longer walks
-                    stepped.attrs["grid_steps"] = \
-                        self.engine.kernel_grid_steps
+                    # one paged kernel call of this step: its grid,
+                    # the steps its rows' walks take and the blocks
+                    # they read (kernel_steps * blocks a step over
+                    # live_blocks is the padding of the walks' tails)
+                    stepped.attrs["grid_steps"] = self.engine.slots
                     stepped.attrs.update(self.engine.loop_attrs)
-                    stepped.attrs["live_blocks"] = int(np.sum(
-                        self._pos[slots]
-                        // self.engine.pool.block_size + 1))
+                    stepped.attrs["kernel_steps"], \
+                        stepped.attrs["live_blocks"] = \
+                        self.engine.kernel_walk(self._pos[slots])
                     new = _Flight(self.engine.dispatch_step(
                         self._tok, self._pos, self._temp, self._topk,
                         from_host=self._from_host), rows, stepped.t0)

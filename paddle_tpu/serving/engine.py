@@ -9,8 +9,8 @@ the serving ``ExecutableCache`` — byte/entry-capped, counted, recordable
 device-resident and shared by every executable; feeds are the only
 per-call traffic.
 """
+import functools
 import json
-import math
 import os
 import time
 
@@ -464,7 +464,7 @@ class GenerationEngine:
                  prefix_cache=None):
         import jax
         from .kvpool import _np_pool_dtype
-        from ..kernels.paged_attention import decode_grid
+        from ..kernels.paged_attention import blocks_per_step, row_steps
         self.gen = generator
         self.slots = int(slots or flag("decode_slots"))
         self.stats = stats if stats is not None else generator.stats
@@ -479,16 +479,18 @@ class GenerationEngine:
             self.slots, block_size=kv_block_size,
             num_blocks=kv_pool_blocks, dtype=kv_dtype, name=pool_name,
             prefix_cache=prefix_cache)
-        # what one paged_attention_decode call of a decode step
-        # launches over the full layers' table (the engine/step
-        # span's grid_steps): the kernel's own function of the
-        # shapes a shard of it sees
-        grid, _ = decode_grid(
-            self.slots,
+        # one paged_attention_decode call of a decode step over the full
+        # layers' table: its grid is the slots, and a row's walk takes a
+        # step for every kernel_blocks_per_step of its live blocks
+        # (kernel_walk): the kernel's own functions of the shapes a
+        # shard of it sees
+        self.kernel_blocks_per_step = blocks_per_step(
             self.pool.num_heads // max(getattr(generator, "tp", 1), 1),
             self.pool.block_size, self.pool.d_head,
             _np_pool_dtype(self.pool.dtype), self.pool.blocks_per_row)
-        self.kernel_grid_steps = math.prod(grid)
+        self._row_steps = functools.partial(
+            row_steps, bs=self.pool.block_size,
+            G=self.kernel_blocks_per_step, nblk=self.pool.blocks_per_row)
         # a generator WITHOUT its own sink adopts the server's (stage
         # histograms land in server.stats()), and a sink a PREVIOUS
         # engine bound is rebound to the live server (else a reused
@@ -514,6 +516,14 @@ class GenerationEngine:
         # int32 [slots] on the device: what the last dispatched step
         # picked, the next step's tokens for the rows that were in it
         self._prev_tokens = None
+
+    def kernel_walk(self, pos):
+        """``(kernel_steps, live_blocks)`` of one ``paged_attention_decode``
+        call of a step over rows at ``pos`` (numpy): the steps their
+        walks take and the blocks they read, in the full layers'
+        table."""
+        _, live, steps = self._row_steps(pos)
+        return int(steps.sum()), int(live.sum())
 
     def _ensure_caches(self):
         self.bank_lost = False

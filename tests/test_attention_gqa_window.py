@@ -8,8 +8,8 @@ import numpy as np
 import pytest
 
 from paddle_tpu.kernels.flash_attention import flash_attention
-from paddle_tpu.kernels.paged_attention import (decode_grid,
-                                                paged_attention,
+from paddle_tpu.kernels.paged_attention import (blocks_per_step,
+                                                paged_attention, row_steps,
                                                 window_blocks)
 
 
@@ -117,6 +117,9 @@ def test_paged_ring_must_hold_the_window_and_groups_must_divide():
         paged_attention(jnp.ones((1, 3, 1, 8)), pool, pool, tables, pos,
                         impl="xla")
     assert window_blocks(1024, 16) == 65
-    # the grid of a window layer's call follows the ring, not the context
-    (rows, steps), g = decode_grid(32, 4, 16, 128, jnp.bfloat16, 65)
-    assert (rows, steps, g) == (32, 3, 32)
+    # the walk of a window layer's row follows the window, not the ring
+    # or the context: 65 blocks of a ring of 96, from the first in reach
+    g = blocks_per_step(4, 16, 128, jnp.bfloat16, 96, window=1024)
+    assert g == blocks_per_step(4, 16, 128, jnp.bfloat16, 65) == 32
+    assert row_steps(6000, 16, g, 96, window=1024) == (311, 65, 3)
+    assert row_steps(100, 16, g, 96, window=1024) == (0, 7, 1)

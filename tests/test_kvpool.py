@@ -207,8 +207,8 @@ def test_paged_attention_interpret_matches_xla_reference():
                                atol=1e-5)
 
 
-# the kernel's grid: (rows, table width / G) steps of G blocks, all heads
-# a step. H=16 takes the serve cell's tile (bs 16, D 64: G is 8 from the
+# the kernel's walk: a step for every G live blocks of a row, all heads a
+# step. H=16 takes the serve cell's tile (bs 16, D 64: G is 8 from the
 # score budget); H=2 takes a small one with the budget lowered to G = 4,
 # so both walk a table that is a multiple of G, one that is not, and one
 # narrower than the budget's G. H=16 and H=12 are D = 64 packed two
@@ -245,7 +245,7 @@ def _grid_positions(pattern, bs, nblk):
 @pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
 def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
                                           width, H):
-    """The grouped grid through the Pallas interpreter against the
+    """The kernel's walk through the Pallas interpreter against the
     gather composite: every pool type, positions at each edge of a block
     and of the table, alone and mixed in one batch, a free row (table
     all trash block 0) beside live ones, every way the table's width
@@ -258,10 +258,12 @@ def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
     bs, D, nblk = shape["bs"], shape["D"], shape["widths"][width]
     if shape["score_lanes"]:
         monkeypatch.setattr(pa, "_SCORE_LANES", shape["score_lanes"])
-    (_, steps), G = pa.decode_grid(1, H, bs, D, jnp.float32, nblk)
+    G = pa.blocks_per_step(H, bs, D, jnp.float32, nblk)
     assert G == {"multiple": nblk // 2, "ragged": 4 if H == 2 else 8,
                  "narrow": nblk}[width]
-    assert steps == -(-nblk // G)
+    # a row at the table's last slot walks the whole table
+    assert pa.row_steps(nblk * bs - 1, bs, G, nblk) == (
+        0, nblk, -(-nblk // G))
 
     pos, free = _grid_positions(pattern, bs, nblk)
     B, N = len(pos), len(pos) * nblk + 1
@@ -285,10 +287,94 @@ def test_paged_kernel_grid_matches_oracle(monkeypatch, kv_dtype, pattern,
         kp, vp = pa.to_stored(kp), pa.to_stored(vp)
         scales = {k: pa.scales_to_stored(v, D) for k, v in scales.items()}
         scales["kv_heads"] = H
-    out = pa.paged_attention(q, kp, vp, tables, pos, impl="interpret",
-                             **scales)
-    # a free row reads one slot of the trash block, like the oracle
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    out = np.asarray(pa.paged_attention(q, kp, vp, tables, pos,
+                                        impl="interpret", **scales))
+    # a free row is not walked: the kernel writes it zeros (the oracle
+    # reads it the trash block)
+    live = np.setdiff1d(np.arange(B), free)
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live], atol=1e-5)
+    assert not out[free].any()
+
+
+# the serve cells' decode shapes cut small: (query heads, KV heads, D,
+# block, table width, window, pool dtype) with the score budget lowered
+# so that a step is G blocks of a wider table
+_WALK_SHAPES = {
+    "gpt2-medium": (4, 4, 64, 4, 12, None, "bf16"),          # f = 2
+    "gpt2-medium-int8": (4, 4, 64, 4, 12, None, "int8"),
+    "ouro": (2, 2, 128, 4, 12, None, "bf16"),                # f = 1
+    "ouro-int8": (2, 2, 128, 4, 12, None, "int8"),
+    "mellum-full": (8, 1, 128, 8, 12, None, "bf16"),         # 8 a KV head
+    "mellum-window": (8, 1, 128, 4, 12, 32, "bf16"),         # a ring of 12
+}
+
+
+def _walk_rows(pattern, G, nblk, reach):
+    """Blocks of context a row (0: a free slot) of one pattern, ``reach``
+    the most blocks a row reads (the table, or the window's)."""
+    return {"one_block": [1], "one_step": [G], "step_and_a_block": [G + 1],
+            "whole_table": [nblk, reach],
+            "free_beside_live": [0, G + 1, 0, 1, 3 * nblk],
+            "no_live_row": [0, 0, 0]}[pattern]
+
+
+@pytest.mark.parametrize("pattern", [
+    "one_block", "one_step", "step_and_a_block", "whole_table",
+    "free_beside_live", "no_live_row"])
+@pytest.mark.parametrize("shape", list(_WALK_SHAPES))
+def test_paged_kernel_walks_live_blocks_only(monkeypatch, shape, pattern):
+    """The kernel that walks a row's blocks itself, through the TPU
+    interpreter (DMA semaphores counted, scratch memory NaN until
+    written): the oracle's numbers on every row that holds blocks, zeros
+    on a free slot, and as many folds a row as ``row_steps`` says, so a
+    free slot and a table's dead tail cost none."""
+    import importlib
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    pa = importlib.import_module("paddle_tpu.kernels.paged_attention")
+    hq, hkv, D, bs, nblk, window, kv_dtype = _WALK_SHAPES[shape]
+    monkeypatch.setattr(pa, "_SCORE_LANES", 2 * 2 * hkv * bs)
+    G = pa.blocks_per_step(hkv, bs, D, jnp.float32, nblk, window)
+    assert G == 4 and G < nblk
+    reach = nblk if window is None else pa.window_blocks(window, bs)
+    ctx = np.array(_walk_rows(pattern, G, nblk, reach))
+    if window is None:
+        ctx = np.minimum(ctx, nblk)
+    B, N = len(ctx), len(ctx) * nblk + 1
+    rng = np.random.default_rng(11)
+    pos = np.maximum(ctx * bs - 1 - rng.integers(0, bs, B), 0)
+    q = jnp.asarray(rng.normal(size=(B, hq, 1, D)).astype(np.float32))
+    kp, vp = (jnp.asarray(rng.normal(size=(N, hkv, bs, D)).astype(
+        np.float32)) for _ in range(2))
+    # a row holds blocks in the columns it has reached (a ring: in all
+    # of them once it has gone round); the rest is the trash block
+    held = np.minimum(ctx, nblk)
+    tables = np.zeros((B, nblk), np.int32)
+    tables[np.arange(nblk) < held[:, None]] = \
+        rng.permutation(np.arange(1, N))[:held.sum()]
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos.astype(np.int32))
+    ks = vs = None
+    if kv_dtype == "int8":
+        (kp, ks), (vp, vs) = pa.quantize_kv(kp), pa.quantize_kv(vp)
+    else:
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    ref = pa.paged_attention(q, kp, vp, tables, pos, ks, vs, impl="xla",
+                             window=window)
+    if ks is not None:
+        ks, vs = pa.scales_to_stored(ks, D), pa.scales_to_stored(vs, D)
+    out, folds = pa._pallas_paged_attention(
+        q, pa.to_stored(kp), pa.to_stored(vp), tables, pos, ks, vs,
+        float(D) ** -0.5, pltpu.InterpretParams(uninitialized_memory="nan"),
+        hq // hkv, window, count_folds=True)
+    out, live = np.asarray(out), ctx > 0
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live], atol=1e-5)
+    assert not out[~live].any()
+    _, blocks, steps = pa.row_steps(np.asarray(pos), bs, G, nblk, window)
+    assert np.array_equal(np.asarray(folds), np.where(live, steps, 0))
+    if window is None:
+        assert np.array_equal(blocks[live], ctx[live])
+    assert (blocks <= reach).all()
+    assert (steps[live] == -(-blocks[live] // G)).all()
 
 
 def test_paged_kernel_names_a_block_too_large_for_vmem():

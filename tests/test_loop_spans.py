@@ -157,19 +157,19 @@ def test_every_stepped_round_links_step_dispatch_and_wait(served):
 
 
 def test_a_decode_step_says_what_the_paged_kernel_walked(served):
-    """``engine/step`` carries the grid one ``paged_attention_decode``
-    call launches (the kernel's own function of the shapes) and the
-    blocks the live rows hold up to their positions: against slots x
-    blocks a row, the share of the table the kernel does not walk."""
+    """``engine/step`` carries the grid of one ``paged_attention_decode``
+    call (a grid step a slot), the steps its rows' walks take (the
+    kernel's own function of their positions) and the blocks the live
+    rows hold up to their positions: against slots x blocks a row, the
+    share of the table the kernel does not walk."""
     from paddle_tpu.flags import flag
-    from paddle_tpu.kernels.paged_attention import decode_grid
+    from paddle_tpu.kernels.paged_attention import blocks_per_step
     from paddle_tpu.models import gpt as gpt_mod
     import jax.numpy as jnp
     cfg = gpt_mod.GPTConfig.tiny()
     bs = int(flag("kv_block_size"))
-    (rows, steps), _ = decode_grid(
-        2, cfg.num_heads, bs, cfg.hidden_size // cfg.num_heads,
-        jnp.float32, -(-32 // bs))
+    G = blocks_per_step(cfg.num_heads, bs, cfg.hidden_size // cfg.num_heads,
+                        jnp.float32, -(-32 // bs))
     # the spans that sent a step (one that only reads the last step in
     # flight launches nothing)
     stepped = [s for s in rows_named(served["rows"], "engine/step")
@@ -177,11 +177,14 @@ def test_a_decode_step_says_what_the_paged_kernel_walked(served):
     assert len(stepped) >= 2
     for step in stepped:
         rnd, = [r for r in served["rows"] if r[SPAN] == step[PARENT]]
-        assert step[ATTRS]["grid_steps"] == rows * steps
+        assert step[ATTRS]["grid_steps"] == 2          # the slots
         # prompts of 4 to 6 and 3 new tokens at most: with the default
-        # block of 16 every live row is in its first block
-        assert bs < 16 or step[ATTRS]["live_blocks"] == rnd[ATTRS]["live"]
+        # block of 16 every live row is in its first block, one step
+        assert bs < 16 or step[ATTRS]["live_blocks"] == rnd[ATTRS]["live"] \
+            == step[ATTRS]["kernel_steps"]
         assert 1 <= step[ATTRS]["live_blocks"] <= rnd[ATTRS]["blocks_in_use"]
+        assert -(-step[ATTRS]["live_blocks"] // G) \
+            <= step[ATTRS]["kernel_steps"] <= step[ATTRS]["live_blocks"]
 
 
 def test_children_lie_inside_their_parents(served):
