@@ -32,6 +32,13 @@ seeded random weights:
            append runs inside the loop of passes) or its scatter, and a
            logits check of prefill then paged decode against the
            family's plain float32 reference
+  state    the hybrid block of benchmark configuration jamba2-3b (its
+           published widths, one period of three layers with its
+           attention layer, twice) through InferenceServer: no copy of a
+           pool array or of the slot bank of recurrent states in its
+           decode step or its scatter, and a logits check of prefill (the
+           scan kernel) then paged decode against the family's plain
+           float32 reference
   mesh     only with four or more devices: the train step under
            with_data_parallel over every chip, and tp=2 paged generation
 
@@ -93,6 +100,8 @@ class Sizes:
             self.kernel_impl = "interpret"
             self.loop = dict(layers=None, slots=2, prompt_lens=(5, 11, 18),
                              new_tokens=4)
+            self.state = dict(layers=None, slots=2, prompt_lens=(5, 11, 18),
+                              new_tokens=4)
         else:
             self.cfg = gpt.GPTConfig.base()
             self.batch, self.seq = 8, 2048
@@ -132,6 +141,10 @@ class Sizes:
             # and four passes, 2 of its 48 layers: 8 cache layers
             self.loop = dict(layers=2, slots=4,
                              prompt_lens=(33, 70, 100, 128), new_tokens=24)
+            # benchmark configuration jamba2-3b at its published widths:
+            # two periods of three layers, an attention layer in each
+            self.state = dict(layers=6, period=3, offset=1, slots=4,
+                              prompt_lens=(33, 70, 300, 520), new_tokens=24)
         self.max_len = self.cfg.max_position
 
 
@@ -961,6 +974,85 @@ def phase_loop(smoke):
             "logits_check": check, "peak_bytes_in_use": _peak_bytes()}
 
 
+# -------------------------------------------------------------------- state
+
+def phase_state(smoke):
+    """The hybrid block (``models/jamba.py``: Mamba-1 layers whose
+    recurrent state and convolution tail live a slot in the pool beside
+    the attention layers' paged keys and values) through
+    ``InferenceServer``: the decode step and the scatter leave no copy
+    of a block array or of the slot bank, and prefill (the scan kernel
+    over a padded bucket) then decode agree a step with the family's
+    plain float32 reference."""
+    import jax.numpy as jnp
+    from benchmark.families import jamba as fam
+    from paddle_tpu.flags import flag, set_flags
+    from paddle_tpu.serving import InferenceServer
+    sz, state = smoke.sizes, smoke.sizes.state
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", "jamba2-3b.json")) as fh:
+        config = json.load(fh)
+    if state["layers"]:
+        config.update(num_hidden_layers=state["layers"],
+                      attn_layer_period=state["period"],
+                      attn_layer_offset=state["offset"])
+    fsz = fam.Sizes(config, rehearsal=sz.rehearsal)
+    kv_dtype = flag("kv_cache_dtype")
+    try:
+        gen = fam.build_generator(
+            fsz, {"kv_cache_dtype": "bf16",
+                  "max_len": 64 if sz.rehearsal else 640}, seed=36)
+        server = InferenceServer(generator=gen, decode_slots=state["slots"],
+                                 loop_watchdog_s=600.0)
+        server.start(serve_network=False)
+        try:
+            rng = np.random.default_rng(0)
+            prompts = [rng.integers(1, fsz.vocab_size, n).astype(np.int32)
+                       for n in state["prompt_lens"]]
+            reqs = [server.submit_generate(
+                p, max_new_tokens=state["new_tokens"]) for p in prompts]
+            outs = [r.wait(timeout=1200)[0] for r in reqs]
+            stats = server.stats()
+            pool = server.gen_engine.pool
+            assert pool.blocks_in_use() == 0, pool.stats()
+        finally:
+            server.stop()
+        assert all(o.shape == (state["new_tokens"],) for o in outs)
+        assert stats["kvpool_state_layers"] == fsz.mamba_layers \
+            == pool.state_layers, stats["kvpool_state_layers"]
+        assert stats["kv_cache_layers"] == fsz.attention_layers
+        relayouts = stats["pool_relayouts"]
+        decode_kinds = [k for k in relayouts
+                        if k.startswith(f"decode_paged_{pool.dtype}+")]
+        assert sz.rehearsal or (
+            decode_kinds and all(relayouts[k] == 0 for k in decode_kinds)
+            and relayouts.get("scatter") == 0), relayouts
+        params = fam.init_params(fsz, 36)
+
+        def reference(seq):
+            return fam.reference_logits(fsz, params,
+                                        jnp.asarray(seq)[None])[0, -1]
+
+        # bfloat16 weights and activations against float32 at
+        # precision=highest through 6 layers whose mixers' outputs are
+        # ten times the embedding's scale: some 2e-2 of logits whose
+        # standard deviation is 1; a state taken at the bucket's end, a
+        # stale tail or a dropped inner norm moves them by tenths
+        check = _logits_check(sz, gen, reference=reference, tol=0.1)
+    finally:
+        set_flags({"kv_cache_dtype": kv_dtype})
+    return {"layers": fsz.num_hidden_layers,
+            "state_layers": int(stats["kvpool_state_layers"]),
+            "kv_cache_layers": int(stats["kv_cache_layers"]),
+            "state_bytes_per_slot": int(
+                stats["kvpool_state_bytes_per_slot"]),
+            "state_slot_writes": int(stats.get("state_slot_writes", 0)),
+            "scan_tokens": int(stats.get("scan_tokens", 0)),
+            "decode_steps": int(stats.get("decode_steps", 0)),
+            "replies": len(outs), "pool_relayouts": relayouts,
+            "logits_check": check, "peak_bytes_in_use": _peak_bytes()}
+
+
 # --------------------------------------------------------------------- mesh
 
 def _assert_per_shard(texts, global_shape, what, expect_kernel):
@@ -1066,7 +1158,8 @@ def phase_mesh(smoke):
 # --------------------------------------------------------------------- main
 
 _PHASES = {"kernels": phase_kernels, "train": phase_train,
-           "serve": phase_serve, "loop": phase_loop, "mesh": phase_mesh}
+           "serve": phase_serve, "loop": phase_loop, "state": phase_state,
+           "mesh": phase_mesh}
 
 
 def main(argv=None):

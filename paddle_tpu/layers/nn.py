@@ -959,6 +959,98 @@ def row_gather(x, index, name=None):
     return out
 
 
+def dense_acc32_nt(input, weight, name=None):
+    """``input`` [..., d] @ ``weight``^T for a matrix that is there
+    already and stored ``[n, d]`` (a head tied to the embedding table):
+    the input is rounded to the matrix's dtype, the product accumulates
+    in float32 and comes out float32."""
+    helper = LayerHelper("dense_acc32_nt", name=name)
+    out = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        type="dense_acc32_nt", inputs={"X": [input], "W": [weight]},
+        outputs={"Out": [out]}, attrs={}, infer_shape=False)
+    out.shape = tuple(input.shape[:-1] or ()) + (int(weight.shape[0]),)
+    out.dtype = "float32"
+    return out
+
+
+def causal_conv1d(input, kernel_size, tail=None, length=None,
+                  param_attr=None, bias_attr=None, name=None):
+    """Causal depthwise convolution over ``input`` [B, L, C] with a bias
+    and a silu (ops/ssm_ops.causal_conv1d): taps ``[kernel_size, C]``,
+    the last the current token's. ``tail`` [B, (kernel_size - 1) * C]
+    holds the inputs before the first (a served row's; zeros without),
+    ``length`` [B] int32 the real tokens of each right-padded row.
+    Returns ``(out [B, L, C] float32, new_tail)``: the inputs a later
+    call starts from, after each row's last real token, in ``tail``'s
+    dtype (float32 without one)."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    ch, k = int(input.shape[-1]), int(kernel_size)
+    w = helper.create_parameter(param_attr, shape=[k, ch], dtype="float32")
+    b = helper.create_parameter(
+        bias_attr, shape=[ch], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.0))
+    out = helper.create_variable_for_type_inference(dtype="float32")
+    tail_dtype = tail.dtype if tail is not None else "float32"
+    new_tail = helper.create_variable_for_type_inference(dtype=tail_dtype)
+    ins = {"X": [input], "W": [w], "Bias": [b]}
+    if tail is not None:
+        ins["Tail"] = [tail]
+    if length is not None:
+        ins["Length"] = [length]
+    helper.append_op(
+        type="causal_conv1d", inputs=ins,
+        outputs={"Out": [out], "NewTail": [new_tail]}, attrs={},
+        infer_shape=False)
+    out.shape = tuple(input.shape or ())
+    out.dtype = "float32"
+    new_tail.shape = tuple(input.shape[:1] or ()) + ((k - 1) * ch,)
+    new_tail.dtype = tail_dtype
+    return out, new_tail
+
+
+def selective_scan(x, delta, z, b, c, state=None, length=None,
+                   param_attr=None, name=None):
+    """The selective state-space recurrence of a Mamba-1 mixer
+    (ops/ssm_ops.selective_scan; kernels/selective_scan.py): ``x``,
+    ``delta`` (the step before its bias and softplus) and the gate ``z``
+    [B, L, C], the token's maps ``b`` and ``c`` [B, L, N]. Creates the
+    float32 parameters ``a_log`` [C, N], ``d`` [C] and ``dt_bias`` [C]
+    (``param_attr`` maps those three names to a ParamAttr each).
+    ``state`` [B, N, C] is a served row's (zeros without), ``length``
+    [B] int32 the real tokens of each right-padded row. Returns ``(out
+    [B, L, C] float32, new_state [B, N, C] float32)``."""
+    helper = LayerHelper("selective_scan", name=name)
+    attr = param_attr or {}
+    ch, n = int(x.shape[-1]), int(b.shape[-1])
+    a_log = helper.create_parameter(
+        attr.get("a_log"), shape=[ch, n], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.0))
+    d_skip = helper.create_parameter(
+        attr.get("d"), shape=[ch], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(1.0))
+    dt_bias = helper.create_parameter(
+        attr.get("dt_bias"), shape=[ch], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.0))
+    out = helper.create_variable_for_type_inference(dtype="float32")
+    new_state = helper.create_variable_for_type_inference(dtype="float32")
+    ins = {"X": [x], "Delta": [delta], "Z": [z], "B": [b], "C": [c],
+           "ALog": [a_log], "D": [d_skip], "DtBias": [dt_bias]}
+    if state is not None:
+        ins["State"] = [state]
+    if length is not None:
+        ins["Length"] = [length]
+    helper.append_op(
+        type="selective_scan", inputs=ins,
+        outputs={"Out": [out], "NewState": [new_state]}, attrs={},
+        infer_shape=False)
+    out.shape = tuple(x.shape or ())
+    out.dtype = "float32"
+    new_state.shape = tuple(x.shape[:1] or ()) + (n, ch)
+    new_state.dtype = "float32"
+    return out, new_state
+
+
 def sample_tokens(logits, temperature, top_k=None, seed=0, name=None):
     """Next-token selection over ``logits`` [B, V] with per-row sampling
     config: ``temperature`` [B] float32 (<= 0 -> greedy argmax), optional

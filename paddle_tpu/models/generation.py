@@ -446,9 +446,9 @@ class GPTGenerator:
         if "cache_vars" in outs:            # paged decode: pool arrays
             return ([outs["logits"].name]
                     + [v.name for v in outs["cache_vars"]] + aux)
-        return ([outs["logits"].name]
-                + [v.name for v in outs.get("cache_k", ())]
-                + [v.name for v in outs.get("cache_v", ())] + aux)
+        kv = [v for k in ("cache_k", "cache_v") for v in outs.get(k, ())]
+        return [outs["logits"].name] + [v.name for v in kv + list(
+            outs.get("cache_state", {}).values())] + aux
 
     def aux_of(self, kind, fetches):
         """``{name: array}`` of what the ``kind`` program fetched beside
@@ -732,13 +732,13 @@ class GPTGenerator:
     # -- stage runners ----------------------------------------------------
     def _unpack_caches(self, kind, fetches):
         """Fetch layout of the cache-bearing programs (_fetch_names):
-        logits at 0, then cache_k_0..n-1, then cache_v_0..n-1, as many
-        as the architecture's ``kind`` program hands back."""
-        n = len(self._ensure_prog(kind)[1]["cache_k"])
-        caches = {}
-        for i in range(n):
-            caches[f"cache_k_{i}"] = fetches[1 + i]
-            caches[f"cache_v_{i}"] = fetches[1 + n + i]
+        logits at 0, then cache_k_0..n-1, cache_v_0..n-1 and what its
+        ``cache_state`` names, as the ``kind`` program hands them back."""
+        outs = self._ensure_prog(kind)[1]
+        n = len(outs["cache_k"])
+        caches = dict(zip(outs.get("cache_state", ()), fetches[1 + 2 * n:]))
+        caches.update({f"cache_{c}_{i}": fetches[1 + j * n + i]
+                       for j, c in enumerate("kv") for i in range(n)})
         return fetches[0], caches
 
     def _run_prefill(self, tokens, pos_ids, last_pos, key, kv_dtype=None,
@@ -756,9 +756,8 @@ class GPTGenerator:
 
     def new_pool(self, slots, **kw):
         """A :class:`serving.kvpool.KVBlockPool` laid out for this
-        architecture: its KV heads, head width and layer groups, which
-        name its cache layers (more than its weight layers where a
-        stack is run several times)."""
+        architecture: its KV heads, head width and layer groups (cache
+        layers, not weight layers; a state group's are not KV layers)."""
         from ..serving.kvpool import KVBlockPool
         arch = self.arch
         if kw.get("dtype") and kw["dtype"] not in arch.kv_dtypes:
@@ -766,7 +765,8 @@ class GPTGenerator:
                                        f"{kw['dtype']} KV pool")
         groups = arch.kv_groups()
         pool = KVBlockPool(
-            slots=slots, num_layers=sum(len(g["layers"]) for g in groups),
+            slots=slots, num_layers=sum(
+                len(g["layers"]) for g in groups if not g.get("state")),
             num_heads=arch.kv_heads, d_head=arch.head_dim,
             max_seq_len=self.max_len, groups=groups, **kw)
         return self.apply_pool_sharding(pool)

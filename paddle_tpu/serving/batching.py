@@ -1448,7 +1448,7 @@ class DecodeBatcher:
                     # they read (kernel_steps * blocks a step over
                     # live_blocks is the padding of the walks' tails)
                     stepped.attrs["grid_steps"] = self.engine.slots
-                    stepped.attrs.update(self.engine.loop_attrs)
+                    stepped.attrs.update(self.engine.step_attrs(len(rows)))
                     stepped.attrs["kernel_steps"], \
                         stepped.attrs["live_blocks"] = \
                         self.engine.kernel_walk(self._pos[slots])

@@ -506,13 +506,13 @@ class GenerationEngine:
         self.bank_lost = False     # see _drop_bank
         self.step_routing = {}     # the last step's moe_* span attrs
         # what every executable of this architecture runs a token: passes
-        # over its weights (more than 1 where a stack of layers is run
-        # several times) and the pool's cache layers (more than its
-        # weight layers there); on the engine/step, generator/prefill
-        # and pool/scatter spans
-        self.loop_attrs = {
-            "ut_steps": int(getattr(generator.arch, "ut_steps", 1)),
-            "cache_layers": self.pool.num_layers}
+        # over its weights (more than 1 where a stack is run several
+        # times), the pool's cache layers and, where it has a state group,
+        # that group's layers; on the engine/step, generator/prefill and
+        # pool/scatter spans
+        self.loop_attrs = dict(
+            ut_steps=int(getattr(generator.arch, "ut_steps", 1)),
+            cache_layers=self.pool.num_layers, **self.pool.state_attrs())
         # int32 [slots] on the device: what the last dispatched step
         # picked, the next step's tokens for the rows that were in it
         self._prev_tokens = None
@@ -685,9 +685,9 @@ class GenerationEngine:
             toks, self._key = self.gen._run_sample(logits, temp, topk,
                                                    self._key)
         # (rows, blocks) is the pair the scatter's jit retraces on
-        with span("pool/scatter", rows=n,
+        with span("pool/scatter", rows=n, cache_layers=self.pool.num_layers,
                   blocks=self.pool.blocks_for_tokens(tokens.shape[1]),
-                  cache_layers=self.pool.num_layers):
+                  **self.pool.state_attrs()):
             try:
                 maybe_fail("serving.slot_insert")
                 self.pool.scatter_prefill(
@@ -710,7 +710,7 @@ class GenerationEngine:
                 self.pool.prefix_insert(req.prompt, slot)
         with span("engine/fetch", rows=n):
             out = np.asarray(toks)[:n]
-            prefilled.attrs.update(self._count_routing(aux, rows=n))
+            prefilled.attrs.update(self._admitted(aux, requests, tokens))
         t1 = time.perf_counter()
         for req in requests:
             if getattr(req, "trace", None) is not None:
@@ -1041,3 +1041,29 @@ class GenerationEngine:
             np.ascontiguousarray(top_k, dtype=np.int32), nd, self._key)
         with _trace.loop_span("engine/fetch"):
             return np.asarray(out), np.asarray(acc)
+
+    def _admitted(self, aux, requests, tokens):
+        """What an admission's ``generator/prefill`` span gains:
+        :meth:`_count_routing`'s attrs and, over a pool with a state
+        group, ``prompt_tokens`` (the real ones) and ``scan_tokens``
+        (admitted rows x the bucket's length: what the recurrence was
+        given to walk), counted into ``scan_tokens`` and
+        ``state_slot_writes`` (a row's state written into its slot)."""
+        attrs = self._count_routing(aux, rows=len(requests))
+        if self.pool.state_layers:
+            attrs["prompt_tokens"] = int(sum(r.prompt.size
+                                             for r in requests))
+            attrs["scan_tokens"] = len(requests) * int(tokens.shape[1])
+            if self.stats:
+                self.stats.bump("scan_tokens", attrs["scan_tokens"])
+                self.stats.bump("state_slot_writes", len(requests))
+        return attrs
+
+    def step_attrs(self, live_rows):
+        """What the ``engine/step`` span of a step sent over
+        ``live_rows`` rows gains: ``loop_attrs`` and, over a pool with a
+        state group, ``state_rows``, the live rows whose state the step
+        advances (it advances the free slots' too, which nobody reads)."""
+        if not self.pool.state_layers:
+            return self.loop_attrs
+        return dict(self.loop_attrs, state_rows=int(live_rows))

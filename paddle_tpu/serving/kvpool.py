@@ -45,6 +45,19 @@ A block id then stands for that block in every cache layer, as it does
 with one pass; the executables add the offset. The prefix cache and
 migration are off for such a pool too.
 
+Not everything a row keeps is keys and values. A ``state`` group's layers
+(a state-space mixer's: a recurrent state and a convolution's tail, the
+same size whatever the context's length) keep arrays a layer indexed by
+SLOT: ``[slots, ...]`` beside the block arrays, no table and no blocks,
+in the one ``arrays()`` dict under ``cache_s<tag>_<m>``
+(:func:`state_array_specs`). They are donated into the decode step and
+adopted back with the block arrays, written by the prefill scatter for
+the admitted slots in the same donated call, dropped and reset with
+them, and left alone by ``free_slot`` (the next admission overwrites
+them). A row's state cannot be cut at a shared prefix or moved without a
+snapshot nobody takes yet: the prefix cache and migration are off for
+such a pool too.
+
 Device side: lazily-built jnp pool arrays (float32 / bfloat16 / int8
 with per-(block, head, slot) float32 scales — ``FLAGS_kv_cache_dtype``;
 at bandwidth-bound decode, halving cache bytes is ~2x tokens/s), a
@@ -208,9 +221,10 @@ def count_pool_relayouts(hlo_text, element_counts):
 
 def pool_element_counts(arrays):
     """The element counts :func:`count_pool_relayouts` looks for: those
-    of the ``cache_p*`` arrays of a feed or of a pool."""
+    of the ``cache_p*`` block arrays and ``cache_s*`` per-slot state
+    arrays of a feed or of a pool."""
     return {int(math.prod(a.shape)) for n, a in arrays.items()
-            if n.startswith("cache_p")}
+            if n.startswith(("cache_p", "cache_s"))}
 
 
 class _PoolJit:
@@ -265,6 +279,17 @@ def pool_feed_names(num_layers, quantized):
     return names
 
 
+def state_array_specs(groups):
+    """``{feed name: (shape a slot, dtype)}`` of the per-slot arrays of
+    ``groups``' state group (an architecture's ``kv_groups()``): array
+    ``tag`` of the group's layer ``m`` is ``cache_s<tag>_<m>``, every
+    layer's first array, then every layer's second: the order in which
+    they follow :func:`pool_feed_names`' in every feed and fetch list."""
+    return {f"cache_s{tag}_{m}": spec
+            for g in groups or () if g.get("state")
+            for tag, spec in g["arrays"].items() for m in g["layers"]}
+
+
 def prompt_prefix_key(tokens, length=None):
     """Content hash of the first ``length`` tokens of a prompt (the
     whole prompt when ``length`` is None) — the ONE prefix key the
@@ -298,10 +323,10 @@ def decode_feed(pool, token, pos):
 def adopt_decode_fetches(pool, fetches):
     """Adopt a paged decode step's fetched (donated-in-place) pool
     arrays back into ``pool`` and return the logits — the fetch-order
-    contract (logits first, then :func:`pool_feed_names` order) lives
-    HERE, next to the feed-order contract, so the two callers cannot
-    drift."""
-    names = pool_feed_names(pool.num_arrays, pool.quantized)
+    contract (logits first, then :meth:`KVBlockPool.feed_names` order)
+    lives HERE, next to the feed-order contract, so the two callers
+    cannot drift."""
+    names = pool.feed_names()
     pool.update_arrays({n: fetches[1 + i] for i, n in enumerate(names)})
     return fetches[0]
 
@@ -409,11 +434,17 @@ class KVBlockPool:
         # there are any, a _WindowGroup beside them
         groups = groups or [{"name": "full", "window": None,
                              "layers": list(range(self.num_layers))}]
+        stateful = [g for g in groups if g.get("state")]
+        groups = [g for g in groups if not g.get("state")]
         full = [g for g in groups if not g.get("window")]
         windowed = [g for g in groups if g.get("window")]
-        if len(full) > 1 or len(windowed) > 1:
-            raise ValueError("KVBlockPool holds one full and one window "
-                             "group of layers at most")
+        if len(full) > 1 or len(windowed) > 1 or len(stateful) > 1:
+            raise ValueError("KVBlockPool holds one full, one window and "
+                             "one state group of layers at most")
+        # feed name -> (shape a slot, dtype) of the state group's arrays
+        # (module docstring); ``num_layers`` counts KV cache layers alone
+        self.state_layers = len(stateful[0]["layers"]) if stateful else 0
+        self.state_arrays = state_array_specs(stateful)
         self.full_layers = list(full[0]["layers"]) if full else []
         # cache layers a weight layer, kept in one array (module docstring)
         self.passes = int(full[0].get("passes", 1)) if full else 1
@@ -455,7 +486,8 @@ class KVBlockPool:
         self.prefix_enabled = bool(flag("kv_prefix_cache")
                                    if prefix_cache is None
                                    else prefix_cache) \
-            and self.window is None and self.passes == 1
+            and self.window is None and self.passes == 1 \
+            and not self.state_layers
         self.array_sharding = None     # NamedSharding under a tp mesh
         self._arrays = None            # lazy device pool
         self._scatter_fn = None
@@ -482,6 +514,24 @@ class KVBlockPool:
         if self.quantized:
             n += 2 * nl * self.num_heads * self.block_size * 4
         return n
+
+    def state_bytes_per_slot(self):
+        """Device bytes of the state group's arrays one slot keeps."""
+        return sum(math.prod(shape) * np.dtype(dt).itemsize
+                   for shape, dt in self.state_arrays.values())
+
+    def state_attrs(self):
+        """``{"state_layers": n}`` for the spans of what runs over a
+        pool with a state group; nothing for any other pool."""
+        return {"state_layers": self.state_layers} \
+            if self.state_layers else {}
+
+    def feed_names(self):
+        """Every array of :meth:`arrays` in the one order the decode
+        program is fed and fetches them: :func:`pool_feed_names`, then
+        :func:`state_array_specs`' names."""
+        return pool_feed_names(self.num_arrays, self.quantized) \
+            + list(self.state_arrays)
 
     def dense_slot_bytes(self):
         """Device bytes ONE dense bank slot costs (fp32, max_seq_len)."""
@@ -695,7 +745,8 @@ class KVBlockPool:
     def arrays(self):
         """The paged decode program's pool feed dict (lazily built
         zeros): ``{cache_pk_i, cache_pv_i[, cache_pks_i, cache_pvs_i]}``
-        — see :func:`pool_feed_names` for the order contract."""
+        and a state group's ``[slots, ...]`` arrays — see
+        :meth:`feed_names` for the order contract."""
         if self._arrays is None:
             import jax.numpy as jnp
             from ..kernels.paged_attention import stored_shape
@@ -718,6 +769,9 @@ class KVBlockPool:
                     # dequantizes 0 * 1.0 instead of hitting a 0-scale
                     arrs[f"cache_pks_{i}"] = jnp.ones(sshape, jnp.float32)
                     arrs[f"cache_pvs_{i}"] = jnp.ones(sshape, jnp.float32)
+            for name, (shape, state_dt) in self.state_arrays.items():
+                arrs[name] = jnp.zeros((self.slots,) + tuple(shape),
+                                       state_dt)
             if self.array_sharding is not None:
                 # tp-mesh placement: blocks sharded on the head axis
                 # (the stored rows are head-major: dim 1), matching
@@ -987,7 +1041,8 @@ class KVBlockPool:
     # -- prefill scatter --------------------------------------------------
     def _scatter(self):
         """The prefill scatter's donated jit (built once): ``(pool,
-        row_caches, tables [n, nblk], ring_src, ring_dst) -> pool``."""
+        row_caches, tables [n, nblk], ring_src, ring_dst, slots [n]) ->
+        pool`` (``slots`` None without a state group)."""
         if self._scatter_fn is None:
             import jax.numpy as jnp
             from ..kernels.paged_attention import (
@@ -996,6 +1051,7 @@ class KVBlockPool:
             passes, per_pass = self.passes, self.num_blocks
             full = [i for i in self.full_layers if i < self.num_arrays]
             windowed = list(self.window.layers) if self.window else []
+            state_names = list(self.state_arrays)
 
             def blocks_of(src, n, nblk):
                 """[bb, H, L, D] -> [n, nblk, H, bs, D]: the first
@@ -1016,7 +1072,7 @@ class KVBlockPool:
                                     vals.shape[3])
                 return vals.transpose(0, 2, 1, 3, 4)
 
-            def scatter(pool, rows, tables, ring_src, ring_dst):
+            def scatter(pool, rows, tables, ring_src, ring_dst, slots=None):
                 out = dict(pool)
                 n, nblk = tables.shape
                 m, tables_flat = n * nblk, tables.reshape(-1)
@@ -1058,6 +1114,12 @@ class KVBlockPool:
                         out[f"cache_p{kind}_{i}"] = dst.at[ring_dst].set(
                             to_stored(vals.reshape(
                                 (-1,) + vals.shape[2:]).astype(dst.dtype)))
+                for name in state_names:
+                    # a row's state into its slot: dimension 0 alone is
+                    # indexed, so in place
+                    dst = out[name]
+                    out[name] = dst.at[slots].set(
+                        rows[name][:n].astype(dst.dtype))
                 return out
 
             self._scatter_fn = _PoolJit(scatter)
@@ -1073,7 +1135,10 @@ class KVBlockPool:
         every cache layer of weight layer ``i``), reshaped into blocks
         and scattered through the block table in ONE donated jitted
         call. Table entries past a row's allocation point at the trash
-        block, so bucket padding lands there. A window group's layers keep only what a row's ring
+        block, so bucket padding lands there. A state group's arrays
+        (``row_caches[cache_s<tag>_<m>]``, ``[bb, ...]``) go to rows
+        ``slot_ids`` of the slot bank in the same call.
+        A window group's layers keep only what a row's ring
         holds: of a prompt of ``lengths[r]`` tokens the last ``ring``
         blocks, each into the column its logical index names. Quantizes
         on the way in for an int8 pool. On ANY failure the donated pool
@@ -1104,7 +1169,8 @@ class KVBlockPool:
                 self.arrays(), dict(row_caches),
                 jnp.asarray(tables, jnp.int32),
                 None if ring_src is None else jnp.asarray(ring_src),
-                None if ring_dst is None else jnp.asarray(ring_dst))
+                None if ring_dst is None else jnp.asarray(ring_dst),
+                jnp.asarray(slots) if self.state_arrays else None)
         except Exception:
             self._arrays = None
             raise
@@ -1157,6 +1223,10 @@ class KVBlockPool:
         return payload
 
     def _no_window(self, what):
+        if self.state_layers:
+            raise BadRequestError(
+                f"KV pool {self.name!r} has a state group of layers: "
+                f"{what} is built for keys and values in blocks only")
         if self.window is not None:
             raise BadRequestError(
                 f"KV pool {self.name!r} has a window group of layers: "
@@ -1359,6 +1429,14 @@ class KVBlockPool:
             "saved_vs_dense_bytes": self.slots * self.dense_slot_bytes()
             - self._bytes_of(by_group, cached),
             "relayouts": self.relayouts(),
+            # a state group's per-slot arrays, and every array the pool
+            # holds on the device: blocks (trash included) and the bank
+            "state_layers": self.state_layers,
+            "state_bytes_per_slot": self.state_bytes_per_slot(),
+            "pool_bytes": self._bytes_of(
+                {"full": self.num_blocks,
+                 "window": self.window.num_blocks if self.window else 0})
+            + self.slots * self.state_bytes_per_slot(),
         }
 
     def relayouts(self):

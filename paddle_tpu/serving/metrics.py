@@ -227,6 +227,9 @@ _COUNTER_KEYS = (
     "moe_experts_hit",      # experts that got a token, summed over layers
     # -- a stack of layers run several times over the same weights --
     "loop_passes",          # passes over the weights: steps and prefills
+    # -- per-slot recurrent state beside the KV blocks --
+    "state_slot_writes",    # rows whose state an admission wrote to a slot
+    "scan_tokens",          # admitted rows x bucket length the scans walked
 )
 
 

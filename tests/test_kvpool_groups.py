@@ -140,3 +140,118 @@ def test_scatter_keeps_the_windows_last_blocks():
                 np.testing.assert_array_equal(got, src)
     with pytest.raises(ValueError, match="lengths"):
         pool.scatter_prefill([0, 1], rows, 32)
+
+
+# ----------------------------------------------------- the state group
+
+STATE_GROUPS = [
+    {"name": "full", "window": None, "layers": [0, 1]},
+    {"name": "state", "state": True, "layers": [0, 1, 2],
+     "arrays": {"c": ((3 * 128,), "float32"), "s": ((8, 128), "float32")}}]
+STATE_NAMES = [f"cache_s{t}_{m}" for t in "cs" for m in range(3)]
+
+
+def _state_pool(**kw):
+    kw.setdefault("num_layers", 2)
+    kw.setdefault("groups", STATE_GROUPS)
+    kw.setdefault("name", "stateful")
+    return _pool(**kw)
+
+
+def test_a_state_groups_arrays_live_a_slot_beside_the_blocks():
+    from paddle_tpu.serving.kvpool import (pool_element_counts,
+                                           state_array_specs)
+    pool = _state_pool()
+    assert list(state_array_specs(STATE_GROUPS)) == STATE_NAMES
+    assert state_array_specs(GROUPS) == state_array_specs(None) == {}
+    # KV cache layers and state layers are counted apart
+    assert (pool.num_layers, pool.num_arrays, pool.state_layers) == (2, 2, 3)
+    assert pool.feed_names() == ["cache_pk_0", "cache_pk_1", "cache_pv_0",
+                                 "cache_pv_1"] + STATE_NAMES
+    arrays = pool.arrays()
+    assert sorted(arrays) == sorted(pool.feed_names())
+    for m in range(3):
+        assert arrays[f"cache_sc_{m}"].shape == (4, 384)
+        assert arrays[f"cache_ss_{m}"].shape == (4, 8, 128)
+        assert str(arrays[f"cache_ss_{m}"].dtype) == "float32"
+        assert not np.asarray(arrays[f"cache_ss_{m}"]).any()
+    # the relayout count looks for the state arrays too
+    assert pool_element_counts(arrays) >= {4 * 384, 4 * 8 * 128}
+    st = pool.stats()
+    assert st["state_layers"] == 3
+    assert st["state_bytes_per_slot"] == 3 * 4 * (384 + 8 * 128)
+    blocks = (4 * 32 + 1) * 2 * 2 * 2 * 4 * 8 * 2
+    assert st["pool_bytes"] == blocks + 4 * st["state_bytes_per_slot"] \
+        == sum(a.size * a.dtype.itemsize for a in arrays.values())
+    assert pool.state_attrs() == {"state_layers": 3}
+    # a pool without one says nothing of it
+    plain = _pool()
+    assert plain.state_layers == 0 and plain.state_attrs() == {}
+    assert plain.stats()["state_bytes_per_slot"] == 0
+    assert plain.feed_names() == sorted(plain.arrays(), key=lambda n: (
+        n[:8], int(n.rsplit("_", 1)[1])))
+    with pytest.raises(ValueError, match="one state group"):
+        _state_pool(groups=STATE_GROUPS + [STATE_GROUPS[1]])
+
+
+def test_scatter_writes_the_admitted_rows_states_into_their_slots():
+    import jax.numpy as jnp
+    pool = _state_pool()
+    rng = np.random.default_rng(1)
+    rows = {f"cache_{k}_{i}": jnp.asarray(
+        rng.normal(size=(2, 2, 16, 8)), jnp.bfloat16)
+        for i in range(2) for k in ("k", "v")}
+    for name in STATE_NAMES:
+        shape = (2, 384) if "_sc_" in name else (2, 8, 128)
+        rows[name] = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    for slot, n in ((3, 9), (1, 14)):
+        pool.alloc(slot, n)
+    pool.scatter_prefill([3, 1], rows, 16, lengths=[9, 14])
+    for name in STATE_NAMES:
+        got = np.asarray(pool.arrays()[name])
+        np.testing.assert_array_equal(got[3], np.asarray(rows[name][0]))
+        np.testing.assert_array_equal(got[1], np.asarray(rows[name][1]))
+        assert not got[0].any() and not got[2].any()
+    # the keys and values went through the table in the same call
+    np.testing.assert_array_equal(
+        pool.logical("cache_pk_1", pool.tables[3, :3]).astype(np.float32)
+        .transpose(1, 0, 2, 3).reshape(2, 12, 8)[:, :9],
+        np.asarray(rows["cache_k_1"][0, :, :9].astype(jnp.float32)))
+    assert pool.relayouts() == {"scatter": 0}
+    # a freed slot's state stays where it lies; an admission overwrites it
+    pool.free_slot(3)
+    assert np.asarray(pool.arrays()["cache_ss_0"])[3].any()
+    assert pool.blocks_in_use() == 4
+    pool.alloc(3, 5)
+    pool.scatter_prefill([3], {n: a[1:] for n, a in rows.items()}, 16,
+                         lengths=[5])
+    np.testing.assert_array_equal(
+        np.asarray(pool.arrays()["cache_ss_0"])[3],
+        np.asarray(rows["cache_ss_0"][1]))
+
+
+def test_a_state_group_resets_drops_and_declines_with_the_blocks():
+    pool = _state_pool(prefix_cache=True)
+    assert not pool.prefix_enabled
+    prompt = np.arange(1, 20, dtype=np.int32)
+    pool.alloc(0, prompt.size)
+    assert pool.prefix_insert(prompt, 0) == 0
+    assert pool.match_prefix(prompt) is None
+    for refuse in (lambda: pool.export_slot(0),
+                   lambda: pool.import_slot(1, {})):
+        with pytest.raises(serving.BadRequestError, match="state group"):
+            refuse()
+    import jax.numpy as jnp
+    held = dict(pool.arrays())
+    held["cache_ss_1"] = jnp.ones_like(held["cache_ss_1"])
+    pool.update_arrays(held)
+    assert np.asarray(pool.arrays()["cache_ss_1"]).all()
+    pool.drop_device()                      # the bank goes with the blocks
+    assert not np.asarray(pool.arrays()["cache_ss_1"]).any()
+    assert pool.blocks_in_use() == 5        # host accounting survives
+    pool.update_arrays(held)
+    pool.reset()
+    assert pool.blocks_in_use() == 0
+    assert not np.asarray(pool.arrays()["cache_ss_1"]).any()
+    with pytest.raises(ValueError, match="int8"):
+        _pool(dtype="int8")

@@ -146,7 +146,9 @@ def test_serving_stats_snapshot_keys_unchanged():
         # PR 32: decode steps sent before the last step's tokens were read
         "decode_steps_ahead",
         # PR 33: passes over the weights, steps and prefills alike
-        "loop_passes"}
+        "loop_passes",
+        # PR 36: per-slot recurrent state beside the KV blocks
+        "state_slot_writes", "scan_tokens"}
     derived = {"uptime_s", "throughput_rps", "mean_batch_size",
                "batch_occupancy", "tokens_per_s", "decode_occupancy",
                "queue_depth", "spec_accept_ratio"}

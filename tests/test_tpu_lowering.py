@@ -301,6 +301,24 @@ def test_kernels_run_per_shard_under_a_mesh():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_selective_scan_lowers_for_tpu_under_its_own_name():
+    """The chunked scan at the published width of the state-space
+    configuration (5,120 channels, 16 states; a 2,048 bucket): Mosaic
+    takes its blocks, its dynamic sublane slices and its masked row
+    assembly, and a device trace finds it as ``selective_scan_fwd``."""
+    from paddle_tpu.kernels.selective_scan import selective_scan
+    rows, seq, ch, n = 2, 2048, 5120, 16
+    tokens = _spec((rows, seq, ch), jnp.float32)
+    maps = _spec((rows, seq, n), jnp.float32)
+    specs = [tokens, tokens, tokens, maps, maps,
+             _spec((ch, n), jnp.float32), _spec((ch,), jnp.float32),
+             _spec((ch,), jnp.float32), _spec((rows, n, ch), jnp.float32),
+             _spec((rows,), jnp.int32)]
+    found = _kernel_names(
+        lambda *a: selective_scan(*a, impl="pallas"), specs)
+    assert found == {"selective_scan_fwd"}
+
+
 def test_chip_smoke_refuses_the_cpu():
     """``JAX_PLATFORMS=cpu python chip_smoke.py`` names the platform it
     found, prints no result line and exits non-zero."""

@@ -1,15 +1,15 @@
 #!/usr/bin/env python3
 """Do two trees lower the serve cells' executables to one text?
 
-    python tools/lowering_identity.py lower ROOT OUT [gpt2-medium] [mellum]
+    python tools/lowering_identity.py lower ROOT OUT [gpt2-medium] [mellum] [ouro]
     python tools/lowering_identity.py diff OUT_A OUT_B
 
 ``lower`` imports ``paddle_tpu`` and ``benchmark`` from the tree at ROOT (a
 checkout, or a ``git archive`` of a commit) and lowers, with abstract
 weights and an abstract pool (nothing is placed on a device), the kinds of
-executable the GPT-2 medium and Mellum serve cells warm: prefills of 1 to 8
-rows at 64 to 6,144 with the scatter of each, the paged decode step, the
-picks. It writes each ``.mlir`` text and the feed and fetch names under
+executable the GPT-2 medium, Mellum and Ouro serve cells warm (all three
+unless some are named): prefills of 1 to 8 rows at 32 to 6,144 with the
+scatter of each, the paged decode step, the picks. It writes each ``.mlir`` text and the feed and fetch names under
 OUT. With ``IDENTITY_TPU_HERE=1`` the kernels take their TPU branch and the
 text is lowered for the TPU platform with no chip; Mosaic's serialized
 bodies are in it, and their ``loc(...)`` carry the checkout's path, so
@@ -32,6 +32,8 @@ CONFIGS = {
                     [(1, 64), (4, 256), (8, 1024)], [1, 4, 8, 32]),
     "mellum": ("mellum2-12b-a2.5b.json", "mellum",
                [(1, 1024), (2, 3072), (4, 6144)], [1, 2, 4, 32]),
+    "ouro": ("ouro-2.6b.json", "ouro",
+             [(1, 32), (2, 64), (4, 128)], [1, 2, 4, 16]),
 }
 
 
@@ -94,12 +96,9 @@ def lower_tree(root, out, which):
         cfg = fam.program_config(sz)
         main, startup = fluid.Program(), fluid.Program()
         with fluid.program_guard(main, startup):
-            if family == "gpt":
-                from paddle_tpu.models import gpt
-                gpt.gpt_logits(cfg)
-            else:
-                from paddle_tpu.models import mellum
-                mellum.mellum_logits(cfg)
+            model = __import__("paddle_tpu.models." + family,
+                               fromlist=["x"])
+            getattr(model, family + "_logits")(cfg)
         gen = GPTGenerator(cfg, fluid.Scope(), max_len=serve["max_len"])
         gen.bind_params({
             p.name: SDS(tuple(p.shape), jnp.dtype(str(p.dtype)))
