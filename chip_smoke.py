@@ -26,6 +26,12 @@ seeded random weights:
            no pool-sized copy in the decode step's or the scatter's
            optimised HLO, then one logits check of paged decode against
            a full recompute
+  prefill  GPT's prefill at benchmark configuration gpt2-medium's
+           widths over a bf16 pool, at the largest and the smallest
+           shape its one-token cell warms: what the executable holds
+           (XLA's memory_analysis: arguments, outputs, temporaries),
+           the rows it hands the scatter (bucket-long, the pool's
+           dtype), and no pool-sized copy in that scatter
   loop     the looped block of benchmark configuration ouro-2.6b (its
            published widths, four passes, 2 of 48 layers) through
            InferenceServer: no pool-sized copy in its decode step (the
@@ -98,6 +104,7 @@ class Sizes:
             self.new_tokens = 4
             self.check_prompt, self.check_steps = 9, 2
             self.kernel_impl = "interpret"
+            self.prefill_shapes = ((4, 64), (1, 16))
             self.loop = dict(layers=None, slots=2, prompt_lens=(5, 11, 18),
                              new_tokens=4)
             self.state = dict(layers=None, slots=2, prompt_lens=(5, 11, 18),
@@ -137,6 +144,9 @@ class Sizes:
             self.new_tokens = 32
             self.check_prompt, self.check_steps = 100, 3
             self.kernel_impl = "pallas"
+            # (rows, length bucket): the largest and the smallest prefill
+            # of benchmark cell gpt2-medium.serve_one_token
+            self.prefill_shapes = ((8, 1024), (1, 64))
             # benchmark configuration ouro-2.6b at its published widths
             # and four passes, 2 of its 48 layers: 8 cache layers
             self.loop = dict(layers=2, slots=4,
@@ -902,6 +912,93 @@ def phase_serve(smoke):
     return out
 
 
+# ------------------------------------------------------------------ prefill
+
+def phase_prefill(smoke):
+    """GPT's prefill at benchmark configuration ``gpt2-medium``'s widths
+    (24 layers of 1,024, 16 heads of 64, a bf16 pool of 1,024
+    positions) at the largest and the smallest shape the one-token cell
+    warms: what XLA says the executable holds, the row caches it hands
+    the scatter, one block of the pool against them, and no pool-sized
+    copy in the scatter. The timings are smoke timings of a warm call."""
+    import jax
+    from benchmark.families import gpt as fam
+    from paddle_tpu.flags import flag, set_flags
+    sz = smoke.sizes
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs",
+                           "gpt2-medium.json")) as fh:
+        config = json.load(fh)
+    fsz = fam.Sizes(config, rehearsal=sz.rehearsal)
+    serve = dict(config["serve"],
+                 **(config["rehearsal"]["serve"] if sz.rehearsal else {}))
+    kv_dtype = flag("kv_cache_dtype")
+    try:
+        gen = fam.build_generator(fsz, serve, seed=37)
+    finally:
+        set_flags({"kv_cache_dtype": kv_dtype})
+    rows_max = max(rows for rows, _ in sz.prefill_shapes)
+    pool = gen.new_pool(rows_max, dtype=serve["kv_cache_dtype"],
+                        name="smoke_prefill")
+    kind = gen.arch.prefill_kind(pool.dtype)
+    key = jax.random.PRNGKey(0)
+    rng = np.random.default_rng(37)
+    shapes = {}
+    for rows, seq in sz.prefill_shapes:
+        # shorter than their bucket: the padding lands in the trash block
+        lens = [seq - 3 - r for r in range(rows)]
+        prompts = [rng.integers(1, fsz.vocab_size, n).astype(np.int32)
+                   for n in lens]
+        tokens, pos_ids, last = gen._pack_prompts(prompts)
+        assert tokens.shape == (rows, seq), tokens.shape
+        slots = list(range(rows))
+
+        def once():
+            for r, n in zip(slots, lens):
+                pool.alloc(r, n)
+            t0 = time.perf_counter()
+            _, row_caches, _ = gen._run_prefill(
+                tokens, pos_ids, last, key, kv_dtype=pool.dtype)
+            t1 = time.perf_counter()
+            pool.scatter_prefill(slots, row_caches, seq, lengths=lens)
+            jax.block_until_ready(pool.arrays())
+            return row_caches, t1 - t0, time.perf_counter() - t1
+
+        row_caches, _, _ = once()                  # compiles
+        k0 = row_caches["cache_k_0"]
+        # row 0's first block is its first positions, cast the pool's way
+        want = np.asarray(k0[0, :, :pool.block_size].astype(
+            pool.arrays()["cache_pk_0"].dtype))
+        got = pool.logical("cache_pk_0", pool.tables[0, :1])[0]
+        assert np.array_equal(got.view(np.uint8), want.view(np.uint8))
+        feed = {"tokens": tokens, "pos_ids": pos_ids, "last_pos": last}
+        mem = gen.cache.get(gen._signature(kind, feed)).memory_analysis()
+        warm = []
+        for _ in range(3):
+            for r in slots:
+                pool.free_slot(r)
+            warm.append(once()[1:])
+        for r in slots:
+            pool.free_slot(r)
+        shapes[f"{rows}x{seq}"] = {
+            "row_cache": f"{k0.dtype.name}{list(k0.shape)}",
+            "cache_bytes": int(sum(a.nbytes for a in row_caches.values())),
+            "prefill_bytes_counted": int(gen.arch.prefill_bytes(
+                rows, seq, gen.max_len, k0.dtype.itemsize)),
+            "memory_analysis": {
+                name: int(getattr(mem, f"{name}_size_in_bytes"))
+                for name in ("argument", "output", "temp", "alias")},
+            "smoke_timings_ms": {
+                "prefill_warm": round(1e3 * min(w[0] for w in warm), 2),
+                "scatter_warm": round(1e3 * min(w[1] for w in warm), 2)}}
+    relayouts = pool._scatter().relayouts
+    assert sz.rehearsal or relayouts == 0, relayouts
+    return {"kind": kind, "kv_cache_dtype": pool.dtype,
+            "max_len": gen.max_len, "layers": fsz.n_layer,
+            "shapes": shapes, "pool_relayouts": {"scatter": relayouts},
+            "peak_bytes_in_use": _peak_bytes()}
+
+
 # --------------------------------------------------------------------- loop
 
 def phase_loop(smoke):
@@ -1158,7 +1255,8 @@ def phase_mesh(smoke):
 # --------------------------------------------------------------------- main
 
 _PHASES = {"kernels": phase_kernels, "train": phase_train,
-           "serve": phase_serve, "loop": phase_loop, "state": phase_state,
+           "serve": phase_serve, "prefill": phase_prefill,
+           "loop": phase_loop, "state": phase_state,
            "mesh": phase_mesh}
 
 

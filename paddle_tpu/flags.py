@@ -182,7 +182,7 @@ _DEFS = {
     "train_restart_budget": (3, int, None),
     # -- KV-cached autoregressive decoding (models/generation, serving
     # decode batching) --
-    # preallocated per-layer KV cache length [B, H, decode_max_len, D]:
+    # the most positions a row's block table holds in the paged KV pool:
     # prompt length + max_new_tokens must fit (clamped to the model's
     # max_position)
     "decode_max_len": (2048, int, None),

@@ -772,7 +772,8 @@ def kv_cache_write(cache, kv, pos, name=None):
     """Append ``kv`` [B, H, S, D] into the preallocated KV ``cache``
     [B, H, max_len, D] at each row's own ``pos`` [B] int32 (vmapped
     position-indexed ``dynamic_update_slice``). Returns the updated
-    cache; the incremental-decoding append (see models/gpt.py)."""
+    cache; the append into a dense cache (the serving programs append
+    through block tables: :func:`paged_kv_cache_write`)."""
     helper = LayerHelper("kv_cache_write", name=name)
     out = helper.create_variable_for_type_inference(dtype=cache.dtype)
     helper.append_op(

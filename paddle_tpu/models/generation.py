@@ -1048,7 +1048,7 @@ class GPTGenerator:
             for r in range(B):
                 pool.alloc(r, lens[r])
             logits, row_caches, key = self._run_prefill(
-                tokens, pos_ids, last, key)
+                tokens, pos_ids, last, key, kv_dtype=kv_dtype)
             pool.scatter_prefill(list(range(B)), row_caches, s)
 
             temp = np.full((bb,), float(temperature), np.float32)

@@ -77,12 +77,10 @@ def decoder_layer(cfg, x, idx, is_test, kv_cache=None, pos=None):
 
     - ``kv_cache=None`` (training / full-sequence eval): causal attention
       through the flash kernel (upper triangle never computed).
-    - ``kv_cache={"k": c_k, "v": c_v, "mode": "prefill"}`` with ``pos``
-      [B] int32: the fresh k/v are written into the preallocated
-      ``[B, H, max_len, D]`` caches at ``pos`` AND attended causally via
-      the flash path (prompt rows start at position 0, so attention runs
-      over the length BUCKET, not the whole cache). Returns
-      ``(x, new_k_cache, new_v_cache)``.
+    - ``kv_cache={"mode": "prefill"}``: the same causal attention over
+      the length BUCKET, and the fresh float32 ``k`` and ``v``
+      ``[B, H, L, D]`` it attended over handed back as they are (the
+      pool's scatter cuts them into blocks). Returns ``(x, k, v)``.
     - ``mode: "paged"`` with ``tables`` [B, nblk] int32: the
       block-paged incremental step — k/v caches are a SHARED pool
       ``[num_blocks, H, block_size, D]`` routed through per-row block
@@ -128,8 +126,7 @@ def decoder_layer(cfg, x, idx, is_test, kv_cache=None, pos=None):
         ctx = layers.nn.paged_attention(q, new_k, new_v, tables, pos,
                                         k_scale=new_ks, v_scale=new_vs)
     else:
-        new_k = layers.nn.kv_cache_write(kv_cache["k"], k, pos)
-        new_v = layers.nn.kv_cache_write(kv_cache["v"], v, pos)
+        new_k, new_v = k, v
         ctx = layers.nn.flash_attention(q, k, v, causal=True)
     ctx = T.reshape(T.transpose(ctx, [0, 2, 1, 3]), [0, 0, h])
     attn_out = _fc(cfg, ctx, h, f"{pre}_att_out")
@@ -231,14 +228,17 @@ def gpt_logits(cfg, batch_size=-1, seq_len=-1):
             "logits": logits}
 
 
-def gpt_prefill(cfg, max_len, batch_size=-1, seq_len=-1):
+def gpt_prefill(cfg, kv_dtype="fp32", batch_size=-1, seq_len=-1):
     """Prompt ingestion: one causal forward over the (length-bucketed)
-    prompt that ALSO materializes every layer's ``[B, H, max_len, D]``
-    KV cache — zero-initialized in-graph, fresh k/v written at position
-    0. Padded rows write garbage beyond their true length; the decode
-    step's per-row position mask never attends it and later appends
-    overwrite it slot by slot. Fetch ``logits`` [B, V] (each row's last
-    real position) plus the caches."""
+    prompt that also returns every layer's keys and values ``[B, H, S,
+    D]`` at the bucket's length ``S``, cast to the dtype a ``kv_dtype``
+    pool stores (the cast happens once, where the values are made); the
+    pool scatters them into blocks (``KVBlockPool.scatter_prefill``).
+    Padded rows hand back garbage beyond their true length: it lands in
+    the trash block, or in slots the decode step's per-row position
+    mask never attends and later appends overwrite. Fetch ``logits``
+    [B, V] (each row's last real position) plus ``cache_k``/``cache_v``."""
+    cache_dt = {"fp32": "float32", "bf16": "bfloat16"}[kv_dtype]
     tokens = T.data("tokens", [batch_size, seq_len], dtype="int32")
     pos_ids = T.data("pos_ids", [batch_size, seq_len], dtype="int32")
     last_pos = T.data("last_pos", [batch_size], dtype="int32")
@@ -248,19 +248,12 @@ def gpt_prefill(cfg, max_len, batch_size=-1, seq_len=-1):
                                           cfg.hidden_size],
                            param_attr=_param(cfg, "pos_embedding"))
     x = M.elementwise_add(emb, pos)
-    n_head, d_head = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    zero_pos = T.fill_constant_batch_size_like(tokens, [-1], "int32", 0)
     cache_k, cache_v = [], []
     for i in range(cfg.num_layers):
-        zk = T.fill_constant_batch_size_like(
-            tokens, [-1, n_head, max_len, d_head], "float32", 0.0)
-        zv = T.fill_constant_batch_size_like(
-            tokens, [-1, n_head, max_len, d_head], "float32", 0.0)
-        x, ck, cv = decoder_layer(
-            cfg, x, i, True,
-            kv_cache={"k": zk, "v": zv, "mode": "prefill"}, pos=zero_pos)
-        cache_k.append(ck)
-        cache_v.append(cv)
+        x, k, v = decoder_layer(cfg, x, i, True,
+                                kv_cache={"mode": "prefill"})
+        cache_k.append(T.cast(k, cache_dt))
+        cache_v.append(T.cast(v, cache_dt))
     logits = _tied_next_logits(cfg, x, last_pos)
     return {"feed_names": ["tokens", "pos_ids", "last_pos"],
             "logits": logits, "cache_k": cache_k, "cache_v": cache_v}
@@ -510,14 +503,12 @@ class GPTServing:
         self.cfg = cfg
 
     def eager_builders(self, max_len):
-        cfg = self.cfg
-        return {"prefill": lambda: gpt_prefill(cfg, max_len),
-                "logits": lambda: gpt_logits(cfg)}
+        return {"logits": lambda: gpt_logits(self.cfg)}
 
     def build(self, kind, max_len):
-        """The program of a lazily built ``kind`` (the paged decode
-        step, chunked prefill and the verify steps exist per KV-cache
-        dtype and most processes never touch them)."""
+        """The program of a lazily built ``kind`` (the prefill, the
+        paged decode step, chunked prefill and the verify steps exist
+        per KV-cache dtype and most processes never touch them)."""
         kv_dtype = kind.rsplit("_", 1)[-1]
         if kind.startswith("verify_paged_"):
             return gpt_verify_step_paged(self.cfg, kv_dtype=kv_dtype)
@@ -525,10 +516,14 @@ class GPTServing:
             return gpt_decode_step_paged(self.cfg, kv_dtype=kv_dtype)
         if kind.startswith("prefill_chunk_"):
             return gpt_prefill_chunk_paged(self.cfg, kv_dtype=kv_dtype)
+        if kind.startswith("prefill_"):
+            return gpt_prefill(self.cfg, kv_dtype=kv_dtype)
         raise KeyError(f"unknown generation program kind {kind!r}")
 
     def prefill_kind(self, kv_dtype):
-        return "prefill"
+        """An int8 pool quantizes in its scatter, from the rows the
+        float32 pool's program hands back."""
+        return f"prefill_{'fp32' if kv_dtype == 'int8' else kv_dtype}"
 
     def apply_tp_sharding(self, main):
         apply_tp_sharding(main, self.cfg)
@@ -546,14 +541,16 @@ class GPTServing:
                  "layers": list(range(self.cfg.num_layers))}]
 
     def prefill_bytes(self, rows, seq, max_len, kv_elem_bytes):
-        """Device bytes one prefill of ``rows`` prompts holds at its
-        peak beyond the weights: every layer's dense float32 ``[rows, H,
-        max_len, D]`` key and value caches, whatever the prompts'
-        length, once as the prefill's result and once more in the
-        scatter that relays them into the pool, and the logits."""
+        """Device bytes one prefill of ``rows`` x ``seq`` holds at its
+        peak beyond the weights: every layer's ``[rows, H, seq, D]``
+        keys and values in the dtype it hands them back in (the pool's;
+        float32 for an int8 pool), once as the prefill's result and
+        once more in the scatter that cuts them into blocks, and the
+        logits."""
         cfg = self.cfg
+        row_elem = 4 if kv_elem_bytes == 1 else int(kv_elem_bytes)
         return int(rows) * (2 * 2 * cfg.num_layers * cfg.hidden_size
-                            * int(max_len) * 4 + cfg.vocab_size * 4)
+                            * int(seq) * row_elem + cfg.vocab_size * 4)
 
 
 # ---- tensor-parallel sharding annotation (Megatron-style over "tp") ----

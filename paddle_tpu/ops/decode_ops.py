@@ -1,19 +1,19 @@
 """Incremental-decoding ops: the KV-cache fast path for autoregressive
-LMs (models/gpt.py generate(), serving decode batching).
+LMs (models/generation.py generate(), serving decode batching).
 
 The reference generates with beam_search/sampling_id over FULL forward
 passes — every new token recomputes all S positions, O(S^2) attention
 per token. These ops implement the standard prefill/decode split from
 the LLM-serving literature (Orca iteration-level scheduling; vLLM's
-cache-centric serving): each decoder layer keeps a preallocated
-``[B, H, max_len, D]`` key/value cache, new tokens append via a
-position-indexed ``lax.dynamic_update_slice`` (vmapped so every row of
-the batch can sit at a DIFFERENT position — the decode batch shares one
-executable), and causal masking is driven by the per-row position
+cache-centric serving): a prefill hands each layer's bucket-long keys
+and values to the block-paged pool (``serving/kvpool``), new tokens
+append through a row's block table (``paged_kv_cache_write``; every row
+of the batch can sit at a DIFFERENT position — the decode batch shares
+one executable), and causal masking is driven by the per-row position
 counters instead of the query/key index triangle. Per-token cost drops
-from a full O(S^2) recompute to one O(S) cache-append + cache-wide
-attention read, which is bandwidth-bound — the difference between a
-demo and a servable LM.
+from an O(S^2) recompute to one O(S) cache append + read, which is
+bandwidth-bound. ``kv_cache_write``, the append into a dense ``[B, H,
+max_len, D]`` cache, is kept as an op; no serving program uses it.
 """
 import jax
 import jax.numpy as jnp

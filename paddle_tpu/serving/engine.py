@@ -677,7 +677,7 @@ class GenerationEngine:
                     self.pool.free_slot(sl)
                 raise
         with span("generator/prefill", rows=n,
-                  **self.loop_attrs) as prefilled:
+                  **self.loop_attrs) as ran:
             logits, row_caches, self._key, aux = self.gen._run_prefill(
                 tokens, pos_ids, last, self._key,
                 kv_dtype=self.pool.dtype, want_aux=True)
@@ -710,7 +710,7 @@ class GenerationEngine:
                 self.pool.prefix_insert(req.prompt, slot)
         with span("engine/fetch", rows=n):
             out = np.asarray(toks)[:n]
-            prefilled.attrs.update(self._admitted(aux, requests, tokens))
+            ran.attrs.update(self._admitted(aux, requests, tokens, row_caches))
         t1 = time.perf_counter()
         for req in requests:
             if getattr(req, "trace", None) is not None:
@@ -1042,14 +1042,19 @@ class GenerationEngine:
         with _trace.loop_span("engine/fetch"):
             return np.asarray(out), np.asarray(acc)
 
-    def _admitted(self, aux, requests, tokens):
+    def _admitted(self, aux, requests, tokens, row_caches):
         """What an admission's ``generator/prefill`` span gains:
-        :meth:`_count_routing`'s attrs and, over a pool with a state
-        group, ``prompt_tokens`` (the real ones) and ``scan_tokens``
-        (admitted rows x the bucket's length: what the recurrence was
-        given to walk), counted into ``scan_tokens`` and
-        ``state_slot_writes`` (a row's state written into its slot)."""
+        ``cache_bytes``, the bytes of the row caches the program handed
+        to the scatter (every row of the bucket, in the dtype and at the
+        length it returned them); :meth:`_count_routing`'s attrs; and,
+        over a pool with a state group, ``prompt_tokens`` (the real
+        ones) and ``scan_tokens`` (admitted rows x the bucket's length:
+        what the recurrence was given to walk), counted into
+        ``scan_tokens`` and ``state_slot_writes`` (a row's state written
+        into its slot)."""
         attrs = self._count_routing(aux, rows=len(requests))
+        attrs["cache_bytes"] = int(sum(a.nbytes
+                                       for a in row_caches.values()))
         if self.pool.state_layers:
             attrs["prompt_tokens"] = int(sum(r.prompt.size
                                              for r in requests))

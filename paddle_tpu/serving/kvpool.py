@@ -1127,11 +1127,13 @@ class KVBlockPool:
 
     def scatter_prefill(self, slot_ids, row_caches, bucket_len,
                         lengths=None):
-        """Move freshly-prefilled dense row caches into the pool: rows
+        """Move a prefill's keys and values into the pool: rows
         ``slot_ids`` of the tables receive the first ``bucket_len``
         positions of ``row_caches[cache_{k,v}_i][:len(slot_ids)]``
-        (shape ``[bb, H, L, D]``, ``L`` the bank's length or the
-        bucket's; with several passes an array ``[U, bb, H, L, D]``,
+        (shape ``[bb, H, L, D]``, ``L`` the bucket's length, as every
+        prefill program hands them back, in the pool's dtype or in
+        float32; a longer ``L`` is sliced, a shorter one zero-padded;
+        with several passes an array ``[U, bb, H, L, D]``,
         every cache layer of weight layer ``i``), reshaped into blocks
         and scattered through the block table in ONE donated jitted
         call. Table entries past a row's allocation point at the trash

@@ -82,10 +82,11 @@ def test_greedy_first_token_matches_executor_forward(tiny_gen):
 
 
 def test_prefill_logits_parity_across_buckets(tiny_gen):
-    """Bucketed prefill (with its in-graph cache writes) must produce
-    the same next-token logits as the cache-free full forward at the
-    same bucket, and padding to a LARGER bucket must not change them
-    beyond tolerance (padded keys are causally masked)."""
+    """Bucketed prefill (which also hands back every layer's keys and
+    values, at the bucket's length) must produce the same next-token
+    logits as the cache-free full forward at the same bucket, and
+    padding to a LARGER bucket must not change them beyond tolerance
+    (padded keys are causally masked)."""
     cfg, _, gen = tiny_gen
     import jax
     key = jax.random.PRNGKey(0)
@@ -103,7 +104,7 @@ def test_prefill_logits_parity_across_buckets(tiny_gen):
         d_head = cfg.hidden_size // cfg.num_heads
         for i in range(cfg.num_layers):
             assert caches[f"cache_k_{i}"].shape == \
-                (1, cfg.num_heads, gen.max_len, d_head)
+                (1, cfg.num_heads, bucket, d_head)
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +115,7 @@ def test_kv_cache_write_position_invariants(tiny_gen):
     """A paged decode step (``decode_paged_fp32``) must change each
     row's keys and values ONLY at that row's own position — the block
     its table names for it, at the offset inside — and nowhere else in
-    the pool; prefill row caches stay [B, H, max_len, D]."""
+    the pool; prefill row caches are [B, H, bucket, D]."""
     cfg, _, gen = tiny_gen
     import jax
     key = jax.random.PRNGKey(1)
@@ -129,7 +130,7 @@ def test_kv_cache_write_position_invariants(tiny_gen):
     _, caches, key = gen._run_prefill(toks, pos_ids, last, key)
     d_head = cfg.hidden_size // cfg.num_heads
     for a in caches.values():
-        assert a.shape == (2, cfg.num_heads, gen.max_len, d_head)
+        assert a.shape == (2, cfg.num_heads, bucket, d_head)
 
     pool = gen.new_pool(2, dtype="fp32", block_size=4, name="invariants")
     pos = np.array([5, 9], np.int32)          # per-row write positions
@@ -329,6 +330,201 @@ def test_token_level_deadline_frees_slot(tiny_gen):
         req.wait(timeout=0.1)
     assert "token-level" in str(ei.value)
     assert batcher._free == [0]      # the slot is reusable
+
+
+# ---------------------------------------------------------------------------
+# what the prefill hands the pool
+# ---------------------------------------------------------------------------
+
+# what a prefill hands a pool of each dtype, and an element's bytes
+ROW_DTYPES = {"bf16": "bfloat16", "fp32": "float32", "int8": "float32"}
+ROW_BYTES = {"bf16": 2, "fp32": 4, "int8": 4}
+
+
+def _forward_kv(cfg, scope, tokens, pos_ids, last):
+    """Every layer's float32 k and v ``[B, H, S, D]`` as the cache-free
+    full forward (``gpt_logits`` through the plain Executor) hands them
+    to its attention: ``[(k, v)]`` a layer."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.gpt_logits(cfg)
+    attn = [op for op in main.global_block().ops
+            if op.type == "flash_attention"]
+    assert len(attn) == cfg.num_layers
+    names = [op.input(slot)[0] for op in attn for slot in ("K", "V")]
+    with fluid.scope_guard(scope):
+        got = fluid.Executor().run(
+            main, feed={"tokens": tokens, "pos_ids": pos_ids,
+                        "last_pos": last}, fetch_list=names)
+    got = [np.asarray(a) for a in got]
+    assert all(a.dtype == np.float32 for a in got)
+    return list(zip(got[0::2], got[1::2]))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "fp32", "int8"])
+def test_prefill_hands_back_bucket_long_rows_in_the_pools_dtype(tiny_gen,
+                                                                kv):
+    """``cache_k_i``/``cache_v_i`` are ``[bb, H, bucket, D]`` (not
+    ``max_len`` long) in the dtype the pool stores; an int8 pool, which
+    quantizes in the scatter, is handed float32 rows by the fp32
+    pool's program."""
+    cfg, _, gen = tiny_gen
+    import jax
+    prompts = _prompts(cfg, (5, 9, 3), seed=41)
+    tokens, pos_ids, last = gen._pack_prompts(prompts)
+    assert tokens.shape == (4, 16) and gen.max_len == 48
+    kind = gen.arch.prefill_kind(kv)
+    assert kind == {"bf16": "prefill_bf16", "fp32": "prefill_fp32",
+                    "int8": "prefill_fp32"}[kv]
+    _, caches, _ = gen._run_prefill(tokens, pos_ids, last,
+                                    jax.random.PRNGKey(0), kv_dtype=kv)
+    d_head = cfg.hidden_size // cfg.num_heads
+    assert sorted(caches) == sorted(
+        f"cache_{c}_{i}" for c in "kv" for i in range(cfg.num_layers))
+    for a in caches.values():
+        assert a.shape == (4, cfg.num_heads, 16, d_head)
+        assert str(a.dtype) == ROW_DTYPES[kv]
+    assert "prefill" not in gen._progs       # no dtype-blind kind left
+
+
+@pytest.mark.parametrize("kv", ["bf16", "fp32", "int8"])
+def test_pool_after_scatter_is_the_forwards_kv_bit_for_bit(tiny_gen, kv):
+    """After prefill and ``scatter_prefill`` every allocated block
+    holds, bit for bit, the full forward's float32 k and v cast (bf16),
+    kept (fp32) or quantized (int8) the pool's way; prompts shorter
+    than their bucket: the padding's blocks land in the trash block and
+    no other block is written."""
+    cfg, scope, gen = tiny_gen
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import quantize_kv
+    prompts = _prompts(cfg, (5, 9), seed=43)
+    lens = [int(p.size) for p in prompts]
+    tokens, pos_ids, last = gen._pack_prompts(prompts)
+    bucket, bs = tokens.shape[1], 4
+    assert bucket == 16
+    want = _forward_kv(cfg, scope, tokens, pos_ids, last)
+    pool = gen.new_pool(2, dtype=kv, block_size=bs, name=f"bits_{kv}")
+    for r, n in enumerate(lens):
+        pool.alloc(r, n)
+    _, caches, _ = gen._run_prefill(tokens, pos_ids, last,
+                                    jax.random.PRNGKey(0), kv_dtype=kv)
+    pool.scatter_prefill([0, 1], caches, bucket, lengths=lens)
+    held = {r: pool.blocks_for_tokens(n) for r, n in enumerate(lens)}
+    assert held == {0: 2, 1: 3}               # of the bucket's 4 blocks
+    live = sorted(int(b) for r, n in held.items()
+                  for b in pool.tables[r, :n])
+    assert 0 not in live and len(set(live)) == 5
+    for i, pair in enumerate(want):
+        for c, full in zip("kv", pair):
+            name = f"cache_p{c}_{i}"
+            for r, n in held.items():
+                ids = pool.tables[r, :n]
+                # [H, n * bs, D] -> [n, H, bs, D]
+                ref = full[r, :, :n * bs].reshape(
+                    cfg.num_heads, n, bs, -1).transpose(1, 0, 2, 3)
+                got = pool.logical(name, ids)
+                if kv == "int8":
+                    q, sc = jax.jit(quantize_kv)(jnp.asarray(ref))
+                    np.testing.assert_array_equal(got, np.asarray(q))
+                    np.testing.assert_array_equal(
+                        pool.logical(f"cache_p{c}s_{i}", ids),
+                        np.asarray(sc))
+                else:
+                    ref = np.asarray(jnp.asarray(ref).astype(got.dtype))
+                    assert got.dtype == ref.dtype
+                    np.testing.assert_array_equal(
+                        got.view(np.uint8), ref.view(np.uint8))
+            # nothing but the rows' own blocks and the trash was written
+            rest = [b for b in range(1, pool.num_blocks) if b not in live]
+            assert not np.any(pool.logical(name, rest))
+
+
+def _generate_by_the_old_hand_over(gen, prompts, new_tokens, kv):
+    """Greedy tokens of the hand-over this repo had before PR 37: the
+    prefill's float32 k and v written at position 0 of zero-filled
+    float32 ``[bb, H, max_len, D]`` row caches, which the scatter slices,
+    casts or quantizes; then the paged decode step."""
+    import jax
+    import jax.numpy as jnp
+    lens = [int(p.size) for p in prompts]
+    tokens, pos_ids, last = gen._pack_prompts(prompts)
+    bb, s = tokens.shape
+    pool = gen.new_pool(bb, dtype=kv, name=f"old_{kv}")
+    for r, n in enumerate(lens):
+        pool.alloc(r, n)
+    logits, rows, key = gen._run_prefill(
+        tokens, pos_ids, last, jax.random.PRNGKey(0), kv_dtype="fp32")
+    dense = {n: jnp.zeros(a.shape[:2] + (gen.max_len, a.shape[3]),
+                          jnp.float32).at[:, :, :s].set(a)
+             for n, a in rows.items()}
+    pool.scatter_prefill(list(range(len(prompts))), dense, s, lengths=lens)
+    zeros = np.zeros((bb,), np.float32), np.zeros((bb,), np.int32)
+    pos = np.zeros((bb,), np.int32)
+    pos[:len(lens)] = lens
+    outs = []
+    for _ in range(new_tokens):
+        tok, key = gen._run_sample(logits, *zeros, key)
+        outs.append(np.asarray(tok)[:len(lens)])
+        for r in range(len(lens)):
+            pool.ensure(r, int(pos[r]))
+        logits, key = gen._run_decode_paged(tok, pos, pool, key)
+        pos[:len(lens)] += 1
+    return list(np.stack(outs, axis=1))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "fp32", "int8"])
+def test_greedy_tokens_are_the_old_hand_overs(tiny_gen, kv):
+    """``generate`` over a bf16, fp32 or int8 pool returns the tokens
+    the float32 ``max_len``-long row caches gave; over fp32 those are
+    ``generate_naive``'s."""
+    cfg, _, gen = tiny_gen
+    prompts = _prompts(cfg, (5, 9, 12), seed=47)
+    got = gen.generate(prompts, max_new_tokens=11, seed=0, kv_dtype=kv)
+    want = _generate_by_the_old_hand_over(gen, prompts, 11, kv)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    if kv == "fp32":
+        for a, b in zip(got, gen.generate_naive(prompts, max_new_tokens=11,
+                                                seed=0)):
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "fp32", "int8"])
+def test_two_requests_admitted_together_through_the_server(tiny_gen, kv):
+    """Two requests waiting when the loop starts share one prefill: the
+    replies are offline ``generate``'s, and the ``generator/prefill``
+    span says what the program handed the scatter: ``cache_bytes``, the
+    bucket's rows x K and V x layers x hidden x the bucket's length x
+    the returned element's bytes."""
+    import time
+    from paddle_tpu import flags, serving
+    from paddle_tpu.observability import tracing
+
+    cfg, _, gen = tiny_gen
+    prompts = _prompts(cfg, (6, 11), seed=53)
+    ref = gen.generate(prompts, max_new_tokens=7, seed=0, kv_dtype=kv)
+    before = flags.flag("kv_cache_dtype")
+    flags.set_flags({"FLAGS_kv_cache_dtype": kv})
+    try:
+        server = serving.InferenceServer(generator=gen, decode_slots=2)
+    finally:
+        flags.set_flags({"FLAGS_kv_cache_dtype": before})
+    assert server.gen_engine.pool.dtype == kv
+    reqs = [server.submit_generate(p, max_new_tokens=7) for p in prompts]
+    t0 = time.perf_counter()
+    server.start(serve_network=False)
+    try:
+        outs = [r.wait(timeout=120)[0] for r in reqs]
+    finally:
+        server.stop()
+    for got, want in zip(outs, ref):
+        np.testing.assert_array_equal(got, want)
+    prefills = [r[7] for r in tracing.loop_spans(t0, time.perf_counter())
+                if r[0] == "generator/prefill"]
+    assert [a["rows"] for a in prefills] == [2]
+    assert prefills[0]["cache_bytes"] == \
+        2 * 2 * cfg.num_layers * cfg.hidden_size * 16 * ROW_BYTES[kv]
 
 
 # ---------------------------------------------------------------------------

@@ -108,8 +108,10 @@ def test_a_round_admits_only_what_its_prefill_fits(monkeypatch):
     gen = GPTGenerator(cfg, scope, max_len=48)
     engine = serving.GenerationEngine(gen, slots=8)
     one = engine.prefill_bytes([5])
-    # GPT-2's count: the dense float32 caches twice over and the logits
-    assert one == 2 * 2 * cfg.num_layers * cfg.hidden_size * 48 * 4 \
+    # GPT-2's count: the bucket-long keys and values in the pool's dtype
+    # twice over and the logits
+    assert (engine.pool.dtype, gen.bucket_min) == ("fp32", 16)
+    assert one == 2 * 2 * cfg.num_layers * cfg.hidden_size * 16 * 4 \
         + cfg.vocab_size * 4
     assert engine.prefill_bytes([5, 5, 5]) == 4 * one     # row bucket 4
 

@@ -562,7 +562,7 @@ def test_zoo_programs_verify_clean():
     gcfg = gpt.GPTConfig.tiny()
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
-        pre = gpt.gpt_prefill(gcfg, 16, batch_size=2, seq_len=8)
+        pre = gpt.gpt_prefill(gcfg, batch_size=2, seq_len=8)
     zoo.append(("gpt-prefill", main,
                 [v.name for v in pre.values()
                  if hasattr(v, "name")][:1]))

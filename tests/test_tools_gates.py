@@ -586,7 +586,7 @@ def _save_tools_gpt_serving(tmp, kind, sharded):
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         if kind == "prefill":
-            d = gpt.gpt_prefill(cfg, max_len=48)
+            d = gpt.gpt_prefill(cfg)
         else:
             d = gpt.gpt_decode_step_paged(cfg)
         if sharded:

@@ -1,14 +1,15 @@
 #!/usr/bin/env python3
 """Do two trees lower the serve cells' executables to one text?
 
-    python tools/lowering_identity.py lower ROOT OUT [gpt2-medium] [mellum] [ouro]
+    python tools/lowering_identity.py lower ROOT OUT [gpt2-medium] \
+        [mellum] [ouro] [jamba]
     python tools/lowering_identity.py diff OUT_A OUT_B
 
 ``lower`` imports ``paddle_tpu`` and ``benchmark`` from the tree at ROOT (a
 checkout, or a ``git archive`` of a commit) and lowers, with abstract
 weights and an abstract pool (nothing is placed on a device), the kinds of
-executable the GPT-2 medium, Mellum and Ouro serve cells warm (all three
-unless some are named): prefills of 1 to 8 rows at 32 to 6,144 with the
+executable the GPT-2 medium, Mellum, Ouro and Jamba serve cells warm (all
+four unless some are named): prefills of 1 to 8 rows at 32 to 6,144 with the
 scatter of each, the paged decode step, the picks. It writes each ``.mlir`` text and the feed and fetch names under
 OUT. With ``IDENTITY_TPU_HERE=1`` the kernels take their TPU branch and the
 text is lowered for the TPU platform with no chip; Mosaic's serialized
@@ -16,7 +17,8 @@ bodies are in it, and their ``loc(...)`` carry the checkout's path, so
 ``diff`` decodes each body and takes the path out before it compares
 two such outputs; it exits 1 on any difference. One process a tree: the
 module under ROOT is what ``import paddle_tpu`` finds. PR 31 wrote it, PR 33 used it again
-(``PERF.md`` section 6).
+(``PERF.md`` section 6), PR 37 gave it the Jamba cell (the scatter of a
+pool with a state group takes the rows' slots too).
 """
 import base64
 import hashlib
@@ -34,6 +36,8 @@ CONFIGS = {
                [(1, 1024), (2, 3072), (4, 6144)], [1, 2, 4, 32]),
     "ouro": ("ouro-2.6b.json", "ouro",
              [(1, 32), (2, 64), (4, 128)], [1, 2, 4, 16]),
+    "jamba": ("jamba2-3b.json", "jamba",
+              [(1, 256), (2, 1024), (4, 2048)], [1, 2, 4, 64]),
 }
 
 
@@ -158,9 +162,10 @@ def lower_tree(root, out, which):
             if pool.window is not None:
                 ring = (i32(rows, pool.window.ring),
                         i32(rows * pool.window.ring))
+            state = (i32(rows),) if pool.state_arrays else ()
             save(f"{tag}.scatter_prefill.r{rows}.b{nblk}", lower(
                 pool._scatter()._jit, dict(pool._arrays),
-                abstract(dict(row_caches)), i32(rows, nblk), *ring))
+                abstract(dict(row_caches)), i32(rows, nblk), *ring, *state))
         slots = eng.slots
         feed = abstract(decode_feed(pool, np.zeros(slots, np.int32),
                                     np.zeros(slots, np.int32)))
