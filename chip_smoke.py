@@ -23,8 +23,9 @@ seeded random weights:
            Executor.run and Executor.run_steps
   serve    GPTGenerator -> InferenceServer -> six
            concurrent Client.generate calls over the loopback socket,
-           no pool-sized copy in the decode step's or the scatter's
-           optimised HLO, then one logits check of paged decode against
+           no pool-sized copy in the decode step's or an admission's
+           optimised HLO (the admission: prefill, pick and scatter in
+           one executable), then one logits check of paged decode against
            a full recompute
   prefill  GPT's prefill at benchmark configuration gpt2-medium's
            widths over a bf16 pool, at the largest and the smallest
@@ -35,14 +36,14 @@ seeded random weights:
   loop     the looped block of benchmark configuration ouro-2.6b (its
            published widths, four passes, 2 of 48 layers) through
            InferenceServer: no pool-sized copy in its decode step (the
-           append runs inside the loop of passes) or its scatter, and a
+           append runs inside the loop of passes) or its admissions, and a
            logits check of prefill then paged decode against the
            family's plain float32 reference
   state    the hybrid block of benchmark configuration jamba2-3b (its
            published widths, one period of three layers with its
            attention layer, twice) through InferenceServer: no copy of a
            pool array or of the slot bank of recurrent states in its
-           decode step or its scatter, and a logits check of prefill (the
+           decode step or its admissions, and a logits check of prefill (the
            scan kernel) then paged decode against the family's plain
            float32 reference
   mesh     only with four or more devices: the train step under
@@ -876,16 +877,17 @@ def phase_serve(smoke):
         assert leaked == 0, pool.stats()
         stats = server.stats()
         # the pool keeps one layout from parameter to result: XLA:TPU
-        # left no pool-sized copy in the decode step or in the scatter
-        # (the interpreter's loops on the CPU copy as they please)
-        # the decode step is keyed by its executable: the decode kind
-        # and the pick inside it (decode_paged_fp32+sample_greedy)
+        # left no pool-sized copy in the decode step or in an admission
+        # (the interpreter's loops on the CPU copy as they please); each
+        # is keyed by its executable: the decode or prefill kind and the
+        # pick inside it (decode_paged_fp32+sample_greedy, the
+        # admission's prefill_bf16+sample_greedy with its scatter)
         relayouts = stats["pool_relayouts"]
-        decode_kinds = [k for k in relayouts
-                        if k.startswith(f"decode_paged_{pool.dtype}+")]
+        writers = [k for k in relayouts if k.startswith(
+            (f"decode_paged_{pool.dtype}+", "prefill_"))]
         assert sz.rehearsal or (
-            decode_kinds and all(relayouts[k] == 0 for k in decode_kinds)
-            and relayouts.get("scatter") == 0), relayouts
+            {k.partition("_")[0] for k in writers} == {"decode", "prefill"}
+            and all(relayouts[k] == 0 for k in writers)), relayouts
         name, arr = next(iter(pool.arrays().items()))
         pool_format = f"{name} {arr.dtype.name}{list(arr.shape)} " \
                       f"{getattr(arr, 'format', None)}"
@@ -1005,7 +1007,7 @@ def phase_loop(smoke):
     """The looped block (``models/ouro.py``: a stack of layers run four
     times over shared weights, a cache a (pass, layer) pair, the passes
     of a weight layer in one pool array) through ``InferenceServer``:
-    the decode step and the scatter leave no pool-sized copy though the
+    the decode step and the admissions leave no pool-sized copy though the
     append runs inside the loop of passes, and prefill then decode agree
     a step with the family's plain float32 reference."""
     import jax.numpy as jnp
@@ -1043,11 +1045,11 @@ def phase_loop(smoke):
         assert stats["kv_cache_layers"] == fsz.cache_layers \
             == pool.passes * pool.num_arrays, stats["kv_cache_layers"]
         relayouts = stats["pool_relayouts"]
-        decode_kinds = [k for k in relayouts
-                        if k.startswith(f"decode_paged_{pool.dtype}+")]
+        writers = [k for k in relayouts if k.startswith(
+            (f"decode_paged_{pool.dtype}+", "prefill_"))]
         assert sz.rehearsal or (
-            decode_kinds and all(relayouts[k] == 0 for k in decode_kinds)
-            and relayouts.get("scatter") == 0), relayouts
+            {k.partition("_")[0] for k in writers} == {"decode", "prefill"}
+            and all(relayouts[k] == 0 for k in writers)), relayouts
         params = fam.init_params(fsz, 33)
 
         def reference(seq):
@@ -1077,7 +1079,7 @@ def phase_state(smoke):
     """The hybrid block (``models/jamba.py``: Mamba-1 layers whose
     recurrent state and convolution tail live a slot in the pool beside
     the attention layers' paged keys and values) through
-    ``InferenceServer``: the decode step and the scatter leave no copy
+    ``InferenceServer``: the decode step and the admissions leave no copy
     of a block array or of the slot bank, and prefill (the scan kernel
     over a padded bucket) then decode agree a step with the family's
     plain float32 reference."""
@@ -1119,11 +1121,11 @@ def phase_state(smoke):
             == pool.state_layers, stats["kvpool_state_layers"]
         assert stats["kv_cache_layers"] == fsz.attention_layers
         relayouts = stats["pool_relayouts"]
-        decode_kinds = [k for k in relayouts
-                        if k.startswith(f"decode_paged_{pool.dtype}+")]
+        writers = [k for k in relayouts if k.startswith(
+            (f"decode_paged_{pool.dtype}+", "prefill_"))]
         assert sz.rehearsal or (
-            decode_kinds and all(relayouts[k] == 0 for k in decode_kinds)
-            and relayouts.get("scatter") == 0), relayouts
+            {k.partition("_")[0] for k in writers} == {"decode", "prefill"}
+            and all(relayouts[k] == 0 for k in writers)), relayouts
         params = fam.init_params(fsz, 36)
 
         def reference(seq):

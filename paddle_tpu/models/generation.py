@@ -139,6 +139,20 @@ _Sent = collections.namedtuple(
     "_Sent", "kind stage fetches key sig compiled t0")
 
 
+class PendingPrefill:
+    """The logits of a prefill into a pool that has not been sent yet:
+    the pool's prefill ``kind``, its ``feed`` (the prompts and the pool's
+    scatter indices) and the ``pool`` its keys, values and states go
+    into. Handed to :meth:`GPTGenerator._run_sample` as the logits, the
+    pick of the first tokens runs inside the prefill's own donated call
+    (``<prefill kind>+<pick kind>``), so neither the logits nor the row
+    caches leave the device. ``sent`` is that call once it was sent."""
+
+    def __init__(self, kind, feed, pool):
+        self.kind, self.feed, self.pool = kind, feed, pool
+        self.sent = None
+
+
 def _spec_accept_program_outs():
     """Acceptance program for speculative decoding: one ``spec_accept``
     op over the verify step's span logits (see ops/decode_ops.py for
@@ -494,8 +508,12 @@ class GPTGenerator:
         return {n: first + i for i, n in enumerate(names)
                 if n in feed_names and first + i < len(fetch_names)}
 
-    def _ensure_fn(self, kind):
-        entry = self._fns.get(kind)
+    def _ensure_fn(self, kind, scatter=None):
+        """``(jitted, device state)`` of the ``kind`` executable; with
+        ``scatter`` (a pool's ``kvpool.ScatterLayout``) the admission
+        ``<prefill kind>+<pick kind>`` into a pool of that layout."""
+        fkey = kind if scatter is None else (kind, scatter)
+        entry = self._fns.get(fkey)
         if entry is not None:
             return entry
         import jax
@@ -523,6 +541,10 @@ class GPTGenerator:
             pick_fn = build_block_fn(
                 pick_main, 0, pick_feeds, [pick_outs["tokens"].name],
                 [], [], mesh=self.mesh)
+        if scatter is not None:
+            run, unpack = self._admission(kind, fn, feed_names, pick_fn,
+                                          pick_feeds, scatter)
+            return self._jit_fn(fkey, run, unpack, main, state_in)
 
         # only the decode step's KV caches are worth donating (XLA
         # aliases the cache append in place — no 2x cache traffic);
@@ -558,8 +580,53 @@ class GPTGenerator:
             return [caches[at[i]] if i in at else next(rest)
                     for i in range(len(fetch_names))]
 
+        return self._jit_fn(fkey, run, unpack, main, state_in)
+
+    def _admission(self, kind, fn, feed_names, pick_fn, pick_feeds,
+                   scatter):
+        """``(run, unpack)`` of an admission, ``<prefill kind>+<pick
+        kind>`` into a pool of layout ``scatter``: the prefill, the pick
+        of the first tokens and the pool's scatter of the keys and
+        values (and states) the prefill made, in one executable. The
+        programs run as they did as three calls (the pick on the key the
+        prefill advanced, the key advanced again), so a seeded request
+        draws the token it drew from them; the logits and the row caches
+        never leave the device. The pool's arrays are donated and come
+        back updated where they lie; every shape is the bucket's (the
+        padding rows' blocks are the trash block's, their slots past the
+        bank's end), so a length bucket compiles once a row bucket. The
+        fetch list: int32 [rows] tokens, the pool's arrays in
+        ``scatter.names`` order (``kvpool.adopt_decode_fetches``), then
+        the prefill's aux."""
+        from ..serving.kvpool import SCATTER_FEEDS, prefill_scatter
+        write = prefill_scatter(scatter)
+        naux = len(self._ensure_prog(kind)[1].get("aux", {}))
+
+        def run(state, caches, feed, base_key):
+            fetches, _, new_key = fn(
+                {}, state, {n: feed[n] for n in feed_names}, base_key)
+            picked, _, new_key = pick_fn(
+                {}, {}, dict({n: feed[n] for n in pick_feeds[1:]},
+                             logits=fetches[0]), new_key)
+            rows = self._unpack_caches(kind, fetches)[1]
+            pool = write(caches, rows,
+                         *(feed.get(n) for n in SCATTER_FEEDS))
+            return ([picked[0]] + fetches[len(fetches) - naux:], pool,
+                    new_key)
+
+        def unpack(rest, pool):
+            return [rest[0]] + [pool[n] for n in scatter.names] \
+                + list(rest[1:])
+
+        return run, unpack
+
+    def _jit_fn(self, fkey, run, unpack, main, state_in):
+        """Jit ``run`` (its second argument, the caches, donated), keep
+        it with its ``unpack`` and a device snapshot of the ``state_in``
+        parameters under ``fkey``."""
+        import jax
         jitted = jax.jit(run, donate_argnums=(1,))
-        self._unpack[kind] = unpack
+        self._unpack[fkey] = unpack
         # one device snapshot per PARAMETER, shared by every kind's
         # state dict (prefill/decode/logits read the same weights — a
         # per-kind device_put would hold N identical copies in HBM)
@@ -587,8 +654,8 @@ class GPTGenerator:
                     a = jax.device_put(np.asarray(v))
                 self._params[n] = a
             state[n] = a
-        self._fns[kind] = (jitted, state)
-        return self._fns[kind]
+        self._fns[fkey] = (jitted, state)
+        return self._fns[fkey]
 
     def bind_params(self, device_params):
         """Adopt arrays that are on the device already as the snapshot
@@ -643,13 +710,15 @@ class GPTGenerator:
         self._await(sent)
         return sent.fetches, sent.key
 
-    def _dispatch(self, kind, stage, feed, key):
+    def _dispatch(self, kind, stage, feed, key, scatter=None):
         """Send the ``kind`` executable on ``feed`` and return at once
         (a fresh signature compiles first): a :class:`_Sent` whose
         results the device fills in. Whoever needs them ready, or
-        wants the call accounted for, hands it to :meth:`_await`."""
+        wants the call accounted for, hands it to :meth:`_await`.
+        ``scatter``: an admission into a pool of that layout
+        (:meth:`_ensure_fn`)."""
         import jax
-        jitted, state = self._ensure_fn(kind)
+        jitted, state = self._ensure_fn(kind, scatter)
         with _trace.loop_span("generator/signature"):
             sig = self._signature(kind, feed)
             caches = {n: a for n, a in feed.items()
@@ -704,7 +773,8 @@ class GPTGenerator:
         with _trace.loop_span("generator/dispatch", kind=kind,
                               stage=stage, compiled=fresh) as sending:
             fetched, kept, new_key = compiled(state, caches, rest, key)
-            fetches = self._unpack[kind](fetched, kept)
+            fetches = self._unpack[kind if scatter is None
+                                   else (kind, scatter)](fetched, kept)
         return _Sent(kind, stage, fetches, new_key, sig, compiled,
                      sending.t0)
 
@@ -862,10 +932,32 @@ class GPTGenerator:
         return fetches[0], key
 
     def _run_sample(self, logits, temperature, top_k, key):
+        """``(tokens, key)``: the pick of one token a row from
+        ``logits``, as a call of its own; from a :class:`PendingPrefill`
+        inside that prefill's call (:meth:`_run_admission`)."""
         kind, feed = pick_for(temperature, top_k)
+        if isinstance(logits, PendingPrefill):
+            return self._run_admission(logits, kind, feed, key)
         fetches, key = self._invoke(kind, "sample",
                                     dict(feed, logits=logits), key)
         return fetches[0], key
+
+    def _run_admission(self, pending, pick, feed, key):
+        """Send ``pending``'s prefill with the ``pick`` program on its
+        logits and the pool's scatter of its row caches as ONE donated
+        call (:meth:`_ensure_fn` with the pool's layout), adopt the
+        pool's arrays it hands back and wait for it. Returns ``(first
+        tokens int32 [rows], key)``."""
+        from ..serving.kvpool import adopt_decode_fetches
+        pool = pending.pool
+        feed.update(pending.feed)
+        feed.update(pool.arrays())
+        pending.sent = sent = self._dispatch(
+            f"{pending.kind}+{pick}", "prefill", feed, key,
+            scatter=pool.scatter_layout())
+        toks = adopt_decode_fetches(pool, sent.fetches)
+        self._await(sent)
+        return toks, sent.key
 
     pick_for = staticmethod(pick_for)
 

@@ -493,8 +493,8 @@ class GenerationEngine:
         # what every executable of this architecture runs a token: passes
         # over its weights (more than 1 where a stack is run several
         # times), the pool's cache layers and, where it has a state group,
-        # that group's layers; on the engine/step, generator/prefill and
-        # pool/scatter spans
+        # that group's layers; on the engine/step and generator/prefill
+        # spans
         self.loop_attrs = dict(
             ut_steps=int(getattr(generator.arch, "ut_steps", 1)),
             cache_layers=self.pool.num_layers, **self.pool.state_attrs())
@@ -613,9 +613,12 @@ class GenerationEngine:
         self.gen.swap_params(device_params)
 
     def admit(self, requests, slot_ids):
-        """Prefill the new requests' prompts (one bucketed batch), sample
-        their first tokens, write their caches into ``slot_ids``.
-        Returns the first tokens as np int32 [len(requests)]."""
+        """Prefill the new requests' prompts (one bucketed batch), pick
+        their first tokens and write their caches into ``slot_ids``, in
+        ONE donated executable (``GPTGenerator``'s ``<prefill
+        kind>+<pick kind>`` over the pool's layout, sent by its
+        ``_run_sample`` on a ``PendingPrefill``). Returns the first
+        tokens as np int32 [len(requests)]."""
         maybe_fail("serving.prefill")
         self._ensure_caches()
         n = len(requests)
@@ -629,73 +632,77 @@ class GenerationEngine:
                 self.admit(requests[fit:], slot_ids[fit:])])
         t0 = time.perf_counter()
         span = _trace.loop_span
+        gen, pool = self.gen, self.pool
         with span("engine/pack", rows=n):
-            tokens, pos_ids, last = self.gen._pack_prompts(
+            tokens, pos_ids, last = gen._pack_prompts(
                 [req.prompt for req in requests])
-            bb = tokens.shape[0]
+            bb, seq = tokens.shape
             temp = np.zeros((bb,), np.float32)
             topk = np.zeros((bb,), np.int32)
             for r, req in enumerate(requests):
                 temp[r] = req.temperature
                 topk[r] = req.top_k
+            feed = dict(tokens=tokens, pos_ids=pos_ids, last_pos=last)
 
-        # allocate each row's prompt blocks BEFORE the prefill (the
-        # scatter routes through the tables); a mid-batch failure
-        # rolls this batch's allocations back untouched
+        # allocate each row's prompt blocks BEFORE the call (the scatter
+        # routes through the tables); a mid-batch failure rolls this
+        # batch's allocations back untouched
         allocated = []
         with span("pool/alloc", rows=n):
             try:
                 for req, slot in zip(requests, slot_ids):
-                    self.pool.free_slot(slot)   # stale holder (if any)
-                    self.pool.alloc(slot, int(req.prompt.size))
+                    pool.free_slot(slot)   # stale holder (if any)
+                    pool.alloc(slot, int(req.prompt.size))
                     allocated.append(slot)
             except Exception:
                 for sl in allocated:
-                    self.pool.free_slot(sl)
+                    pool.free_slot(sl)
                 raise
-        with span("generator/prefill", rows=n,
+            feed.update(pool.scatter_indices(
+                slot_ids, seq, [int(r.prompt.size) for r in requests],
+                rows=bb))
+        # the first tokens are picked from the prefill's logits inside
+        # its own call: the pick is handed the prefill before it is sent
+        from ..models.generation import PendingPrefill
+        prefill = PendingPrefill(gen.arch.prefill_kind(pool.dtype), feed,
+                                 pool)
+        with span("generator/prefill", rows=n, fused=1,
                   **self.loop_attrs) as ran:
-            logits, row_caches, self._key, aux = self.gen._run_prefill(
-                tokens, pos_ids, last, self._key,
-                kv_dtype=self.pool.dtype, want_aux=True)
-        with span("generator/sample", rows=n):
-            toks, self._key = self.gen._run_sample(logits, temp, topk,
-                                                   self._key)
-        # (rows, blocks) is the pair the scatter's jit retraces on
-        with span("pool/scatter", rows=n, cache_layers=self.pool.num_layers,
-                  blocks=self.pool.blocks_for_tokens(tokens.shape[1]),
-                  **self.pool.state_attrs()):
             try:
                 maybe_fail("serving.slot_insert")
-                self.pool.scatter_prefill(
-                    list(slot_ids), row_caches, tokens.shape[1],
-                    lengths=[int(r.prompt.size) for r in requests])
+                toks, self._key = gen._run_sample(prefill, temp, topk,
+                                                  self._key)
             except Exception:
-                # the donated device pool is lost (scatter dropped
-                # it); this batch's blocks go back, the batcher
-                # fails the other active rows via bank_lost
+                # the donated device pool is lost; this batch's blocks
+                # go back, the batcher fails the other active rows via
+                # bank_lost
                 for sl in slot_ids:
-                    self.pool.free_slot(sl)
-                self.bank_lost = True
+                    pool.free_slot(sl)
+                self._drop_bank()
                 raise
-        if self.pool.prefix_enabled:
+        if self.stats:
+            self.stats.bump("admissions")
+            self.stats.bump("admissions_fused")
+        if pool.prefix_enabled:
             # deposit the freshly prefilled prompt blocks into the
             # prefix index (refcounted co-ownership — they outlive the
             # slot's EOS until evicted LRU); later requests sharing the
             # prompt prefix adopt them instead of recomputing
             for req, slot in zip(requests, slot_ids):
-                self.pool.prefix_insert(req.prompt, slot)
+                pool.prefix_insert(req.prompt, slot)
         with span("engine/fetch", rows=n):
             out = np.asarray(toks)[:n]
-            ran.attrs.update(self._admitted(aux, requests, tokens, row_caches))
+            sent = prefill.sent
+            ran.attrs.update(self._admitted(
+                gen.aux_of(sent.kind, sent.fetches), requests, bb, seq))
         t1 = time.perf_counter()
         for req in requests:
             if getattr(req, "trace", None) is not None:
                 _trace.record_child("serving/prefill", t0, t1, req.trace)
-        # freeing the prefill's device results hands the interpreter to
+        # freeing the call's device results hands the interpreter to
         # whatever thread waits for it (_step's engine/release)
         with span("engine/release"):
-            logits = row_caches = aux = toks = None
+            prefill = sent = toks = None
         return out
 
     def _count_routing(self, aux, rows=None):
@@ -1028,23 +1035,22 @@ class GenerationEngine:
         with _trace.loop_span("engine/fetch"):
             return np.asarray(out), np.asarray(acc)
 
-    def _admitted(self, aux, requests, tokens, row_caches):
+    def _admitted(self, aux, requests, rows, seq):
         """What an admission's ``generator/prefill`` span gains:
-        ``cache_bytes``, the bytes of the row caches the program handed
-        to the scatter (every row of the bucket, in the dtype and at the
-        length it returned them); :meth:`_count_routing`'s attrs; and,
-        over a pool with a state group, ``prompt_tokens`` (the real
-        ones) and ``scan_tokens`` (admitted rows x the bucket's length:
-        what the recurrence was given to walk), counted into
-        ``scan_tokens`` and ``state_slot_writes`` (a row's state written
-        into its slot)."""
+        ``cache_bytes``, the bytes its call wrote into the pool (every
+        row of the ``rows`` x ``seq`` bucket, the padding's on the trash
+        block: ``KVBlockPool.scatter_bytes``); :meth:`_count_routing`'s
+        attrs; and, over a pool with a state group, ``prompt_tokens``
+        (the real ones) and ``scan_tokens`` (admitted rows x the
+        bucket's length: what the recurrence was given to walk), counted
+        into ``scan_tokens`` and ``state_slot_writes`` (a row's state
+        written into its slot)."""
         attrs = self._count_routing(aux, rows=len(requests))
-        attrs["cache_bytes"] = int(sum(a.nbytes
-                                       for a in row_caches.values()))
+        attrs["cache_bytes"] = self.pool.scatter_bytes(rows, seq)
         if self.pool.state_layers:
             attrs["prompt_tokens"] = int(sum(r.prompt.size
                                              for r in requests))
-            attrs["scan_tokens"] = len(requests) * int(tokens.shape[1])
+            attrs["scan_tokens"] = len(requests) * int(seq)
             if self.stats:
                 self.stats.bump("scan_tokens", attrs["scan_tokens"])
                 self.stats.bump("state_slot_writes", len(requests))

@@ -87,7 +87,7 @@ import hashlib
 import math
 import re
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, namedtuple
 
 import numpy as np
 
@@ -331,6 +331,121 @@ def adopt_decode_fetches(pool, fetches):
     return fetches[0]
 
 
+# what the prefill scatter's body is built from: a pool's layout
+# (KVBlockPool.scatter_layout), and the feed names of the host arrays it
+# routes a prefill through (KVBlockPool.scatter_indices), in its order
+ScatterLayout = namedtuple(
+    "ScatterLayout",
+    "names block_size quantized d_head passes per_pass full windowed state")
+SCATTER_FEEDS = ("scatter_tables", "scatter_ring_src", "scatter_ring_dst",
+                 "scatter_slots")
+
+
+def prefill_scatter(layout):
+    """The prefill scatter's body for a pool of ``layout`` (a
+    :class:`ScatterLayout`), a pure function any jit may trace:
+    ``(pool, row_caches, tables [n, nblk], ring_src, ring_dst, slots) ->
+    pool``, the pool's arrays with every row's keys and values (and
+    states) written (:meth:`KVBlockPool.scatter_prefill` says what goes
+    where). ``ring_src``/``ring_dst`` are None without a window group,
+    ``slots`` None without a state group; a slot past the bank's end is
+    dropped."""
+    import jax.numpy as jnp
+    from ..kernels.paged_attention import (
+        pool_packing, quantize_kv, scales_to_stored, to_stored)
+    bs, quant, d_head = layout.block_size, layout.quantized, layout.d_head
+    passes, per_pass = layout.passes, layout.per_pass
+
+    def blocks_of(src, n, nblk):
+        """[bb, H, L, D] -> [n, nblk, H, bs, D]: the first ``nblk``
+        blocks of the first ``n`` rows, logical (what the int8 pool
+        quantizes), zero-padded past ``L``."""
+        cover = nblk * bs
+        take = min(cover, src.shape[2])
+        vals = src[:n, :, :take]
+        if take < cover:
+            pad = jnp.zeros((n, src.shape[1], cover - take, src.shape[3]),
+                            src.dtype)
+            vals = jnp.concatenate([vals, pad], axis=2)
+        vals = vals.reshape(n, vals.shape[1], nblk, bs, vals.shape[3])
+        return vals.transpose(0, 2, 1, 3, 4)
+
+    def stored_blocks(src, n, nblk):
+        """[bb, H, L, D] -> [n, nblk, H * bs // f, f * D]: the same
+        blocks as the pool stores them (``to_stored`` of
+        :func:`blocks_of`), formed from the positions-major ``[n, L, H,
+        D]`` the prefill computed them in (the transpose back cancels
+        its own) with the ``f`` slots of a stored row joined on the lane
+        axis, so the one transpose left writes whole 128-lane rows (a
+        v5e admits a bucket of 8 x 1,024 GPT-2 medium tokens in 97.9 ms
+        so, in 103.6 ms through ``blocks_of``). Sliced here,
+        inside the jit, where the slice fuses with the gather; the
+        covered length is shape-determined, zero-padded past ``L``."""
+        H, D = src.shape[1], src.shape[3]
+        f = pool_packing(D, bs)
+        cover = nblk * bs
+        take = min(cover, src.shape[2])
+        vals = src[:n, :, :take].transpose(0, 2, 1, 3)
+        if take < cover:
+            pad = jnp.zeros((n, cover - take, H, D), src.dtype)
+            vals = jnp.concatenate([vals, pad], axis=1)
+        vals = vals.reshape(n, nblk, bs // f, f, H, D)
+        vals = jnp.concatenate([vals[:, :, :, r] for r in range(f)],
+                               axis=-1)
+        return vals.transpose(0, 1, 3, 2, 4).reshape(
+            n, nblk, H * bs // f, f * D)
+
+    def scatter(pool, rows, tables, ring_src, ring_dst, slots=None):
+        out = dict(pool)
+        n, nblk = tables.shape
+        m, tables_flat = n * nblk, tables.reshape(-1)
+        # several passes in an array: [U,bb,H,L,D] row caches, pass u's
+        # blocks at the table's ids + u * per_pass. One pass keeps the
+        # path it had, with no leading axis: the scatters the other cells
+        # warm lower to the text they lowered to (an add and a
+        # concatenate of one part would move their compile-cache keys)
+        at = tables_flat if passes == 1 else jnp.concatenate(
+            [tables_flat + u * per_pass for u in range(passes)])
+        for i in layout.full:
+            for kind in ("k", "v"):
+                src = rows[f"cache_{kind}_{i}"]    # [bb,H,L,D]
+                dst = out[f"cache_p{kind}_{i}"]
+                # whole blocks into rows of the stored array: dimension 0
+                # alone is indexed, so in place
+                if quant:
+                    q, sc = quantize_kv(blocks_of(src, n, nblk).reshape(
+                        (m, -1, bs, d_head)))
+                    out[f"cache_p{kind}_{i}"] = \
+                        dst.at[tables_flat].set(to_stored(q))
+                    skey = f"cache_p{kind}s_{i}"
+                    out[skey] = out[skey].at[tables_flat].set(
+                        scales_to_stored(sc, d_head))
+                    continue
+                vals = stored_blocks(src, n, nblk) if passes == 1 \
+                    else jnp.concatenate([stored_blocks(src[u], n, nblk)
+                                          for u in range(passes)])
+                out[f"cache_p{kind}_{i}"] = dst.at[at].set(
+                    vals.reshape((passes * m,) + vals.shape[2:]).astype(
+                        dst.dtype))
+        for i in layout.windowed:
+            for kind in ("k", "v"):
+                vals = stored_blocks(rows[f"cache_{kind}_{i}"], n, nblk)
+                vals = jnp.take_along_axis(
+                    vals, ring_src[:, :, None, None], axis=1)
+                dst = out[f"cache_p{kind}_{i}"]
+                out[f"cache_p{kind}_{i}"] = dst.at[ring_dst].set(
+                    vals.reshape((-1,) + vals.shape[2:]).astype(dst.dtype))
+        for name in layout.state:
+            # a row's state into its slot: dimension 0 alone is indexed,
+            # so in place
+            dst = out[name]
+            out[name] = dst.at[slots].set(rows[name][:n].astype(dst.dtype),
+                                          mode="drop")
+        return out
+
+    return scatter
+
+
 class _WindowGroup:
     """The window layers' share of a pool: block ids of its own, a LIFO
     free list and a ring table a slot. Not thread-safe by itself: the
@@ -491,6 +606,7 @@ class KVBlockPool:
         self.array_sharding = None     # NamedSharding under a tp mesh
         self._arrays = None            # lazy device pool
         self._scatter_fn = None
+        self._layout = None            # scatter_layout(), made once
         self._import_fn = None         # migration scatter (import_slot)
         self._copy_fn = None           # COW block duplication
         self._update_gauges()
@@ -1039,91 +1155,83 @@ class KVBlockPool:
             raise
 
     # -- prefill scatter --------------------------------------------------
+    def scatter_layout(self):
+        """What :func:`prefill_scatter`'s body is built from: this
+        pool's layout, never its contents (hashable: one body and one
+        executable a layout and shape)."""
+        if self._layout is None:
+            self._layout = ScatterLayout(
+                names=tuple(self.feed_names()), block_size=self.block_size,
+                quantized=self.quantized, d_head=self.d_head,
+                passes=self.passes, per_pass=self.num_blocks,
+                full=tuple(i for i in self.full_layers
+                           if i < self.num_arrays),
+                windowed=tuple(self.window.layers) if self.window else (),
+                state=tuple(self.state_arrays))
+        return self._layout
+
     def _scatter(self):
-        """The prefill scatter's donated jit (built once): ``(pool,
-        row_caches, tables [n, nblk], ring_src, ring_dst, slots [n]) ->
-        pool`` (``slots`` None without a state group)."""
+        """The prefill scatter as a donated jit of its own (built once):
+        what :meth:`scatter_prefill` runs."""
         if self._scatter_fn is None:
-            import jax.numpy as jnp
-            from ..kernels.paged_attention import (
-                quantize_kv, scales_to_stored, to_stored)
-            bs, quant, d_head = self.block_size, self.quantized, self.d_head
-            passes, per_pass = self.passes, self.num_blocks
-            full = [i for i in self.full_layers if i < self.num_arrays]
-            windowed = list(self.window.layers) if self.window else []
-            state_names = list(self.state_arrays)
-
-            def blocks_of(src, n, nblk):
-                """[bb, H, L, D] -> [n, nblk, H, bs, D]: the first
-                ``nblk`` blocks of the first ``n`` rows (sliced here,
-                inside the jit, where the slice fuses with the gather:
-                outside it is a copy of every row cache). The covered
-                length is shape-determined (the jit retraces per
-                (n, nblk) pair), zero-padded past ``L``."""
-                cover = nblk * bs
-                take = min(cover, src.shape[2])
-                vals = src[:n, :, :take]
-                if take < cover:
-                    pad = jnp.zeros(
-                        (n, src.shape[1], cover - take, src.shape[3]),
-                        src.dtype)
-                    vals = jnp.concatenate([vals, pad], axis=2)
-                vals = vals.reshape(n, vals.shape[1], nblk, bs,
-                                    vals.shape[3])
-                return vals.transpose(0, 2, 1, 3, 4)
-
-            def scatter(pool, rows, tables, ring_src, ring_dst, slots=None):
-                out = dict(pool)
-                n, nblk = tables.shape
-                m, tables_flat = n * nblk, tables.reshape(-1)
-                # several passes in an array: [U,bb,H,L,D] row caches,
-                # pass u's blocks at the table's ids + u * per_pass. One
-                # pass keeps the path it had, with no leading axis: the
-                # scatters the other cells warm lower to the text they
-                # lowered to (an add and a concatenate of one part would
-                # move their compile-cache keys)
-                at = tables_flat if passes == 1 else jnp.concatenate(
-                    [tables_flat + u * per_pass for u in range(passes)])
-                for i in full:
-                    for kind in ("k", "v"):
-                        src = rows[f"cache_{kind}_{i}"]    # [bb,H,L,D]
-                        vals = blocks_of(src, n, nblk) if passes == 1 \
-                            else jnp.concatenate([blocks_of(src[u], n, nblk)
-                                                  for u in range(passes)])
-                        vals = vals.reshape((passes * m,) + vals.shape[2:])
-                        dst = out[f"cache_p{kind}_{i}"]
-                        # whole blocks into rows of the stored array:
-                        # dimension 0 alone is indexed, so in place
-                        if quant:
-                            q, sc = quantize_kv(vals)
-                            out[f"cache_p{kind}_{i}"] = \
-                                dst.at[tables_flat].set(to_stored(q))
-                            skey = f"cache_p{kind}s_{i}"
-                            out[skey] = out[skey].at[tables_flat].set(
-                                scales_to_stored(sc, d_head))
-                        else:
-                            out[f"cache_p{kind}_{i}"] = \
-                                dst.at[at].set(
-                                    to_stored(vals.astype(dst.dtype)))
-                for i in windowed:
-                    for kind in ("k", "v"):
-                        vals = blocks_of(rows[f"cache_{kind}_{i}"], n, nblk)
-                        vals = jnp.take_along_axis(
-                            vals, ring_src[:, :, None, None, None], axis=1)
-                        dst = out[f"cache_p{kind}_{i}"]
-                        out[f"cache_p{kind}_{i}"] = dst.at[ring_dst].set(
-                            to_stored(vals.reshape(
-                                (-1,) + vals.shape[2:]).astype(dst.dtype)))
-                for name in state_names:
-                    # a row's state into its slot: dimension 0 alone is
-                    # indexed, so in place
-                    dst = out[name]
-                    out[name] = dst.at[slots].set(
-                        rows[name][:n].astype(dst.dtype))
-                return out
-
-            self._scatter_fn = _PoolJit(scatter)
+            self._scatter_fn = _PoolJit(prefill_scatter(
+                self.scatter_layout()))
         return self._scatter_fn
+
+    def scatter_indices(self, slot_ids, bucket_len, lengths=None,
+                        rows=None):
+        """The host arrays :func:`prefill_scatter` routes a prefill of
+        ``bucket_len`` positions through, for a batch of ``rows`` rows
+        (``len(slot_ids)`` by default) whose first rows go to
+        ``slot_ids``: ``scatter_tables`` int32 [rows, nblk] (the slots'
+        block tables; a row past ``slot_ids`` points every entry at the
+        trash block), with a window group ``scatter_ring_src`` int32
+        [rows, ring] and ``scatter_ring_dst`` int32 [rows * ring] (of a
+        prompt of ``lengths[r]`` tokens the last ``ring`` logical
+        blocks, each into the ring column its index names; a row past
+        ``slot_ids`` writes the window's trash block), with a state group
+        ``scatter_slots`` int32 [rows] (a row past ``slot_ids`` names
+        the slot past the bank's end, which the scatter drops)."""
+        n = len(slot_ids)
+        rows = n if rows is None else int(rows)
+        nblk = self.blocks_for_tokens(bucket_len)
+        slots = np.asarray(slot_ids, np.int32)
+        tables = np.zeros((rows, nblk), np.int32)
+        tables[:n] = self.tables[slots, :nblk]
+        out = {"scatter_tables": tables}
+        if self.window is not None:
+            if lengths is None:
+                raise ValueError("a pool with a window group scatters a "
+                                 "prefill by the prompts' lengths")
+            w = self.window
+            last = (np.asarray(lengths, np.int64)[:n] - 1) // self.block_size
+            first = np.maximum(last - (w.ring - 1), 0)
+            logical = first[:, None] + np.arange(w.ring)[None, :]
+            ring_src = np.zeros((rows, w.ring), np.int32)
+            ring_src[:n] = np.minimum(logical, nblk - 1)
+            ring_dst = np.zeros((rows, w.ring), np.int32)
+            ring_dst[:n] = np.where(logical <= last[:, None],
+                                    w.tables[slots[:, None], logical % w.ring],
+                                    0)
+            out["scatter_ring_src"] = ring_src
+            out["scatter_ring_dst"] = ring_dst.reshape(-1)
+        if self.state_arrays:
+            out["scatter_slots"] = np.full((rows,), self.slots, np.int32)
+            out["scatter_slots"][:n] = slots
+        return out
+
+    def scatter_bytes(self, rows, bucket_len):
+        """Device bytes :func:`prefill_scatter` writes into the pool for
+        a batch of ``rows`` rows of ``bucket_len`` positions: every
+        row's blocks in each cache layer (a window layer's ring of them)
+        and its slot of the state arrays, padding rows' included (they
+        land on the trash block)."""
+        rows, nblk = int(rows), self.blocks_for_tokens(bucket_len)
+        n = rows * nblk * self.block_bytes()
+        if self.window:
+            n += rows * self.window.ring * self.block_bytes(
+                self.window.layers)
+        return n + rows * self.state_bytes_per_slot()
 
     def scatter_prefill(self, slot_ids, row_caches, bucket_len,
                         lengths=None):
@@ -1141,38 +1249,19 @@ class KVBlockPool:
         (``row_caches[cache_s<tag>_<m>]``, ``[bb, ...]``) go to rows
         ``slot_ids`` of the slot bank in the same call.
         A window group's layers keep only what a row's ring
-        holds: of a prompt of ``lengths[r]`` tokens the last ``ring``
-        blocks, each into the column its logical index names. Quantizes
-        on the way in for an int8 pool. On ANY failure the donated pool
-        arrays must be presumed lost — callers reset the pool."""
+        holds (:meth:`scatter_indices`). Quantizes on the way in for an
+        int8 pool. On ANY failure the donated pool arrays must be
+        presumed lost — callers reset the pool. The serving engine's
+        admission runs the same body inside its prefill's executable
+        (``GPTGenerator``'s ``<prefill kind>+<pick kind>``)."""
         import jax.numpy as jnp
 
-        n = len(slot_ids)
-        nblk = self.blocks_for_tokens(bucket_len)
-        slots = np.asarray(slot_ids, np.int32)
-        tables = np.ascontiguousarray(self.tables[slots, :nblk])
-        ring_src = ring_dst = None
-        if self.window is not None:
-            if lengths is None:
-                raise ValueError("a pool with a window group scatters a "
-                                 "prefill by the prompts' lengths")
-            w = self.window
-            last = (np.asarray(lengths, np.int64)[:n] - 1) // self.block_size
-            first = np.maximum(last - (w.ring - 1), 0)
-            logical = first[:, None] + np.arange(w.ring)[None, :]
-            ring_src = np.minimum(logical, nblk - 1).astype(np.int32)
-            ring_dst = np.where(
-                logical <= last[:, None],
-                w.tables[slots[:, None], logical % w.ring],
-                0).astype(np.int32).reshape(-1)           # [n*ring]
-
+        idx = self.scatter_indices(slot_ids, bucket_len, lengths)
         try:
             self._arrays = self._scatter()(
                 self.arrays(), dict(row_caches),
-                jnp.asarray(tables, jnp.int32),
-                None if ring_src is None else jnp.asarray(ring_src),
-                None if ring_dst is None else jnp.asarray(ring_dst),
-                jnp.asarray(slots) if self.state_arrays else None)
+                *(None if idx.get(k) is None else jnp.asarray(idx[k])
+                  for k in SCATTER_FEEDS))
         except Exception:
             self._arrays = None
             raise

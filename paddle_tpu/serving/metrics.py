@@ -230,6 +230,9 @@ _COUNTER_KEYS = (
     # -- per-slot recurrent state beside the KV blocks --
     "state_slot_writes",    # rows whose state an admission wrote to a slot
     "scan_tokens",          # admitted rows x bucket length the scans walked
+    # -- admission: prefill, first-token pick and scatter into the pool --
+    "admissions",           # prefills GenerationEngine.admit ran
+    "admissions_fused",     # of those, sent as one executable
 )
 
 

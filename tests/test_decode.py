@@ -272,7 +272,11 @@ def test_decode_batcher_slot_reuse(tiny_gen):
         assert st["decode_steps"] > 0
         assert 0.0 < st["decode_occupancy"] <= 1.0
         assert st["decode_free_slots"] == 2          # all slots returned
-        assert st["prefill_count"] >= 1 and st["sample_count"] >= 1
+        assert st["prefill_count"] >= 1 and st["decode_count"] >= 1
+        # the picks run inside the admission's and the decode step's
+        # executables: no pick of its own, every admission one call
+        assert st["sample_count"] == 0
+        assert st["admissions"] == st["admissions_fused"] >= 1
         assert st["tokens_per_s"] > 0
     finally:
         server.stop()
@@ -336,9 +340,10 @@ def test_token_level_deadline_frees_slot(tiny_gen):
 # what the prefill hands the pool
 # ---------------------------------------------------------------------------
 
-# what a prefill hands a pool of each dtype, and an element's bytes
+# what a prefill hands a pool of each dtype, and an element's bytes in
+# the pool
 ROW_DTYPES = {"bf16": "bfloat16", "fp32": "float32", "int8": "float32"}
-ROW_BYTES = {"bf16": 2, "fp32": 4, "int8": 4}
+POOL_BYTES = {"bf16": 2, "fp32": 4, "int8": 1}
 
 
 def _forward_kv(cfg, scope, tokens, pos_ids, last):
@@ -494,9 +499,10 @@ def test_greedy_tokens_are_the_old_hand_overs(tiny_gen, kv):
 def test_two_requests_admitted_together_through_the_server(tiny_gen, kv):
     """Two requests waiting when the loop starts share one prefill: the
     replies are offline ``generate``'s, and the ``generator/prefill``
-    span says what the program handed the scatter: ``cache_bytes``, the
-    bucket's rows x K and V x layers x hidden x the bucket's length x
-    the returned element's bytes."""
+    span says what its call wrote into the pool: ``cache_bytes``, the
+    bucket's rows x K and V x layers x the bucket's length x (hidden x
+    the pool's element bytes, and an int8 pool's float32 scale a
+    head)."""
     import time
     from paddle_tpu import flags, serving
     from paddle_tpu.observability import tracing
@@ -524,7 +530,9 @@ def test_two_requests_admitted_together_through_the_server(tiny_gen, kv):
                 if r[0] == "generator/prefill"]
     assert [a["rows"] for a in prefills] == [2]
     assert prefills[0]["cache_bytes"] == \
-        2 * 2 * cfg.num_layers * cfg.hidden_size * 16 * ROW_BYTES[kv]
+        2 * 2 * cfg.num_layers * 16 * (
+            cfg.hidden_size * POOL_BYTES[kv]
+            + (4 * cfg.num_heads if kv == "int8" else 0))
 
 
 # ---------------------------------------------------------------------------

@@ -420,18 +420,19 @@ def test_inference_server_serves_it_and_its_spans_say_what_ran():
     sent = [r[7] for r in rows if r[0] == "engine/step"
             and "state_rows" in r[7]]
     prefills = [r[7] for r in rows if r[0] == "generator/prefill"]
-    scatters = [r[7] for r in rows if r[0] == "pool/scatter"]
+    assert not [r for r in rows if r[0] == "pool/scatter"]
     assert sent and all(1 <= a["state_rows"] <= 2
                         and a["state_layers"] == MAMBA for a in sent)
     assert prefills and all(a["state_layers"] == MAMBA for a in prefills)
     assert sum(a["prompt_tokens"] for a in prefills) == 21 + 9 + 30 + 14
     assert all(a["scan_tokens"] >= a["prompt_tokens"] for a in prefills)
-    assert scatters and all(a["state_layers"] == MAMBA
-                            and a["cache_layers"] == 2 for a in scatters)
+    assert all(a["cache_layers"] == 2 and a["fused"] == 1
+               for a in prefills)
     assert stats["state_slot_writes"] == 4
     assert stats["scan_tokens"] == sum(a["scan_tokens"] for a in prefills)
+    assert stats["admissions_fused"] == stats["admissions"] == len(prefills)
     assert set(stats["pool_relayouts"]) >= {
-        "decode_paged_fp32+sample_greedy", "scatter"}
+        "decode_paged_fp32+sample_greedy", "prefill_fp32+sample_greedy"}
 
 
 def test_the_paths_it_is_not_built_for_refuse_by_name():

@@ -305,10 +305,17 @@ def test_admission_leaves_its_phases_with_their_counts(served):
     for admit in admits:
         kids = {k[NAME]: k for k in children_of(rows, admit)}
         assert {"engine/pack", "pool/alloc", "generator/prefill",
-                "generator/sample", "pool/scatter",
-                "serving/deliver"} <= set(kids)
-        assert kids["pool/scatter"][ATTRS]["blocks"] >= 1
-        assert kids["pool/scatter"][ATTRS]["rows"] == admit[ATTRS]["rows"]
+                "engine/fetch", "serving/deliver"} <= set(kids)
+        # the prefill, its pick and its scatter are one call
+        assert not {"generator/sample", "pool/scatter"} & set(kids)
+        prefill = kids["generator/prefill"]
+        assert prefill[ATTRS]["rows"] == admit[ATTRS]["rows"]
+        assert prefill[ATTRS]["fused"] == 1
+        assert prefill[ATTRS]["cache_bytes"] >= 1
+        sent = [k for k in children_of(rows, prefill)
+                if k[NAME] == "generator/dispatch"]
+        assert len(sent) == 1 and sent[0][ATTRS]["kind"].startswith(
+            "prefill_")
         # the round it ran in counts what it admitted
         rnd = next(r for r in rows_named(rows, "serving/round")
                    if r[SPAN] == admit[PARENT])
@@ -666,6 +673,7 @@ def test_loop_spans_returns_what_overlaps_the_interval():
     profiler.reset_profiler()
 
 
+@pytest.mark.usefixtures("quiet_gc")
 @pytest.mark.parametrize("attrs", [{}, {"step": 7, "kind": "decode",
                                         "cpu_s": 0.01, "dropped": False}])
 def test_a_ring_row_leaves_the_collectors_lists(attrs):

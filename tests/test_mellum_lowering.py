@@ -153,9 +153,10 @@ def test_a_round_admits_only_what_its_prefill_fits(monkeypatch):
     monkeypatch.setattr(engine_mod, "_free_device_bytes",
                         lambda: 2 * one + 1)
     calls = []
-    real = gen._run_prefill
-    monkeypatch.setattr(gen, "_run_prefill", lambda *a, **k: (
-        calls.append(a[0].shape[0]), real(*a, **k))[1])
+    real = gen._dispatch
+    monkeypatch.setattr(gen, "_dispatch", lambda kind, stage, feed, *a, **k: (
+        calls.append(feed["tokens"].shape[0]) if stage == "prefill" else None,
+        real(kind, stage, feed, *a, **k))[1])
     for slot in range(8):
         engine.release_slot(slot)
     fresh = DecodeBatcher(RequestQueue(max_depth=16), engine)

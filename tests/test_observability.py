@@ -148,7 +148,9 @@ def test_serving_stats_snapshot_keys_unchanged():
         # PR 33: passes over the weights, steps and prefills alike
         "loop_passes",
         # PR 36: per-slot recurrent state beside the KV blocks
-        "state_slot_writes", "scan_tokens"}
+        "state_slot_writes", "scan_tokens",
+        # admissions, and those sent as one executable
+        "admissions", "admissions_fused"}
     derived = {"uptime_s", "throughput_rps", "mean_batch_size",
                "batch_occupancy", "tokens_per_s", "decode_occupancy",
                "queue_depth", "spec_accept_ratio"}

@@ -321,17 +321,18 @@ def test_inference_server_serves_it_and_its_spans_say_what_ran():
     read = [r[7] for r in rows if r[0] == "engine/step"
             and "exit_pass_mean" in r[7]]
     prefills = [r[7] for r in rows if r[0] == "generator/prefill"]
-    scatters = [r[7] for r in rows if r[0] == "pool/scatter"]
+    assert not [r for r in rows if r[0] == "pool/scatter"]
     assert sent and all(a["ut_steps"] == 4 and a["cache_layers"] == 8
                         for a in sent + prefills)
-    assert scatters and all(a["cache_layers"] == 8 for a in scatters)
+    assert all(a["fused"] == 1 for a in prefills)
     assert read and all(1.0 <= a["exit_pass_mean"] <= 4.0 for a in read)
     assert all(1.0 <= a["exit_pass_mean"] <= 4.0 for a in prefills)
     # four passes an executable, steps and prefills alike
     assert stats["loop_passes"] == 4 * (stats["decode_steps"]
                                         + len(prefills))
+    assert stats["admissions_fused"] == stats["admissions"] == len(prefills)
     assert set(stats["pool_relayouts"]) >= {
-        "decode_paged_fp32+sample_greedy", "scatter"}
+        "decode_paged_fp32+sample_greedy", "prefill_fp32+sample_greedy"}
 
 
 def test_the_paths_it_is_not_built_for_refuse_by_name():

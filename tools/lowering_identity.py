@@ -10,7 +10,8 @@ checkout, or a ``git archive`` of a commit) and lowers, with abstract
 weights and an abstract pool (nothing is placed on a device), the kinds of
 executable the GPT-2 medium, Mellum, Ouro and Jamba serve cells warm (all
 four unless some are named): prefills of 1 to 8 rows at 32 to 6,144 with the
-scatter of each, the paged decode step, the picks. It writes each ``.mlir`` text and the feed and fetch names under
+scatter of each and, on a tree that has it, the admission that runs both
+and the greedy pick as one executable, the paged decode step, the picks. It writes each ``.mlir`` text and the feed and fetch names under
 OUT. With ``IDENTITY_TPU_HERE=1`` the kernels take their TPU branch and the
 text is lowered for the TPU platform with no chip; Mosaic's serialized
 bodies are in it, and their ``loc(...)`` carry the checkout's path, so
@@ -166,6 +167,15 @@ def lower_tree(root, out, which):
             save(f"{tag}.scatter_prefill.r{rows}.b{nblk}", lower(
                 pool._scatter()._jit, dict(pool._arrays),
                 abstract(dict(row_caches)), i32(rows, nblk), *ring, *state))
+            if hasattr(pool, "scatter_layout"):
+                # the served admission: that prefill, its greedy pick and
+                # that scatter in one executable
+                kind = f"{pre_kind}+sample_greedy"
+                jitted, state = gen._ensure_fn(kind, pool.scatter_layout())
+                rest = dict(feed, **abstract(pool.scatter_indices(
+                    list(range(rows)), s, [s] * rows)))
+                save(f"{tag}.{kind}.r{rows}.s{s}", lower(
+                    jitted, state, dict(pool._arrays), rest, key))
         slots = eng.slots
         feed = abstract(decode_feed(pool, np.zeros(slots, np.int32),
                                     np.zeros(slots, np.int32)))
