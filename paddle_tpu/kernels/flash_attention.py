@@ -36,7 +36,8 @@ q-blocks, recomputes p once, and produces dq (streamed) plus dk/dv
 Sk the classic two-kernel (dq; dk/dv) decomposition remains, updated to
 the same base-2/fused-chain scheme.
 
-Layout: q [B, H, Sq, D], k/v [B, H, Sk, D], optional additive key-position
+Layout: q [B, H, Sq, D], k/v [B, H, Sk, D] (the forward takes v
+[B, H, Sk, Dv] of another width too), optional additive key-position
 bias [B, 1, 1, Sk] (the BERT padding-mask layout), optional causal masking.
 The bias is treated as a constant mask (zero cotangent) — masks are data,
 not parameters, in every caller in this framework.
@@ -147,7 +148,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
                        v_sc, *, scale, bq, causal, window=None):
     """Whole Sk in one tile: no online state. Grid (B, H, nq)."""
     b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]
     aug = v_sc is not None
 
     if aug:
@@ -203,7 +204,7 @@ def _fwd_online_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
     Grid (B, H, nq, nk)."""
     b, h = pl.program_id(0), pl.program_id(1)
     qi, ki = pl.program_id(2), pl.program_id(3)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]
     aug = v_sc is not None
 
     if aug:
@@ -261,11 +262,11 @@ def _fwd_online_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
 def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
                 window=None):
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     # grouped queries: query head h reads KV head h // rep through the
     # index map, so the KV is never repeated in memory
     rep = H // k.shape[1]
-    aug = D < _LANES
+    aug = Dv < _LANES
     bq, bk = _block_sizes(Sq, Sk, bq, bk, per_elem_bytes=6,
                           causal=causal)
     nq, nk = Sq // bq, Sk // bk
@@ -287,7 +288,7 @@ def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, h, i, *j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, bk, D), kv_map),
-        pl.BlockSpec((1, 1, bk, D), kv_map),
+        pl.BlockSpec((1, 1, bk, Dv), kv_map),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -316,7 +317,7 @@ def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
         grid = (B, H, nq, nk)
         scratch = [
             pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES if aug else D), jnp.float32),
+            pltpu.VMEM((bq, _LANES if aug else Dv), jnp.float32),
         ]
         if aug:
             scratch.append(pltpu.VMEM((bk, _LANES), v.dtype))
@@ -336,11 +337,11 @@ def _fwd_pallas(q, k, v, bias, scale, causal, bq, bk, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, *j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, *j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, *j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -614,7 +615,8 @@ def _xla_attention(q, k, v, bias, scale, causal, window=None):
             keep = keep & (rows - cols < window)
         s = jnp.where(keep, s, _NEG_INF)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrqk,bgkd->bgrqd", p, v).reshape(B, H, Sq, D)
+    return jnp.einsum("bgrqk,bgkd->bgrqd", p, v).reshape(
+        B, H, Sq, v.shape[-1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -632,11 +634,12 @@ def _flash_fwd(q, k, v, bias, scale, causal, bq, bk, interpret, window):
 
 def _flash_bwd(scale, causal, bq, bk, interpret, window, res, do):
     q, k, v, bias, out, lse = res
-    if window is not None or q.shape[1] != k.shape[1]:
+    if window is not None or q.shape[1] != k.shape[1] \
+            or v.shape[-1] != q.shape[-1]:
         raise NotImplementedError(
-            "flash_attention's Pallas backward has neither a window nor "
-            "grouped queries (forward only: serving prefill); train "
-            "through impl='xla'")
+            "flash_attention's Pallas backward has neither a window, "
+            "grouped queries nor a value width of its own (forward only: "
+            "serving prefill); train through impl='xla'")
     dq, dk, dv = _bwd_pallas(q, k, v, bias, scale, causal, bq, bk,
                              interpret, out, lse, do)
     dbias = None if bias is None else jnp.zeros_like(bias)
@@ -649,13 +652,15 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
                     impl=None, block_q=None, block_k=None, mesh=None,
                     window=None):
-    """Blockwise fused attention. q [B,H,Sq,D], k/v [B,Hkv,Sk,D] with
-    ``H`` a multiple of ``Hkv`` (query head h reads KV head
-    ``h // (H // Hkv)``; the KV is not repeated in memory), optional
+    """Blockwise fused attention. q [B,H,Sq,D], k [B,Hkv,Sk,D], v
+    [B,Hkv,Sk,Dv] with ``H`` a multiple of ``Hkv`` (query head h reads KV
+    head ``h // (H // Hkv)``; the KV is not repeated in memory; the
+    values may be narrower than the keys, as latent attention's 128
+    against 192), optional
     additive key bias [B,1,1,Sk] (constant — zero cotangent). ``window``
     (with ``causal``) keeps key j for query i iff ``i - window < j <=
     i``; blocks wholly outside the band are skipped as the causal
-    triangle's are. Returns [B,H,Sq,D]. impl: None (auto), "pallas",
+    triangle's are. Returns [B,H,Sq,Dv]. impl: None (auto), "pallas",
     "interpret", "xla". The Pallas backward raises for a window or
     grouped queries. Under ``mesh`` the kernel runs per shard (batch
     over the data axes, heads over tp); the composite is left to

@@ -908,14 +908,18 @@ def dense_acc32(input, size, dtype="float32", param_attr=None, name=None):
 
 def routed_experts(input, num_experts, num_experts_per_tok,
                    intermediate_size, norm_topk_prob=True, dtype="float32",
-                   valid=None, name=None, param_attr=None, impl=None):
+                   valid=None, name=None, param_attr=None, impl=None,
+                   scoring_func="softmax", routed_scaling_factor=1.0):
     """Top-k routed SwiGLU experts, dropless (ops/moe_ops.routed_experts):
     a float32 router [d, E] (softmax over all experts, the k largest,
     renormalised with ``norm_topk_prob``) and per-expert gate, up
     [E, d, f] and down [E, f, d] matrices held in ``dtype``.
     ``param_attr`` maps ``router``, ``gate``, ``up``, ``down`` to a
     ParamAttr each; ``valid`` marks real tokens (padding routes
-    nowhere). Returns ``(out, counts)``: out like ``input`` in float32,
+    nowhere). ``scoring_func="sigmoid"`` routes by sigmoid scores and a
+    float32 selection bias [E] (``param_attr["e_bias"]``, zeros by
+    default), the weights scaled by ``routed_scaling_factor``.
+    Returns ``(out, counts)``: out like ``input`` in float32,
     counts [E] int32 the assignments each expert got."""
     helper = LayerHelper("routed_experts", name=name)
     attr = param_attr or {}
@@ -934,11 +938,17 @@ def routed_experts(input, num_experts, num_experts_per_tok,
            "WUp": [w_up], "WDown": [w_down]}
     if valid is not None:
         ins["Valid"] = [valid]
+    attrs = {"top_k": int(num_experts_per_tok),
+             "norm_topk_prob": bool(norm_topk_prob), "impl": impl or ""}
+    if scoring_func != "softmax":
+        ins["EBias"] = [helper.create_parameter(
+            attr.get("e_bias"), shape=[E], dtype="float32",
+            default_initializer=init_mod.ConstantInitializer(0.0))]
+        attrs.update(scoring_func=scoring_func,
+                     routed_scaling_factor=float(routed_scaling_factor))
     helper.append_op(
         type="routed_experts", inputs=ins,
-        outputs={"Out": [out], "Counts": [counts]},
-        attrs={"top_k": int(num_experts_per_tok),
-               "norm_topk_prob": bool(norm_topk_prob), "impl": impl or ""},
+        outputs={"Out": [out], "Counts": [counts]}, attrs=attrs,
         infer_shape=False)
     out.shape = tuple(input.shape or ())
     out.dtype = "float32"
@@ -1050,6 +1060,131 @@ def selective_scan(x, delta, z, b, c, state=None, length=None,
     new_state.shape = tuple(x.shape[:1] or ()) + (n, ch)
     new_state.dtype = "float32"
     return out, new_state
+
+
+def hc_pre(input, n, epsilon=1e-6, hc_eps=1e-6, iters=20, clamp=(-30, 30),
+           param_attr=None, name=None):
+    """A sublayer's hyper-connection mix (ops/hc_ops.hc_pre) over the
+    flat streams ``input`` [..., n * C]: creates the float32 ``phi``
+    [n * C, n * (n + 2)], ``alpha`` [3] and ``bias`` [n * (n + 2)]
+    (``param_attr`` maps those names to a ParamAttr each). Returns
+    ``(h [..., C], post [..., n], res [..., n, n])``, float32."""
+    helper = LayerHelper("hc_pre", name=name)
+    attr = param_attr or {}
+    width, m = int(input.shape[-1]), n * (n + 2)
+    phi = helper.create_parameter(attr.get("phi"), shape=[width, m],
+                                  dtype="float32")
+    alpha = helper.create_parameter(
+        attr.get("alpha"), shape=[3], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.1))
+    bias = helper.create_parameter(
+        attr.get("bias"), shape=[m], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.0))
+    lead = tuple(input.shape[:-1] or ())
+    outs = {}
+    for key, shape in (("H", lead + (width // n,)), ("Post", lead + (n,)),
+                       ("Res", lead + (n, n))):
+        outs[key] = helper.create_variable_for_type_inference("float32")
+        outs[key].shape, outs[key].dtype = shape, "float32"
+    helper.append_op(
+        type="hc_pre",
+        inputs={"X": [input], "Phi": [phi], "Alpha": [alpha],
+                "Bias": [bias]},
+        outputs={k: [v] for k, v in outs.items()},
+        attrs={"n": int(n), "epsilon": float(epsilon),
+               "hc_eps": float(hc_eps), "iters": int(iters),
+               "clamp_min": float(clamp[0]), "clamp_max": float(clamp[1])},
+        infer_shape=False)
+    return outs["H"], outs["Post"], outs["Res"]
+
+
+def hc_post(input, y, post, res, name=None):
+    """The write-back of a sublayer's output ``y`` [..., C] into the
+    flat streams ``input`` [..., n * C] (ops/hc_ops.hc_post)."""
+    helper = LayerHelper("hc_post", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="hc_post",
+        inputs={"X": [input], "Y": [y], "Post": [post], "Res": [res]},
+        outputs={"Out": [out]}, attrs={}, infer_shape=False)
+    out.shape, out.dtype = tuple(input.shape or ()), "float32"
+    return out
+
+
+def hc_head(input, n, epsilon=1e-6, param_attr=None, name=None):
+    """The streams ``input`` [..., n * C] mixed down to one [..., C]
+    (ops/hc_ops.hc_head): creates ``phi`` [n * C, n], ``alpha`` [1] and
+    ``bias`` [n], float32."""
+    helper = LayerHelper("hc_head", name=name)
+    attr = param_attr or {}
+    width = int(input.shape[-1])
+    phi = helper.create_parameter(attr.get("phi"), shape=[width, n],
+                                  dtype="float32")
+    alpha = helper.create_parameter(
+        attr.get("alpha"), shape=[1], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.1))
+    bias = helper.create_parameter(
+        attr.get("bias"), shape=[n], dtype="float32",
+        default_initializer=init_mod.ConstantInitializer(0.0))
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="hc_head",
+        inputs={"X": [input], "Phi": [phi], "Alpha": [alpha],
+                "Bias": [bias]},
+        outputs={"Out": [out]}, attrs={"epsilon": float(epsilon)},
+        infer_shape=False)
+    out.shape = tuple(input.shape[:-1] or ()) + (width // n,)
+    out.dtype = "float32"
+    return out
+
+
+def _mla_out(helper, q_nope, heads, dv):
+    out = helper.create_variable_for_type_inference("float32")
+    out.shape = (q_nope.shape[0], q_nope.shape[2], heads * dv)
+    out.dtype = "float32"
+    return out
+
+
+def mla_prefill(q_nope, q_pe, c_kv, k_pe, w_kvb, v_head_dim, scale,
+                scope=None, name=None):
+    """Causal latent attention over a fed sequence, the latent expanded
+    into every head's keys and values (ops/mla_ops.mla_prefill):
+    ``q_nope`` [B, H, S, dn], ``q_pe`` [B, H, S, R], ``c_kv`` [B, S, C],
+    ``k_pe`` [B, 1, S, R], ``w_kvb`` [C, H * (dn + dv)]. Returns [B, S,
+    H * dv] float32."""
+    helper = LayerHelper("mla_prefill", name=name)
+    heads = int(q_nope.shape[1])
+    out = _mla_out(helper, q_nope, heads, v_head_dim)
+    helper.append_op(
+        type="mla_prefill",
+        inputs={"QNope": [q_nope], "QPe": [q_pe], "CKV": [c_kv],
+                "KPe": [k_pe], "WKvb": [w_kvb]},
+        outputs={"Out": [out]},
+        attrs={"scale": float(scale), "scope": scope or ""},
+        infer_shape=False)
+    return out
+
+
+def mla_decode(q_nope, q_pe, w_kvb, c_pool, r_pool, tables, pos, v_head_dim,
+               scale, impl=None, scope=None, name=None):
+    """One query a row over a layer's latent pool, the up-projections
+    absorbed (ops/mla_ops.mla_decode; kernels/mla_decode.py): ``q_nope``
+    [B, H, 1, dn], ``q_pe`` [B, H, 1, R], the pool's latents ``c_pool``
+    and rotary keys ``r_pool`` in their stored shapes, ``tables`` [B,
+    nblk], ``pos`` [B]. Returns [B, 1, H * dv] float32."""
+    helper = LayerHelper("mla_decode", name=name)
+    heads = int(q_nope.shape[1])
+    out = _mla_out(helper, q_nope, heads, v_head_dim)
+    helper.append_op(
+        type="mla_decode",
+        inputs={"QNope": [q_nope], "QPe": [q_pe], "WKvb": [w_kvb],
+                "CPool": [c_pool], "RPool": [r_pool], "Tables": [tables],
+                "Pos": [pos]},
+        outputs={"Out": [out]},
+        attrs={"scale": float(scale), "impl": impl or "",
+               "scope": scope or ""},
+        infer_shape=False)
+    return out
 
 
 def sample_tokens(logits, temperature, top_k=None, seed=0, name=None):

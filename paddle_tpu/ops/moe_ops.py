@@ -87,6 +87,12 @@ def routed_experts(ctx, ins, attrs):
     top_k, norm_topk_prob, impl ("" = auto). The router is float32
     throughout: logits at ``precision=highest``, softmax over all E,
     then the k largest, renormalised to sum 1 with ``norm_topk_prob``.
+    With the attr ``scoring_func`` "sigmoid" (DeepSeek-V3's
+    ``noaux_tc``) each expert's score is the sigmoid of its logit, the k
+    experts chosen are those of the largest score plus the optional
+    input EBias [E] (a selection bias the weights do not see), and the
+    chosen scores are the weights, renormalised with ``norm_topk_prob``;
+    ``routed_scaling_factor`` multiplies them last.
     outputs: Out [..., d] float32; Counts [E] int32, the assignments
     each expert got."""
     from ..kernels.moe_experts import routed_experts as _experts
@@ -100,11 +106,28 @@ def routed_experts(ctx, ins, attrs):
     with jax.named_scope("moe/router"):
         logits = jnp.dot(x2, x_of(ins, "RouterW").astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_i = jax.lax.top_k(probs, k)
-        if attrs.get("norm_topk_prob", True):
-            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        if attrs.get("scoring_func", "softmax") == "sigmoid":
+            top_w, top_i = _sigmoid_top_k(logits, ins.get("EBias"), k,
+                                          attrs)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_w, top_i = jax.lax.top_k(probs, k)
+            if attrs.get("norm_topk_prob", True):
+                top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     out, counts = _experts(x2, top_i, top_w, x_of(ins, "WGate"),
                            x_of(ins, "WUp"), x_of(ins, "WDown"),
                            valid=valid, impl=attrs.get("impl") or None)
     return {"Out": out.reshape(lead + (d,)), "Counts": counts}
+
+
+def _sigmoid_top_k(logits, e_bias, k, attrs):
+    """``noaux_tc`` routing of float32 ``logits`` [N, E]: ``(weights,
+    experts)`` [N, k] (see ``routed_experts``)."""
+    scores = jax.nn.sigmoid(logits)
+    chooser = scores if not e_bias else \
+        scores + e_bias[0].astype(jnp.float32)
+    _, top_i = jax.lax.top_k(chooser, k)
+    top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    if attrs.get("norm_topk_prob", True):
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_w * float(attrs.get("routed_scaling_factor", 1.0)), top_i

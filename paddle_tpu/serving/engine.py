@@ -1044,9 +1044,14 @@ class GenerationEngine:
         (the real ones) and ``scan_tokens`` (admitted rows x the
         bucket's length: what the recurrence was given to walk), counted
         into ``scan_tokens`` and ``state_slot_writes`` (a row's state
-        written into its slot)."""
+        written into its slot); over a pool with a latent group,
+        ``mla_positions`` (:meth:`_count_latent`)."""
         attrs = self._count_routing(aux, rows=len(requests))
         attrs["cache_bytes"] = self.pool.scatter_bytes(rows, seq)
+        if self.pool.latent:
+            # the expanded prefill reads each real position's latent
+            # once a layer
+            self._count_latent(attrs, sum(r.prompt.size for r in requests))
         if self.pool.state_layers:
             attrs["prompt_tokens"] = int(sum(r.prompt.size
                                              for r in requests))
@@ -1060,7 +1065,21 @@ class GenerationEngine:
         """What the ``engine/step`` span of a step sent over
         ``live_rows`` rows gains: ``loop_attrs`` and, over a pool with a
         state group, ``state_rows``, the live rows whose state the step
-        advances (it advances the free slots' too, which nobody reads)."""
+        advances (it advances the free slots' too, which nobody reads);
+        over a pool with a latent group ``mla_positions``, the
+        positions the live rows read (all they hold, the one appended
+        included) summed over the layers, and ``mla_rows``."""
+        if self.pool.latent:
+            attrs = dict(self.loop_attrs, mla_rows=int(live_rows))
+            self._count_latent(attrs, self.pool.tokens_held())
+            return attrs
         if not self.pool.state_layers:
             return self.loop_attrs
         return dict(self.loop_attrs, state_rows=int(live_rows))
+
+    def _count_latent(self, attrs, positions):
+        """``mla_positions`` into ``attrs`` and the counter of the same
+        name: latent ``positions`` a layer, summed over the layers."""
+        attrs["mla_positions"] = int(positions) * self.pool.latent_layers
+        if self.stats:
+            self.stats.bump("mla_positions", attrs["mla_positions"])

@@ -58,6 +58,18 @@ them). A row's state cannot be cut at a shared prefix or moved without a
 snapshot nobody takes yet: the prefix cache and migration are off for
 such a pool too.
 
+Not every cache keeps keys and values a head. A ``latent`` group's layers
+(multi-head latent attention: a position keeps one normalised latent and
+one rotary key that every head shares) keep, a layer, one array a tag of
+the group's ``latent`` map (``{"c": 512, "r": 64}``: tag -> width), a
+block of it ``[block_size, width]`` and stored as the pool stores a
+one-head block (``cache_pc_<i>`` ``[N, bs, 512]``; ``cache_pr_<i>``
+``[N, bs // 2, 128]``, two slots to a row), under the full group's block
+ids, free list and tables: allocated, donated into the decode step,
+written by the prefill scatter and freed as those are. There is no value
+array, and no int8 pool. The prefix cache and migration are off for such
+a pool.
+
 Device side: lazily-built jnp pool arrays (float32 / bfloat16 / int8
 with per-(block, head, slot) float32 scales — ``FLAGS_kv_cache_dtype``;
 at bandwidth-bound decode, halving cache bytes is ~2x tokens/s), a
@@ -279,6 +291,14 @@ def pool_feed_names(num_layers, quantized):
     return names
 
 
+def latent_feed_names(num_layers, tags):
+    """Feed/fetch names of a latent group's arrays: every layer's array
+    of the first tag, then every layer's of the next (``cache_pc_0 ..
+    cache_pc_<n-1>, cache_pr_0 ..``), in the order the decode program
+    and the pool share."""
+    return [f"cache_p{tag}_{i}" for tag in tags for i in range(num_layers)]
+
+
 def state_array_specs(groups):
     """``{feed name: (shape a slot, dtype)}`` of the per-slot arrays of
     ``groups``' state group (an architecture's ``kv_groups()``): array
@@ -336,7 +356,8 @@ def adopt_decode_fetches(pool, fetches):
 # routes a prefill through (KVBlockPool.scatter_indices), in its order
 ScatterLayout = namedtuple(
     "ScatterLayout",
-    "names block_size quantized d_head passes per_pass full windowed state")
+    "names block_size quantized d_head passes per_pass full windowed state "
+    "kinds")
 SCATTER_FEEDS = ("scatter_tables", "scatter_ring_src", "scatter_ring_dst",
                  "scatter_slots")
 
@@ -349,7 +370,9 @@ def prefill_scatter(layout):
     states) written (:meth:`KVBlockPool.scatter_prefill` says what goes
     where). ``ring_src``/``ring_dst`` are None without a window group,
     ``slots`` None without a state group; a slot past the bank's end is
-    dropped."""
+    dropped. A full layer's row caches are ``cache_<kind>_<i>`` into
+    ``cache_p<kind>_<i>`` for each of ``layout.kinds``: keys and values
+    (``k``, ``v``), or a latent group's tags."""
     import jax.numpy as jnp
     from ..kernels.paged_attention import (
         pool_packing, quantize_kv, scales_to_stored, to_stored)
@@ -407,7 +430,7 @@ def prefill_scatter(layout):
         at = tables_flat if passes == 1 else jnp.concatenate(
             [tables_flat + u * per_pass for u in range(passes)])
         for i in layout.full:
-            for kind in ("k", "v"):
+            for kind in layout.kinds:
                 src = rows[f"cache_{kind}_{i}"]    # [bb,H,L,D]
                 dst = out[f"cache_p{kind}_{i}"]
                 # whole blocks into rows of the stored array: dimension 0
@@ -556,6 +579,14 @@ class KVBlockPool:
         if len(full) > 1 or len(windowed) > 1 or len(stateful) > 1:
             raise ValueError("KVBlockPool holds one full, one window and "
                              "one state group of layers at most")
+        # a latent group is the full group with arrays of its own: tag ->
+        # width a position (module docstring)
+        self.latent = dict(full[0]["latent"]) \
+            if full and full[0].get("latent") else None
+        if self.latent and (windowed or self.quantized
+                            or full[0].get("passes", 1) > 1):
+            raise ValueError("a latent group is the pool's only group of "
+                             "blocks, of one pass, and not int8")
         # feed name -> (shape a slot, dtype) of the state group's arrays
         # (module docstring); ``num_layers`` counts KV cache layers alone
         self.state_layers = len(stateful[0]["layers"]) if stateful else 0
@@ -602,7 +633,7 @@ class KVBlockPool:
                                    if prefix_cache is None
                                    else prefix_cache) \
             and self.window is None and self.passes == 1 \
-            and not self.state_layers
+            and not self.state_layers and not self.latent
         self.array_sharding = None     # NamedSharding under a tp mesh
         self._arrays = None            # lazy device pool
         self._scatter_fn = None
@@ -626,10 +657,29 @@ class KVBlockPool:
         full group's by default), K+V, scales included."""
         elem = _ELEM_BYTES[self.dtype]
         nl = len(self.full_layers) if layers is None else len(layers)
-        n = 2 * nl * self.num_heads * self.block_size * self.d_head * elem
+        n = nl * self.block_size * self.position_width() * elem
         if self.quantized:
             n += 2 * nl * self.num_heads * self.block_size * 4
         return n
+
+    def position_width(self):
+        """Elements a cache layer keeps a position: keys and values of
+        every head, or a latent group's widths."""
+        if self.latent:
+            return sum(self.latent.values())
+        return 2 * self.num_heads * self.d_head
+
+    @property
+    def latent_layers(self):
+        """Cache layers of a latent group (0 without one)."""
+        return self.num_layers if self.latent else 0
+
+    def tokens_held(self):
+        """Positions the live slots hold, every slot's allocation as
+        accounted (a decode step reads them all, the one it appends
+        included, once ``ensure`` covered it)."""
+        with self._lock:
+            return sum(self._slot_tokens.values())
 
     def state_bytes_per_slot(self):
         """Device bytes of the state group's arrays one slot keeps."""
@@ -646,13 +696,15 @@ class KVBlockPool:
         """Every array of :meth:`arrays` in the one order the decode
         program is fed and fetches them: :func:`pool_feed_names`, then
         :func:`state_array_specs`' names."""
+        if self.latent:
+            return latent_feed_names(self.num_arrays, self.latent)
         return pool_feed_names(self.num_arrays, self.quantized) \
             + list(self.state_arrays)
 
     def dense_slot_bytes(self):
         """Device bytes ONE dense bank slot costs (fp32, max_seq_len)."""
-        return 2 * self.num_layers * self.num_heads * self.max_seq_len \
-            * self.d_head * 4
+        return self.num_layers * self.position_width() * self.max_seq_len \
+            * 4
 
     # -- allocator --------------------------------------------------------
     def check_fits(self, ntokens):
@@ -870,7 +922,11 @@ class KVBlockPool:
                                  self.block_size, self.d_head)
             dt = _np_pool_dtype(self.dtype)
             arrs = {}
-            for i in range(self.num_arrays):
+            for tag, width in (self.latent or {}).items():
+                for i in range(self.num_arrays):
+                    arrs[f"cache_p{tag}_{i}"] = jnp.zeros(stored_shape(
+                        self.num_blocks, 1, self.block_size, width), dt)
+            for i in range(0 if self.latent else self.num_arrays):
                 # a window layer's arrays hold its group's blocks, an
                 # array of several passes every pass's
                 n = self.window.num_blocks if self.window \
@@ -917,6 +973,8 @@ class KVBlockPool:
                                                              np.int32)])
         if name.startswith(("cache_pks_", "cache_pvs_")):
             return scales_to_logical(a, self.num_heads)
+        if self.latent:
+            return to_logical(a, 1, self.latent[name[len("cache_p")]])
         return to_logical(a, self.num_heads, self.d_head)
 
     def drop_device(self):
@@ -1167,7 +1225,8 @@ class KVBlockPool:
                 full=tuple(i for i in self.full_layers
                            if i < self.num_arrays),
                 windowed=tuple(self.window.layers) if self.window else (),
-                state=tuple(self.state_arrays))
+                state=tuple(self.state_arrays),
+                kinds=tuple(self.latent or ("k", "v")))
         return self._layout
 
     def _scatter(self):
@@ -1314,6 +1373,10 @@ class KVBlockPool:
         return payload
 
     def _no_window(self, what):
+        if self.latent:
+            raise BadRequestError(
+                f"KV pool {self.name!r} has a latent group of layers: "
+                f"{what} is built for keys and values a head only")
         if self.state_layers:
             raise BadRequestError(
                 f"KV pool {self.name!r} has a state group of layers: "
@@ -1523,6 +1586,7 @@ class KVBlockPool:
             # a state group's per-slot arrays, and every array the pool
             # holds on the device: blocks (trash included) and the bank
             "state_layers": self.state_layers,
+            "latent_layers": self.latent_layers,
             "state_bytes_per_slot": self.state_bytes_per_slot(),
             "pool_bytes": self._bytes_of(
                 {"full": self.num_blocks,

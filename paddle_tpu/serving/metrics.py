@@ -230,6 +230,8 @@ _COUNTER_KEYS = (
     # -- per-slot recurrent state beside the KV blocks --
     "state_slot_writes",    # rows whose state an admission wrote to a slot
     "scan_tokens",          # admitted rows x bucket length the scans walked
+    # -- a latent cache (one latent and rotary key a position a layer) --
+    "mla_positions",        # latent positions read, summed over layers
     # -- admission: prefill, first-token pick and scatter into the pool --
     "admissions",           # prefills GenerationEngine.admit ran
     "admissions_fused",     # of those, sent as one executable

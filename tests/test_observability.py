@@ -150,7 +150,9 @@ def test_serving_stats_snapshot_keys_unchanged():
         # PR 36: per-slot recurrent state beside the KV blocks
         "state_slot_writes", "scan_tokens",
         # admissions, and those sent as one executable
-        "admissions", "admissions_fused"}
+        "admissions", "admissions_fused",
+        # latent positions read over a pool with a latent group
+        "mla_positions"}
     derived = {"uptime_s", "throughput_rps", "mean_batch_size",
                "batch_occupancy", "tokens_per_s", "decode_occupancy",
                "queue_depth", "spec_accept_ratio"}
